@@ -17,7 +17,8 @@ serialize). This is the TPU-native equivalent:
 Prints one JSON line per metric:
   {"metric": ..., "value": ..., "unit": "rows/s", ...}
 
-Run:  JAX_PLATFORMS=cpu python benchmarks/micro.py        (or on TPU)
+Run:  python benchmarks/micro.py   (on whatever platform JAX selects;
+      JAX_PLATFORMS=cpu for a CPU run — every kernel line names it)
 Env:  MICRO_ROWS (default 200000), MICRO_REPS (default 3)
 """
 
@@ -233,27 +234,19 @@ def main():
 
 
 def _kernel_tier_benches(rows, reps):
-    """Per-kernel pallas-vs-jnp microbenches (cold and warm) for the
-    hand-scheduled suite behind ``backend/tpu/pallas/``, so BENCH_* runs
-    record what each kernel tier actually costs next to the formulation
-    it replaces. Off-TPU the Pallas programs run INTERPRETED — those
-    numbers prove parity and cache behavior, not speed (``pallas_mode``
-    says which was measured; the jnp number is the honest CPU baseline)."""
+    """Pallas-vs-jnp microbench (cold and warm) for the kernel behind
+    ``backend/tpu/pallas/``, next to the formulation it replaces. Off-TPU
+    the Pallas program runs INTERPRETED — those numbers prove parity and
+    cache behavior, not speed (``pallas_mode`` says which was measured;
+    the jnp number is the honest CPU baseline)."""
     import jax
     import jax.numpy as jnp
 
-    from tpu_cypher.backend.tpu import bucketing
     from tpu_cypher.backend.tpu import jit_ops as J
-    from tpu_cypher.backend.tpu.pallas import (
-        aggregate as PA,
-        expand as PE,
-        frontier as PF,
-        join as PJ,
-    )
+    from tpu_cypher.backend.tpu.pallas import aggregate as PA
 
     on_tpu = jax.default_backend() == "tpu"
     interpret = not on_tpu
-    pallas_mode = "compiled" if on_tpu else "interpret"
     rng = np.random.default_rng(23)
 
     def timed_ms(fn):
@@ -267,104 +260,32 @@ def _kernel_tier_benches(rows, reps):
             warms.append((time.perf_counter() - t0) * 1000.0)
         return cold, float(np.median(warms))
 
-    def emit_kernel(name, pallas_fn, jnp_fn):
-        cold, warm = timed_ms(pallas_fn)
-        _, jnp_warm = timed_ms(jnp_fn)
-        print(json.dumps({
-            "metric": f"pallas_{name}",
-            "value": round(warm, 3),
-            "unit": "ms",
-            "cold_ms": round(cold, 3),
-            "warm_ms": round(warm, 3),
-            "jnp_warm_ms": round(jnp_warm, 3),
-            "pallas_mode": pallas_mode,
-            "speedup_vs_jnp": round(jnp_warm / max(warm, 1e-9), 3),
-        }))
-
-    # frontier degree-sum
-    n_nodes = max(rows // 2, 8)
-    deg = rng.integers(0, 6, n_nodes).astype(np.int64)
-    rp = jnp.asarray(np.concatenate([[0], np.cumsum(deg)]).astype(np.int32))
-    pos = jnp.asarray(rng.integers(0, n_nodes, rows))
-    present = jnp.asarray(rng.random(rows) < 0.9)
-    emit_kernel(
-        "frontier_deg_sum",
-        lambda: PF._csr_deg_sum_pallas(rp, pos, present, interpret=interpret),
-        lambda: PF._csr_deg_sum_jnp(rp, pos, present),
-    )
-
-    # CSR expand materialize
-    n_edges = int(deg.sum())
-    ci = jnp.asarray(rng.integers(0, n_nodes, n_edges).astype(np.int32))
-    eo = jnp.asarray(rng.integers(0, 1 << 40, n_edges))
-    dd, t_dev = J.expand_degrees_total(rp, pos, present)
-    size = bucketing.round_up_pow2(int(t_dev), 32)
-    emit_kernel(
-        "expand_rows",
-        lambda: PE._expand_rows_pallas(
-            rp, ci, eo, pos, dd, t_dev, size=size, interpret=interpret
-        ),
-        lambda: J.expand_materialize_counted(
-            rp, ci, eo, pos, dd, t_dev, size=size
-        ),
-    )
-
-    # hash-join probe (build once per side — probe is the streamed part)
-    nb = max(rows // 2, 4)
-    rd = jnp.asarray(rng.integers(0, nb, nb) + (np.int64(3) << 54))
-    ld = jnp.asarray(rng.integers(0, nb, rows) + (np.int64(3) << 54))
-    rd_s, r_order, nvalid_dev = J.join_build(rd, (), is_f64=False, is_bool=False)
-    cap = min(bucketing.round_up_pow2(int(nvalid_dev)), nb)
-    tab = PJ._hash_build(
-        rd_s, r_order, nvalid_dev,
-        cap=cap, size=bucketing.round_up_pow2(2 * cap),
-    )
-    lvalid = jnp.ones(rows, bool)
-    emit_kernel(
-        "join_probe",
-        lambda: PJ._hash_probe_pallas(
-            tab[0], tab[1], tab[2], tab[3], ld, lvalid, interpret=interpret
-        ),
-        lambda: J.join_probe_bucketed(
-            rd_s, r_order, ld, (), nvalid_dev,
-            nvalid_cap=cap, is_f64=False, is_bool=False,
-        ),
-    )
-
-    # WCOJ sorted-key range count (the leapfrog search step): ascending
-    # synthetic edge keys probed by a zipf-ish query stream — the exact
-    # work profile of one close-constraint membership pass
-    from tpu_cypher.backend.tpu.pallas import intersect as PI
-
-    n_keys = max(rows // 2, 16)
-    keys = jnp.asarray(
-        np.sort(rng.integers(0, n_keys * 8, n_keys).astype(np.int64))
-    )
-    q = jnp.asarray(rng.integers(0, n_keys * 8, rows).astype(np.int64))
-    qvalid = jnp.asarray(rng.random(rows) < 0.9)
-    npow = bucketing.round_up_pow2(n_keys)
-    emit_kernel(
-        "intersect_range_count",
-        lambda: PI._range_count_pallas(
-            keys, q, qvalid, npow=npow, interpret=interpret
-        ),
-        lambda: PI._range_count_jnp(keys, q, qvalid),
-    )
-
-    # masked grouped segment sum
+    # masked grouped count
     k = 64
     data = jnp.asarray(rng.integers(-1000, 1000, rows))
     valid = jnp.asarray(rng.random(rows) < 0.9)
     seg = jnp.asarray(rng.integers(0, k, rows))
-    emit_kernel(
-        "segment_agg",
+    cold, warm = timed_ms(
         lambda: PA._segment_aggregate_pallas(
-            data, valid, seg, name="sum", kind="i64", k=k, interpret=interpret
-        ),
-        lambda: J.segment_aggregate(
-            data, valid, None, seg, name="sum", kind="i64", k=k
-        ),
+            data, valid, seg, name="count", k=k, interpret=interpret
+        )
     )
+    _, jnp_warm = timed_ms(
+        lambda: J.segment_aggregate(
+            data, valid, None, seg, name="count", kind="i64", k=k
+        )
+    )
+    print(json.dumps({
+        "metric": "pallas_segment_agg",
+        "value": round(warm, 3),
+        "unit": "ms",
+        "cold_ms": round(cold, 3),
+        "warm_ms": round(warm, 3),
+        "jnp_warm_ms": round(jnp_warm, 3),
+        "platform": jax.default_backend(),
+        "pallas_mode": "compiled" if on_tpu else "interpret",
+        "speedup_vs_jnp": round(jnp_warm / max(warm, 1e-9), 3),
+    }))
 
 
 if __name__ == "__main__":

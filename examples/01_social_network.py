@@ -3,23 +3,18 @@
 The TPU-native analog of the reference's ``morpheus-examples``
 ``CaseClassExample``/``DataFrameInputExample``: tables in, Cypher out.
 
-Run:  JAX_PLATFORMS=cpu python examples/01_social_network.py
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
+Run:  python examples/01_social_network.py
 """
 
 import os
 import sys
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-import jax
-
-try:
-    # quickstart demos pin CPU: some environments pre-register an accelerator
-    # platform that wins over env vars (see tests/conftest.py); on real TPU
-    # hardware drop this line
-    jax.config.update("jax_platforms", "cpu")
-except Exception:
-    pass
 
 from tpu_cypher import CypherSession
 from tpu_cypher.api.mapping import NodeMappingBuilder, RelationshipMappingBuilder

@@ -3,23 +3,18 @@
 Mirrors the reference's ``MultipleGraphExample``: CATALOG CREATE GRAPH,
 FROM GRAPH, CONSTRUCT ... RETURN GRAPH, and parameterized views.
 
-Run:  JAX_PLATFORMS=cpu python examples/02_multiple_graphs.py
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
+Run:  python examples/02_multiple_graphs.py
 """
 
 import os
 import sys
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-import jax
-
-try:
-    # quickstart demos pin CPU: some environments pre-register an accelerator
-    # platform that wins over env vars (see tests/conftest.py); on real TPU
-    # hardware drop this line
-    jax.config.update("jax_platforms", "cpu")
-except Exception:
-    pass
 
 from tpu_cypher import CypherSession
 

@@ -4,16 +4,16 @@ While a ``use_mesh`` context is active, TpuTable columns and the CSR
 topology carry ``NamedSharding(mesh, P('rows'))`` and XLA GSPMD inserts
 the collectives — the TPU-native replacement for Spark/Flink shuffle
 (SURVEY §2.3). On one chip this is a no-op; on a v5e-8 slice the same
-code shards across ICI. Here: a virtual 8-device CPU mesh.
+code shards across ICI. Runs on whatever platform JAX selects, over every
+device it reports (at most eight), and prints which; on a CPU host it
+asks XLA for eight virtual devices.
 
-Run:  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      python examples/03_sharded_mesh.py
+Run:  python examples/03_sharded_mesh.py
 """
 
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
@@ -26,17 +26,14 @@ import numpy as np
 def main():
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
     from tpu_cypher.api.mapping import NodeMappingBuilder, RelationshipMappingBuilder
     from tpu_cypher.parallel.mesh import make_row_mesh, use_mesh
     from tpu_cypher.relational.graphs import ElementTable
 
-    mesh = make_row_mesh(jax.devices()[:8])
+    devices = jax.devices()[:8]
+    print(f"platform: {devices[0].platform}, {len(devices)} device(s)")
+    mesh = make_row_mesh(devices)
     n, e = 64, 256
     rng = np.random.default_rng(0)
     ids = np.arange(n, dtype=np.int64)

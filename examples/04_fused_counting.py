@@ -11,7 +11,8 @@ the physical level and runs the WHOLE plan as one XLA program:
 * ``ORDER BY ... LIMIT k`` -> one ``lax.top_k`` over a packed rank.
 
 The printed plans show the fused operators; the timings show that query
-latency is dominated by round trips, not rows.
+latency is dominated by round trips, not rows. Runs on whatever platform
+JAX selects and prints which.
 
 Run:  python examples/04_fused_counting.py
 """
@@ -20,11 +21,6 @@ import os
 import sys
 import time
 
-# run on CPU unless explicitly pointed at an accelerator: examples must not
-# hang on a half-available device (set EXAMPLE_ALLOW_ACCELERATOR=1 to use
-# whatever JAX_PLATFORMS selects)
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -35,15 +31,11 @@ import numpy as np
 def main():
     import jax
 
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
     from tpu_cypher.api.mapping import NodeMappingBuilder, RelationshipMappingBuilder
     from tpu_cypher.relational.graphs import ElementTable
 
+    print(f"platform: {jax.devices()[0].platform}")
     rng = np.random.default_rng(7)
     n, e = 20_000, 200_000
     ids = np.arange(n, dtype=np.int64) * 3 + 11

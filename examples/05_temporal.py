@@ -6,20 +6,18 @@ localdatetime = int64 micros-since-epoch live in HBM, and accessors,
 range filters, grouping, and min/max run as branch-free calendar math on
 the VPU — ``session.record_fallbacks`` proves no host islands.
 
-Run:  JAX_PLATFORMS=cpu python examples/05_temporal.py
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
+Run:  python examples/05_temporal.py
 """
 
 import os
 import sys
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-import jax
-
-try:
-    jax.config.update("jax_platforms", "cpu")  # drop on real TPU hardware
-except Exception:
-    pass
 
 from tpu_cypher import CypherSession
 

@@ -8,14 +8,16 @@ PageRank runs as a jitted ``segment_sum`` power iteration (an SpMV — the
 TPU-shaped formulation), and the scores flow back through ``read_from``
 as a property column queryable by Cypher.
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/06_pagerank_csr.py
 """
 
 import os
 import sys
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -25,11 +27,6 @@ import numpy as np
 
 def main():
     import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
     import jax.numpy as jnp
 
     from tpu_cypher import CypherSession

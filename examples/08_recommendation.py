@@ -5,27 +5,22 @@ customers who bought the same products recommend each other's other
 purchases. The 3-hop co-purchase pattern compiles to the engine's fused
 CSR expand chain; the NOT-exists filter rides the semijoin flag planning.
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/08_recommendation.py
 """
 
 import os
 import sys
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
 
     g = CypherSession.tpu().create_graph_from_create_query(

@@ -4,27 +4,22 @@ The TPU-native analog of the reference's ``ViewsExample``: a view is a
 stored Cypher text producing a graph, re-planned per use with its graph
 parameters (reference ``CypherCatalog`` views / CREATE VIEW).
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/09_views.py
 """
 
 import os
 import sys
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
 
     session = CypherSession.tpu()

@@ -5,6 +5,9 @@ filesystem data source under a catalog namespace, store a graph (parquet
 tables in the reference's directory layout, written in parallel), and load
 it back through the catalog in a fresh session.
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/10_fs_roundtrip.py
 """
 
@@ -12,21 +15,13 @@ import os
 import sys
 import tempfile
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
     from tpu_cypher.io.fs import FSGraphSource
 

@@ -7,14 +7,16 @@ mapped onto a property graph by the reference's Graph DDL language
 (``GraphDdlParser.scala:66``), then queried with Cypher. Both of the
 reference's id-generation strategies work; HASHED_ID is used here.
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/11_sql_graphddl.py
 """
 
 import os
 import sys
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -59,13 +61,6 @@ TABLES = {
 
 
 def main():
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
     from tpu_cypher.io.sql import (
         InMemoryTables,

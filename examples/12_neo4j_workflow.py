@@ -9,6 +9,9 @@ readers, MERGE write-back with index creation, exactly the reference's
 the **bulk CSV sink** (reference ``Neo4jBulkCSVDataSink``), which writes
 a graph as `neo4j-admin import`-ready CSVs plus the load script.
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/12_neo4j_workflow.py
 """
 
@@ -16,21 +19,13 @@ import os
 import sys
 import tempfile
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
     from tpu_cypher.io.neo4j import Neo4jBulkCSVDataSink
 

@@ -6,14 +6,16 @@ tabular data (a pandas DataFrame here) becomes a property graph through
 element mappings, and query results come back as a DataFrame for the
 surrounding data pipeline.
 
+A CPU tool by purpose (a toy-sized walkthrough of the API): it defaults
+``JAX_PLATFORMS`` to ``cpu``; set the variable to run it elsewhere.
+
 Run:  python examples/13_dataframe_roundtrip.py
 """
 
 import os
 import sys
 
-if os.environ.get("EXAMPLE_ALLOW_ACCELERATOR") != "1":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -22,13 +24,6 @@ import pandas as pd
 
 
 def main():
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
     from tpu_cypher import CypherSession
     from tpu_cypher.api.mapping import (
         NodeMappingBuilder,

@@ -2,12 +2,17 @@
 
 Mirrors SURVEY.md §4's "cluster testing without a cluster": sharded plans are
 validated on host CPU devices so no TPU pod is needed (the reference's analog
-is Spark local[*] / Flink local ExecutionEnvironment). The real chip is
-reserved for bench.py."""
+is Spark local[*] / Flink local ExecutionEnvironment). The chip is met by
+``chip_smoke.py``, through the chip tool."""
 
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+# the suite counts cold compiles and spawns worker processes: the
+# persistent compile cache (on by default for ``CypherSession.tpu()``)
+# stays OFF here, for this process and every child it spawns, unless a
+# test opts in by setting this variable back
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -17,13 +22,6 @@ os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import jax
 import pytest
-
-# belt and braces: some environments pre-select an accelerator platform
-# before env vars are read (e.g. an externally initialized plugin)
-try:
-    jax.config.update("jax_platforms", "cpu")
-except Exception:
-    pass
 
 
 def _memory_map_count() -> int:

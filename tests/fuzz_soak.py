@@ -4,7 +4,10 @@ Not collected by pytest (no ``test_`` prefix) — run directly when you want
 hours of randomized oracle-vs-TPU differential coverage beyond the fixed
 regression seeds in ``test_fuzz_differential.py``:
 
-    JAX_PLATFORMS=cpu python tests/fuzz_soak.py [seconds] [seed] [--faults]
+    python tests/fuzz_soak.py [seconds] [seed] [--faults]
+
+It runs on whatever platform JAX selects (``JAX_PLATFORMS=cpu`` for a CPU
+run) and names it in its last line.
 
 Every query from all three grammar families (general, adversarial
 uniqueness graphs, temporal) must produce identical bags on both
@@ -24,7 +27,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -120,7 +122,12 @@ def main(budget_s: float, seed: int, chaos: bool = False) -> int:
             print(f"{kind} (seed {seed}, faults {spec}): {q}\n  {type(exc).__name__}: {exc}")
         n += 1
     mode = " (chaos)" if chaos else ""
-    print(f"fuzz soak{mode}: {n} queries in {budget_s:.0f}s, {fails} failures")
+    import jax
+
+    print(
+        f"fuzz soak{mode} on {jax.devices()[0].platform}: {n} queries in "
+        f"{budget_s:.0f}s, {fails} failures"
+    )
     return 1 if fails else 0
 
 

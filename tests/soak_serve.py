@@ -3,7 +3,11 @@
 Not collected by pytest (no ``test_`` prefix) — run directly, like
 ``fuzz_soak.py``:
 
-    JAX_PLATFORMS=cpu python tests/soak_serve.py [seconds] [clients] [--faults]
+    python tests/soak_serve.py [seconds] [clients] [--faults]
+
+It runs on whatever platform JAX selects (``JAX_PLATFORMS=cpu`` for a CPU
+run) and names it in its report (``platform``); under pytest the suite's
+conftest pins the CPU.
 
 Defaults: 20 s x 100 clients. Every client keeps exactly one query in
 flight over its own TCP connection, drawing from a mixed TCK-shaped
@@ -72,7 +76,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -273,9 +276,14 @@ def main(budget_s: float = 20.0, clients: int = 100, chaos: bool = False,
 
     combos = _combos()
     if workers > 0:
+        import tempfile
+
         server = ClusterServer(
             workers=workers, port=0, max_concurrent=max_concurrent * workers,
             batch_window_ms=batch_window_ms, cache_bytes=cache_bytes,
+            # a soak starts from an empty log: never the deployment's
+            # durable default beside the compile cache
+            wal_dir=tempfile.mkdtemp(prefix="tpu-cypher-soak-wal-"),
         )
         server.register_graph("soak", _create_query(),
                               mutable=mutable)
@@ -432,7 +440,14 @@ def main(budget_s: float = 20.0, clients: int = 100, chaos: bool = False,
         }
         total_disp = max(disp["true"] + disp["false"], 1)
         lat_ms = np.asarray(stats["latencies"]) * 1000.0
+        if workers > 0:  # the front end holds no device: ask a worker
+            platform = server.supervisor.workers[0].device.get("platform")
+        else:
+            import jax
+
+            platform = jax.devices()[0].platform
         report = {
+            "platform": platform,
             "queries": stats["queries"],
             "failures": stats["failures"],
             "clients": clients,

@@ -414,7 +414,6 @@ def test_config_registry_enumerates_engine_surface():
         "TPU_CYPHER_MXU_TILED_MAX",
         "TPU_CYPHER_BROADCAST_LIMIT",
         "TPU_CYPHER_ISLAND_WARN_ROWS",
-        "TPU_CYPHER_COMPILE_CACHE_DIR",
         "TPU_CYPHER_METRICS_FILE",
         "TPU_CYPHER_PROFILE_DIR",
     }

@@ -14,8 +14,15 @@ Two guarantees under test:
   only — jit-cache hits count nothing).
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
+
+import jax
 
 from tpu_cypher import CypherSession
 from tpu_cypher.backend.tpu import bucketing
@@ -269,3 +276,122 @@ def test_round_up_pow2_shared_helper():
     assert bucketing.round_up_pow2(16) == 16
     assert bucketing.round_up_pow2(17) == 32
     assert bucketing.round_up_pow2(5, floor=16) == 16
+
+
+# ---------------------------------------------------------------------------
+# the persistent compile cache is placed from OUTSIDE
+# ---------------------------------------------------------------------------
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def cache_config():
+    """Snapshot/restore of the three JAX cache options the engine touches
+    (the suite runs with the persistent cache OFF — tests/conftest.py)."""
+    names = (
+        "jax_enable_compilation_cache",
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    before = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in before.items():
+        jax.config.update(n, v)
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout():
+    assert bucketing.DEFAULT_CACHE_DIR == os.path.join(_REPO, ".jax_cache")
+
+
+def test_suite_runs_with_the_persistent_cache_off():
+    """conftest's switch, inherited by every child a test spawns: cold
+    compile counts keep their meaning, and no WAL or calibration file
+    lands beside a cache nobody asked for."""
+    assert os.environ["JAX_ENABLE_COMPILATION_CACHE"] == "false"
+    assert jax.config.jax_enable_compilation_cache is False
+    CypherSession.tpu()
+    assert bucketing.persistent_cache_dir() is None
+
+
+def test_enable_persistent_cache_defaults_only_when_nothing_is_set(cache_config):
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", None)
+    CypherSession.tpu()
+    assert jax.config.jax_compilation_cache_dir == bucketing.DEFAULT_CACHE_DIR
+    assert bucketing.persistent_cache_dir() == bucketing.DEFAULT_CACHE_DIR
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_enable_persistent_cache_yields_to_a_configured_directory(
+    cache_config, tmp_path
+):
+    """What JAX_COMPILATION_CACHE_DIR sets (JAX reads it into this option
+    at start-up) is never overridden."""
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    CypherSession.tpu()
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    assert bucketing.persistent_cache_dir() == str(tmp_path)
+
+
+def test_engine_places_the_cache_in_exactly_one_place():
+    """``jax_compilation_cache_dir`` is updated in one place of the package,
+    and nothing places a cache (or anything else of the serve tier) in a
+    directory that moves."""
+    updates, movers = [], []
+    for dirpath, _dirs, files in os.walk(os.path.join(_REPO, "tpu_cypher")):
+        for fname in files:
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, fname)
+            text = open(path).read()
+            for i, line in enumerate(text.splitlines(), 1):
+                if 'update("jax_compilation_cache_dir"' in line:
+                    updates.append(f"{os.path.relpath(path, _REPO)}:{i}")
+                if "mkdtemp" in line and os.sep + "serve" + os.sep in path:
+                    movers.append(f"{os.path.relpath(path, _REPO)}:{i}")
+    assert len(updates) == 1 and updates[0].startswith(
+        os.path.join("tpu_cypher", "backend", "tpu", "bucketing.py")
+    ), updates
+    assert movers == []
+
+
+_CACHE_PROBE = r"""
+import json
+from tpu_cypher import CypherSession
+g = CypherSession.tpu().create_graph_from_create_query(
+    "CREATE (a:P {x:1})-[:R]->(b:P {x:2})-[:R]->(c:P {x:3})")
+r = g.cypher("MATCH (a:P)-[:R]->(b:P) RETURN a.x AS a, b.x AS b ORDER BY a")
+rows = [dict(x) for x in r.records.collect()]
+from tpu_cypher.backend.tpu import bucketing
+print(json.dumps({"rows": rows, "dir": bucketing.persistent_cache_dir(),
+                  **r.compile_stats}))
+"""
+
+
+def test_second_process_hits_the_cache_the_environment_placed(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR=/x: a first process fills /x, a second one
+    answers the same query from it — persistent-cache hits, zero compiles."""
+    env = dict(
+        os.environ,
+        JAX_ENABLE_COMPILATION_CACHE="true",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+        PYTHONPATH=_REPO,
+    )
+    reports = []
+    for _ in range(2):
+        proc = subprocess.run(
+            [sys.executable, "-c", _CACHE_PROBE], env=env, cwd=str(tmp_path),
+            capture_output=True, text=True, timeout=300,
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        reports.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+    cold, warm = reports
+    assert cold["dir"] == warm["dir"] == str(tmp_path)
+    assert cold["rows"] == warm["rows"] == [{"a": 1, "b": 2}, {"a": 2, "b": 3}]
+    assert cold["persistent_cache_misses"] > 0 and cold["compiles"] > 0
+    assert warm["persistent_cache_hits"] > 0
+    assert warm["persistent_cache_misses"] == 0 and warm["compiles"] == 0
+    assert os.listdir(tmp_path)

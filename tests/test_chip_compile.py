@@ -1,0 +1,254 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED (``jax.experimental.topologies``, ``v5e:2x2``) and not attached.
+A compile that passes is not a chip run — nothing executes — but what the
+compiler refuses here it refuses on the chip, and that costs no chip time:
+every Pallas kernel in the tree once passed all its interpret-mode tests
+and was refused by this very lowering.
+
+Under test, at the shapes ``chip_smoke.py`` dispatches at its default scale
+(1M persons, ~45M edges, 2**20-lane materializes). Programs whose compile
+takes minutes at that size (the count chain: 2-3 min at 45M edges; the
+DISTINCT final hop: a minute at ANY size) run here at a reduced size, with
+the full size ``slow``-marked:
+
+* the XLA programs of the served path — the fused count chain, the counted
+  expand materialize, the fused DISTINCT final hop, the bucketed join
+  probe, the WCOJ range count;
+* the one Pallas kernel (``tpu_custom_call`` in the compiled text), at its
+  eligibility cap, and the proof that the refusals that removed the other
+  four are real properties of the lowering (int64 planes);
+* the four-chip programs — a ``parallel/agg.py`` shard_map aggregate and
+  the sharded count chain on a ``Mesh`` of the described devices, with
+  their collectives in the compiled text.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU library, and xdist workers all import
+this file), the persistent cache is off around the compiles (a described-
+device executable cannot be read back), and nothing here starts a child.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from tpu_cypher.backend.tpu import jit_ops as J
+from tpu_cypher.backend.tpu.pallas import aggregate as PA
+from tpu_cypher.utils.config import PALLAS_MAX_GROUPS
+
+# chip_smoke.py's default deployment: generate_snb(scale=100)
+NODES = 1_000_000
+EDGES = 45_000_000
+LANES = 1 << 20  # a bucketed materialize of the anchored shapes
+FRONTIER = 1 << 15  # its input frontier
+
+# (nodes, edges): the reduced size tier-1 compiles at, and the smoke's own
+GRAPHS = [
+    pytest.param((100_000, 4_500_000), id="scale10"),
+    pytest.param((NODES, EDGES), id="scale100", marks=pytest.mark.slow),
+]
+
+I32, I64, BOOL = jnp.int32, jnp.int64, jnp.bool_
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    return shape
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    mesh = Mesh(np.array(topo.devices[:4]), ("rows",))
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    return mesh, shape
+
+
+def _csr(shape, nodes=NODES, edges=EDGES):
+    return shape((nodes + 1,), I32), shape((edges,), I32), shape((edges,), I64)
+
+
+# ---------------------------------------------------------------------------
+# the served path's XLA programs, one chip
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("graph", GRAPHS)
+def test_fused_count_chain_compiles(one_chip, graph):
+    """``path_count_chain`` over the whole graph: the 2-hop count(*) as one
+    program, two scatter-free SpMVs over every edge."""
+    nodes, edges = graph
+    rp, ci, _ = _csr(one_chip, nodes, edges)
+    mask = one_chip((nodes,), BOOL)
+    hop = (rp, ci, None, None, None, mask)
+    compiled = J.path_count_chain.lower(
+        one_chip((nodes,), I64), one_chip((nodes,), I64), None, (hop, hop),
+        num_nodes=nodes,
+    ).compile()
+    # the working set is the edge arrays, far inside one chip's 16 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_expand_materialize_counted_compiles(one_chip):
+    rp, ci, eo = _csr(one_chip)
+    J.expand_materialize_counted.lower(
+        rp, ci, eo, one_chip((FRONTIER,), I64), one_chip((FRONTIER,), I64),
+        one_chip((), I64), size=LANES,
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "total",
+    [
+        pytest.param(1 << 16, id="2**16"),
+        pytest.param(LANES, id="smoke-2**20", marks=pytest.mark.slow),
+        pytest.param(1 << 28, id="2**28", marks=pytest.mark.slow),
+    ],
+)
+def test_distinct_pairs_count_final_compiles(one_chip, total):
+    """The fused DISTINCT final hop (materialize + packed int64 sort +
+    run count) — the program an earlier round saw kill the compiler. It
+    compiles, slowly (a minute at 2**16 lanes, ~100 s from 2**24 up), and
+    fits: 6.5 GB of temporaries at 2**28."""
+    rp, ci, _ = _csr(one_chip)
+    frontier = min(total >> 5, 1 << 22)
+    compiled = J.distinct_pairs_count_final.lower(
+        rp, ci, one_chip((frontier,), I64), one_chip((frontier,), I64),
+        one_chip((frontier,), I64), one_chip((NODES,), BOOL),
+        total=total, use_a=True, use_c=True, num_nodes=NODES,
+        nvalid=one_chip((), I64),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 << 30
+
+
+def test_join_probe_bucketed_compiles(one_chip):
+    """Sort-probe join: the window's persons probed against all 1M."""
+    build = NODES
+    J.join_probe_bucketed.lower(
+        one_chip((build,), I64), one_chip((build,), I64),
+        one_chip((LANES,), I64), (one_chip((LANES,), BOOL),),
+        one_chip((), I64), nvalid_cap=build, is_f64=False, is_bool=False,
+    ).compile()
+
+
+def test_wcoj_range_count_compiles(one_chip):
+    """The WCOJ close probe: 2**20 candidate lanes searched in the 45M
+    sorted edge keys."""
+    J.range_count.lower(
+        one_chip((EDGES,), I64), one_chip((LANES,), I64),
+        one_chip((LANES,), BOOL),
+    ).compile()
+
+
+# ---------------------------------------------------------------------------
+# the Pallas tier
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("op,identity", [("sum", 0), ("min", 2**31 - 1)])
+@pytest.mark.parametrize("rows", [NODES, 1 << 26], ids=["1M", "2**26"])
+def test_segment_kernel_compiles_at_its_cap(one_chip, rows, op, identity):
+    """The one kernel that stayed: Mosaic accepts it at the GROUP BY cap,
+    and the compiled program really contains it."""
+    compiled = PA._seg_reduce_pallas.lower(
+        one_chip((rows,), I32), one_chip((rows,), I32),
+        identity=identity, op=op, k=PALLAS_MAX_GROUPS.get(), interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["count", "min", "max"])
+def test_segment_aggregate_drop_in_compiles(one_chip, name):
+    """The dispatching drop-in's eligible subset, engine dtypes in (int64
+    group index, bool values), kernel inside."""
+    compiled = PA._segment_aggregate_pallas.lower(
+        one_chip((NODES,), BOOL), one_chip((NODES,), BOOL),
+        one_chip((NODES,), I64), name=name, k=7, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sixty_four_bit_planes_are_refused(one_chip):
+    """Why int64/float64 aggregates decline by eligibility instead of
+    riding the kernel: a custom call's 64-bit operand cannot be lowered.
+    If this ever starts compiling, the eligibility can widen."""
+    with pytest.raises(Exception, match="X64|64"):
+        PA._seg_reduce_pallas.lower(
+            one_chip((NODES,), I64), one_chip((NODES,), I32),
+            identity=0, op="sum", k=8, interpret=False,
+        ).compile()
+
+
+# ---------------------------------------------------------------------------
+# four chips: one program across the mesh
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name", ["count", "sum", "max"])
+def test_sharded_segment_agg_compiles_for_four_chips(four_chips, name):
+    """``parallel/agg.py``: per-shard segment partials combined over the
+    mesh — the collective is in the compiled text. (``max`` once used
+    ``lax.pmax``, which this lowering refuses for int64: only SUM
+    all-reduces of 64-bit integers are lowered.)"""
+    from tpu_cypher.parallel.agg import _agg_fn
+
+    mesh, shape = four_chips
+    rows = NODES  # divisible by 4, as ingest pads it
+    fn = _agg_fn(mesh, "rows", name, False, 7)
+    compiled = fn.lower(
+        shape((rows,), I64, P("rows")), shape((rows,), BOOL, P("rows")),
+        shape((rows,), I64, P("rows")),
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+
+
+@pytest.mark.parametrize("graph", GRAPHS)
+def test_sharded_count_chain_compiles_for_four_chips(four_chips, graph):
+    """The mesh phase's 2-hop count: the explicit shard_map SpMV over the
+    row-sharded edge array, psum over the mesh, per-device bytes a quarter
+    of the edges plus the replicated node vectors."""
+    nodes, edges = graph
+    mesh, shape = four_chips
+    rp = shape((nodes + 1,), I32, P())
+    ci = shape((edges,), I32, P("rows"))
+    mask = shape((nodes,), BOOL, P())
+    hop = (rp, ci, None, None, None, mask)
+    run = J.path_count_chain_on_mesh(mesh, "rows")
+    compiled = run.lower(
+        shape((nodes,), I64, P()), shape((nodes,), I64, P("rows")), None,
+        (hop, hop), num_nodes=nodes,
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

@@ -1,0 +1,125 @@
+"""``chip_smoke.py`` and ``bench.py`` off the chip: what must fail, fails.
+
+The chip check itself runs through the chip tool. Here, on the CPU:
+
+* the REHEARSAL — ``chip_smoke.py --rehearse-cpu`` drives every phase of the
+  one-chip path (server, wire client, NumPy references, WAL write and
+  replay, router + worker) at a tiny scale, names the CPU on every line,
+  and can never print the TPU success line;
+* NO FALLBACK — without the rehearsal flag a machine without an accelerator
+  gets a non-zero exit and no result line, before anything is loaded; so
+  does a directory that holds the script and nothing else of the repo;
+* ``bench.py`` — one process that raises off the chip, a peaks table keyed
+  by ``device_kind`` where an unknown device is an error.
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _run(args, cwd=REPO, script=SMOKE, timeout=600):
+    return subprocess.run(
+        [sys.executable, script, *args], cwd=cwd, capture_output=True,
+        text=True, timeout=timeout,
+    )
+
+
+def _last_json(stdout: str):
+    lines = [l for l in stdout.splitlines() if l.strip()]
+    return json.loads(lines[-1]) if lines and lines[-1].startswith("{") else None
+
+
+@pytest.fixture(scope="module")
+def rehearsal():
+    return _run(["--rehearse-cpu", "--scale", "0.05"])
+
+
+def test_rehearsal_passes_every_phase(rehearsal):
+    assert rehearsal.returncode == 0, rehearsal.stdout + rehearsal.stderr
+    out = rehearsal.stdout
+    for phase in ("serve", "cluster"):
+        assert f"phase {phase} passed" in out, out
+    # the shapes the issue lists all answered, equal to their references
+    for name in (
+        "scan_filter", "two_hop_count", "grouped_aggregate",
+        "order_by_limit", "property_projection", "expand_materialize",
+        "sort_probe_join", "distinct_two_hop", "triangle_close",
+        "var_length", "string_starts_with",
+    ):
+        assert f"query {name}: rows=" in out, name
+    assert "read back after attach_wal replay" in out
+    assert "front end holds no device" in out
+
+
+def test_rehearsal_names_the_cpu_on_every_line(rehearsal):
+    lines = [l for l in rehearsal.stdout.splitlines() if l.strip()]
+    assert len(lines) > 20
+    for line in lines[:-1]:
+        assert line.startswith("[cpu rehearsal] "), line
+    last = json.loads(lines[-1])
+    assert last["device"]["platform"] == "cpu" and last["rehearsal"] is True
+
+
+def test_rehearsal_can_never_print_the_tpu_success_line(rehearsal):
+    assert '"platform": "tpu"' not in rehearsal.stdout
+
+
+def test_no_accelerator_fails_before_loading_anything():
+    proc = _run(["--scale", "0.05"])
+    assert proc.returncode not in (0, None)
+    assert _last_json(proc.stdout) is None, proc.stdout
+    assert "deployment:" not in proc.stdout  # nothing was loaded
+    assert "no accelerator" in proc.stderr
+
+
+def test_script_alone_in_a_directory_fails(tmp_path):
+    """Without the program beside it the script cannot pass — on the chip
+    either (there the driver runs exactly this)."""
+    alone = shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    for args in (["--scale", "0.05"], ["--rehearse-cpu", "--scale", "0.05"]):
+        proc = _run(args, cwd=tmp_path, script=alone)
+        assert proc.returncode != 0
+        assert _last_json(proc.stdout) is None, proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# bench.py
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def bench():
+    sys.path.insert(0, REPO)
+    import bench
+
+    return bench
+
+
+def test_bench_raises_off_the_chip(bench, monkeypatch):
+    monkeypatch.delenv("TPU_CYPHER_BENCH_FORCE_CPU", raising=False)
+    with pytest.raises(RuntimeError, match="no CPU fallback"):
+        bench.main()
+
+
+def test_bench_peaks_are_keyed_by_device_kind(bench):
+    v5e = bench.device_peaks("TPU v5 lite")
+    assert v5e == {"flops": 197e12, "bytes": 819e9}
+    with pytest.raises(KeyError, match="no peak table entry"):
+        bench.device_peaks("TPU v99")
+    with pytest.raises(KeyError):
+        bench.device_peaks("cpu")
+
+
+def test_bench_roofline_reports_no_utilization_without_peaks(bench):
+    model = bench._roofline(100, 1000, 5000, 0.5, None)
+    assert set(model) == {"est_bytes", "est_flops", "arith_intensity"}
+    on_chip = bench._roofline(100, 1000, 5000, 0.5, bench.PEAKS["TPU v5 lite"])
+    assert {"bandwidth_util", "mfu", "bound", "roofline_frac"} <= set(on_chip)

@@ -94,7 +94,10 @@ class FakeWorkerTransport:
         )
         self.port = self._server.sockets[0].getsockname()[1]
         return {"ready": True, "port": self.port, "pid": self.pid,
-                "worker": self.worker_id, "warmup": {"compiles": 0}}
+                "worker": self.worker_id, "warmup": {"compiles": 0},
+                "device": self.owner.devices.get(
+                    self.worker_id, {"platform": "cpu", "count": 1}
+                )}
 
     async def _handle(self, reader, writer):
         try:
@@ -147,6 +150,7 @@ class FakeLauncher:
     def __init__(self, scripts=None, boot_fail=None):
         self.scripts = scripts or {}
         self.boot_fail = boot_fail or {}
+        self.devices = {}  # worker id -> the device its READY line reports
         self.spawns = collections.Counter()
         self.live = {}
         self.executes = collections.defaultdict(list)
@@ -172,6 +176,155 @@ async def _until(cond, timeout=5.0, what="condition"):
         if time.monotonic() >= deadline:
             raise AssertionError(f"timed out waiting for {what}")
         await asyncio.sleep(0.01)
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: assignment, and a start that fails loudly
+# ---------------------------------------------------------------------------
+
+
+def test_chip_environment_gives_each_worker_its_own_chip():
+    from tpu_cypher.serve.supervisor import chip_environment
+
+    envs = [chip_environment(f"w{i}") for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    ports = {e["TPU_MESH_CONTROLLER_PORT"] for e in envs}
+    assert len(ports) == 4, "each process runs its own mesh controller"
+
+
+@pytest.mark.parametrize("assign", [False, True])
+def test_launcher_assigns_chips_only_to_a_fleet(monkeypatch, assign):
+    """A fleet's workers get one chip each IN THE CHILD'S ENVIRONMENT; a
+    single worker keeps the host's default view. Nothing the launcher
+    passes overrides where the environment places the compile cache."""
+    from tpu_cypher.serve import supervisor as S
+
+    seen = {}
+
+    class _Proc:
+        pid, returncode = 1, None
+
+        class stdin:
+            write = staticmethod(lambda data: seen.setdefault("cfg", data))
+
+            @staticmethod
+            async def drain():
+                pass
+
+    async def fake_exec(*cmd, env=None, **kw):
+        seen["env"] = env
+        return _Proc()
+
+    monkeypatch.setattr(asyncio, "create_subprocess_exec", fake_exec)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    launcher = S.SubprocessLauncher({}, {}, assign_chips=assign)
+    asyncio.run(launcher.spawn("w2"))
+    env = seen["env"]
+    assert env.get("TPU_VISIBLE_CHIPS") == ("2" if assign else None)
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/x"
+    assert b"cache" not in seen["cfg"]
+
+
+def _transport_fed(lines):
+    """A ``SubprocessTransport`` over a fake child whose stdout holds
+    ``lines`` and then ends."""
+    from tpu_cypher.serve.supervisor import SubprocessTransport
+
+    class _Proc:
+        pid, returncode = 4242, 1
+
+        async def wait(self):
+            return self.returncode
+
+    proc = _Proc()
+    proc.stdout = asyncio.StreamReader()
+    for line in lines:
+        proc.stdout.feed_data(line.encode() + b"\n")
+    proc.stdout.feed_eof()
+    return SubprocessTransport(proc, "127.0.0.1")
+
+
+def test_worker_that_reports_its_failure_surfaces_typed():
+    """``{"ready": false}``: the worker's own account (its chip is held by
+    another process) reaches the caller as a typed ``WorkerLost``."""
+
+    async def run():
+        t = _transport_fed([
+            "libtpu noise, not JSON",
+            '{"ready": false, "worker": "w1", "error": "DeviceLost", '
+            '"message": "UNAVAILABLE: TPU is already in use by pid 7"}',
+        ])
+        with pytest.raises(ERR.WorkerLost, match="already in use") as info:
+            await t.wait_ready(5.0)
+        assert info.value.worker == "w1"
+
+    asyncio.run(run())
+
+
+def test_worker_that_dies_before_ready_surfaces_typed():
+    async def run():
+        t = _transport_fed(["some noise"])
+        with pytest.raises(ERR.WorkerLost, match="exited before READY"):
+            await t.wait_ready(5.0)
+
+    asyncio.run(run())
+
+
+def test_start_refuses_a_fleet_on_mixed_platforms():
+    """A worker that came up on another platform than its peers (it could
+    not have its chip and fell back) fails the start — typed, and with no
+    child left running."""
+
+    async def run():
+        launcher = FakeLauncher()
+        launcher.devices = {
+            "w0": {"platform": "tpu", "count": 1},
+            "w1": {"platform": "cpu", "count": 1},
+        }
+        sup = _supervisor(launcher, n=2)
+        with pytest.raises(ERR.WorkerLost, match="different platforms"):
+            await sup.start()
+        assert all(t.poll() is not None for t in launcher.live.values())
+        assert sup.ready_workers == [] or all(
+            w.transport.poll() is not None for w in sup.workers
+        )
+
+    asyncio.run(run())
+
+
+def test_start_records_the_device_each_worker_holds():
+    async def run():
+        launcher = FakeLauncher()
+        launcher.devices = {
+            "w0": {"platform": "tpu", "count": 1, "chip": "0"},
+            "w1": {"platform": "tpu", "count": 1, "chip": "1"},
+        }
+        sup = _supervisor(launcher, n=2)
+        await sup.start()
+        assert [w.device["chip"] for w in sup.workers] == ["0", "1"]
+        await sup.stop()
+
+    asyncio.run(run())
+
+
+def test_boot_failure_at_start_stops_the_whole_fleet():
+    """A first boot that fails raises out of ``start()`` and leaves no
+    sibling running (a cluster that cannot start whole says so)."""
+
+    async def run():
+        launcher = FakeLauncher(boot_fail={"w1": 1})
+        sup = _supervisor(launcher, n=2)
+        with pytest.raises(EOFError):
+            await sup.start()
+        await _until(
+            lambda: all(t.poll() is not None for t in launcher.live.values()),
+            what="every spawned worker stopped",
+        )
+
+    asyncio.run(run())
 
 
 # ---------------------------------------------------------------------------
@@ -612,7 +765,7 @@ def _done_of(msgs, qid):
     raise AssertionError(f"no terminal for {qid}: {msgs}")
 
 
-def test_cluster_e2e_crash_sigkill_drain(tmp_path):
+def test_cluster_e2e_crash_sigkill_drain(tmp_path, monkeypatch):
     """The acceptance scenario against REAL worker processes: rows match
     serial execution; an injected ``crash@expand`` kills a worker
     mid-query and the client still gets its exact rows (rung "replica" in
@@ -621,10 +774,14 @@ def test_cluster_e2e_crash_sigkill_drain(tmp_path):
     submits typed."""
     from tpu_cypher.serve.cluster import ClusterServer
 
+    # this test opts in to the persistent cache: the workers inherit the
+    # environment, and the environment alone places their shared cache
+    monkeypatch.setenv("JAX_ENABLE_COMPILATION_CACHE", "true")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+
     async def run():
         server = ClusterServer(
             workers=2, port=0, batch_window_ms=0, lanes=2,
-            persistent_cache_dir=str(tmp_path / "cache"),
         )
         server.register_graph("g", CREATE_Q)
         server.warmup([COUNT_Q, HOP_Q, ROWS_Q], "g")
@@ -632,6 +789,10 @@ def test_cluster_e2e_crash_sigkill_drain(tmp_path):
         try:
             sup = server.supervisor
             assert len(sup.ready_workers) == 2
+            # each READY line says what the worker holds; a fleet's
+            # workers were each given a chip of their own
+            assert [w.device["platform"] for w in sup.workers] == ["cpu"] * 2
+            assert [w.device["chip"] for w in sup.workers] == ["0", "1"]
 
             # serial goldens from the front end's own replica
             golden = {}
@@ -698,3 +859,5 @@ def test_cluster_e2e_crash_sigkill_drain(tmp_path):
             await server.stop()
 
     asyncio.run(run())
+    # the workers wrote their compiled programs where the environment said
+    assert os.listdir(tmp_path / "cache"), "workers never used the cache dir"

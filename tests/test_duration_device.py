@@ -1,4 +1,4 @@
-"""Device-resident DURATION execution (VERDICT r3 missing #2 / SURVEY §2.2):
+"""Device-resident DURATION execution:
 durations ride as int64 (n, 3) device triples — months / days / total
 microseconds (the reference's CalendarInterval model, ``TemporalUdafs.scala``
 aggregates + ``okapi-api Duration.scala`` components) — so duration columns,

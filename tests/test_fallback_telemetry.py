@@ -1,4 +1,4 @@
-"""Device-coverage telemetry (VERDICT r2 weak #7 / next #8): per-query
+"""Device-coverage telemetry: per-query
 fallback recording on CypherResult, and a regression gate on the aggregate
 fallback rate across the TCK corpus run on the TPU backend — a silent
 device-coverage regression (joins/group/distinct dropping to the oracle)

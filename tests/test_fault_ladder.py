@@ -10,7 +10,7 @@ rung fails and the host oracle answers) and asserts:
 * every attempt lands in ``result.execution_log`` with its typed error,
 * no RAW (untyped) error ever escapes ``CypherResult`` — with the ladder
   disabled the caller sees a ``tpu_cypher.errors`` class, never an
-  ``InjectedFault``/``XlaRuntimeError``.
+  ``InjectedFault``/``JaxRuntimeError``.
 """
 
 import os
@@ -323,17 +323,17 @@ def test_injected_timeout_is_terminal(graphs):
 
 
 def test_classify_raw_markers():
-    class XlaRuntimeError(RuntimeError):
-        pass
+    # the raw class the INSTALLED runtime raises (what a chip fault is)
+    from jax.errors import JaxRuntimeError
 
-    oom = ERR.classify(XlaRuntimeError("RESOURCE_EXHAUSTED: out of memory"))
+    oom = ERR.classify(JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory"))
     assert isinstance(oom, ERR.DeviceOOM)
-    lost = ERR.classify(XlaRuntimeError("UNAVAILABLE: device lost"))
+    lost = ERR.classify(JaxRuntimeError("UNAVAILABLE: device lost"))
     assert isinstance(lost, ERR.DeviceLost)
-    comp = ERR.classify(XlaRuntimeError("INTERNAL: error while compiling"))
+    comp = ERR.classify(JaxRuntimeError("INTERNAL: error while compiling"))
     assert isinstance(comp, ERR.CompileFailure)
     # unknown raw device error still classifies (generic DeviceError)
-    other = ERR.classify(XlaRuntimeError("something odd"))
+    other = ERR.classify(JaxRuntimeError("something odd"))
     assert isinstance(other, ERR.DeviceError)
     # non-device exceptions pass through unclassified
     assert ERR.classify(ValueError("RESOURCE_EXHAUSTED-looking text")) is None

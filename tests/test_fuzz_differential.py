@@ -209,7 +209,7 @@ def test_fuzz_differential_adversarial(fuzz_graphs_adversarial, qseed):
 # ---------------------------------------------------------------------------
 # Temporal fuzz: zoned datetime / date properties + accessor, comparison,
 # ordering, aggregation, and duration-arithmetic shapes (round-5 de-bias:
-# VERDICT r4 asked the generator to cover the temporal-zoned family)
+# the generator covers the temporal-zoned family)
 # ---------------------------------------------------------------------------
 
 

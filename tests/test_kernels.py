@@ -201,22 +201,14 @@ def test_microbenchmarks_run(monkeypatch):
     assert kernel_lines >= 8
 
 
-def test_pallas_frontier_degree_sum_matches_jnp():
-    """The Pallas degree-sum program (interpret mode on CPU) is bit-identical
-    to the jnp gather+sum it replaces, incl. masked slots and empty input."""
+def test_frontier_degree_sum_matches_numpy():
+    """The frontier degree-sum program equals the NumPy gather+sum, incl.
+    masked slots and empty input."""
     import numpy as np
     import jax.numpy as jnp
 
-    from tpu_cypher.backend.tpu.pallas_kernels import (
-        HAVE_PALLAS,
-        _csr_deg_sum_jnp,
-        csr_frontier_degree_sum,
-    )
+    from tpu_cypher.backend.tpu.jit_ops import frontier_degree_sum
 
-    if not HAVE_PALLAS:
-        import pytest
-
-        pytest.skip("pallas unavailable in this jax build")
     rng = np.random.default_rng(5)
     for n_nodes, n_frontier in [(1, 1), (7, 3), (1000, 3333), (4096, 1024)]:
         deg = rng.integers(0, 100, n_nodes).astype(np.int32)
@@ -226,23 +218,10 @@ def test_pallas_frontier_degree_sum_matches_jnp():
         want = int(
             np.where(np.asarray(present), deg[np.asarray(pos)], 0).sum()
         )
-        got_pallas = int(
-            csr_frontier_degree_sum(
-                rp, pos, present, max_deg=int(deg.max()), interpret=True
-            )
-        )
-        got_jnp = int(_csr_deg_sum_jnp(rp, pos, present))
-        assert got_pallas == want
-        assert got_jnp == want
-    # empty frontier routes to the jnp path and sums to zero
+        assert int(frontier_degree_sum(rp, pos, present)) == want
     rp = jnp.asarray(np.array([0, 5, 12], np.int32))
     assert (
-        int(
-            csr_frontier_degree_sum(
-                rp, jnp.zeros(0, jnp.int64), jnp.zeros(0, bool), max_deg=7,
-                interpret=True,
-            )
-        )
+        int(frontier_degree_sum(rp, jnp.zeros(0, jnp.int64), jnp.zeros(0, bool)))
         == 0
     )
 
@@ -667,19 +646,19 @@ def test_count_chain_failure_falls_back_to_classic(monkeypatch):
 
 
 def test_count_only_1hop_uses_degree_sum_path(monkeypatch):
-    """Single-hop unrestricted count routes through the Pallas/jnp frontier
-    degree-sum (O(frontier) with VMEM tiling on TPU), not the edge dot."""
+    """Single-hop unrestricted count routes through the O(frontier)
+    degree-sum, not the edge dot."""
     from tpu_cypher import CypherSession
-    from tpu_cypher.backend.tpu import pallas_kernels
+    from tpu_cypher.backend.tpu import jit_ops
 
     calls = {"n": 0}
-    orig = pallas_kernels.csr_frontier_degree_sum
+    orig = jit_ops.frontier_degree_sum
 
-    def spy(rp, pos, present, **kw):
+    def spy(rp, pos, present):
         calls["n"] += 1
-        return orig(rp, pos, present, **kw)
+        return orig(rp, pos, present)
 
-    monkeypatch.setattr(pallas_kernels, "csr_frontier_degree_sum", spy)
+    monkeypatch.setattr(jit_ops, "frontier_degree_sum", spy)
 
     create = "CREATE (a:V)-[:E]->(b:V)-[:E]->(c:V), (a)-[:E]->(c)"
     q = "MATCH (x:V)-[:E]->(y) RETURN count(*) AS c"

@@ -1,22 +1,24 @@
-"""The Pallas kernel suite behind the dispatch layer (ISSUE 3).
+"""The Pallas kernel tier behind the dispatch layer.
 
 Four guarantees under test:
 
-* DIFFERENTIAL — every kernel under ``interpret=True`` is bit-identical
-  to the jnp formulation it replaces across the fuzz-corpus shapes (empty
+* DIFFERENTIAL — the kernel under ``interpret`` is bit-identical to the
+  jnp formulation it replaces across the fuzz-corpus shapes (empty
   frontier, all-masked lanes, single-bucket, max-bucket), both at the
-  kernel contract level and end-to-end through the engine; and
-  ``TPU_CYPHER_PALLAS=off`` restores today's exact execution path.
-* FAULTS — the ``kernel_*`` sites drive the PR-2 degrade-and-retry ladder
+  kernel contract level and end-to-end through the engine;
+  ``TPU_CYPHER_PALLAS=off`` restores the pre-kernel execution path; and
+  the jnp formulations that are the ONE path for expand and join-probe
+  equal a NumPy reference on the same shapes.
+* FAULTS — the ``kernel_agg`` site drives the degrade-and-retry ladder
   exactly like the relational sites: results stay oracle-identical, every
   failed attempt lands typed in ``execution_log``.
 * GUARDS — every ``pl.pallas_call`` in ``backend/tpu`` lives inside a
-  dispatch-registered impl (no raw calls bypassing eligibility/fallback),
-  and repeated bucketed queries with kernels enabled compile ZERO new XLA
+  dispatch-registered impl (no raw calls bypassing eligibility), and
+  repeated bucketed queries with kernels enabled compile ZERO new XLA
   programs once warm.
-* REGISTRY — a forced-interpret lowering failure re-raises and is never
-  memoized (no cross-test poisoning); a compiled-path failure memoizes
-  broken-once per (kernel, variant) and ``reset()`` clears it.
+* NO HIDDEN FALLBACK — an interpreted failure re-raises as it is; a
+  compiled-path refusal raises typed ``CompileFailure`` (never the jnp
+  answer); a device fault mid-kernel surfaces as what it is.
 """
 
 import os
@@ -30,19 +32,14 @@ from tpu_cypher import CypherSession
 from tpu_cypher import errors as ERR
 from tpu_cypher.backend.tpu import bucketing
 from tpu_cypher.backend.tpu import jit_ops as J
-from tpu_cypher.backend.tpu.pallas import (
-    aggregate as PA,
-    dispatch,
-    expand as PE,
-    frontier as PF,
-    join as PJ,
-)
+from tpu_cypher.backend.tpu.pallas import aggregate as PA, dispatch
+from tpu_cypher.utils.config import PALLAS_MAX_GROUPS
 from tpu_cypher.runtime import faults, guard
 
 
 @pytest.fixture(autouse=True)
 def _clean_dispatch():
-    """Every test leaves mode, broken memoization, and fault specs as it
+    """Every test leaves mode, launch counters, and fault specs as it
     found them — the no-cross-test-poisoning contract, enforced."""
     yield
     dispatch.MODE.reset()
@@ -78,89 +75,89 @@ SHAPES = [
 
 
 @pytest.mark.parametrize("shape_name,n,density", SHAPES)
-def test_expand_kernel_differential(interpret_mode, shape_name, n, density):
+def test_expand_materialize_counted_vs_numpy(shape_name, n, density):
     rng = np.random.default_rng(hash(shape_name) % 2**31)
     n_nodes = max(n // 2, 4)
     deg = rng.integers(0, 6, n_nodes).astype(np.int64)
-    rp = jnp.asarray(np.concatenate([[0], np.cumsum(deg)]).astype(np.int32))
+    rp_np = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
     n_edges = int(deg.sum())
-    ci = jnp.asarray(rng.integers(0, n_nodes, max(n_edges, 1)).astype(np.int32)[:n_edges])
-    eo = jnp.asarray(rng.integers(0, 10**9, n_edges))
-    pos = jnp.asarray(rng.integers(0, n_nodes, n))
-    present = jnp.asarray(rng.random(n) < density)
+    ci_np = rng.integers(0, n_nodes, max(n_edges, 1)).astype(np.int32)[:n_edges]
+    eo_np = rng.integers(0, 10**9, n_edges)
+    pos_np = rng.integers(0, n_nodes, n)
+    present_np = rng.random(n) < density
+    rp, ci, eo = jnp.asarray(rp_np), jnp.asarray(ci_np), jnp.asarray(eo_np)
+    pos, present = jnp.asarray(pos_np), jnp.asarray(present_np)
     dd, t_dev = J.expand_degrees_total(rp, pos, present)
     total = int(t_dev)
+    # the NumPy repeat cascade: one lane per (frontier row, adjacent edge)
+    fdeg = np.where(present_np, deg[pos_np], 0)
+    assert total == int(fdeg.sum())
+    row_np = np.repeat(np.arange(n), fdeg)
+    edge_np = (
+        np.repeat(rp_np[pos_np], fdeg)
+        + np.arange(total)
+        - np.repeat(np.cumsum(fdeg) - fdeg, fdeg)
+    )
     # size 0 only pairs with total 0 (the engine's round_size(0) == 0 —
-    # a nonzero pad-only materialize is outside the jnp contract too)
+    # a nonzero pad-only materialize is outside the contract)
     sizes = (
         {total, bucketing.round_up_pow2(total, 32), total * 2 + 32}
         if total
         else {0}
     )
     for size in sizes:
-        want = J.expand_materialize_counted(rp, ci, eo, pos, dd, t_dev, size=size)
-        got = PE.expand_materialize_counted(rp, ci, eo, pos, dd, t_dev, size=size)
-        for w, g, nm in zip(want, got, ("row", "nbr", "orig", "live")):
-            assert (np.asarray(w) == np.asarray(g)).all(), (shape_name, size, nm)
-    if total > 0 and n > 0:
-        assert _counts()["expand_rows"]["pallas"] > 0
-    else:  # size 0 / empty frontier declines to the jnp path
-        assert _counts()["expand_rows"]["pallas"] == 0
+        row, nbr, orig, live = J.expand_materialize_counted(
+            rp, ci, eo, pos, dd, t_dev, size=size
+        )
+        live = np.asarray(live)
+        assert live.sum() == total and live[:total].all(), (shape_name, size)
+        assert (np.asarray(row)[:total] == row_np).all(), (shape_name, size)
+        assert (np.asarray(nbr)[:total] == ci_np[edge_np]).all()
+        assert (np.asarray(orig)[:total] == eo_np[edge_np]).all()
+        # pad lanes are sanitized, never a raw out-of-bounds gather
+        for arr in (row, nbr, orig):
+            assert (np.asarray(arr)[total:] == 0).all(), (shape_name, size)
 
 
 @pytest.mark.parametrize("shape_name,n,density", SHAPES)
-def test_join_kernel_differential(interpret_mode, shape_name, n, density):
+def test_join_probe_bucketed_vs_numpy(shape_name, n, density):
     rng = np.random.default_rng(hash(shape_name) % 2**31 + 1)
     tag = 7 << 54  # graph-tagged ids: keys live far past int32
     nr = max(n // 3, 1)
-    rd = jnp.asarray(rng.integers(0, max(nr // 2, 1), nr) + tag)
-    rvalid = jnp.asarray(rng.random(nr) < density)
-    ld = jnp.asarray(rng.integers(0, max(nr, 1), n) + tag)
-    lvalid = jnp.asarray(rng.random(n) < max(density, 0.5))
+    rd_np = rng.integers(0, max(nr // 2, 1), nr) + tag
+    rvalid_np = rng.random(nr) < density
+    ld_np = rng.integers(0, max(nr, 1), n) + tag
+    lvalid_np = rng.random(n) < max(density, 0.5)
+    rd, rvalid = jnp.asarray(rd_np), jnp.asarray(rvalid_np)
+    ld, lvalid = jnp.asarray(ld_np), jnp.asarray(lvalid_np)
     rd_s, r_order, nvalid_dev = J.join_build(
         rd, (rvalid,), is_f64=False, is_bool=False
     )
     nvalid = int(nvalid_dev)
+    assert nvalid == int(rvalid_np.sum())
     cap = min(bucketing.round_up_pow2(nvalid, 32), nr)
-    want = J.join_probe_bucketed(
+    r_idx, lo, counts, total_dev = J.join_probe_bucketed(
         rd_s, r_order, ld, (lvalid,), nvalid_dev,
         nvalid_cap=cap, is_f64=False, is_bool=False,
     )
-    got = PJ.join_probe_bucketed(
-        rd_s, r_order, ld, (lvalid,), nvalid_dev,
-        nvalid_cap=cap, is_f64=False, is_bool=False,
+    # per probe row: how many VALID build rows carry its key
+    build_keys = rd_np[rvalid_np]
+    want = np.where(
+        lvalid_np, (ld_np[:, None] == build_keys[None, :]).sum(axis=1), 0
     )
-    cw, cg = np.asarray(want[2]), np.asarray(got[2])
-    assert (cw == cg).all(), shape_name
-    matched = cw > 0
-    assert (np.asarray(want[1])[matched] == np.asarray(got[1])[matched]).all()
-    assert int(want[3]) == int(got[3])
-    assert (np.asarray(want[0])[:cap] == np.asarray(got[0])[:cap]).all()
-    # the shared materialize must emit identical pairs either way
-    total = int(want[3])
+    assert (np.asarray(counts) == want).all(), shape_name
+    total = int(total_dev)
+    assert total == int(want.sum())
     if total:
+        # the shared materialize emits exactly the matching pairs
         size = bucketing.round_up_pow2(total, 32)
-        mw = J.join_materialize_counted(want[0], want[1], want[2], want[3], size=size)
-        mg = J.join_materialize_counted(got[0], got[1], got[2], got[3], size=size)
-        for w, g in zip(mw, mg):
-            assert (np.asarray(w) == np.asarray(g)).all(), shape_name
-
-
-def test_join_kernel_declines_float_keys(interpret_mode):
-    rng = np.random.default_rng(3)
-    rd = jnp.asarray(rng.normal(0, 5, 64))
-    ld = jnp.asarray(rng.normal(0, 5, 128))
-    rd_s, r_order, nvalid_dev = J.join_build(rd, (), is_f64=True, is_bool=False)
-    got = PJ.join_probe_bucketed(
-        rd_s, r_order, ld, (), nvalid_dev,
-        nvalid_cap=64, is_f64=True, is_bool=False,
-    )
-    want = J.join_probe_bucketed(
-        rd_s, r_order, ld, (), nvalid_dev,
-        nvalid_cap=64, is_f64=True, is_bool=False,
-    )
-    assert (np.asarray(want[2]) == np.asarray(got[2])).all()
-    assert _counts()["join_probe"]["pallas"] == 0  # searchsorted path kept
+        left, right, _ = J.join_materialize_counted(
+            r_idx, lo, counts, total_dev, size=size
+        )
+        left, right = np.asarray(left)[:total], np.asarray(right)[:total]
+        assert (ld_np[left] == rd_np[right]).all(), shape_name
+        assert rvalid_np[right].all() and lvalid_np[left].all()
+        assert len({(a, b) for a, b in zip(left, right)}) == total
 
 
 AGG_CASES = [
@@ -175,7 +172,7 @@ def test_aggregate_kernel_differential(
     interpret_mode, name, kind, shape_name, n, density
 ):
     rng = np.random.default_rng(abs(hash((name, kind, shape_name))) % 2**31)
-    k = max(min(n // 4, PA.MAX_GROUPS), 1)
+    k = max(min(n // 4, PALLAS_MAX_GROUPS.get()), 1)
     if kind == "i64":
         data = jnp.asarray(rng.integers(-(10**12), 10**12, n))
     elif kind == "f64":
@@ -199,44 +196,24 @@ def test_aggregate_kernel_differential(
             )
         else:
             assert (w == g).all(), (name, kind, shape_name, w, g)
-    assert _counts()["segment_agg"]["pallas"] > 0
+    # the kernel takes 32-bit planes only: count over anything, min/max
+    # over BOOL. 64-bit values decline by eligibility — Mosaic never sees
+    # them — and the drop-in still answers identically
+    if name == "count" or kind == "bool":
+        assert _counts()["segment_agg"]["pallas"] > 0
+    else:
+        assert _counts()["segment_agg"] == {"pallas": 0, "fallback": 1}
 
 
 def test_aggregate_kernel_declines_over_group_cap(interpret_mode):
-    n, k = 2000, PA.MAX_GROUPS + 1
+    n, k = 2000, PALLAS_MAX_GROUPS.get() + 1
     rng = np.random.default_rng(5)
     data = jnp.asarray(rng.integers(0, 100, n))
     seg = jnp.asarray(rng.integers(0, k, n))
-    want = J.segment_aggregate(data, None, None, seg, name="sum", kind="i64", k=k)
-    got = PA.segment_aggregate(data, None, None, seg, name="sum", kind="i64", k=k)
+    want = J.segment_aggregate(data, None, None, seg, name="count", kind="i64", k=k)
+    got = PA.segment_aggregate(data, None, None, seg, name="count", kind="i64", k=k)
     assert (np.asarray(want[0]) == np.asarray(got[0])).all()
     assert _counts()["segment_agg"]["pallas"] == 0
-
-
-def test_two_hop_count_rides_frontier_kernel(interpret_mode):
-    """``kernels.two_hop_count`` is the frontier degree-sum shape; with
-    ``max_deg`` it must launch the kernel and agree with the jnp path."""
-    from tpu_cypher.backend.tpu.kernels import CsrGraph, two_hop_count
-
-    rng = np.random.default_rng(17)
-    ids = np.arange(50, dtype=np.int64)
-    src = rng.integers(0, 50, 200)
-    dst = rng.integers(0, 50, 200)
-    g = CsrGraph.build(ids, src, dst)
-    base = int(two_hop_count(g.row_ptr, g.col_idx))  # no max_deg: jnp path
-    got = int(two_hop_count(g.row_ptr, g.col_idx, max_deg=g.max_degree))
-    assert base == got
-    assert _counts()["frontier_deg_sum"]["pallas"] == 1
-
-
-def test_frontier_kernel_all_masked(interpret_mode):
-    rp = jnp.asarray(np.array([0, 3, 7, 7, 12], np.int32))
-    pos = jnp.asarray(np.array([0, 1, 2, 3, 3]))
-    present = jnp.zeros(5, bool)
-    got = int(PF.csr_frontier_degree_sum(rp, pos, present, max_deg=5))
-    want = int(PF._csr_deg_sum_jnp(rp, pos, present))
-    assert got == want == 0
-    assert _counts()["frontier_deg_sum"]["pallas"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -270,6 +247,7 @@ ENGINE_CORPUS = [
     "min(r.w) AS lo, max(r.w) AS hi, sum(r.w) AS s ORDER BY t",
     "MATCH (a:P) OPTIONAL MATCH (a)-[:K]->(b) RETURN a.id, b.id",
     "MATCH (a:P) RETURN a.age AS g, count(*) AS c ORDER BY g",
+    "MATCH (a:P)-[:K]->(b) RETURN b.id AS t, count(a.age) AS c ORDER BY t",
 ]
 
 
@@ -288,12 +266,12 @@ def test_engine_differential_kernels_on_vs_off():
         got = g_on.cypher(q).records.to_bag()
         assert got == want[q], f"kernels diverged on: {q}"
     used = {k: v["pallas"] for k, v in _counts().items() if v["pallas"]}
-    assert {"expand_rows", "join_probe", "segment_agg"} <= set(used), used
+    assert set(used) == {"segment_agg"}, used
 
 
 def test_mode_off_never_reaches_pallas_fn():
     dispatch.MODE.set("off")
-    dispatch.register("_probe_test_kernel", "kernel_frontier", impls=())
+    dispatch.register("_probe_test_kernel", "kernel_agg", impls=())
     calls = {"pallas": 0}
 
     def pallas_fn(interpret):
@@ -309,25 +287,12 @@ def test_mode_off_never_reaches_pallas_fn():
 # ---------------------------------------------------------------------------
 
 # site -> query that reaches the kernel; which rung finally answers under
-# ``:*`` (join/expand kernels live in the BUCKETED branch, so the
-# bucket-exact rung already bypasses them; agg/frontier kernels run at
-# every device rung, so only the host oracle escapes the fault)
+# ``:*`` (the aggregate kernel runs at every device rung, so only the host
+# oracle escapes the fault)
 KERNEL_SITE_QUERIES = {
-    "kernel_join": (
-        "MATCH (x:P), (y:P) WHERE x.ref = y.id RETURN x.id AS a, y.id AS b",
-        guard.RUNG_BUCKET_EXACT,
-    ),
-    "kernel_expand": (
-        "MATCH (a:P)-[:K]->(b:P) RETURN a.id AS a, b.id AS b",
-        guard.RUNG_BUCKET_EXACT,
-    ),
     "kernel_agg": (
-        "MATCH (a:P)-[:K]->(b:P) RETURN b.ref AS t, min(b.id) AS m, "
-        "sum(b.id) AS s",
-        guard.RUNG_HOST,
-    ),
-    "kernel_frontier": (
-        "MATCH (a:P)-[:K]->(b) RETURN count(*) AS c",
+        "MATCH (a:P)-[:K]->(b:P) RETURN b.ref AS t, count(a.id) AS c, "
+        "min(b.id) AS m",
         guard.RUNG_HOST,
     ),
 }
@@ -383,82 +348,84 @@ def test_kernel_fault_matrix(fault_graphs, site, kind, depth):
 
 
 # ---------------------------------------------------------------------------
-# broken-once memoization semantics
+# no hidden fallback: a selected kernel runs or raises
 # ---------------------------------------------------------------------------
 
 
-def test_force_interpret_failure_is_not_memoized(monkeypatch):
-    """A forced-interpret lowering failure re-raises and must NOT poison
-    the registry for later calls (satellite: clean reset between tests)."""
-    dispatch.register("_broken_test_kernel", "kernel_frontier", impls=())
+def test_interpret_failure_reraises_and_kernel_stays_live():
+    """An interpreted failure is a real bug: it re-raises as it is, is
+    never answered by the fallback, and leaves the kernel live."""
+    dispatch.register("_raising_test_kernel", "kernel_agg", impls=())
 
     def boom(interpret):
         raise RuntimeError("synthetic interpret-mode failure")
 
     dispatch.MODE.set("interpret")
     with pytest.raises(RuntimeError):
-        dispatch.launch("_broken_test_kernel", boom, lambda: "fallback")
-    assert not dispatch.is_broken("_broken_test_kernel")
-    # the kernel stays live: a healthy program runs on the next call
+        dispatch.launch("_raising_test_kernel", boom, lambda: "fallback")
     out = dispatch.launch(
-        "_broken_test_kernel", lambda interpret: "pallas", lambda: "fallback"
+        "_raising_test_kernel", lambda interpret: "pallas", lambda: "fallback"
     )
     assert out == "pallas"
 
 
-def test_compiled_failure_memoizes_broken_once(monkeypatch):
-    """On a real TPU backend a non-device lowering failure is paid ONCE:
-    later calls go straight to the fallback without re-touching Pallas."""
-    dispatch.register("_broken_test_kernel2", "kernel_frontier", impls=())
+def test_compiled_refusal_raises_compile_failure(monkeypatch):
+    """On a TPU backend a lowering refusal is NEVER swallowed into the jnp
+    formulation: it raises typed ``CompileFailure`` on every call."""
+    dispatch.register("_refused_test_kernel", "kernel_agg", impls=())
     monkeypatch.setattr(dispatch, "_backend_is_tpu", lambda: True)
-    calls = {"pallas": 0}
+    calls = {"pallas": 0, "fallback": 0}
 
-    def boom(interpret):
+    def refused(interpret):
+        assert interpret is False
         calls["pallas"] += 1
-        raise RuntimeError("synthetic Mosaic refusal")
+        raise ValueError("Cannot do int indexing on TPU")
 
-    assert dispatch.launch("_broken_test_kernel2", boom, lambda: "fb") == "fb"
-    assert dispatch.is_broken("_broken_test_kernel2")
-    assert dispatch.launch("_broken_test_kernel2", boom, lambda: "fb") == "fb"
-    assert calls["pallas"] == 1  # second call never re-enters Pallas
-    dispatch.reset("_broken_test_kernel2")
-    assert not dispatch.is_broken("_broken_test_kernel2")
+    def fallback():
+        calls["fallback"] += 1
+        return "fb"
 
-
-def test_variant_isolation_in_broken_memo(monkeypatch):
-    """An f64 lowering failure must not disable the int64 variant."""
-    dispatch.register("_broken_test_kernel3", "kernel_agg", impls=())
-    monkeypatch.setattr(dispatch, "_backend_is_tpu", lambda: True)
-
-    def boom(interpret):
-        raise RuntimeError("f64 unsupported")
-
-    dispatch.launch("_broken_test_kernel3", boom, lambda: 0, variant="float64")
-    assert dispatch.is_broken("_broken_test_kernel3", "float64")
-    assert not dispatch.is_broken("_broken_test_kernel3", "int64")
-    out = dispatch.launch(
-        "_broken_test_kernel3", lambda interpret: 1, lambda: 0, variant="int64"
-    )
-    assert out == 1
+    for _ in range(2):
+        with pytest.raises(ERR.CompileFailure) as info:
+            dispatch.launch("_refused_test_kernel", refused, fallback)
+        assert info.value.site == "kernel_agg"
+        assert isinstance(info.value.cause, ValueError)
+    assert calls == {"pallas": 2, "fallback": 0}
+    assert _counts()["_refused_test_kernel"] == {"pallas": 0, "fallback": 0}
 
 
 def test_device_fault_inside_kernel_surfaces_typed(monkeypatch):
-    """An OOM raised DURING a compiled kernel run must re-raise typed (the
-    ladder handles it), never be memoized as a lowering failure."""
-    dispatch.register("_broken_test_kernel4", "kernel_join", impls=())
+    """An OOM raised DURING a compiled kernel run must re-raise typed as
+    what it is (the ladder handles it), not as a lowering refusal."""
+    import jax
+
+    dispatch.register("_oom_test_kernel", "kernel_agg", impls=())
     monkeypatch.setattr(dispatch, "_backend_is_tpu", lambda: True)
 
-    class XlaRuntimeError(RuntimeError):  # classify() is raw-type-gated
-        pass
-
     def oom(interpret):
-        raise XlaRuntimeError(
+        raise jax.errors.JaxRuntimeError(
             "RESOURCE_EXHAUSTED: out of memory allocating 1 bytes"
         )
 
     with pytest.raises(ERR.DeviceOOM):
-        dispatch.launch("_broken_test_kernel4", oom, lambda: 0)
-    assert not dispatch.is_broken("_broken_test_kernel4")
+        dispatch.launch("_oom_test_kernel", oom, lambda: 0)
+
+
+def test_auto_mode_off_tpu_answers_from_jnp(monkeypatch):
+    """``auto`` off a TPU backend never reaches the kernel (and never the
+    interpreter); on a TPU backend it compiles — ``interpret=False``."""
+    dispatch.register("_auto_test_kernel", "kernel_agg", impls=())
+    seen = []
+
+    def pallas_fn(interpret):
+        seen.append(interpret)
+        return "pallas"
+
+    assert dispatch.launch("_auto_test_kernel", pallas_fn, lambda: "fb") == "fb"
+    assert seen == []
+    monkeypatch.setattr(dispatch, "_backend_is_tpu", lambda: True)
+    assert dispatch.launch("_auto_test_kernel", pallas_fn, lambda: "fb") == "pallas"
+    assert seen == [False]
 
 
 # ---------------------------------------------------------------------------
@@ -526,13 +493,14 @@ def test_kernels_keep_compile_stats_flat():
             "CREATE " + ", ".join(parts)
         )
 
-    # grouped aggregation stays out of this corpus: the group
-    # factorization runs at EXACT sizes by design (seed behavior — "out
-    # of the bucketing contract"), kernel tier or not; the kernel-level
-    # k-static reuse is covered by the contract differentials above
+    # the grouped count rides the kernel; its group factorization runs at
+    # EXACT sizes by design (seed behavior — "out of the bucketing
+    # contract"), kernel tier or not, so a fresh size costs the kernel
+    # path exactly what it costs the scatter path
     queries = [
         "MATCH (a:P)-[:K]->(b:P) RETURN a.id AS a, b.id AS b",
         "MATCH (x:P), (y:P) WHERE x.ref = y.id RETURN count(*) AS c",
+        "MATCH (a:P) RETURN a.ref AS r, count(a.id) AS c",
     ]
 
     def run(g):
@@ -551,47 +519,10 @@ def test_kernels_keep_compile_stats_flat():
     dispatch.MODE.set("interpret")
     g1 = build(46)
     run(g1)  # cold: compiles the bucket-lattice programs incl. kernels
-    used_cold = {k: v["pallas"] for k, v in _counts().items()}
-    assert used_cold.get("expand_rows") and used_cold.get("join_probe")
+    assert _counts()["segment_agg"]["pallas"] > 0
     assert run(g1) == 0, "same graph re-run must compile nothing"
     # fresh size in the same buckets: the kernel tier must add ZERO
     # compiles over the pre-kernel path's own delta
     assert run(build(50)) == baseline, (
         "kernels broke warm-path compile_stats flatness"
     )
-
-
-# ---------------------------------------------------------------------------
-# bench.py wrapper: the always-one-JSON-line contract
-# ---------------------------------------------------------------------------
-
-
-def test_bench_final_line_passthrough_and_synthesis():
-    import json
-    import sys
-
-    sys.path.insert(
-        0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    import bench
-
-    # a healthy child: its JSON line passes through untouched, trailing
-    # native noise ignored
-    good = json.dumps({"metric": "m", "value": 1.0})
-    out = bench._final_line(0, f"init noise\n{good}\ntrailing libtpu spam", "")
-    assert json.loads(out)["value"] == 1.0
-
-    # a crashed child with no line: synthesized error line, typed class
-    out = bench._final_line(
-        1, "garbage not json", "RESOURCE_EXHAUSTED: hbm exhausted"
-    )
-    parsed = json.loads(out)
-    assert parsed["error_class"] == "DeviceOOM"
-    assert parsed["child_rc"] == 1
-    assert parsed["tpu_init_failed"] is True
-
-    out = bench._final_line(134, "", "Mosaic lowering failed for fusion")
-    assert json.loads(out)["error_class"] == "CompileFailure"
-
-    out = bench._final_line(139, "", "Segmentation fault in libtpu.so")
-    assert json.loads(out)["error_class"] == "DeviceLost"

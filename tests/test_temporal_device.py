@@ -1,4 +1,4 @@
-"""Device-resident temporal execution (VERDICT r2 missing #2 / SURVEY §2.2):
+"""Device-resident temporal execution:
 date = int32 days-since-epoch, localdatetime = int64 micros-since-epoch
 device columns; accessors/comparisons/aggregates run as traced calendar math
 (reference executes these on executors, ``TemporalUdfs.scala:40-160``)."""

@@ -1,6 +1,6 @@
 """Adversarial + larger-cardinality differential tests for the TPU backend.
 
-VERDICT r1 weak #5/#10: the differential surface was 10 queries over 7
+The differential surface once was 10 queries over 7
 elements. This suite runs outer-join-heavy shapes, OPTIONAL MATCH chains,
 var-length, CONSTRUCT, and adversarial values (null / NaN / -0.0 / mixed
 int-float / empty strings / huge ids near the 2**53 float cliff) over a

@@ -7,17 +7,17 @@ Five guarantees under test:
   ``MultiwayIntersectOp`` under ``TPU_CYPHER_WCOJ=force`` are bit-identical
   to the forced binary plan (``=off``) and to the local host oracle, on
   loopy and loop-free graphs, both bucket modes, kernels on and off; and
-  the ``pallas/intersect.py`` range-count kernel under ``interpret=True``
-  matches the jnp searchsorted formulation at the contract level.
+  ``jit_ops.range_count`` (the sorted-range search step) matches a NumPy
+  ``searchsorted`` reference at the contract level.
 * ELIGIBILITY — ``auto`` mode applies the EmptyHeaded-style rule: routes
   to WCOJ only when the degree-stats blowup estimate clears
   ``TPU_CYPHER_WCOJ_MIN_ROWS``; small graphs keep the binary plan.
-* FAULTS — ``kernel_intersect`` drives the degrade-and-retry ladder like
-  every other kernel site: typed failures in ``execution_log``, results
-  oracle-identical, ``:*`` lands on the host oracle (the intersect kernel
-  runs at every device rung), the unsupported multi-close materialize
-  degrades to the classic shadow plan.
-* GUARDS — the kernel is dispatch-registered (site + impl allowlist), the
+* FAULTS — the operator's ``expand`` site drives the degrade-and-retry
+  ladder: typed failures in ``execution_log``, results oracle-identical,
+  ``:*`` lands on the host oracle (the site is passed at every device
+  rung), the unsupported multi-close materialize degrades to the classic
+  shadow plan.
+* GUARDS — the
   ``TPU_CYPHER_WCOJ*`` knobs live in the config registry, the engine lint
   reports zero findings on the new modules, and warm cyclic queries with
   kernels on compile ZERO new XLA programs.
@@ -38,7 +38,8 @@ from tpu_cypher import errors as ERR
 from tpu_cypher.backend.tpu import bucketing
 from tpu_cypher.backend.tpu import graph_index as GI
 from tpu_cypher.backend.tpu.graph_index import GraphIndex, GraphIndexError
-from tpu_cypher.backend.tpu.pallas import dispatch, intersect as PI
+from tpu_cypher.backend.tpu import jit_ops as J
+from tpu_cypher.backend.tpu.pallas import dispatch
 from tpu_cypher.backend.tpu import wcoj as W
 from tpu_cypher.runtime import faults, guard
 from tpu_cypher.utils.config import FACTORIZE, REGISTRY, WCOJ_MIN_ROWS, WCOJ_MODE
@@ -46,8 +47,8 @@ from tpu_cypher.utils.config import FACTORIZE, REGISTRY, WCOJ_MIN_ROWS, WCOJ_MOD
 
 @pytest.fixture(autouse=True)
 def _clean():
-    """Every test leaves WCOJ routing, kernel mode, broken memoization,
-    bucketing, and fault specs as it found them."""
+    """Every test leaves WCOJ routing, kernel mode, bucketing, and fault
+    specs as it found them."""
     yield
     WCOJ_MODE.reset()
     WCOJ_MIN_ROWS.reset()
@@ -97,10 +98,10 @@ def _loop_free_create(seed=13, n=40, e=220):
 
 
 # ---------------------------------------------------------------------------
-# kernel-contract differential: pallas range count vs jnp searchsorted
+# contract differential: jit_ops.range_count vs NumPy searchsorted
 # ---------------------------------------------------------------------------
 
-KERNEL_SHAPES = [
+RANGE_SHAPES = [
     ("single_key", 1, 4, 1.0),
     ("dense", 700, 900, 0.85),
     ("all_invalid", 64, 200, 0.0),
@@ -109,58 +110,38 @@ KERNEL_SHAPES = [
 ]
 
 
-@pytest.mark.parametrize("name,nk,nq,density", KERNEL_SHAPES)
-def test_intersect_kernel_differential(name, nk, nq, density):
+def _range_count_np(keys, q, qvalid):
+    lo = np.searchsorted(keys, q, side="left")
+    hi = np.searchsorted(keys, q, side="right")
+    counts = np.where(qvalid, hi - lo, 0)
+    return lo, counts, counts.sum()
+
+
+@pytest.mark.parametrize("name,nk,nq,density", RANGE_SHAPES)
+def test_range_count_differential(name, nk, nq, density):
     rng = np.random.default_rng(abs(hash(name)) % 2**31)
     lo = 0 if name != "dup_heavy" else 5  # duplicates: narrow key space
-    keys = jnp.asarray(np.sort(rng.integers(lo, max(nk, 8), nk).astype(np.int64)))
-    q = jnp.asarray(rng.integers(0, max(nk, 8) + 2, nq).astype(np.int64))
-    qvalid = jnp.asarray(rng.random(nq) < density)
-    npow = bucketing.round_up_pow2(nk)
-    want = PI._range_count_jnp(keys, q, qvalid)
-    got = PI._range_count_pallas(keys, q, qvalid, npow=npow, interpret=True)
+    keys = np.sort(rng.integers(lo, max(nk, 8), nk).astype(np.int64))
+    q = rng.integers(0, max(nk, 8) + 2, nq).astype(np.int64)
+    qvalid = rng.random(nq) < density
+    want = _range_count_np(keys, q, qvalid)
+    got = J.range_count(jnp.asarray(keys), jnp.asarray(q), jnp.asarray(qvalid))
     for w, g, nm in zip(want, got, ("lo", "counts", "total")):
         assert (np.asarray(w) == np.asarray(g)).all(), (name, nm)
 
 
-def test_intersect_kernel_sentinel_padded_keys():
+def test_range_count_sentinel_padded_keys():
     """Keys arrive device-padded with the ``1 << 62`` sentinel (the
-    ``GraphIndex.edge_keys`` contract): the kernel's pow2 pad must stack
-    more sentinels without perturbing any real range."""
+    ``GraphIndex.edge_keys`` contract): sentinels sort past every real
+    query, so they never enter a counted range."""
     real = np.sort(np.random.default_rng(3).integers(0, 50, 37).astype(np.int64))
     padded = np.concatenate([real, np.full(7, 1 << 62, np.int64)])
-    q = jnp.asarray(np.arange(-2, 55, dtype=np.int64))
-    qvalid = jnp.ones(q.shape[0], bool)
-    want = PI._range_count_jnp(jnp.asarray(padded), q, qvalid)
-    got = PI._range_count_pallas(
-        jnp.asarray(padded), q, qvalid,
-        npow=bucketing.round_up_pow2(len(padded)), interpret=True,
-    )
-    for w, g in zip(want, got):
+    q = np.arange(-2, 55, dtype=np.int64)
+    qvalid = np.ones(q.shape[0], bool)
+    got = J.range_count(jnp.asarray(padded), jnp.asarray(q), jnp.asarray(qvalid))
+    base = _range_count_np(real, q, qvalid)
+    for w, g in zip(base, got):
         assert (np.asarray(w) == np.asarray(g)).all()
-    # and against the unpadded truth: sentinels are invisible
-    base = PI._range_count_jnp(jnp.asarray(real), q, qvalid)
-    assert (np.asarray(base[1]) == np.asarray(got[1])).all()
-
-
-def test_intersect_kernel_launches_and_declines(monkeypatch):
-    dispatch.MODE.set("interpret")
-    keys = jnp.asarray(np.arange(32, dtype=np.int64))
-    q = jnp.asarray(np.arange(16, dtype=np.int64))
-    ok = jnp.ones(16, bool)
-    lo, cnt, total = PI.intersect_range_count(keys, q, ok)
-    want = PI._range_count_jnp(keys, q, ok)
-    assert (np.asarray(want[1]) == np.asarray(cnt)).all()
-    assert int(total) == int(np.asarray(cnt).sum())
-    assert dispatch.use_counts()["intersect"]["pallas"] == 1
-    # past the VMEM residency cap the launch must decline to the
-    # searchsorted path (same results, no kernel) — pin the knob the
-    # cost model honors verbatim
-    monkeypatch.setenv("TPU_CYPHER_PALLAS_MAX_KEYS", "8")
-    lo2, cnt2, _ = PI.intersect_range_count(keys, q, ok)
-    assert (np.asarray(cnt2) == np.asarray(cnt)).all()
-    assert (np.asarray(lo2) == np.asarray(lo)).all()
-    assert dispatch.use_counts()["intersect"]["pallas"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -207,7 +188,6 @@ def test_engine_differential_with_kernels_on(loopy_oracle):
     for q in CYCLIC_CORPUS:
         got = [dict(r) for r in g.cypher(q).records.collect()]
         assert got == [dict(r) for r in loopy_oracle[q]], q
-    assert dispatch.use_counts()["intersect"]["pallas"] > 0
 
 
 def test_count_tier_on_loop_free_graph():
@@ -331,7 +311,7 @@ def test_auto_mode_hands_pure_count_back_to_fused_binary():
 
 
 # ---------------------------------------------------------------------------
-# fault injection at kernel_intersect: the full ladder
+# fault injection at the operator's expand site: the full ladder
 # ---------------------------------------------------------------------------
 
 KIND_TO_ERROR = {
@@ -352,19 +332,19 @@ def fault_graphs():
 
 @pytest.mark.parametrize("kind", sorted(KIND_TO_ERROR))
 @pytest.mark.parametrize("depth", ["1", "*"])
-def test_kernel_intersect_fault_matrix(fault_graphs, kind, depth):
+def test_wcoj_expand_fault_matrix(fault_graphs, kind, depth):
     g_tpu, g_loc = fault_graphs
     want = g_loc.cypher(TRIANGLE).records.to_bag()
 
     WCOJ_MODE.set("force")
     dispatch.MODE.set("interpret")
     bucketing.MODE.set("pow2")
-    faults.set_spec(f"{kind}@kernel_intersect:{depth}")
+    faults.set_spec(f"{kind}@expand:{depth}")
     r = g_tpu.cypher(TRIANGLE)
     got = r.records.to_bag()
     faults.set_spec(None)
 
-    assert got == want, f"kernel_intersect/{kind}:{depth} diverged"
+    assert got == want, f"expand/{kind}:{depth} diverged"
     log = r.execution_log
     assert log and log[-1]["ok"] is True
     failed = [e for e in log if not e["ok"]]
@@ -372,9 +352,8 @@ def test_kernel_intersect_fault_matrix(fault_graphs, kind, depth):
     for e in failed:
         assert e["error"] == KIND_TO_ERROR[kind].__name__, log
     if depth == "*":
-        # unlike the join/expand kernels, the intersect kernel runs at
-        # every device rung (range counting is not a bucketed-only branch)
-        # so only the host oracle escapes a persistent fault
+        # the operator passes its expand site at every device rung, so
+        # only the host oracle escapes a persistent fault
         assert log[-1]["rung"] == guard.RUNG_HOST, log
     else:
         assert log[-1]["rung"] not in (guard.RUNG_DEVICE, guard.RUNG_HOST), log
@@ -383,12 +362,6 @@ def test_kernel_intersect_fault_matrix(fault_graphs, kind, depth):
 # ---------------------------------------------------------------------------
 # guards: registry, config knobs, engine lint, compile flatness
 # ---------------------------------------------------------------------------
-
-
-def test_intersect_kernel_is_dispatch_registered():
-    spec = dispatch.registry()["intersect"]
-    assert spec.site == "kernel_intersect"
-    assert "_range_count_pallas" in spec.impls
 
 
 def test_wcoj_knobs_in_config_registry():
@@ -407,10 +380,7 @@ def test_engine_lint_clean_on_wcoj_modules():
         "backend",
         "tpu",
     )
-    targets = [
-        os.path.join(root, "wcoj.py"),
-        os.path.join(root, "pallas", "intersect.py"),
-    ]
+    targets = [os.path.join(root, "wcoj.py")]
     # parse the whole backend so interprocedural rules keep their
     # substrate; report only on the new modules (--changed-only semantics)
     report = analysis.run_paths([root], restrict_to=targets)

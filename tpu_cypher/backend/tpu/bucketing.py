@@ -33,6 +33,7 @@ bucketed results bit-identical to ``off``.
 
 from __future__ import annotations
 
+import pathlib
 import threading
 from typing import Dict, Optional
 
@@ -284,11 +285,18 @@ _PCACHE_MISSES = _REGISTRY.counter(
 
 _LISTENER_INSTALLED = False
 
+# the installed JAX times ``backend_compile_duration`` around the whole
+# compile-or-load step, so a program LOADED from the persistent cache fires
+# it too — right after its ``cache_hits`` event, on the same thread. The
+# flag lets that one duration event pass uncounted: a load is not a compile.
+_LOADED = threading.local()
+
 
 def _on_event_duration(name: str, secs: float, **_kw) -> None:
-    # '/jax/core/compile/backend_compile_duration' fires once per actual
-    # XLA compilation (cache hits emit no event)
     if name.endswith("backend_compile_duration"):
+        if getattr(_LOADED, "from_cache", False):
+            _LOADED.from_cache = False
+            return
         _COMPILES_TOTAL.inc()
         _COMPILE_SECONDS_TOTAL.inc(float(secs))
 
@@ -298,6 +306,7 @@ def _on_event(name: str, **_kw) -> None:
     # the persistent (disk) cache when one is enabled
     if name.endswith("compilation_cache/cache_hits"):
         _PCACHE_HITS.inc()
+        _LOADED.from_cache = True
     elif name.endswith("compilation_cache/cache_misses"):
         _PCACHE_MISSES.inc()
 
@@ -347,30 +356,37 @@ def compile_delta(before: Dict[str, float]) -> Dict[str, float]:
 # persistent compilation cache
 # ---------------------------------------------------------------------------
 
-_CACHE_DIR: Optional[str] = None
+# where compiled programs persist when the environment names no place: one
+# fixed directory beside the package (the checkout's root). The path is
+# part of the cache key, so it must never move — no tempfile, pid or clock.
+DEFAULT_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+)
 
 
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so warm
-    caches survive process restarts (the disk tier under the in-process
-    jit caches; shape bucketing keeps the entry count bounded). Safe to
-    call repeatedly with the same directory."""
-    global _CACHE_DIR
+def enable_persistent_cache() -> None:
+    """Turn on JAX's persistent compilation cache so warm programs survive
+    process restarts and are shared by every engine process of a
+    deployment (the disk tier under the in-process jit caches; shape
+    bucketing keeps the entry count bounded). The directory is placed from
+    OUTSIDE: ``JAX_COMPILATION_CACHE_DIR`` (JAX reads it itself) wins, and
+    only when nothing has named one does the cache land in
+    ``DEFAULT_CACHE_DIR``. Idempotent."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     # default thresholds skip small/fast programs — the engine's composites
     # are exactly those, and they are the ones worth persisting
-    for k, v in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(k, v)
-        except Exception:  # fault-ok: older/newer JAX without the knob
-            pass
-    _CACHE_DIR = cache_dir
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def persistent_cache_dir() -> Optional[str]:
-    return _CACHE_DIR
+    """The persistent-cache directory in force, or None while the cache is
+    off (disabled, or no engine session has enabled it yet)."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir or None

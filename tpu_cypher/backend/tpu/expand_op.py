@@ -620,12 +620,7 @@ class CsrExpandOp(_FusedExpandBase):
         )
         if bucketing.enabled():
             size = bucketing.round_size(total)
-            # kernel tier: the Pallas row-search materialize when eligible
-            # (dispatch falls back to the jnp repeat cascade; see
-            # backend/tpu/pallas/expand.py)
-            from .pallas import expand_materialize_counted
-
-            row, nbr, orig, live = expand_materialize_counted(
+            row, nbr, orig, live = J.expand_materialize_counted(
                 rp, ci, eo, pos, deg, t_dev, size=size
             )
             if drop_loops and total:
@@ -709,14 +704,11 @@ class CsrExpandOp(_FusedExpandBase):
                 gi, ctx, hops, id_col, final, carry, mask_pairs
             )
         if len(hops) == 1 and not self.undirected and not self.far_labels:
-            # single unrestricted hop: O(frontier) Pallas degree-sum (VMEM
-            # tiling) beats the chain's O(edges) SpMV
-            from .pallas_kernels import csr_frontier_degree_sum
-
+            # single unrestricted hop: the O(frontier) degree sum beats
+            # the chain's O(edges) SpMV
             pos, present = gi.compact_of(id_col, ctx)
             rp, _, _ = gi.csr(self.types_key, self.backwards, ctx)
-            max_deg, _ = gi.csr_degree_stats(self.types_key, self.backwards, ctx)
-            return int(csr_frontier_degree_sum(rp, pos, present, max_deg=max_deg))
+            return int(J.frontier_degree_sum(rp, pos, present))
         hop_data = []
         for hop in reversed(hops):  # deepest (first executed) hop first
             mask = gi.label_mask(hop.far_labels, ctx)

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -48,7 +48,6 @@ class CsrGraph:
     row_ptr: jnp.ndarray
     col_idx: jnp.ndarray
     src_idx: jnp.ndarray
-    _max_deg: Optional[int] = None
 
     @property
     def num_nodes(self) -> int:
@@ -98,37 +97,17 @@ class CsrGraph:
     def degrees(self) -> jnp.ndarray:
         return self.row_ptr[1:] - self.row_ptr[:-1]
 
-    @property
-    def max_degree(self) -> int:
-        """Host-cached max out-degree — the Pallas frontier kernel's
-        eligibility input (``two_hop_count(..., max_deg=)``); one sync,
-        paid once per graph."""
-        if self._max_deg is None:
-            # tpulint: allow[host-sync] reason=one cached sync per graph at ingest (kernel eligibility input), not on the per-query path
-            self._max_deg = int(jnp.max(self.degrees)) if self.num_nodes else 0
-        return self._max_deg
-
 
 # ---------------------------------------------------------------------------
 # 2-hop (Expand -> Expand)
 # ---------------------------------------------------------------------------
 
 
-def two_hop_count(
-    row_ptr: jnp.ndarray, col_idx: jnp.ndarray, max_deg: Optional[int] = None
-) -> jnp.ndarray:
-    """Number of 2-hop paths a->b->c = sum over edges (a,b) of outdeg(b).
-
-    This is exactly the frontier degree-sum shape (frontier = ``col_idx``,
-    every slot present), so it rides the Pallas kernel tier when active —
-    pass ``max_deg`` (``CsrGraph.max_degree``) for eligibility; without it
-    the dispatch layer keeps the jitted gather+sum formulation."""
-    from .pallas import csr_frontier_degree_sum
-
-    present = jnp.ones(col_idx.shape[0], bool)
-    return csr_frontier_degree_sum(
-        row_ptr, col_idx.astype(jnp.int64), present, max_deg=max_deg
-    )
+@jax.jit
+def two_hop_count(row_ptr: jnp.ndarray, col_idx: jnp.ndarray) -> jnp.ndarray:
+    """Number of 2-hop paths a->b->c = sum over edges (a,b) of outdeg(b)."""
+    deg = row_ptr[1:] - row_ptr[:-1]
+    return jnp.sum(jnp.take(deg, col_idx).astype(jnp.int64))
 
 
 @partial(jax.jit, static_argnames=("total", "count_distinct"))

@@ -1,26 +1,17 @@
-"""Hand-scheduled Pallas kernel suite for the query hot path.
+"""Hand-scheduled Pallas kernels for the query hot path.
 
 One package, one policy: every kernel registers with ``dispatch`` (mode /
-eligibility / broken-once fallback / fault sites / use counters) and ships
-next to the jnp formulation it replaces, so ``TPU_CYPHER_PALLAS=off`` is
-always the exact pre-kernel execution path and ``=interpret`` runs the
-identical programs on any backend (tier-1 parity). See
-docs/performance.md ("kernel tiers") and docs/pad-invariants.md.
+eligibility / fault sites / use counters) and ships next to the jnp
+formulation it replaces, so ``TPU_CYPHER_PALLAS=off`` is always the exact
+pre-kernel execution path and ``=interpret`` runs the identical programs
+on any backend (tier-1 parity). A kernel stays here only while the chip's
+compiler accepts it at its eligibility cap (``tests/test_chip_compile.py``)
+— see docs/performance.md ("kernel tiers") and docs/pad-invariants.md.
 
 Kernels:
 
-* ``frontier.csr_frontier_degree_sum`` — frontier degree-sum reduction
-* ``join.join_probe_bucketed``         — hash-join probe (open addressing)
-* ``expand.expand_materialize_counted`` — CSR expand row-search materialize
-* ``aggregate.segment_aggregate``       — masked grouped segment reduce
-* ``intersect.intersect_range_count``   — WCOJ sorted-key range count
+* ``aggregate.segment_aggregate`` — masked grouped segment reduce
 """
 
 from . import dispatch  # noqa: F401
 from .aggregate import segment_aggregate  # noqa: F401
-from .expand import expand_materialize_counted  # noqa: F401
-from .frontier import csr_frontier_degree_sum  # noqa: F401
-from .intersect import intersect_range_count  # noqa: F401
-from .join import join_probe_bucketed  # noqa: F401
-
-HAVE_PALLAS = dispatch.HAVE_PALLAS

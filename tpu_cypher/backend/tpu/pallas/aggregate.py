@@ -4,27 +4,27 @@
 with ``jax.ops.segment_sum``/``segment_min``/``segment_max`` — XLA lowers
 those as scatter-reduces, which the TPU serializes (SURVEY: scatter is the
 one primitive the VPU cannot vectorize). The hand-scheduled version never
-scatters: each (8, 128) value tile reduces into a PER-PROGRAM partial
-vector of all ``k`` groups via a broadcast compare against a group iota
-(k × 1024 VPU lanes per tile), and the per-program partials — written to
-independent output rows, no cross-program races — combine with one dense
-tree reduction outside the kernel. Group counts stay small on the query
-hot path (GROUP BY cardinality), so the k × BLOCK compare matrix stays
-comfortably inside VMEM; eligibility caps ``k``.
+scatters: the rows stream through VMEM in ``(_TILE_ROWS, 128)`` tiles, and
+each 128-lane row is compared against a group iota broadcast along
+sublanes, folding into ONE VMEM-resident ``(k_pad, 128)`` accumulator of
+per-(group, lane) partials that every grid step revisits. The 128 lane
+partials per group combine with one dense reduction outside the kernel.
+Nothing in the kernel crosses lanes or relayouts a vector, and every block
+obeys the TPU lowering's (8, 128) rule.
 
-Masking discipline (docs/pad-invariants.md): invalid rows AND kernel tile
-pad lanes carry segment id -1, which matches no group lane — mask-dead
-INSIDE the kernel, not at the materialize boundary. Identities (int max /
-±inf) mirror ``segment_aggregate``'s exactly, so empty groups come out
-bit-identical to the ``jax.ops.segment_*`` formulation, including the
-sentinel payloads that validity masks hide downstream.
+Lane width: Mosaic has no 64-bit lanes (and XLA's x64 rewriter cannot
+split a custom call's int64 operand), so the kernel takes 32-bit planes
+only. That covers ``count`` over any column (a 0/1 int32 mask) and
+min/max over BOOL (0/1 int32); int64 / float64 / dict-coded values keep
+the scatter formulation by eligibility — a 64-bit kernel is never handed
+to the compiler.
 
-Exactness: integer sum/count are associative (mod 2**64 — even a wrapped
-int64 sum matches); min/max are associative for ints and for the NaN-free
-floats ``segment_aggregate`` feeds them. Float SUMS are NOT associative
-and stay on the jnp formulation (eligibility), as do the aggregate names
-(avg/stdev/percentile/collect/duration) whose post-processing the oracle
-path owns.
+Masking discipline (docs/pad-invariants.md): kernel tile pad lanes carry
+segment id -1, which matches no group lane — mask-dead INSIDE the kernel,
+not at the materialize boundary. Identities mirror ``segment_aggregate``'s
+exactly, so empty groups come out bit-identical to the ``jax.ops.segment_*``
+formulation, including the sentinel payloads that validity masks hide
+downstream.
 """
 
 from __future__ import annotations
@@ -33,87 +33,87 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
+from ....utils.config import PALLAS_MAX_GROUPS
 from . import dispatch
 from .. import jit_ops as J
-from ..jit_ops import BOOL, F64, I64, STR
+from ..jit_ops import BOOL
 
-if dispatch.HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-
-_ROWS = 8
 _LANES = 128
-_BLOCK = _ROWS * _LANES
+# rows of 128 lanes per grid step: 128 KiB per int32 input tile
+_TILE_ROWS = 256
+_BLOCK = _TILE_ROWS * _LANES
 
-# group-count cap: the (k_pad, BLOCK) compare matrix at 8 B/lane stays
-# ~2 MiB; larger GROUP BYs keep the scatter formulation. Declared-default
-# mirror; eligibility routes through ``optimizer.cost.pallas_cap`` so a
-# ``TPU_CYPHER_PALLAS_MAX_GROUPS`` pin is honored verbatim.
-MAX_GROUPS = 256
-
-
-def _max_groups() -> int:
-    from ....optimizer.cost import pallas_cap
-
-    return pallas_cap("aggregate")
+def _i32(x: int):
+    # index arithmetic stays int32 under JAX_ENABLE_X64: Mosaic refuses an
+    # i64 block index or loop counter
+    return jnp.int32(x)
 
 
-def _seg_reduce_kernel_for(op: str, identity):
+def _seg_reduce_kernel_for(op: str, identity: int):
     def kernel(vals_ref, seg_ref, out_ref):
-        v = vals_ref[...].reshape(1, _BLOCK)
-        s = seg_ref[...].reshape(1, _BLOCK)
-        k_pad = out_ref.shape[1]
-        kidx = jax.lax.broadcasted_iota(jnp.int32, (k_pad, _BLOCK), 0)
-        m = s == kidx  # dead lanes carry -1: never matches a group lane
-        if op == "sum":
-            # dtype pinned: under JAX_ENABLE_X64 jnp.sum promotes int32
-            # partials to int64 (numpy semantics), which the out_ref rejects
-            out_ref[0, :] = jnp.sum(
-                jnp.where(m, v, jnp.zeros((), v.dtype)), axis=1, dtype=v.dtype
-            )
-        elif op == "min":
-            out_ref[0, :] = jnp.min(jnp.where(m, v, identity), axis=1)
-        else:
-            out_ref[0, :] = jnp.max(jnp.where(m, v, identity), axis=1)
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
+
+        kidx = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+        ident = jnp.full(out_ref.shape, identity, out_ref.dtype)
+
+        def body(r, acc):
+            v = vals_ref[pl.ds(r, 1), :]
+            s = seg_ref[pl.ds(r, 1), :]
+            # dead lanes carry -1: never matches a group lane
+            x = jnp.where(s == kidx, v, ident)
+            if op == "sum":
+                return acc + x
+            if op == "min":
+                return jnp.minimum(acc, x)
+            return jnp.maximum(acc, x)
+
+        out_ref[...] = jax.lax.fori_loop(
+            _i32(0), _i32(vals_ref.shape[0]), body, out_ref[...]
+        )
 
     return kernel
 
 
 @partial(jax.jit, static_argnames=("identity", "op", "k", "interpret"))
-def _seg_reduce_pallas(vals, seg, identity, op: str, k: int, interpret: bool):
-    """One segment reduction, exactly ``jax.ops.segment_<op>(vals, seg,
-    num_segments=k)``: tile the rows, per-program partials over all
-    groups, dense combine. Only kernel TILE PAD lanes carry segment -1
-    (mask-dead inside the kernel); value-level masking is the CALLER's,
-    same as the scatter formulation's ``where``-fed inputs — so per-group
-    results (including the empty-group identity) are bit-identical.
-    ``identity`` is the op's neutral element as a STATIC Python scalar
-    (Pallas kernels cannot close over traced values)."""
+def _seg_reduce_pallas(vals, seg, identity: int, op: str, k: int, interpret: bool):
+    """One int32 segment reduction, exactly ``jax.ops.segment_<op>(vals,
+    seg, num_segments=k)``: tile the rows, fold every tile into the
+    resident per-(group, lane) accumulator, dense lane combine. Only
+    kernel TILE PAD lanes carry segment -1 (mask-dead inside the kernel);
+    value-level masking is the CALLER's, same as the scatter formulation's
+    ``where``-fed inputs — so per-group results (including the empty-group
+    identity) are bit-identical. ``identity`` is the op's neutral element
+    as a STATIC Python scalar (Pallas kernels cannot close over traced
+    values)."""
     n = vals.shape[0]
     npad = ((max(n, 1) + _BLOCK - 1) // _BLOCK) * _BLOCK
-    k_pad = ((k + _LANES - 1) // _LANES) * _LANES
+    k_pad = ((k + 7) // 8) * 8
     pad = npad - n
     if pad:
         vals = jnp.concatenate([vals, jnp.full(pad, identity, vals.dtype)])
         seg = jnp.concatenate([seg, jnp.full(pad, -1, seg.dtype)])
     shape2d = (npad // _LANES, _LANES)
-    grid = (npad // _BLOCK,)
+    tile = pl.BlockSpec((_TILE_ROWS, _LANES), lambda i: (i, _i32(0)))
     partials = pl.pallas_call(
         _seg_reduce_kernel_for(op, identity),
-        out_shape=jax.ShapeDtypeStruct((grid[0], k_pad), vals.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, k_pad), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((k_pad, _LANES), jnp.int32),
+        grid=(npad // _BLOCK,),
+        in_specs=[tile, tile],
+        # every grid step revisits the one accumulator block
+        out_specs=pl.BlockSpec(
+            (k_pad, _LANES), lambda i: (_i32(0), _i32(0))
+        ),
         interpret=interpret,
     )(vals.reshape(shape2d), seg.reshape(shape2d))
     if op == "sum":
-        return jnp.sum(partials, axis=0)[:k]
+        return jnp.sum(partials, axis=1, dtype=jnp.int32)[:k]
     if op == "min":
-        return jnp.min(partials, axis=0)[:k]
-    return jnp.max(partials, axis=0)[:k]
+        return jnp.min(partials, axis=1)[:k]
+    return jnp.max(partials, axis=1)[:k]
 
 
 dispatch.register(
@@ -121,14 +121,14 @@ dispatch.register(
 )
 
 
-@partial(jax.jit, static_argnames=("name", "kind", "k", "interpret"))
+@partial(jax.jit, static_argnames=("name", "k", "interpret"))
 def _segment_aggregate_pallas(
-    data, valid, seg_j, name: str, kind: str, k: int, interpret: bool
+    data, valid, seg_j, name: str, k: int, interpret: bool
 ):
     """Kernel-backed mirror of ``jit_ops.segment_aggregate`` for the
-    eligible subset (count / int sum / min / max, no int_flag). Every
-    masking rule, orderability identity, and output dtype matches the
-    scatter formulation bit-for-bit — pinned by the differential tests."""
+    eligible subset (count over anything; min / max over BOOL). Every
+    masking rule, identity, and output dtype matches the scatter
+    formulation bit-for-bit — pinned by the differential tests."""
     n = data.shape[0]
     v = valid if valid is not None else jnp.ones(n, bool)
     seg32 = seg_j.astype(jnp.int32)
@@ -137,78 +137,45 @@ def _segment_aggregate_pallas(
     ).astype(jnp.int64)
     if name == "count":
         return cnt, None, None, None
-    if name == "sum":  # I64 only (eligibility): zero-filled masked lanes
-        ssum = _seg_reduce_pallas(
-            jnp.where(v, data, 0).astype(jnp.int64), seg32, 0, "sum", k,
-            interpret,
-        )
-        return ssum, None, None, None
-    # min / max with Cypher orderability, mirroring segment_aggregate
-    # value-for-value (invalid rows participate carrying the identity-side
-    # sentinel, empty groups come out as the segment op's identity): BOOL
-    # compares as 0/1 ints (int32 here — the int8 min-tile shape is
-    # (32, 128), hostile to the shared (8, 128) grid; the bool output is
-    # identical), F64 keeps NaN as its own class above numbers
-    d = data.astype(jnp.int32) if kind == BOOL else data
-    if kind == F64:
-        isnan = jnp.isnan(d) & v
-        nn_valid = v & ~isnan
-        nan_cnt = _seg_reduce_pallas(
-            isnan.astype(jnp.int32), seg32, 0, "sum", k, interpret
-        ).astype(jnp.int64)
-    else:
-        nn_valid = v
-        nan_cnt = None
-    big = float("inf") if kind == F64 else int(jnp.iinfo(d.dtype).max)
-    lowest = float("-inf") if kind == F64 else int(jnp.iinfo(d.dtype).min)
+    # BOOL min / max compare as 0/1 ints, mirroring segment_aggregate
+    # value-for-value: invalid rows participate carrying the identity-side
+    # sentinel, empty groups come out as the segment op's identity
+    d = data.astype(jnp.int32)
+    big = int(jnp.iinfo(jnp.int32).max)
+    lowest = int(jnp.iinfo(jnp.int32).min)
     if name == "min":
         agged = _seg_reduce_pallas(
-            jnp.where(nn_valid, d, big), seg32, big, "min", k, interpret
+            jnp.where(v, d, big), seg32, big, "min", k, interpret
         )
-        if nan_cnt is not None:
-            agged = jnp.where(
-                (cnt - nan_cnt == 0) & (nan_cnt > 0), jnp.nan, agged
-            )
     else:
-        low = -big if kind != STR else -1
         agged = _seg_reduce_pallas(
-            jnp.where(nn_valid, d, low), seg32, lowest, "max", k, interpret
+            jnp.where(v, d, -big), seg32, lowest, "max", k, interpret
         )
-        if nan_cnt is not None:
-            agged = jnp.where(nan_cnt > 0, jnp.nan, agged)
-    if kind == BOOL:
-        agged = agged.astype(bool)
-    return agged, cnt > 0, None, None
+    return agged.astype(bool), cnt > 0, None, None
 
 
 def segment_aggregate(data, valid, iflag, seg_j, *, name: str, kind: str, k: int):
     """Dispatching drop-in for ``jit_ops.segment_aggregate`` (same 4-tuple
-    contract). Eligible: count over anything; sum over I64 (associative
-    exact — float sums reorder); min/max over I64/BOOL/STR/F64 when no
-    int_flag bookkeeping rides along (the first-occurrence row hunt stays
-    with the oracle formulation). GROUP BY cardinality is capped by the
-    VMEM compare-matrix budget."""
+    contract). Eligible: count over anything; min/max over BOOL — the
+    aggregates whose working planes are 32-bit (see the module docstring).
+    GROUP BY cardinality is capped (``TPU_CYPHER_PALLAS_MAX_GROUPS``): the
+    (k_pad, 128) int32 accumulator is 128 KiB (32 vregs) at the declared
+    default; larger GROUP BYs keep the scatter formulation."""
     eligible = (
-        0 < k <= _max_groups()
+        0 < k <= int(PALLAS_MAX_GROUPS.get())
         and data.ndim == 1
         and (
             name == "count"
-            or (name == "sum" and kind == I64 and iflag is None)
-            or (
-                name in ("min", "max")
-                and kind in (I64, BOOL, STR, F64)
-                and iflag is None
-            )
+            or (name in ("min", "max") and kind == BOOL and iflag is None)
         )
     )
     return dispatch.launch(
         "segment_agg",
         lambda interpret: _segment_aggregate_pallas(
-            data, valid, seg_j, name=name, kind=kind, k=k, interpret=interpret
+            data, valid, seg_j, name=name, k=k, interpret=interpret
         ),
         lambda: J.segment_aggregate(
             data, valid, iflag, seg_j, name=name, kind=kind, k=k
         ),
         eligible=eligible,
-        variant=str(data.dtype),
     )

@@ -1,13 +1,7 @@
 """Kernel dispatch: ONE gate between the engine and every Pallas program.
 
-The first hand-scheduled kernel (the frontier degree-sum) carried its own
-ad-hoc policy: a module-global ``_PALLAS_BROKEN`` flag, an inline backend
-check, an inline eligibility test. With a kernel SUITE that policy must be
-shared and per-kernel, or one bad Mosaic lowering poisons every kernel and
-no two kernels agree on when they may run. This module is that policy:
-
 * **mode** — ``TPU_CYPHER_PALLAS=auto|interpret|off``:
-  ``auto`` (default) compiles kernels on a TPU backend and falls back to
+  ``auto`` (default) compiles kernels on a TPU backend and answers from
   the jnp formulation elsewhere; ``interpret`` runs the IDENTICAL Pallas
   programs through the interpreter on any backend (tier-1/CPU parity —
   the differential tests pin them bit-identical to the jnp oracle);
@@ -15,36 +9,30 @@ no two kernels agree on when they may run. This module is that policy:
 * **registry** — every kernel registers (name, fault site, the names of
   the functions that contain its raw ``pl.pallas_call``). The AST guard
   test walks ``backend/tpu`` and fails on any ``pallas_call`` outside a
-  registered impl — no kernel can bypass eligibility/fallback.
-* **broken-once memoization** — a Mosaic lowering failure on a real TPU is
-  remembered PER (kernel, variant) so it is paid once, not per query.
-  ``interpret``-mode failures are never memoized (a forced-interpret
-  lowering failure in one test must not poison the next) and re-raise.
+  registered impl — no kernel can bypass eligibility.
+* **no hidden fallback** — a kernel that is selected either runs or
+  raises: a lowering refusal on the compiled path surfaces as a typed
+  ``CompileFailure`` (the session ladder handles it in the open, and
+  ``tests/test_chip_compile.py`` keeps every kernel compiling for the
+  chip at its eligibility cap). Only a data-dependent DECLINE
+  (``pallas_fn`` returns ``None``) hands the call to the jnp formulation.
 * **fault sites** — each launch passes through ``fault_point(site)``, so
-  ``TPU_CYPHER_FAULTS=oom@kernel_join:1`` etc. drive the PR-2 ladder
+  ``TPU_CYPHER_FAULTS=oom@kernel_agg:1`` etc. drive the degrade ladder
   through the kernel tier with no TPU attached.
 * **use counters** — per-kernel pallas/fallback counts served by the
-  unified obs registry (``tpu_cypher_pallas_launch_total``); bench.py
-  records which tier each rung actually used, and each launch opens a
-  ``kernel:<name>`` trace span carrying the tier it resolved to.
+  unified obs registry (``tpu_cypher_pallas_launch_total``), and each
+  launch opens a ``kernel:<name>`` trace span carrying the tier it
+  resolved to.
 """
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from ....obs import trace as _obs_trace
 from ....obs.metrics import REGISTRY as _REGISTRY
 from ....utils.config import PALLAS_MODE as MODE
-
-try:  # pragma: no cover - availability depends on the jax build
-    from jax.experimental import pallas as pl  # noqa: F401
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover - fault-ok: import probe only
-    HAVE_PALLAS = False
 
 # auto      — compiled kernels on a TPU backend, jnp fallback elsewhere
 # interpret — interpreted kernels on ANY backend (tests/CPU parity)
@@ -70,8 +58,6 @@ class KernelSpec:
 
 
 _KERNELS: Dict[str, KernelSpec] = {}
-_BROKEN: Dict[str, str] = {}  # "name" or "name/variant" -> repr(exc)
-_LOCK = threading.Lock()
 
 # per-kernel launch counts, served by the unified obs registry
 # (docs/observability.md): tier="pallas" is a real kernel launch,
@@ -95,30 +81,9 @@ def registry() -> Dict[str, KernelSpec]:
     return dict(_KERNELS)
 
 
-def broken() -> Dict[str, str]:
-    """Snapshot of memoized lowering failures (diagnostics/bench)."""
-    with _LOCK:
-        return dict(_BROKEN)
-
-
-def is_broken(name: str, variant: str = "") -> bool:
-    key = f"{name}/{variant}" if variant else name
-    with _LOCK:
-        return key in _BROKEN
-
-
 def reset(name: Optional[str] = None) -> None:
-    """Clear broken memoization (and counters) — for tests and for an
-    operator who swapped in a fixed jax/libtpu build mid-process. ``name``
-    limits the reset to one kernel's entries."""
-    with _LOCK:
-        if name is None:
-            _BROKEN.clear()
-        else:
-            for key in [
-                k for k in _BROKEN if k == name or k.startswith(name + "/")
-            ]:
-                del _BROKEN[key]
+    """Zero the launch counters (tests); ``name`` limits the reset to one
+    kernel's series."""
     if name is None:
         PALLAS_LAUNCH.reset()
     else:
@@ -148,65 +113,47 @@ def launch(
     fallback_fn: Callable[[], Any],
     *,
     eligible: bool = True,
-    variant: str = "",
-    force_interpret: bool = False,
 ) -> Any:
     """Run ``pallas_fn(interpret=...)`` when the kernel tier is active for
     ``name``, else ``fallback_fn()``.
 
     ``eligible``: the caller's per-call shape/dtype/VMEM verdict.
-    ``variant``: sub-key for broken-once memoization (e.g. a dtype — an
-    f64 lowering failure must not disable the int64 variant).
-    ``force_interpret``: per-call interpreter override (tests exercising
-    kernel semantics off-TPU regardless of mode).
 
     A ``pallas_fn`` may return ``None`` to DECLINE after a data-dependent
-    check (e.g. the hash build didn't converge) — the fallback runs and
-    nothing is memoized. Exceptions from an interpreted program re-raise
-    (real bugs, never memoized); a compiled-path failure is classified
-    first (``reraise_if_device`` — an OOM mid-kernel must surface typed to
-    the ladder, not masquerade as a lowering problem), then memoized
-    broken-once and the jnp formulation takes over.
+    check — the fallback runs. Exceptions from an interpreted program
+    re-raise as they are (real bugs); a compiled-path failure is
+    classified (``reraise_if_device`` — an OOM mid-kernel surfaces typed
+    to the ladder as what it is) and anything else the lowering raised
+    becomes a typed ``CompileFailure``. It never turns into a silent
+    fallback.
     """
     spec = _KERNELS[name]
     m = mode()
-    key = f"{name}/{variant}" if variant else name
-    active = (
-        HAVE_PALLAS
-        and eligible
-        and not is_broken(name, variant)
-        and (
-            force_interpret
-            or (
-                m != "off"
-                and (m == "interpret" or _backend_is_tpu())
-            )
-        )
-    )
+    interp = m == "interpret"
+    active = eligible and (interp or (m != "off" and _backend_is_tpu()))
     with _obs_trace.span(f"kernel:{name}", kind="kernel") as sp:
         if not active:
             sp.note("tier", "fallback")
             _count(name, "fallback")
             return fallback_fn()
-        interp = force_interpret or m == "interpret" or not _backend_is_tpu()
         from ....runtime.faults import fault_point
 
         fault_point(spec.site)
         try:
             out = pallas_fn(interpret=interp)
         except Exception as exc:
-            from ....errors import reraise_if_device
-
-            reraise_if_device(exc, site=spec.site)
             if interp:
                 raise
-            with _LOCK:
-                _BROKEN[key] = repr(exc)
-            sp.note("tier", "fallback")
-            sp.note("broken", True)
-            _count(name, "fallback")
-            return fallback_fn()
-        if out is None:  # kernel declined post-eligibility (build didn't fit)
+            from ....errors import CompileFailure, reraise_if_device
+
+            reraise_if_device(exc, site=spec.site)
+            raise CompileFailure(
+                f"[site={spec.site}] Pallas kernel {name!r} was refused by "
+                f"the TPU lowering: {type(exc).__name__}: {exc}",
+                site=spec.site,
+                cause=exc,
+            ) from exc
+        if out is None:  # kernel declined post-eligibility
             sp.note("tier", "fallback")
             sp.note("declined", True)
             _count(name, "fallback")

@@ -28,10 +28,9 @@ MINIMUM-degree list and binary-searches the others. Total expanded lanes
 are bounded by sum(min_k deg_k) — the AGM-style bound that keeps the
 SF10 triangle at ~E*log instead of ~E*d rows. All intermediate sizes
 round up the bucket lattice (one compiled program per bucket, pad lanes
-masked dead), the sorted-range search dispatches to the hand-scheduled
-``pallas/intersect.py`` kernel behind the usual registry, and every
-failure degrades: kernel -> jnp searchsorted (dispatch), fused op ->
-classic shadow plan (``GraphIndexError``), query -> guard ladder.
+masked dead), the sorted-range search is ``jit_ops.range_count``, and
+every failure degrades: fused op -> classic shadow plan
+(``GraphIndexError``), query -> guard ladder.
 
 Bag semantics match the classic cascade by construction: one output row
 per (input row, pivot edge, close-edge combination), candidate label
@@ -379,7 +378,6 @@ class MultiwayIntersectOp(_FusedExpandBase):
         whose minimum-degree list is that arm, range-count every other
         list, multiply, sum. No output materialize, no acyclic
         intermediate; expanded lanes total sum(min_k deg_k)."""
-        from . import pallas as P
 
         fault_point("expand")  # the per-arm count-tier syncs below
 
@@ -406,7 +404,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
             bucketing.admit(n_a, 24 + 8 * (len(lists) - 1), "intersect")
             if bucketed:
                 size = bucketing.round_size(n_a)
-                row, cand, _, live = P.expand_materialize_counted(
+                row, cand, _, live = J.expand_materialize_counted(
                     lst.rp, lst.ci, lst.eo, lst.pos, deg_a, t_dev, size=size
                 )
             else:
@@ -424,7 +422,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
                 if mesh_tier is not None:
                     cnt = mesh_count(other.keys, q, qok)
                 else:
-                    _, cnt, _ = P.intersect_range_count(other.keys, q, qok)
+                    _, cnt, _ = J.range_count(other.keys, q, qok)
                 m = cnt if m is None else _mul(m, cnt)
             if mask is not None:
                 m = _apply_label_mask(m, mask, cand)
@@ -441,7 +439,6 @@ class MultiwayIntersectOp(_FusedExpandBase):
         whose rel vars someone reads) runs through
         :meth:`_materialize_multi_close` instead of declining to the
         shadow."""
-        from . import pallas as P
         from .table import TpuTable
         from ...optimizer.cost import prefer_factorized
 
@@ -456,7 +453,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
         bucketed = bucketing.enabled()
         if bucketed:
             size = bucketing.round_size(total)
-            row, cand, orig_p, live = P.expand_materialize_counted(
+            row, cand, orig_p, live = J.expand_materialize_counted(
                 pivot.rp, pivot.ci, pivot.eo, pivot.pos, deg, t_dev, size=size
             )
         else:
@@ -465,7 +462,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
             )
             live = None
         q, qok = _probe_queries(close.pos, close.ok, row, cand, live, n=n)
-        lo, m, out_dev = P.intersect_range_count(close.keys, q, qok)
+        lo, m, out_dev = J.range_count(close.keys, q, qok)
         if mask is not None:
             m = _apply_label_mask(m, mask, cand)
             out_dev = _sum_counts(m)
@@ -545,7 +542,6 @@ class MultiwayIntersectOp(_FusedExpandBase):
         the output stays a ``FactorizedTable``, or the decode walks the
         runs directly at the OUTPUT extent (cycle-count-sized).
         ``TPU_CYPHER_FACTORIZE=off`` keeps the classic decline-to-shadow."""
-        from . import pallas as P
         from .factorized import _decode_runs, _runs_weights, factorize_mode
         from .table import TpuTable
         from ...optimizer.cost import prefer_factorized
@@ -564,7 +560,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
         bucketed = bucketing.enabled()
         if bucketed:
             size = bucketing.round_size(total)
-            row, cand, orig_p, live = P.expand_materialize_counted(
+            row, cand, orig_p, live = J.expand_materialize_counted(
                 pivot.rp, pivot.ci, pivot.eo, pivot.pos, deg, t_dev, size=size
             )
         else:
@@ -575,7 +571,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
         los, cnts = [], []
         for j, close in enumerate(closes):
             q, qok = _probe_queries(close.pos, close.ok, row, cand, live, n=n)
-            lo_j, m_j, _ = P.intersect_range_count(close.keys, q, qok)
+            lo_j, m_j, _ = J.range_count(close.keys, q, qok)
             if j == 0 and mask is not None:
                 m_j = _apply_label_mask(m_j, mask, cand)
             los.append(lo_j)
@@ -815,8 +811,7 @@ class MultiwayIntersectOp(_FusedExpandBase):
         from .table import TpuTable
 
         # the multiway count/materialize syncs sit behind the expand-class
-        # fault site like every other fused CSR operator; the kernel tier
-        # adds its own kernel_intersect site per dispatch
+        # fault site like every other fused CSR operator
         fault_point("expand")
         gi = GraphIndex.of(self.graph)
         ctx = self.context
@@ -908,7 +903,7 @@ def _est_binary_blowup(gi: GraphIndex, ctx, types_key, rev: bool) -> int:
     got = cache.get((types_key, rev))
     if got is None:
         s, _, _ = gi._edge_endpoints(types_key, ctx)
-        max_deg, _ = gi.csr_degree_stats(types_key, rev, ctx)
+        max_deg = gi.csr_max_degree(types_key, rev, ctx)
         got = cache[(types_key, rev)] = int(len(s)) * int(max(max_deg, 1))
     return got
 

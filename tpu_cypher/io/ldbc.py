@@ -103,12 +103,13 @@ def load_snb_csv(directory: str, session, delimiter: str = "|") -> ScanGraph:
     )
 
 
-def generate_snb(
-    scale: float, session, seed: int = 42
-) -> ScanGraph:
-    """Synthetic SNB-like Person/KNOWS graph. ``scale=1.0`` approximates SF1
-    density (~10k persons, ~450k directed KNOWS edges); degrees are
-    power-law-ish (preferential-attachment flavored)."""
+def snb_arrays(scale: float, seed: int = 42) -> Dict[str, np.ndarray]:
+    """The generator's host arrays, before any ingest: ``ids``,
+    ``birthday`` and (up to 200k persons) ``firstname`` per person, and
+    the KNOWS endpoints ``src``/``dst`` as person ids. ``scale=1.0``
+    approximates SF1 density (~10k persons, ~450k directed KNOWS edges);
+    degrees are power-law-ish (preferential-attachment flavored).
+    Deterministic per seed."""
     num_people = max(2, int(10_000 * scale))
     num_knows = int(num_people * 45)
     rng = np.random.default_rng(seed)
@@ -118,12 +119,31 @@ def generate_snb(
     src_i = np.where(rng.random(num_knows) < 0.5, head, uni)
     dst_i = rng.integers(0, num_people, size=num_knows)
     keep = src_i != dst_i
-    src, dst = ids[src_i[keep]], ids[dst_i[keep]]
-    # birthday: days-since-epoch ints (IS3-style property filters); numpy so
-    # the bulk ingestion path stays one H2D copy per column at SF10 scale
-    person_cols: Dict[str, List] = {
-        "id": ids,
+    arrays = {
+        "ids": ids,
+        "src": ids[src_i[keep]],
+        "dst": ids[dst_i[keep]],
+        # birthday: days-since-epoch ints (IS3-style property filters)
         "birthday": rng.integers(0, 18_000, size=num_people, dtype=np.int64),
+    }
+    if num_people <= 200_000:  # string props only at list-walkable sizes
+        arrays["firstname"] = np.array([f"p{i}" for i in range(num_people)])
+    return arrays
+
+
+def generate_snb(
+    scale: float, session, seed: int = 42
+) -> ScanGraph:
+    """Synthetic SNB-like Person/KNOWS graph from ``snb_arrays``."""
+    return graph_from_snb_arrays(session, snb_arrays(scale, seed))
+
+
+def graph_from_snb_arrays(session, arrays: Dict[str, np.ndarray]) -> ScanGraph:
+    """Ingest ``snb_arrays`` output. Columns stay numpy so the bulk
+    ingestion path is one H2D copy per column at SF10 scale and beyond."""
+    person_cols: Dict[str, List] = {
+        "id": arrays["ids"],
+        "birthday": arrays["birthday"],
     }
     # expose the id column as a property too (LDBC queries anchor on
     # ``a.id`` ranges; the bench's var-length source filter does the same)
@@ -131,16 +151,16 @@ def generate_snb(
         "id": T.CTInteger.nullable,
         "birthday": T.CTInteger.nullable,
     }
-    if num_people <= 200_000:  # string props only at list-walkable sizes
-        person_cols["firstname"] = [f"p{i}" for i in range(num_people)]
+    if "firstname" in arrays:
+        person_cols["firstname"] = arrays["firstname"].tolist()
         prop_types["firstname"] = T.CTString.nullable
     return _graph_from_arrays(
         session,
-        ids,
+        arrays["ids"],
         person_cols,
         prop_types,
-        src,
-        dst,
+        arrays["src"],
+        arrays["dst"],
         undirected_knows=False,
     )
 

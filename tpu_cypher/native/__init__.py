@@ -190,7 +190,7 @@ def two_hop_distinct_native(
     if not use_a and not use_c:
         # the kernel counts one hit per frontier ROW in this mode while the
         # device path would count at most one GLOBAL row — reject rather
-        # than silently diverge (ADVICE r4)
+        # than silently diverge
         return None
     ak = np.ascontiguousarray(akeys, dtype=np.int64)
     if not _grouped(ak):

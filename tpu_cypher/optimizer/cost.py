@@ -28,9 +28,7 @@ hand override, detected via ``ConfigOption.overridden``):
   instead of trusting syntax order;
 * MXU tier gating — :func:`mxu_dense_node_cap` (modelled from the HBM
   budget when one is set) and :func:`mxu_tiled_node_cap` replace the
-  fixed node caps in ``graph_index.dense_adj`` / ``expand_op``;
-* Pallas eligibility — :func:`pallas_cap` derives each kernel's size cap
-  from its VMEM working-set budget instead of a per-module constant.
+  fixed node caps in ``graph_index.dense_adj`` / ``expand_op``.
 """
 
 from __future__ import annotations
@@ -42,11 +40,6 @@ from ..utils.config import (
     MEM_BUDGET,
     MXU_DENSE_MAX,
     MXU_TILED_MAX,
-    PALLAS_MAX_BUILD,
-    PALLAS_MAX_FRONTIER,
-    PALLAS_MAX_GROUPS,
-    PALLAS_MAX_KEYS,
-    PALLAS_MAX_NODES,
     WCOJ_MIN_ROWS,
 )
 from .stats import GraphStatistics
@@ -316,35 +309,6 @@ def mxu_tiled_node_cap() -> int:
     routing through the cost model keeps the gate a single decision
     point beside the dense cap it backstops."""
     return int(MXU_TILED_MAX.get())
-
-
-# -- Pallas kernel eligibility caps (backend/tpu/pallas/*) ----------------
-
-# per-kernel VMEM working-set model: (knob, budget bytes, bytes/element).
-# The unpinned cap is budget // bytes_per_element — each knob's declared
-# default equals that quotient, so routing through the model changes no
-# behavior until an operator pins a knob or the budgets are retuned.
-_PALLAS_BUDGETS = {
-    "expand": (PALLAS_MAX_FRONTIER, 2 << 20, 8),  # cum + starts, int32
-    "frontier": (PALLAS_MAX_NODES, 4 << 20, 4),  # degree vector, int32
-    "intersect": (PALLAS_MAX_KEYS, 8 << 20, 8),  # two int32 key planes
-    "join": (PALLAS_MAX_BUILD, 4 << 20, 32),  # 4 table vecs at LF 1/2
-}
-
-
-def pallas_cap(kernel: str) -> int:
-    """Eligibility size cap for one Pallas kernel. A pinned
-    ``TPU_CYPHER_PALLAS_MAX_*`` knob wins verbatim; otherwise the cap is
-    the kernel's VMEM working-set budget divided by its bytes-per-element
-    — the byte-budget decision the old per-module constants hand-encoded.
-    ``aggregate`` caps GROUP BY cardinality (a compare-matrix shape, not a
-    resident buffer) so it keeps its declared lane-tile default."""
-    if kernel == "aggregate":
-        return int(PALLAS_MAX_GROUPS.get())
-    knob, vmem_bytes, bytes_per_elem = _PALLAS_BUDGETS[kernel]
-    if knob.overridden:
-        return int(knob.get())
-    return vmem_bytes // bytes_per_elem
 
 
 # -- serve admission (serve/scheduler.estimate_cost_bytes) ----------------

@@ -12,7 +12,7 @@ This module reduces those spans to per-operator-class EMAs of
 
 Calibrations are **per graph**, keyed by the statistics fingerprint, and
 persisted as one small JSON beside the compile cache
-(``<TPU_CYPHER_COMPILE_CACHE_DIR>/optimizer_calibration.json``) so a
+(``<bucketing.persistent_cache_dir()>/optimizer_calibration.json``) so a
 restarted process resumes with its measured weights; without a persistent
 cache dir they are process-local. Everything here is advisory: any
 failure degrades to the uncalibrated model (weights 1.0) and never takes

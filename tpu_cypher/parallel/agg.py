@@ -3,8 +3,9 @@
 The reference delegates grouped aggregation to the engines' shuffle-reduce
 (partial aggregates per partition, combined at the exchange — SURVEY §2.3);
 the mesh analog computes each shard's ``segment_*`` partial over its local
-row block and combines the k-sized partials with ``psum``/``pmin``/``pmax``
-inside one ``shard_map`` program, so no shard ever holds the full row set.
+row block and combines the k-sized partials (``psum``; for min/max a
+``psum``-gathered stack reduced locally) inside one ``shard_map`` program,
+so no shard ever holds the full row set.
 
 Eligibility is deliberately narrow: INTEGER data (I64/BOOL) and the
 aggregates whose combine is exact over the integers (count/sum/min/max,
@@ -54,6 +55,17 @@ def _agg_fn(mesh, axis: str, name: str, is_bool: bool, k: int):
     if got is not None:
         return got
 
+    nsh = mesh.shape[axis]
+
+    def _every_shard(x):
+        """Every shard's ``x``, stacked and replicated — as the SUM
+        all-reduce of a one-hot placement, because that is the one int64
+        all-reduce the TPU's 64-bit rewriter lowers (``pmin``/``pmax`` of
+        int64 are refused by the chip's compiler). k partials per shard
+        is tiny."""
+        slot = jnp.zeros((nsh,) + x.shape, x.dtype)
+        return lax.psum(slot.at[lax.axis_index(axis)].set(x), axis)
+
     def local(data, valid, seg):
         # pad rows staged valid=False: they contribute the combine identity
         cnt = jax.ops.segment_sum(
@@ -77,12 +89,12 @@ def _agg_fn(mesh, axis: str, name: str, is_bool: bool, k: int):
             agged = jax.ops.segment_min(
                 jnp.where(valid, d, big), seg, num_segments=k
             )
-            agged = lax.pmin(agged, axis)
+            agged = jnp.min(_every_shard(agged), axis=0)
         else:
             agged = jax.ops.segment_max(
                 jnp.where(valid, d, -big), seg, num_segments=k
             )
-            agged = lax.pmax(agged, axis)
+            agged = jnp.max(_every_shard(agged), axis=0)
         return agged, cnt
 
     spec = P(axis)

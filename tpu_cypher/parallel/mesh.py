@@ -29,19 +29,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # JAX >= 0.7 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
 from ..utils import config as _config
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the mesh positional, as every program factory
+    here and in ``agg.py``/``shuffle.py`` writes it."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+
 
 EDGE_AXIS = "edges"
 
@@ -153,8 +148,8 @@ def shard_rows(arr):
     leading dim is divisible by the mesh size (NamedSharding requires
     divisibility); other arrays stay as-is. Engine ingest uses
     ``padded_to_mesh`` instead, which pads arbitrary row counts to a shard
-    multiple (VERDICT r2 weak #3: the divisible-only skip silently
-    un-sharded real workloads — 1,999,987 edges on an 8-mesh)."""
+    multiple (a divisible-only skip would silently un-shard real
+    workloads — 1,999,987 edges on an 8-mesh)."""
     mesh = current_mesh()
     if mesh is None:
         return arr

@@ -7,8 +7,7 @@ intermediate is a deliberate plan decision, not an accident of input
 layout. The engine's default device join is one global sort + binary-search
 probe, which XLA/GSPMD partitions by propagating the INPUT shardings; at
 pod scale a global ``lax.sort`` degenerates to an all-gather. This module
-is the deliberate alternative (SURVEY §2.3 "distributed join / shuffle",
-VERDICT r3 missing #3):
+is the deliberate alternative (SURVEY §2.3 "distributed join / shuffle"):
 
 * each device buckets its local key block by ``key % n_shards`` — a row's
   bucket depends only on its VALUE, so equal keys land on equal shards;
@@ -109,7 +108,7 @@ def _bucketize(keys, rows, nsh: int, cap: int, pad_key: int, axis: str):
     order = jnp.argsort(tgt, stable=True)
     tgt_s = jnp.take(tgt, order)
     is_real = ~jnp.take(is_pad, order)
-    # rank REAL rows only (ADVICE r4): pads sorted ahead within a bucket
+    # rank REAL rows only: pads sorted ahead within a bucket
     # must not inflate real ranks, or near-capacity buckets trip the
     # overflow fallback spuriously
     creal = jnp.cumsum(is_real.astype(jnp.int64))
@@ -394,7 +393,7 @@ def hash_repartition_join(
     for arr in (l_key, l_valid, r_key, r_valid):
         # multi-process meshes hold row-sharded GLOBAL arrays whose remote
         # shards this process cannot read — np.asarray staging would raise,
-        # so keep the default (GSPMD-partitioned) sort-probe join (ADVICE r4)
+        # so keep the default (GSPMD-partitioned) sort-probe join
         if arr is not None and not getattr(arr, "is_fully_addressable", True):
             return None
 

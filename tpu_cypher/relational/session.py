@@ -161,7 +161,7 @@ class CypherResult:
     re-executes the SAME relational plan at the next rung — exact bucket
     sizes, then chunked materializes, then the host oracle — and every
     attempt lands in ``execution_log``. Either the query succeeds or it
-    raises a typed ``TpuCypherError``; raw ``XlaRuntimeError`` never
+    raises a typed ``TpuCypherError``; raw ``JaxRuntimeError`` never
     escapes."""
 
     def __init__(self, session, logical_plan, relational_plan, returns, graph=None):
@@ -178,7 +178,7 @@ class CypherResult:
         # per-query device-coverage telemetry: {reason: count} of local-
         # oracle fallbacks + host islands recorded while THIS result's plan
         # materialized (populated on first .records access when the session
-        # records fallbacks — VERDICT r2 weak #7)
+        # records fallbacks)
         self.fallbacks: Optional[Dict[str, int]] = None
         # per-query compile telemetry: {"compiles": n, "compile_seconds": s}
         # of REAL XLA compilations observed while THIS result's plan
@@ -503,7 +503,6 @@ class CypherSession:
     def __init__(
         self,
         table_cls,
-        persistent_cache_dir: Optional[str] = None,
         memory_budget_bytes: Optional[int] = None,
         query_deadline_seconds: Optional[float] = None,
     ):
@@ -526,12 +525,6 @@ class CypherSession:
         # compile telemetry is always on (one string compare per
         # jax.monitoring event): every result carries ``compile_stats``
         bucketing.install_compile_listener()
-        # persistent compilation cache: the disk tier under the in-process
-        # jit caches, so warm programs survive process restarts. Option
-        # wins; the env var covers deployments that cannot touch code.
-        cache_dir = persistent_cache_dir or _config.COMPILE_CACHE_DIR.get()
-        if cache_dir:
-            bucketing.enable_persistent_cache(cache_dir)
         self._catalog: Dict[str, RelationalCypherGraph] = {}
         self._views: Dict[str, Tuple[Tuple[str, ...], str]] = {}
         # (view, arg qgns, referenced params) -> (argument graph objects,
@@ -579,7 +572,6 @@ class CypherSession:
 
     @staticmethod
     def tpu(
-        persistent_cache_dir: Optional[str] = None,
         memory_budget_bytes: Optional[int] = None,
         query_deadline_seconds: Optional[float] = None,
         mesh=None,
@@ -591,16 +583,22 @@ class CypherSession:
         var sets the same default without code changes). Activation is
         process-global — the mesh decides the physical layout of graph
         ingest, which outlives any one session scope; use
-        ``parallel.mesh.use_mesh`` for scoped activation."""
+        ``parallel.mesh.use_mesh`` for scoped activation.
+
+        Compiled programs persist across processes: in
+        ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else in
+        one fixed directory of the checkout
+        (``bucketing.enable_persistent_cache``)."""
+        from ..backend.tpu import bucketing
         from ..backend.tpu.table import TpuTable
 
+        bucketing.enable_persistent_cache()
         if mesh is not None:
             from ..parallel import mesh as _mesh
 
             _mesh.activate_mesh(_mesh.resolve_mesh(mesh))
         return CypherSession(
             TpuTable,
-            persistent_cache_dir=persistent_cache_dir,
             memory_budget_bytes=memory_budget_bytes,
             query_deadline_seconds=query_deadline_seconds,
         )

@@ -9,10 +9,9 @@ specs):
 
 * ``kind``  — ``oom`` | ``compile`` | ``lost`` | ``timeout`` | ``crash``
 * ``site``  — a named fault site (``join``, ``expand``, ``var_expand``,
-  ``filter``, ``compact``, ``shuffle``, ``agg``, plus the Pallas kernel-tier sites
-  ``kernel_join``/``kernel_expand``/``kernel_agg``/``kernel_frontier``
-  fired by ``backend.tpu.pallas.dispatch.launch`` just before a kernel
-  launch, and the write-path sites ``wal_append`` (before the WAL
+  ``filter``, ``compact``, ``shuffle``, ``agg``, plus the Pallas kernel-tier
+  site ``kernel_agg`` fired by ``backend.tpu.pallas.dispatch.launch`` just
+  before a kernel launch, and the write-path sites ``wal_append`` (before the WAL
   append: the write fails with nothing durable), ``delta_apply``
   (after the append, before the in-memory apply: commit rolls the WAL
   back to the pre-append offset) and ``compact`` again inside
@@ -80,7 +79,7 @@ FAULT_SITE_HITS = _REGISTRY.counter(
 
 class InjectedFault(RuntimeError):
     """Synthetic RAW device fault (classified by message, like jaxlib's
-    ``XlaRuntimeError``). Carries the site + occurrence for diagnostics."""
+    ``JaxRuntimeError``). Carries the site + occurrence for diagnostics."""
 
     def __init__(self, message: str, site: str, n: int):
         super().__init__(message)
@@ -93,7 +92,7 @@ _KIND_MESSAGES = {
     "1099511627776 bytes on device",
     "compile": "INTERNAL: injected XLA compilation failure while compiling "
     "fused computation",
-    "lost": "UNAVAILABLE: injected device lost (TPU driver tunnel closed)",
+    "lost": "UNAVAILABLE: injected device lost (chip reset)",
     "crash": "UNAVAILABLE: injected worker crash (disarmed outside an "
     "engine-worker process)",
 }

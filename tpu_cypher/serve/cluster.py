@@ -18,9 +18,10 @@ of recompiling.
 
 Graphs are REPLICATED, not shared: ``register_graph`` takes the CREATE
 query text and every worker builds its own copy (device buffers cannot
-cross process boundaries; the text is the portable form, and the local
-replica built from the same text keeps cost estimation and the
-``/metrics`` surface identical to single-process serving). The same
+cross process boundaries; the text is the portable form). The front end
+keeps a replica too, for cost estimation and batching keys — on the HOST
+backend: a chip belongs to one process, and it belongs to a worker, so the
+router process never initialises the device. The same
 deferral applies to ``warmup``: the corpus is recorded and each worker
 runs it at boot — readiness is warmup-gated per worker.
 
@@ -32,13 +33,13 @@ the cluster's admission ceiling defaults to ``max_concurrent x workers``
 from __future__ import annotations
 
 import asyncio
-import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
+from ..backend.tpu import bucketing
+from ..relational.session import CypherSession
 from ..storage.wal import wal_directory
 from ..utils.config import (
-    COMPILE_CACHE_DIR,
     SERVE_DRAIN_TIMEOUT_S,
     SERVE_MAX_CONCURRENT,
     SERVE_WORKERS,
@@ -60,7 +61,6 @@ class ClusterServer(QueryServer):  # shared-by: loop
         max_concurrent: Optional[int] = None,
         batch_window_ms: Optional[float] = None,
         tenant_quota: Optional[int] = None,
-        persistent_cache_dir: Optional[str] = None,
         launcher=None,
         retry_max: Optional[int] = None,
         hedge_ms: Optional[float] = None,
@@ -74,24 +74,25 @@ class ClusterServer(QueryServer):  # shared-by: loop
         if max_concurrent is None:
             # the fleet runs n_workers engines; admit what it can execute
             max_concurrent = int(SERVE_MAX_CONCURRENT.get()) * self.n_workers
+        # the router never executes queries, and never holds a chip: its
+        # replica session is the host backend (see the module docstring)
         super().__init__(
+            session=CypherSession.local(),
             host=host, port=port, max_concurrent=max_concurrent,
             batch_window_ms=batch_window_ms, tenant_quota=tenant_quota,
             cache_bytes=cache_bytes,
         )
-        # one compile-cache dir shared by every worker: restart warmups
-        # load artifacts from here instead of recompiling
-        self.persistent_cache_dir = (
-            persistent_cache_dir
-            or COMPILE_CACHE_DIR.get()
-            or tempfile.mkdtemp(prefix="tpu-cypher-cluster-cache-")
-        )
+        # compiles nothing here: only resolves the cache directory the
+        # workers will share, for the WAL default below
+        bucketing.enable_persistent_cache()
         self.lanes = int(lanes)
         # where worker WAL files live (one per mutable graph); defaults to
-        # 'wal/' beside the shared compile cache — durability artifacts
-        # ride next to the compile artifacts a restarted worker re-warms
-        # from (storage.wal.wal_directory resolution)
-        self.wal_dir = wal_directory(wal_dir, self.persistent_cache_dir)
+        # 'wal/' beside the compile cache every worker shares — durability
+        # artifacts ride next to the compile artifacts a restarted worker
+        # re-warms from (storage.wal.wal_directory resolution)
+        self.wal_dir = wal_directory(
+            wal_dir, bucketing.persistent_cache_dir()
+        )
         self._graph_specs: Dict[str, str] = {}
         self._mutable_graphs: set = set()
         self._warmup_specs: Dict[str, List[str]] = {}
@@ -107,9 +108,9 @@ class ClusterServer(QueryServer):  # shared-by: loop
         self, name: str, create_query: str, mutable: bool = False
     ) -> None:  # type: ignore[override]
         """Mount a graph cluster-wide from its CREATE query text. The
-        front end builds a LOCAL replica too (cost estimation, batching
-        keys, and the single-process protocol surface all need a real
-        graph object); workers each build theirs at boot. ``mutable``
+        front end builds a host-backend replica too (cost estimation,
+        batching keys, and the single-process protocol surface all need a
+        real graph object); workers each build theirs at boot. ``mutable``
         graphs boot on the workers as delta-CSR stores sharing one WAL
         file under ``wal_dir`` — the front-end replica stays immutable
         (it never executes queries; its fingerprint is refreshed from
@@ -135,10 +136,10 @@ class ClusterServer(QueryServer):  # shared-by: loop
         if self._launcher is None:
             self._launcher = SubprocessLauncher(
                 self._graph_specs, self._warmup_specs,
-                persistent_cache_dir=self.persistent_cache_dir,
                 host=self.host, lanes=self.lanes,
                 mutable=sorted(self._mutable_graphs),
                 wal_dir=self.wal_dir,
+                assign_chips=self.n_workers > 1,
             )
         canary = None
         if self._graph_specs:

@@ -38,7 +38,8 @@ scheduler, and a batch coalescer, and serves two protocols on ONE port:
   ``session.metrics_text()`` VERBATIM (golden-tested against the
   in-process text so the surfaces cannot drift), ``GET /queries/<id>``
   returns the per-query record — status, execution log, ladder rungs,
-  batch tags, and the full ``profile()`` span tree as JSON.
+  batch tags, compile stats, recorded fallbacks, and the full ``profile()``
+  span tree as JSON.
   ``GET /cache`` reports result-cache occupancy and hit counters;
   ``POST /cache/flush`` drops every cached result (cluster mode fans the
   flush out to its worker processes; GET on it is 405 — a probe or
@@ -722,6 +723,7 @@ class QueryServer:  # shared-by: loop
                 rungs=payload["rungs"],
                 degraded=payload["degraded"],
                 compile_stats=payload["compile_stats"],
+                fallbacks=payload.get("fallbacks"),
                 profile=payload["profile"],
                 cached=bool(payload.get("cached", False)),
             )

@@ -30,6 +30,13 @@ Recovery model, in order of escalation:
 Workers are spawned with ``asyncio.create_subprocess_exec`` — child
 lifecycle rides the event loop like everything else here; nothing in this
 module blocks.
+
+One process per chip: a chip belongs to one process at a time, so a fleet
+of N > 1 workers is given chips 0..N-1 of the host, one each, in the
+child's environment (``chip_environment``). A worker that cannot have its
+chip says so on its readiness line and ``start()`` raises it typed —
+never a hang, never two workers on one chip, never a worker that quietly
+came up on another platform than its peers.
 """
 
 from __future__ import annotations
@@ -51,6 +58,7 @@ from ..utils.config import (
     SERVE_RESTART_BACKOFF_MAX_S,
     SERVE_RESTART_BACKOFF_S,
 )
+from .. import errors as ERR
 from . import wire
 
 WORKER_RESTARTS = _REGISTRY.counter(
@@ -141,6 +149,7 @@ class WorkerHandle:  # shared-by: loop
         self.breaker = breaker
         self.transport = None  # set by Supervisor on every (re)spawn
         self.state = STARTING
+        self.device: Dict[str, Any] = {}  # what its READY line reported
         self.restarts = 0  # completed restarts, lifetime
         self.restart_attempt = 0  # consecutive failures, resets on canary
         self.restarting = False
@@ -193,13 +202,12 @@ class SubprocessTransport:
         """Block until the child prints its readiness line (warmup-gated by
         construction — see ``serve/worker.py``), skipping any non-JSON
         noise a library emits on stdout first."""
-        deadline = time.monotonic() + timeout
-
         async def _scan() -> Dict[str, Any]:
             while True:
                 line = await self._proc.stdout.readline()
                 if not line:
-                    raise EOFError(
+                    await self._proc.wait()
+                    raise ERR.WorkerLost(
                         f"worker pid={self.pid} exited before READY "
                         f"(code={self.poll()})"
                     )
@@ -207,19 +215,46 @@ class SubprocessTransport:
                     msg = json.loads(line)
                 except ValueError:
                     continue  # fault-ok: stray stdout noise before READY
-                if isinstance(msg, dict) and msg.get("ready"):
+                if isinstance(msg, dict) and "ready" in msg:
                     return msg
 
-        msg = await asyncio.wait_for(
-            _scan(), max(deadline - time.monotonic(), 0.001)
-        )
+        msg = await asyncio.wait_for(_scan(), max(timeout, 0.001))
+        if not msg["ready"]:
+            # the worker's own account of why it could not come up (its
+            # chip is held by another process, a graph would not build)
+            raise ERR.WorkerLost(
+                f"worker {msg.get('worker')} pid={self.pid} failed to "
+                f"start: {msg.get('error')}: {msg.get('message')}",
+                worker=msg.get("worker"),
+            )
         self.port = int(msg["port"])
         return msg
 
 
+def chip_environment(worker_id: str) -> Dict[str, str]:
+    """Environment that gives worker ``w<i>`` chip ``i`` of this host and
+    nothing else: libtpu then opens that one chip as a 1x1x1 topology of
+    its own. ``ALLOW_MULTIPLE_LIBTPU_LOAD`` lets the fleet's processes
+    each load the TPU library; what keeps two of them off one chip is the
+    assignment itself (a chip that is held refuses a second opener). The
+    mesh-controller port differs per worker because each process runs its
+    own. On a host without TPUs the variables are inert."""
+    chip = int(worker_id.lstrip("w"))
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + chip}",
+        "TPU_MESH_CONTROLLER_PORT": str(8476 + chip),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+
+
 class SubprocessLauncher:
     """Spawns engine workers as child processes and feeds each its config
-    line (graphs to replicate, warmup corpus, shared compile-cache dir).
+    line (graphs to replicate, warmup corpus). The compile cache every
+    worker shares is placed by the environment the children inherit
+    (``JAX_COMPILATION_CACHE_DIR``, else the checkout's fixed directory).
     Tests substitute a fake launcher whose transports are in-process
     asyncio servers — everything above the transport interface is
     exercised without JAX subprocess boot costs."""
@@ -228,15 +263,17 @@ class SubprocessLauncher:
         self,
         graphs: Dict[str, str],
         warmup: Dict[str, List[str]],
-        persistent_cache_dir: Optional[str] = None,
         host: str = "127.0.0.1",
         lanes: int = 4,
         mutable: Optional[List[str]] = None,
         wal_dir: Optional[str] = None,
+        assign_chips: bool = False,
     ):
+        # one chip per worker (see ``chip_environment``); a single worker
+        # keeps the host's default view of its devices
+        self.assign_chips = assign_chips
         self.graphs = dict(graphs)
         self.warmup = {k: list(v) for k, v in warmup.items()}
-        self.persistent_cache_dir = persistent_cache_dir
         self.host = host
         self.lanes = lanes
         self.mutable = sorted(mutable or ())
@@ -250,6 +287,8 @@ class SubprocessLauncher:
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (repo_root, env.get("PYTHONPATH")) if p
         )
+        if self.assign_chips:
+            env.update(chip_environment(worker_id))
         proc = await asyncio.create_subprocess_exec(
             sys.executable, "-m", "tpu_cypher.serve.worker",
             stdin=asyncio.subprocess.PIPE,
@@ -261,7 +300,6 @@ class SubprocessLauncher:
             "host": self.host,
             "graphs": self.graphs,
             "warmup": self.warmup,
-            "persistent_cache_dir": self.persistent_cache_dir,
             "lanes": self.lanes,
             "mutable": self.mutable,
             "wal_dir": self.wal_dir,
@@ -335,15 +373,37 @@ class Supervisor:  # shared-by: loop
     async def start(self) -> None:
         """Cold-start every worker CONCURRENTLY (they warm independently;
         serial boot would multiply cold-start latency by N) and begin the
-        health loop. Raises if any worker fails its first boot — a cluster
-        that cannot start whole should say so, not limp up."""
-        await asyncio.gather(*(self._boot(w) for w in self.workers))
+        health loop. Raises typed (``WorkerLost``, carrying the worker's
+        own account) if any worker fails its first boot, or if the fleet
+        disagrees on the platform it came up on — a cluster that cannot
+        start whole should say so, not limp up; no child is left running."""
+        try:
+            await asyncio.gather(*(self._boot(w) for w in self.workers))
+            platforms = {
+                w.worker_id: w.device.get("platform") for w in self.workers
+            }
+            if len(set(platforms.values())) > 1:
+                raise ERR.WorkerLost(
+                    "workers came up on different platforms (a worker "
+                    f"without a chip of its own fell back): {platforms}"
+                )
+        except BaseException:
+            await self.stop()
+            raise
         self._health_task = asyncio.ensure_future(self._health_loop())
 
     async def _boot(self, w: WorkerHandle) -> Dict[str, Any]:
         w.state = STARTING
         w.transport = await self.launcher.spawn(w.worker_id)
-        ready = await w.transport.wait_ready(self.ready_timeout_s)
+        try:
+            ready = await w.transport.wait_ready(self.ready_timeout_s)
+        except asyncio.TimeoutError as exc:
+            raise ERR.WorkerLost(
+                f"worker {w.worker_id} was not READY within "
+                f"{self.ready_timeout_s}s",
+                worker=w.worker_id,
+            ) from exc
+        w.device = dict(ready.get("device") or {})
         w.state = READY
         self._note_up()
         return ready
@@ -493,10 +553,11 @@ class Supervisor:  # shared-by: loop
                 await asyncio.sleep(delay)
                 try:
                     w.transport = await self.launcher.spawn(w.worker_id)
-                    await w.transport.wait_ready(self.ready_timeout_s)
+                    ready = await w.transport.wait_ready(self.ready_timeout_s)
                 except Exception:  # fault-ok: failed spawn feeds the backoff
                     w.restart_attempt += 1
                     continue
+                w.device = dict(ready.get("device") or {})
                 w.state = READY
                 w.restarts += 1
                 WORKER_RESTARTS.inc(worker=w.worker_id)

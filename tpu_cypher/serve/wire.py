@@ -15,7 +15,7 @@ cannot drift:
   session inside the caller's already-fresh context (request deadline and
   chaos schedule scoped in) and returns the JSON-safe result dict
   {rows, columns, seconds, execution_log, rungs, degraded, compile_stats,
-  profile}. ``QueryServer._execute`` and the worker's execute op are both
+  fallbacks, profile}. ``QueryServer._execute`` and the worker's execute op are both
   one-line wrappers over it — 'byte-identical rows across serving modes'
   stays a checkable property.
 
@@ -94,6 +94,9 @@ def execute_payload(
         "rungs": rungs,
         "degraded": bool(rungs and rungs[-1] != G.RUNG_DEVICE),
         "compile_stats": result.compile_stats,
+        # {reason: count} of host-oracle fallbacks / host islands; None
+        # unless the session records them (``session.record_fallbacks``)
+        "fallbacks": result.fallbacks,
         "profile": result.profile(execute=False).to_dict(),
     }
     write_stats = getattr(result, "write_stats", None)

@@ -15,7 +15,6 @@ Boot protocol (stdin/stdout, so no ports need pre-agreement):
 1. parent writes ONE config JSON line to stdin::
 
        {"worker_id": "w0", "host": "127.0.0.1",
-        "persistent_cache_dir": "/tmp/cc",
         "graphs": {"g": "CREATE (a:Person ...)"},
         "warmup": {"g": ["MATCH ...", ...]}}
 
@@ -24,11 +23,18 @@ Boot protocol (stdin/stdout, so no ports need pre-agreement):
    ephemeral TCP port and prints ONE readiness line to stdout::
 
        {"ready": true, "port": 41234, "pid": 7, "worker": "w0",
-        "warmup": {"queries": n, "compiles": c, ...}}
+        "warmup": {"queries": n, "compiles": c, ...},
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "id": 0,
+                   "count": 1, "chip": "2"}}
 
    Readiness is gated on warmup BY CONSTRUCTION: the line cannot be
    printed before the caches are hot, so the supervisor never routes
-   traffic to a cold worker.
+   traffic to a cold worker. ``device`` is what this process holds, as
+   JAX reports it, plus the chip the supervisor assigned (``chip``, from
+   ``TPU_VISIBLE_CHIPS``; null when none was assigned). A setup failure
+   (the chip is held by another process, a graph will not build) prints
+   ``{"ready": false, "error": <type>, "message": ...}`` instead and
+   exits non-zero, so the supervisor can say WHY the worker never came up.
 
 3. thereafter the worker speaks the ``serve/wire.py`` framing on its TCP
    port: ``execute`` (one query per request, typed errors by name),
@@ -51,6 +57,7 @@ import sys
 from typing import Any, Dict, Optional
 
 from .. import errors as ERR
+from ..backend.tpu import bucketing
 from ..relational.session import CypherSession
 from ..runtime import faults as F
 from ..storage.wal import wal_directory
@@ -90,7 +97,7 @@ class EngineWorker:  # shared-by: loop
 
     # -- lifecycle -------------------------------------------------------
 
-    async def serve(self, warmup_stats: Dict[str, Any]) -> None:
+    async def serve(self, ready_info: Dict[str, Any]) -> None:
         self._server = await asyncio.start_server(
             self._handle_conn, self.host, 0
         )
@@ -102,7 +109,7 @@ class EngineWorker:  # shared-by: loop
         # the readiness line: the parent's wait_ready() blocks on this
         print(json.dumps({
             "ready": True, "port": self.port, "pid": os.getpid(),
-            "worker": self.worker_id, "warmup": warmup_stats,
+            "worker": self.worker_id, **ready_info,
         }), flush=True)
         try:
             while not (self.draining and self.inflight == 0):
@@ -226,23 +233,51 @@ class EngineWorker:  # shared-by: loop
         return payload
 
 
+def held_device() -> Dict[str, Any]:
+    """The device this process holds, as JAX reports it, plus the chip the
+    supervisor assigned to it (``serve/supervisor.py``)."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "id": dev.id,
+        "count": len(jax.devices()),
+        "chip": os.environ.get("TPU_VISIBLE_CHIPS"),
+    }
+
+
 def main() -> None:
     cfg = json.loads(sys.stdin.readline())
     # only an expendable worker process ever arms process-killing faults
     F.enable_crash()
+    try:
+        worker, ready_info = _boot(cfg)
+    except Exception as exc:  # fault-ok: reported to the supervisor, then exit 1
+        typed = ERR.classify(exc)
+        print(json.dumps({
+            "ready": False,
+            "worker": str(cfg.get("worker_id") or "w?"),
+            "error": type(typed if typed is not None else exc).__name__,
+            "message": str(exc)[:2000],
+        }), flush=True)
+        raise
+    asyncio.run(worker.serve(ready_info))
+
+
+def _boot(cfg: Dict[str, Any]):
     # ALL blocking setup happens here, synchronously, BEFORE the loop
     # exists: session boot, graph replica construction, corpus warmup.
     # Printing READY after this is what makes readiness warmup-gated.
-    session = CypherSession.tpu(
-        persistent_cache_dir=cfg.get("persistent_cache_dir") or None
-    )
+    session = CypherSession.tpu()
     # graphs marked mutable boot as delta-CSR stores with a WAL persisted
     # beside the compile cache: the CREATE-query replay rebuilds the base,
     # then attach_wal replays every committed batch — a SIGKILLed worker
     # restarts with exactly the committed writes (docs/mutation.md)
     mutable_names = set(cfg.get("mutable") or ())
     wal_dir = wal_directory(
-        cfg.get("wal_dir"), cfg.get("persistent_cache_dir")
+        cfg.get("wal_dir"), bucketing.persistent_cache_dir()
     )
     graphs = {}
     for name, create_query in (cfg.get("graphs") or {}).items():
@@ -269,7 +304,7 @@ def main() -> None:
         host=str(cfg.get("host") or "127.0.0.1"),
         lanes=int(cfg.get("lanes") or 4),
     )
-    asyncio.run(worker.serve(warmup_stats))
+    return worker, {"warmup": warmup_stats, "device": held_device()}
 
 
 if __name__ == "__main__":

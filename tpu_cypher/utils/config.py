@@ -203,44 +203,13 @@ MXU_DENSE_MAX = declare(
     "(Npad^2 bf16 per matrix); modelled from the HBM budget unless pinned",
 )
 
-# per-kernel Pallas eligibility caps (backend/tpu/pallas/*). Each default
-# mirrors the kernel's VMEM working-set budget; the effective cap routes
-# through optimizer/cost.pallas_cap so a pin is honored verbatim while the
-# unpinned value stays a derived byte-budget decision.
-PALLAS_MAX_FRONTIER = declare(
-    "TPU_CYPHER_PALLAS_MAX_FRONTIER",
-    1 << 18,
-    int,
-    help="frontier cap for the Pallas expand kernel (resident cum+starts "
-    "state, ~8 B per frontier element of a ~2 MiB VMEM budget)",
-)
-PALLAS_MAX_NODES = declare(
-    "TPU_CYPHER_PALLAS_MAX_NODES",
-    1 << 20,
-    int,
-    help="node cap for the Pallas frontier-degree kernel (resident int32 "
-    "degree vector, 4 B per node of a ~4 MiB VMEM budget)",
-)
-PALLAS_MAX_KEYS = declare(
-    "TPU_CYPHER_PALLAS_MAX_KEYS",
-    1 << 20,
-    int,
-    help="pow2-padded key cap for the Pallas intersect kernel (two int32 "
-    "planes, 8 B per key of an ~8 MiB VMEM budget)",
-)
-PALLAS_MAX_BUILD = declare(
-    "TPU_CYPHER_PALLAS_MAX_BUILD",
-    1 << 17,
-    int,
-    help="build-side cap for the Pallas hash-join kernel (4 int32 table "
-    "vectors at load factor 1/2, 32 B per build row of a ~4 MiB budget)",
-)
+# GROUP BY cap of the one Pallas kernel (backend/tpu/pallas/aggregate.py)
 PALLAS_MAX_GROUPS = declare(
     "TPU_CYPHER_PALLAS_MAX_GROUPS",
     256,
     int,
     help="GROUP BY cardinality cap for the Pallas segment-aggregate "
-    "kernel (the (k_pad, block) compare matrix budget)",
+    "kernel (its VMEM-resident (k_pad, 128) int32 accumulator)",
 )
 
 # worst-case-optimal multiway join (backend/tpu/wcoj.py)
@@ -350,14 +319,6 @@ ISLAND_WARN_ROWS = declare(
     1_000_000,
     int,
     help="row count above which a cartesian island emits a warning",
-)
-
-# persistent compile cache (relational/session.py)
-COMPILE_CACHE_DIR = declare(
-    "TPU_CYPHER_COMPILE_CACHE_DIR",
-    "",
-    str,
-    help="persistent XLA compile cache directory; empty = disabled",
 )
 
 # multi-tenant query server (serve/): the asyncio front end that admits,
