@@ -27,7 +27,6 @@ Five guarantees under test:
 import json
 import os
 import threading
-import warnings
 
 import pytest
 
@@ -168,6 +167,184 @@ def test_fault_site_sync_points_on_spans():
         for k, v in sp.attrs.get("sites", {}).items():
             sites[k] = sites.get(k, 0) + v
     assert sites, "no fault-site sync points stamped on any span"
+
+
+# ---------------------------------------------------------------------------
+# the tree's clock (schema 2): start and end on every span, the device-trace
+# annotations, the sync and build leaves, the log of finished trees
+# ---------------------------------------------------------------------------
+
+
+def _assert_nested_in_time(node, parent=None):
+    """Every span's [start_s, start_s + seconds] lies inside its parent's,
+    and siblings (one thread) do not overlap. 2 us of slack: to_dict
+    rounds to the microsecond."""
+    eps = 2e-6
+    lo, hi = node["start_s"], node["start_s"] + node["seconds"]
+    if parent is not None:
+        plo, phi = parent["start_s"], parent["start_s"] + parent["seconds"]
+        assert plo - eps <= lo and hi <= phi + eps, (node["name"], parent["name"])
+    at = lo - eps
+    for child in node.get("children", ()):
+        assert child["start_s"] >= at - eps, (child["name"], node["name"])
+        at = child["start_s"] + child["seconds"]
+        _assert_nested_in_time(child, node)
+
+
+def test_every_span_has_its_start_inside_its_parent():
+    s = CypherSession.tpu()
+    g = _chain_graph(s)
+    r = g.cypher(THREE_HOP)
+    r.records.collect()
+    d = r.profile().to_dict()
+    assert d["schema_version"] == 2 == OT.SCHEMA_VERSION
+    assert d["start_perf_s"] > 0 and d["start_unix_ns"] > 10**18
+    spans = []
+
+    def walk(n):
+        spans.append(n)
+        for c in n.get("children", ()):
+            walk(c)
+
+    walk(d["root"])
+    assert len(spans) > 10 and all("start_s" in n for n in spans)
+    assert d["root"]["start_s"] == 0.0
+    # an unclosed root (a lazy result) renders with its children's extent
+    assert d["root"]["seconds"] >= d["total_seconds"] - 1e-5
+    _assert_nested_in_time(d["root"])
+    json.dumps(d)
+
+
+def test_plan_cache_is_a_phase_on_hit_and_on_miss():
+    s = CypherSession.tpu()
+    g = _chain_graph(s)
+    q = "MATCH (a:P) WHERE a.id > 4 RETURN count(*) AS c"
+    miss = g.cypher(q)
+    miss.records.collect()
+    hit = g.cypher(q)
+    hit.records.collect()
+    planning = {"parse", "ir", "logical", "logical_opt", "relational",
+                "prune", "cse"}
+    names = [sp.name for sp in miss.profile().trace.root.children]
+    assert names[0] == "plan_cache" and planning <= set(names)
+    assert miss.profile().trace.root.attrs["plan_cache"] == "miss"
+    names = [sp.name for sp in hit.profile().trace.root.children]
+    assert names[0] == "plan_cache" and not planning & set(names)
+    assert hit.profile().trace.root.children[0].kind == "phase"
+    # the phase lands in the stage histogram, where plan_s reads it
+    assert OM.STAGE_SECONDS.summary(stage="plan_cache")["count"] >= 2
+
+
+def test_spans_annotate_any_capture_without_the_profile_dir(monkeypatch):
+    """A span opens its TraceAnnotation whenever a trace is active —
+    TPU_CYPHER_PROFILE_DIR unset — and the untraced path opens nothing."""
+    from tpu_cypher.utils.config import PROFILE_DIR
+
+    assert not PROFILE_DIR.get()
+    opened = []
+
+    class Stub:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            opened.append("exit")
+
+    monkeypatch.setattr(OT, "_ANNOTATION", Stub)
+    with OT.span("nobody", kind="operator") as sp:
+        assert sp is OT.NULL_SPAN
+    assert opened == []
+    with OT.activate(OT.QueryTrace("query")):
+        with OT.span("CsrExpandOp", kind="operator"):
+            with OT.sync("expand"):
+                pass
+    assert opened == ["tpu_cypher:operator:CsrExpandOp",
+                      "tpu_cypher:sync:expand", "exit", "exit"]
+
+
+def test_sync_spans_wrap_the_reads_and_count_the_same_when_warm():
+    s = CypherSession.tpu()
+    g = _chain_graph(s)
+    q = "MATCH (a:P)-[:K]->(b:P) RETURN a.id AS a, count(b) AS n ORDER BY a"
+    g.cypher(q).records.collect()  # cold: builds, compiles
+
+    def syncs():
+        return sum(v for _, v in OT.HOST_SYNCS.items())
+
+    moved = []
+    for _ in range(2):
+        before = syncs()
+        r = g.cypher(q)
+        r.records.collect()
+        moved.append(syncs() - before)
+    assert moved[0] == moved[1] > 0
+    leaves = [sp for sp in r.profile().trace.spans() if sp.kind == "sync"]
+    assert len(leaves) == moved[1]  # one span a read, one count a span
+    assert not any(sp.children for sp in leaves)
+    assert {sp.name for sp in leaves} <= {
+        "expand", "agg", "compact", "to_host", "order", "distinct"}
+    assert OT.HOST_SYNCS.value(site="to_host") > 0
+
+
+def test_index_builds_are_spans_and_seconds_of_the_first_expand_only():
+    from tpu_cypher.backend.tpu.graph_index import INDEX_BUILD_SECONDS
+
+    def built():
+        return {lbl["index"]: v for lbl, v in INDEX_BUILD_SECONDS.items()}
+
+    s = CypherSession.tpu()
+    g = _chain_graph(s)
+    before = built()
+    q = "MATCH (a:P)-[:K]->(b:P) RETURN count(*) AS c"
+    first = g.cypher(q)
+    first.records.collect()
+    after_first = built()
+    second = g.cypher(q)
+    second.records.collect()
+    assert after_first["csr"] > before.get("csr", 0.0)
+    assert after_first["rel_scan"] > before.get("rel_scan", 0.0)
+    assert built() == after_first  # a warm index never builds again
+    builds = [sp for sp in first.profile().trace.spans() if sp.kind == "build"]
+    assert {"index:csr", "index:rel_scan", "index:node_scan"} <= {
+        sp.name for sp in builds}
+    csr = next(sp for sp in builds if sp.name == "index:csr")
+    assert csr.attrs["orientation"] == "forward" and csr.attrs["rows"] == 23
+    assert not [sp for sp in second.profile().trace.spans()
+                if sp.kind == "build"]
+
+
+def test_a_load_from_the_persistent_cache_counts_seconds_and_no_compile():
+    load = OM.REGISTRY.get("tpu_cypher_persistent_cache_load_seconds_total")
+    before = (load.value(), bucketing.compile_snapshot())
+    bucketing._on_event("/jax/compilation_cache/cache_hits")
+    bucketing._on_event_duration("/jax/core/compile/backend_compile_duration", 0.25)
+    assert load.value() == pytest.approx(before[0] + 0.25)
+    after = bucketing.compile_snapshot()
+    assert after["compiles"] == before[1]["compiles"]
+    assert after["compile_seconds"] == before[1]["compile_seconds"]
+    bucketing._on_event_duration("/jax/core/compile/backend_compile_duration", 0.5)
+    assert bucketing.compile_snapshot()["compiles"] == after["compiles"] + 1
+    assert load.value() == pytest.approx(before[0] + 0.25)
+
+
+def test_recent_is_bounded_ordered_and_plain_data(monkeypatch):
+    import collections
+
+    monkeypatch.setattr(OT, "_RECENT", collections.deque(maxlen=3))
+    for k in range(5):
+        tr = OT.QueryTrace("request", kind="serve", id=f"r{k}")
+        tr.root.add("queue_wait", "serve", tr.root.t0, tr.root.t0 + 2e-6)
+        assert OT.finish(tr) is tr and tr.root.t1 is not None  # closed
+    log = OT.recent()
+    assert [t["root"]["attrs"]["id"] for t in log] == ["r2", "r3", "r4"]
+    assert log[-1] == tr.to_dict()  # rendered when read, not when kept
+    assert OT.RECENT_CAPACITY == 4096
+    assert json.loads(json.dumps(log)) == log  # numbers and strings only
+    child = log[0]["root"]["children"][0]
+    assert child["kind"] == "serve" and child["seconds"] == 2e-6
 
 
 # ---------------------------------------------------------------------------
@@ -472,19 +649,6 @@ def test_legacy_counters_served_by_registry():
         OM.REGISTRY.get("tpu_cypher_fault_site_hits_total").value(site="join")
     ) == 1
     faults.reset_counters()
-
-
-def test_measurement_shim_is_deprecated_but_works():
-    import importlib
-    import tpu_cypher.utils.measurement as m
-
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        importlib.reload(m)
-    assert any(issubclass(x.category, DeprecationWarning) for x in w)
-    out = m.time_stage("t_shim", lambda a: a + 1, 41)
-    assert out == 42
-    assert "t_shim" in m.last_timings()
 
 
 # ---------------------------------------------------------------------------

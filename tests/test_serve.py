@@ -486,6 +486,262 @@ def test_http_query_record_serves_profile(session, graph):
     assert mstatus.endswith("404 Not Found")
 
 
+# ---------------------------------------------------------------------------
+# one span tree per served request (obs/trace.py): the serving stages as
+# spans of kind "serve", the engine's tree under ``dispatch``
+# ---------------------------------------------------------------------------
+
+
+def _flat(node, out=None):
+    out = [] if out is None else out
+    out.append(node)
+    for c in node.get("children", ()):
+        _flat(c, out)
+    return out
+
+
+def _assert_nested(node, parent=None, eps=2e-6):
+    lo, hi = node["start_s"], node["start_s"] + node["seconds"]
+    if parent is not None:
+        plo, phi = parent["start_s"], parent["start_s"] + parent["seconds"]
+        assert plo - eps <= lo and hi <= phi + eps, (node["name"], parent["name"])
+    for c in node.get("children", ()):
+        _assert_nested(c, node)
+
+
+def test_served_request_is_one_tree_from_request_to_the_sync_leaves(
+        session, graph):
+    from tpu_cypher.obs import trace as OT
+
+    async def run():
+        async with _serve(session, graph, cache_bytes=0) as srv:
+            for qid in ("t0", "t1"):  # the second is a plan-cache hit
+                await _client(srv.host, srv.port, [
+                    {"op": "submit", "id": qid, "graph": "g", "query": HOP_Q,
+                     "tenant": "acme"},
+                ])
+            _, body = await _http(srv.host, srv.port, "/queries/t1")
+        return json.loads(body)
+
+    rec = asyncio.run(run())
+    prof = rec["profile"]
+    assert prof == OT.recent()[-1]  # /queries/<id> renders the kept tree
+    assert prof["schema_version"] == 2
+    root = prof["root"]
+    assert (root["name"], root["kind"]) == ("request", "serve")
+    assert root["attrs"] == {"id": "t1", "graph": "g", "tenant": "acme"}
+    assert [c["name"] for c in root["children"]] == [
+        "cache", "batch_window", "queue_wait", "dispatch", "serialize",
+        "demux"]
+    assert all(c["kind"] == "serve" for c in root["children"])
+    dispatch = root["children"][3]
+    assert [(c["name"], c["kind"]) for c in dispatch["children"]] == [
+        ("route", "serve"), ("engine", "query"), ("route", "serve")]
+    assert [c["attrs"]["hop"] for c in dispatch["children"][::2]] == [
+        "out", "back"]
+    engine = dispatch["children"][1]
+    assert engine["attrs"]["plan_cache"] == "hit"
+    assert [c["name"] for c in engine["children"]] == [
+        "plan_cache", "execute", "feedback", "collect", "encode"]
+    assert all(c["kind"] == "phase" for c in engine["children"])
+    spans = _flat(root)
+    assert all("start_s" in sp for sp in spans)
+    assert [sp for sp in spans if sp["kind"] == "sync"]  # down to the reads
+    assert [sp for sp in spans if sp["kind"] == "operator"]
+    _assert_nested(root)
+    assert rec["status"] == "done" and rec["rungs"] == ["device"]
+
+
+def test_stages_are_the_sums_of_the_serve_spans(session, graph):
+    """One measurement, two views: ``QueryServer.stages[k]`` is the sum of
+    the ``serve`` spans named ``k`` over every request's tree."""
+
+    async def run():
+        async with _serve(session, graph, cache_bytes=0) as srv:
+            ids = [f"s{i}" for i in range(4)]
+            for qid, q in zip(ids, (COUNT_Q, HOP_Q, ROWS_Q, HOP_Q)):
+                await _client(srv.host, srv.port, [
+                    {"op": "submit", "id": qid, "graph": "g", "query": q},
+                ])
+            await _stream_client(srv.host, srv.port, {
+                "op": "submit", "id": "s4", "graph": "g", "query": ROWS_Q,
+                "stream": True,
+            })
+            return dict(srv.stages), [
+                srv._records[q]["profile"].to_dict() for q in ids + ["s4"]]
+
+    stages, profiles = asyncio.run(run())
+    sums = {}
+    for prof in profiles:
+        for sp in _flat(prof["root"])[1:]:
+            if sp["kind"] == "serve":
+                sums[sp["name"]] = sums.get(sp["name"], 0.0) + sp["seconds"]
+    assert set(stages) == set(sums) == {
+        "cache", "batch_window", "queue_wait", "dispatch", "route",
+        "serialize", "demux"}
+    for name, seconds in stages.items():
+        # spans render rounded to the microsecond
+        assert seconds == pytest.approx(sums[name], abs=2e-5), name
+    assert stages["route"] < stages["dispatch"]
+
+
+@pytest.mark.parametrize("path", ["error", "cancelled", "cached"])
+def test_every_terminal_path_closes_its_tree(session, graph, path):
+    from tpu_cypher.obs import trace as OT
+
+    async def run():
+        kw = {"max_concurrent": 1} if path == "cancelled" else {}
+        async with _serve(session, graph, **kw) as srv:
+            if path == "error":
+                await _client(srv.host, srv.port, [
+                    {"op": "submit", "id": "x", "graph": "g",
+                     "query": "MATCH (a:P RETURN a"},
+                ])
+            elif path == "cached":
+                for qid in ("warm", "x"):
+                    await _client(srv.host, srv.port, [
+                        {"op": "submit", "id": qid, "graph": "g",
+                         "query": ROWS_Q},
+                    ])
+            else:
+                await srv.scheduler.acquire(1, "holder")
+                reader, writer = await asyncio.open_connection(
+                    srv.host, srv.port)
+                for msg in ({"op": "submit", "id": "x", "graph": "g",
+                             "query": COUNT_Q},):
+                    writer.write((json.dumps(msg) + "\n").encode())
+                await writer.drain()
+                await asyncio.sleep(0.05)  # the window elapses; x queues
+                writer.write((json.dumps({"op": "cancel", "id": "x"})
+                              + "\n").encode())
+                await writer.drain()
+                while json.loads(await asyncio.wait_for(
+                        reader.readline(), 30)).get("type") != "cancelled":
+                    pass
+                srv.scheduler.release("holder")
+                writer.close()
+            _, body = await _http(srv.host, srv.port, "/queries/x")
+        return json.loads(body)
+
+    rec = asyncio.run(run())
+    root = rec["profile"]["root"]
+    assert rec["profile"] == OT.recent()[-1]
+    assert root["name"] == "request" and root["attrs"]["id"] == "x"
+    assert root["seconds"] > 0  # closed in _terminal, whatever the path
+    names = [c["name"] for c in root["children"]]
+    if path == "error":
+        assert rec["status"] == "error" and root["status"] == "error"
+        assert names[:3] == ["cache", "batch_window", "queue_wait"]
+    elif path == "cancelled":
+        assert rec["status"] == "cancelled" and root["status"] == "cancelled"
+        assert "dispatch" not in names  # it never reached the engine
+    else:
+        assert rec["cached"] is True and root["status"] == "ok"
+        assert names == ["cache", "serialize", "demux"]
+        assert root["children"][0]["attrs"] == {"hit": True}
+    _assert_nested(root)
+
+
+def test_batch_follower_hangs_the_shared_execution_under_its_wait(
+        session, graph):
+    async def run():
+        async with _serve(session, graph, batch_window_ms=50,
+                          cache_bytes=0) as srv:
+            msgs = await _client(srv.host, srv.port, [
+                {"op": "submit", "id": f"f{i}", "graph": "g", "query": HOP_Q}
+                for i in range(3)
+            ])
+            return msgs, {q: srv._records[q]["profile"].to_dict()
+                          for q in ("f0", "f1", "f2")}
+
+    msgs, recs = asyncio.run(run())
+    leader = _terminals(msgs)["f0"]["batch_leader"]
+    follower = next(q for q in recs if q != leader)
+    names = [c["name"] for c in recs[leader]["root"]["children"]]
+    assert "batch_window" in names and "dispatch" in names
+    froot = recs[follower]["root"]
+    wait = next(c for c in froot["children"] if c["name"] == "batch_wait")
+    assert wait["attrs"] == {"leader": leader}
+    shared = wait["children"][0]
+    assert shared["name"] == "dispatch"
+    assert "engine" in [c["name"] for c in shared["children"]]
+    assert "dispatch" not in [c["name"] for c in froot["children"]]
+    _assert_nested(froot)
+
+
+def test_streamed_pages_keep_a_bounded_number_of_spans(session, graph,
+                                                       monkeypatch):
+    from tpu_cypher.serve import server as SRV
+
+    monkeypatch.setattr(SRV, "PAGE_ROWS", 1)
+    monkeypatch.setattr(SRV, "_PAGE_SPANS_MAX", 3)
+    q = "MATCH (a:P) RETURN a.id AS id ORDER BY id"  # 16 one-row pages
+
+    async def run():
+        async with _serve(session, graph) as srv:
+            msgs = await _stream_client(srv.host, srv.port, {
+                "op": "submit", "id": "pg", "graph": "g", "query": q,
+                "stream": True,
+            })
+            return (msgs, srv._records["pg"]["profile"].to_dict(),
+                    dict(srv.stages))
+
+    msgs, prof, stages = asyncio.run(run())
+    assert _terminals(msgs)["pg"]["rows"] == 16
+    root = prof["root"]
+    ser = [c for c in root["children"] if c["name"] == "serialize"]
+    assert len(ser) == 3 and ser[-1]["attrs"]["pages"] == 14
+    assert sum(c["seconds"] for c in ser) == pytest.approx(
+        stages["serialize"], abs=2e-5)
+    engine = next(c for c in _flat(root) if c["name"] == "engine")
+    (encode,) = [c for c in engine["children"] if c["name"] == "encode"]
+    assert encode["attrs"]["pages"] == 16  # one span, however many pages
+
+
+def test_cluster_records_keep_a_profile_with_the_workers_tree_under_route():
+    """Cluster mode: the engine ran in another process on another clock,
+    so its rendered tree hangs under the front end's ``route`` span with
+    its own offsets, marked as such."""
+    from tpu_cypher.serve.cluster import ClusterServer
+
+    worker_tree = {"name": "query", "kind": "query", "start_s": 0.0,
+                   "seconds": 0.002, "children": [
+                       {"name": "execute", "kind": "phase",
+                        "start_s": 0.0001, "seconds": 0.0015}]}
+
+    class StubRouter:
+        async def submit(self, **kw):
+            return {"rows": [{"n": 2}], "columns": ["n"], "seconds": 0.002,
+                    "execution_log": [{"rung": "device", "ok": True}],
+                    "rungs": ["device"], "degraded": False,
+                    "compile_stats": {},
+                    "profile": {"schema_version": 2, "root": worker_tree}}
+
+    async def run():
+        srv = ClusterServer(workers=1, port=0, batch_window_ms=0,
+                            cache_bytes=0)
+        srv.register_graph("g", "CREATE (:P {id: 1})-[:K]->(:P {id: 2})")
+        srv.router = StubRouter()
+        await QueryServer.start(srv)
+        try:
+            msgs = await _client(srv.host, srv.port, [
+                {"op": "submit", "id": "c", "graph": "g", "query": COUNT_Q},
+            ])
+            _, body = await _http(srv.host, srv.port, "/queries/c")
+        finally:
+            await QueryServer.stop(srv)
+        return msgs, json.loads(body), dict(srv.stages)
+
+    msgs, rec, stages = asyncio.run(run())
+    assert _rows_of(msgs, "c") == [{"n": 2}]
+    dispatch = next(c for c in rec["profile"]["root"]["children"]
+                    if c["name"] == "dispatch")
+    (route,) = dispatch["children"]
+    assert route["name"] == "route" and route["kind"] == "serve"
+    assert route["children"] == [{**worker_tree, "clock": "worker"}]
+    assert stages["route"] == pytest.approx(route["seconds"], abs=2e-6)
+
+
 def test_http_healthz_and_404(session, graph):
     async def run():
         async with _serve(session, graph) as srv:

@@ -283,12 +283,19 @@ _PCACHE_MISSES = _REGISTRY.counter(
     "persistent compilation cache misses (compile went to XLA)",
 )
 
+_PCACHE_LOAD_SECONDS = _REGISTRY.counter(
+    "tpu_cypher_persistent_cache_load_seconds_total",
+    "seconds spent loading programs from the persistent compilation cache "
+    "(the compile-or-load step of a cache hit: not a compile)",
+)
+
 _LISTENER_INSTALLED = False
 
 # the installed JAX times ``backend_compile_duration`` around the whole
 # compile-or-load step, so a program LOADED from the persistent cache fires
 # it too — right after its ``cache_hits`` event, on the same thread. The
-# flag lets that one duration event pass uncounted: a load is not a compile.
+# flag sends that one duration event to the load counter: a load is not a
+# compile, and the two compile counters never see it.
 _LOADED = threading.local()
 
 
@@ -296,6 +303,7 @@ def _on_event_duration(name: str, secs: float, **_kw) -> None:
     if name.endswith("backend_compile_duration"):
         if getattr(_LOADED, "from_cache", False):
             _LOADED.from_cache = False
+            _PCACHE_LOAD_SECONDS.inc(float(secs))
             return
         _COMPILES_TOTAL.inc()
         _COMPILE_SECONDS_TOTAL.inc(float(secs))

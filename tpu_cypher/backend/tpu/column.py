@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ...api import types as T
 from ...api.types import CypherType
+from ...obs import trace as _obs_trace
 from ...parallel.mesh import padded_to_mesh
 
 def to_host(arr) -> np.ndarray:
@@ -477,18 +478,25 @@ class Column:
         D2H transfer per column regardless of how many chunks it spans
         (and never compiles a per-bounds device slice program)."""
         if self._host_fetch is None:
+            def fetch(arr):
+                if isinstance(arr, np.ndarray):
+                    return arr
+                # the read that waits for whatever program fills ``arr``
+                with _obs_trace.sync("to_host"):
+                    return to_host(arr)
+
             data = (
                 self._np_cache if self._np_cache is not None
-                else to_host(self.data)
+                else fetch(self.data)
             )
             if self.valid is None:
                 valid = None
             elif self._np_valid is not None:
                 valid = self._np_valid
             else:
-                valid = to_host(self.valid)
+                valid = fetch(self.valid)
             iflag = (
-                to_host(self.int_flag) if self.int_flag is not None else None
+                fetch(self.int_flag) if self.int_flag is not None else None
             )
             self._host_fetch = (data, valid, iflag)
         return self._host_fetch
@@ -746,7 +754,9 @@ def mask_to_idx_bucketed(mask) -> Tuple[Any, int]:
     from .jit_ops import mask_nonzero, mask_sum
 
     fault_point("compact")
-    count = int(mask_sum(mask))
+    n_dev = mask_sum(mask)
+    with _obs_trace.sync("compact"):
+        count = int(n_dev)
     return mask_nonzero(mask, size=round_size(count)), count
 
 

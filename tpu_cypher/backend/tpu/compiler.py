@@ -251,7 +251,6 @@ class TpuEvaluator:
             header, params, n = self.header, used_params, self.n
             meta: Dict[str, Any] = {}
 
-            @jax.jit
             def fn(ci):
                 cols = {
                     c: Column(
@@ -265,6 +264,9 @@ class TpuEvaluator:
                 meta["vocab"] = out.vocab
                 return out.data, out.valid, out.int_flag
 
+            # a name of its own in device traces: jit_eval_<expression>
+            fn.__name__ = f"eval_{type(expr).__name__.lower()}"
+            fn = jax.jit(fn)
             if len(_EVAL_JIT_CACHE) >= _EVAL_JIT_CACHE_MAX:
                 _EVAL_JIT_CACHE.clear()
             try:

@@ -205,7 +205,8 @@ def _fused_chain_walk(
         rp, ci, eo = gi.csr(hop.types_key, hop.backwards, ctx)
         mask = gi.label_mask(hop.far_labels, ctx)
         deg, t_dev = J.expand_degrees_total(rp, pos, present)
-        total = int(t_dev)
+        with _obs_trace.sync("expand"):
+            total = int(t_dev)
         if total == 0:
             return 0
         # bucketed: the static materialize size rounds up to the lattice;
@@ -373,7 +374,9 @@ class _FusedExpandBase(RelationalOperator):
             idx, n2 = _mask_to_idx_bucketed(keep)
             taken = J.tree_take((row, orig) + tuple(extras), idx)
             return taken[0], taken[1], tuple(taken[2:]), n2
-        n2 = int(J.mask_sum(keep))
+        n_dev = J.mask_sum(keep)
+        with _obs_trace.sync("compact"):
+            n2 = int(n_dev)
         if n2 != n_out:
             # tpulint: allow[pad-invariant] reason=bucketing-off branch only (the enabled branch above routes through _mask_to_idx_bucketed); exact size is the contract here
             idx = J.mask_nonzero(keep, size=n2)
@@ -612,7 +615,8 @@ class CsrExpandOp(_FusedExpandBase):
         ctx = self.context
         rp, ci, eo = gi.csr(self.types_key, reverse, ctx)
         deg, t_dev = J.expand_degrees_total(rp, pos, present)
-        total = int(t_dev)
+        with _obs_trace.sync("expand"):
+            total = int(t_dev)
         # pre-flight: (row, nbr, orig) int64 lanes + every gathered output
         # column (8B data + 1B mask), padded on the bucket lattice
         bucketing.admit(
@@ -708,7 +712,9 @@ class CsrExpandOp(_FusedExpandBase):
             # the chain's O(edges) SpMV
             pos, present = gi.compact_of(id_col, ctx)
             rp, _, _ = gi.csr(self.types_key, self.backwards, ctx)
-            return int(J.frontier_degree_sum(rp, pos, present))
+            n_dev = J.frontier_degree_sum(rp, pos, present)
+            with _obs_trace.sync("expand"):
+                return int(n_dev)
         hop_data = []
         for hop in reversed(hops):  # deepest (first executed) hop first
             mask = gi.label_mask(hop.far_labels, ctx)
@@ -738,15 +744,15 @@ class CsrExpandOp(_FusedExpandBase):
             if divisible and size > 1:
                 chain = J.path_count_chain_on_mesh(mesh, axis)
                 _obs_trace.note("expand_shards", size)
-        return int(
-            chain(
-                dev_ids,
-                id_col.data,
-                id_col.valid,
-                tuple(hop_data),
-                num_nodes=gi.num_nodes,
-            )
+        n_dev = chain(
+            dev_ids,
+            id_col.data,
+            id_col.valid,
+            tuple(hop_data),
+            num_nodes=gi.num_nodes,
         )
+        with _obs_trace.sync("expand"):  # the read that waits for the chain
+            return int(n_dev)
 
     def distinct_endpoints_count(self, fields) -> Optional[int]:
         """count(DISTINCT endpoints) over a fused expand chain WITHOUT
@@ -942,7 +948,8 @@ class CsrExpandOp(_FusedExpandBase):
             return None
         fault_point("expand")  # the run-total scalar sync below
         lo, cnt, t_dev = _csr_run_bounds(rp, pos, present, np.int64(in_t.size))
-        total = int(t_dev)
+        with _obs_trace.sync("expand"):
+            total = int(t_dev)
         nexprs = max(len(self.header.expressions), 1)
         if not prefer_factorized(total, 24 + 9 * nexprs):
             return None
@@ -1061,7 +1068,9 @@ class CsrExpandOp(_FusedExpandBase):
                     row, orig, far_rows = J.tree_take((row, orig, far_rows), idx)
         elif gi.num_nodes:
             far_rows, keep = J.far_lookup(row_map, nbr)
-            n_out = int(J.mask_sum(keep))
+            n_dev = J.mask_sum(keep)
+            with _obs_trace.sync("expand"):
+                n_out = int(n_dev)
             if n_out != int(row.shape[0]):  # skip nonzero+gather when all match
                 idx = J.mask_nonzero(keep, size=n_out)
                 if swapped is not None:
@@ -1458,7 +1467,8 @@ class CsrOptionalExpandOp(_FusedExpandBase):
         deg, counts, t_dev = J.optional_expand_degrees(
             rp, pos, present, nrows=nrows
         )
-        total = int(t_dev)
+        with _obs_trace.sync("expand"):
+            total = int(t_dev)
         row, nbr, orig, matched = J.optional_expand_materialize(
             rp, ci, eo, pos, deg, counts, total=total
         )
@@ -1705,7 +1715,8 @@ class CsrVarExpandOp(_FusedExpandBase):
         for level in range(1, self._resolved_upper(ci) + 1):
             fault_point("var_expand")
             deg, t_dev = J.expand_degrees_total(rp, pos, present)
-            total = int(t_dev)
+            with _obs_trace.sync("var_expand"):
+                total = int(t_dev)
             if total == 0:
                 break
             # pre-flight: each hop row carries (row0, nbr, orig) plus one

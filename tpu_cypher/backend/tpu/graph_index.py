@@ -24,7 +24,10 @@ types), so one cache serves every query variable name.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+import contextlib
+import contextvars
+import time
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -32,12 +35,48 @@ import jax.numpy as jnp
 
 from ...api import types as T
 from ...ir import expr as E
+from ...obs import trace as _obs_trace
+from ...obs.metrics import REGISTRY as _REGISTRY
 from .bucketing import ID_SENTINEL, bucket_pad_host
 from .column import Column, TpuBackendError, device_padded
 
 # canonical scan variable names (reserved: queries cannot produce '$' vars)
 CANON_NODE = "$gi_n"
 CANON_REL = "$gi_r"
+
+
+INDEX_BUILD_SECONDS = _REGISTRY.counter(
+    "tpu_cypher_index_build_seconds_total",
+    "host seconds building a graph's lazy indexes (each build's own time: "
+    "a build made inside another counts once, under its own name)",
+    labels=("index",),
+)
+
+# seconds of the builds nested in the one open in this context
+_NESTED: contextvars.ContextVar[Optional[List[float]]] = contextvars.ContextVar(
+    "tpu_cypher_index_build_nested", default=None
+)
+
+
+@contextlib.contextmanager
+def _build(what: str, **attrs) -> Iterator[Any]:
+    """One lazy index build: a span ``index:<what>`` (kind ``build``) and
+    its own seconds — less those of the builds it made on the way — in
+    ``tpu_cypher_index_build_seconds_total{index=<what>}``. A graph's first
+    queries are made of these; a warm index never comes here."""
+    nested = [0.0]
+    token = _NESTED.set(nested)
+    t0 = time.perf_counter()
+    try:
+        with _obs_trace.span(f"index:{what}", kind="build", **attrs) as sp:
+            yield sp
+    finally:
+        _NESTED.reset(token)
+        seconds = time.perf_counter() - t0
+        INDEX_BUILD_SECONDS.inc(max(seconds - nested[0], 0.0), index=what)
+        outer = _NESTED.get()
+        if outer is not None:
+            outer[0] += seconds
 
 
 class GraphIndexError(TpuBackendError):
@@ -168,10 +207,15 @@ class GraphIndex:
         got = self._node_scans.get(key)
         if got is not None:
             return got
+        with _build("node_scan", labels=":".join(key)) as sp:
+            return self._build_node_scan(key, ctx, sp)
+
+    def _build_node_scan(self, key: Tuple[str, ...], ctx, sp):
         op = self.graph.scan_operator(
-            CANON_NODE, T.CTNodeType(frozenset(labels)), ctx
+            CANON_NODE, T.CTNodeType(frozenset(key)), ctx
         )
         table = op.table
+        sp.note("rows", table.size)
         header = op.header
         id_col = table._cols[header.column(E.Id(E.Var(CANON_NODE)))]
         ids_np = _host_logical(id_col, table.size)
@@ -210,8 +254,9 @@ class GraphIndex:
         if not key:
             return None
         if key not in self._label_mask:
-            self.node_scan(key, ctx)
-            self._label_mask[key] = jnp.asarray(self._row_map_np[key] >= 0)
+            with _build("label_mask", labels=":".join(key)):
+                self.node_scan(key, ctx)
+                self._label_mask[key] = jnp.asarray(self._row_map_np[key] >= 0)
         return self._label_mask[key]
 
     # -- relationships -----------------------------------------------------
@@ -225,12 +270,14 @@ class GraphIndex:
         got = self._rel_scans.get(types_key)
         if got is not None:
             return got
-        op = self.graph.scan_operator(
-            CANON_REL, T.CTRelationshipType(frozenset(types_key)), ctx
-        )
-        out = (op.table._cols, op.header)
-        self._rel_scans[types_key] = out
-        self._rel_sizes[types_key] = op.table.size
+        with _build("rel_scan", types=":".join(types_key)) as sp:
+            op = self.graph.scan_operator(
+                CANON_REL, T.CTRelationshipType(frozenset(types_key)), ctx
+            )
+            out = (op.table._cols, op.header)
+            self._rel_scans[types_key] = out
+            self._rel_sizes[types_key] = op.table.size
+            sp.note("rows", op.table.size)
         return out
 
     def rel_row_index(self, types_key: Tuple[str, ...], ctx):
@@ -257,9 +304,15 @@ class GraphIndex:
     def _edge_endpoints(self, types_key: Tuple[str, ...], ctx):
         """Resolve one type set's relationships to compact endpoint
         positions: (src_pos int64, dst_pos int64, num_nodes) — the shared
-        front half of every CSR build (validates endpoints)."""
+        front half of every CSR build (validates endpoints). Not kept:
+        each CSR orientation, and the planner's loop-free probe, resolve
+        the endpoints anew (a ``searchsorted`` per edge and end)."""
         cols, header = self.rel_scan(types_key, ctx)
         nrel = self._rel_sizes[types_key]
+        with _build("edge_endpoints", rows=nrel):
+            return self._resolve_endpoints(cols, header, nrel, ctx)
+
+    def _resolve_endpoints(self, cols, header, nrel: int, ctx):
         rel = E.Var(CANON_REL)
         start = cols[header.column(E.StartNode(rel))]
         end = cols[header.column(E.EndNode(rel))]
@@ -305,7 +358,13 @@ class GraphIndex:
         got = self._csr.get((types_key, reverse))
         if got is not None:
             return got
+        orientation = "reverse" if reverse else "forward"
+        with _build("csr", orientation=orientation) as sp:
+            return self._build_csr(types_key, reverse, ctx, sp)
+
+    def _build_csr(self, types_key: Tuple[str, ...], reverse: bool, ctx, sp):
         s, d, n = self._edge_endpoints(types_key, ctx)
+        sp.note("rows", len(s))
         a, b = (d, s) if reverse else (s, d)
         row_ptr, order, a_sorted = self._sorted_csr(a, b, n)
         degs = row_ptr[1:] - row_ptr[:-1]
@@ -356,8 +415,13 @@ class GraphIndex:
         got = self._csr_und.get(types_key)
         if got is not None:
             return got
+        with _build("csr", orientation="undirected") as sp:
+            return self._build_csr_undirected(types_key, ctx, sp)
+
+    def _build_csr_undirected(self, types_key: Tuple[str, ...], ctx, sp):
         s, d, n = self._edge_endpoints(types_key, ctx)
         nrel = len(s)
+        sp.note("rows", nrel)
         nonloop = s != d
         a = np.concatenate([s, d[nonloop]])
         b = np.concatenate([d, s[nonloop]])

@@ -37,6 +37,7 @@ DUR = "dur"  # int64 (n, 3): months / days / total micros (column.DUR)
 # (api.values.duration_order_us) so device and host ordering can never drift
 from ...api.values import _DUR_DAY_US as DUR_DAY_US  # noqa: E402
 from ...api.values import _DUR_MONTH_US as DUR_MONTH_US  # noqa: E402
+from ...obs import trace as _obs_trace  # noqa: E402
 
 
 def _dur_order_key(d2):
@@ -120,7 +121,9 @@ def mask_to_idx(mask) -> Tuple[Any, int]:
     from ...runtime.faults import fault_point
 
     fault_point("compact")
-    count = int(mask_sum(mask))
+    n_dev = mask_sum(mask)
+    with _obs_trace.sync("compact"):
+        count = int(n_dev)
     # tpulint: allow[pad-invariant] reason=the exact-compact primitive itself; bucketed callers go through mask_to_idx_bucketed, and the ladder's bucket-exact rung NEEDS the unrounded size
     return mask_nonzero(mask, size=count), count
 
@@ -609,7 +612,8 @@ def _csr_spmv(rp, ci, w):
     safety: a sharding pad tail (``ci`` = -1, clipped to 0) accumulates
     into cumsum positions past ``rp[-1]`` that no boundary ever reads."""
     t = jnp.take(w, jnp.clip(ci, 0).astype(jnp.int64))
-    ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
+    with jax.named_scope("scan"):
+        ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
     rp64 = rp.astype(jnp.int64)
     return jnp.take(ps, rp64[1:]) - jnp.take(ps, rp64[:-1])
 
@@ -633,7 +637,8 @@ def _sharded_spmv(mesh, axis: str):
             jnp.take(w_r, jnp.clip(ci_shard, 0).astype(jnp.int64)),
             jnp.zeros((), w_r.dtype),
         )
-        ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
+        with jax.named_scope("scan"):
+            ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
         lo = lax.axis_index(axis).astype(jnp.int64) * size
         rp64 = rp_r.astype(jnp.int64)
         a = jnp.clip(rp64[:-1] - lo, 0, size)
@@ -651,21 +656,26 @@ def _sharded_spmv(mesh, axis: str):
 
 def _chain_body(dev_ids, ids, valid, hops, num_nodes: int, spmv):
     """Shared traced body of the fused count chain (see
-    ``path_count_chain``); ``spmv`` is the single-device or sharded SpMV."""
+    ``path_count_chain``); ``spmv`` is the single-device or sharded SpMV.
+    Each hop's operations carry a ``hop<i>`` scope (``i`` counts in the
+    order executed; metadata only), so a kept device trace tells the hops
+    of one program apart."""
     w = jnp.ones(num_nodes, jnp.int64)
-    for (rp_a, ci_a, rp_b, ci_b, loop_cnt, mask) in reversed(hops):
-        if mask is not None:  # far-label filter of this hop
-            w = jnp.where(mask, w, 0)
-        nw = spmv(rp_a, ci_a, w)
-        if rp_b is not None:
-            nw = nw + spmv(rp_b, ci_b, w) - loop_cnt * w
-        w = nw
+    for i, (rp_a, ci_a, rp_b, ci_b, loop_cnt, mask) in enumerate(reversed(hops)):
+        with jax.named_scope(f"hop{i}"):
+            if mask is not None:  # far-label filter of this hop
+                w = jnp.where(mask, w, 0)
+            nw = spmv(rp_a, ci_a, w)
+            if rp_b is not None:
+                nw = nw + spmv(rp_b, ci_b, w) - loop_cnt * w
+            w = nw
     # base frontier: one completion-count gather per input row
-    pos = jnp.clip(jnp.searchsorted(dev_ids, ids), 0, num_nodes - 1)
-    present = jnp.take(dev_ids, pos) == ids
-    if valid is not None:
-        present = present & valid
-    return jnp.sum(jnp.where(present, jnp.take(w, pos), 0))
+    with jax.named_scope("frontier"):
+        pos = jnp.clip(jnp.searchsorted(dev_ids, ids), 0, num_nodes - 1)
+        present = jnp.take(dev_ids, pos) == ids
+        if valid is not None:
+            present = present & valid
+        return jnp.sum(jnp.where(present, jnp.take(w, pos), 0))
 
 
 @partial(jax.jit, static_argnames=("num_nodes",))
@@ -1358,14 +1368,18 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
     int_flag using the scalar so column metadata stays canonical."""
     n = data.shape[0]
     v = valid if valid is not None else jnp.ones(n, bool)
-    cnt = jax.ops.segment_sum(v.astype(jnp.int64), seg_j, num_segments=k)
+    # the phases carry scopes of their own (metadata only: a kept device
+    # trace tells them apart): count, sum, minmax, intness
+    with jax.named_scope("count"):
+        cnt = jax.ops.segment_sum(v.astype(jnp.int64), seg_j, num_segments=k)
     if name == "count":
         return cnt, None, None, None
     if name in ("sum", "avg", "stdev", "stdevp"):
         zero = jnp.zeros((), data.dtype)
-        ssum = jax.ops.segment_sum(
-            jnp.where(v, data, zero), seg_j, num_segments=k
-        )
+        with jax.named_scope("sum"):
+            ssum = jax.ops.segment_sum(
+                jnp.where(v, data, zero), seg_j, num_segments=k
+            )
         if name == "sum":
             if kind == F64:
                 # Cypher sum over no values is the INTEGER 0, and the sum
@@ -1417,17 +1431,19 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
         else jnp.asarray(jnp.iinfo(d.dtype).max, d.dtype)
     )
     if name == "min":
-        agged = jax.ops.segment_min(
-            jnp.where(nn_valid, d, big), seg_j, num_segments=k
-        )
+        with jax.named_scope("minmax"):
+            agged = jax.ops.segment_min(
+                jnp.where(nn_valid, d, big), seg_j, num_segments=k
+            )
         if nan_cnt is not None:
             # all-NaN group: min is NaN (NaN sorts above numbers)
             agged = jnp.where((cnt - nan_cnt == 0) & (nan_cnt > 0), jnp.nan, agged)
     else:
         low = -big if kind != STR else -jnp.ones((), d.dtype)
-        agged = jax.ops.segment_max(
-            jnp.where(nn_valid, d, low), seg_j, num_segments=k
-        )
+        with jax.named_scope("minmax"):
+            agged = jax.ops.segment_max(
+                jnp.where(nn_valid, d, low), seg_j, num_segments=k
+            )
         if nan_cnt is not None:
             # any NaN: NaN is the maximum under Cypher orderability
             agged = jnp.where(nan_cnt > 0, jnp.nan, agged)
@@ -1439,15 +1455,16 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
         # Cypher intness of the winning value: the oracle's min/max keeps
         # the FIRST minimal/maximal element in row order, so take the
         # int_flag of the first row matching the aggregate
-        cand = nn_valid & (d == jnp.take(agged, seg_j))
-        first_row = jax.ops.segment_min(
-            jnp.where(cand, jnp.arange(n, dtype=jnp.int64), n),
-            seg_j,
-            num_segments=k,
-        )
-        safe_row = jnp.clip(first_row, 0, max(n - 1, 0))
-        out_iflag = jnp.take(iflag, safe_row) & (first_row < n)
-        iflag_any = jnp.any(out_iflag)
+        with jax.named_scope("intness"):
+            cand = nn_valid & (d == jnp.take(agged, seg_j))
+            first_row = jax.ops.segment_min(
+                jnp.where(cand, jnp.arange(n, dtype=jnp.int64), n),
+                seg_j,
+                num_segments=k,
+            )
+            safe_row = jnp.clip(first_row, 0, max(n - 1, 0))
+            out_iflag = jnp.take(iflag, safe_row) & (first_row < n)
+            iflag_any = jnp.any(out_iflag)
     return agged, cnt > 0, out_iflag, iflag_any
 
 

@@ -67,6 +67,7 @@ from .column import (
 from .compiler import TpuEvaluator, TpuUnsupportedExpr
 
 
+from ...obs import trace as _obs_trace
 from ...obs.metrics import REGISTRY as _OBS_REGISTRY
 
 _FALLBACKS = _OBS_REGISTRY.counter(
@@ -995,8 +996,9 @@ class TpuTable(Table):
         datas = tuple(c.data for c in cols)
         valids = tuple(c.valid for c in cols)
         mins, maxs = J.order_minmax(datas, valids)
-        mins = np.asarray(mins)
-        maxs = np.asarray(maxs)
+        with _obs_trace.sync("order"):
+            mins = np.asarray(mins)
+            maxs = np.asarray(maxs)
         pack = []
         total_bits = 0
         for lo, hi in zip(mins, maxs):
@@ -1072,8 +1074,9 @@ class TpuTable(Table):
         if nkeys < min_keys:
             return None
         mins, maxs = J.equivalence_minmax(datas, valids, extras, kinds)
-        mins = np.asarray(mins)
-        maxs = np.asarray(maxs)
+        with _obs_trace.sync("agg"):
+            mins = np.asarray(mins)
+            maxs = np.asarray(maxs)
         bits = [(int(hi) - int(lo)).bit_length() for lo, hi in zip(mins, maxs)]
         if sum(bits) > 63:
             return None
@@ -1104,11 +1107,13 @@ class TpuTable(Table):
             sharded = self._sharded_distinct_count(datas, valids, kinds, pack)
             if sharded is not None:
                 return sharded
-            return int(J.distinct_count_packed(datas, valids, (), kinds, pack))
-        # unpackable keys: sort unpacked directly — re-probing min/max via
-        # _first_occurrence_index would repeat the device round trip
-        _, _, cnt = J.equivalence_sort(datas, valids, (), kinds, pack=None)
-        return int(cnt)
+            cnt = J.distinct_count_packed(datas, valids, (), kinds, pack)
+        else:
+            # unpackable keys: sort unpacked directly — re-probing min/max
+            # via _first_occurrence_index would repeat the device round trip
+            _, _, cnt = J.equivalence_sort(datas, valids, (), kinds, pack=None)
+        with _obs_trace.sync("agg"):
+            return int(cnt)
 
     def _sharded_distinct_count(self, datas, valids, kinds, pack):
         """Mesh tier of the distinct-count pushdown: hash-repartition the
@@ -1180,7 +1185,8 @@ class TpuTable(Table):
         extras = (np.arange(phys) >= n,)
         order, flags, _ = self._first_occurrence_index(on, extra_keys=extras)
         flags, cnt = J.live_first_flags(order, flags, n)
-        cnt = int(cnt)
+        with _obs_trace.sync("distinct"):
+            cnt = int(cnt)
         first = J.first_occurrence_rows_counted(
             order, flags, cnt, k=bucketing.round_size(cnt)
         )
@@ -1254,7 +1260,8 @@ class TpuTable(Table):
             )
         if by and n > 0:
             order, flags, cnt = self._first_occurrence_index(by)
-            k = int(cnt)
+            with _obs_trace.sync("agg"):  # the group count
+                k = int(cnt)
             # group ids renumbered in first-occurrence order (= the local
             # oracle), one jitted dispatch
             seg_j, first_rows = J.group_index(order, flags, k=k)

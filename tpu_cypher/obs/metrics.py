@@ -24,7 +24,7 @@ Design points:
   discipline the fallback counter proved. Scopes nest; each sees its own
   copy.
 * **Histograms** — count/sum/min/max plus p50/p95 over a bounded window
-  (the ``utils/measurement.py`` stage-timing role, folded in here).
+  (``tpu_cypher_stage_seconds``: one observation per phase span).
 * **Export sinks** — Prometheus text format (``prometheus_text`` /
   ``CypherSession.metrics_text()``) and JSON-lines events appended to
   ``TPU_CYPHER_METRICS_FILE`` (one line per query; see ``write_event``).
@@ -35,8 +35,7 @@ from __future__ import annotations
 import contextvars
 import json
 import threading
-import time
-from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple
 
 # PRINT_TIMINGS: the stage-timing echo flag, ONE declaration shared with
 # the session's timing path; METRICS_FILE: the JSON-lines per-query sink.
@@ -218,8 +217,7 @@ class Histogram(Metric):
 
     def summary(self, **labels) -> Dict[str, float]:
         """count / sum / min / max / p50 / p95 for one series (zeros when
-        the series has never observed) — the ``utils/measurement.py``
-        p50/p95/max histogram, per labeled series."""
+        the series has never observed)."""
         with self._reg._lock:
             st = self._series.get(self._key_locked(labels))
             return st.summary() if st is not None else _HistState().summary()
@@ -455,42 +453,23 @@ def write_event(event: Dict[str, Any]) -> None:
 
 
 # ---------------------------------------------------------------------------
-# stage timing (folded in from utils/measurement.py)
+# stage timing
 # ---------------------------------------------------------------------------
 
 STAGE_SECONDS = REGISTRY.histogram(
     "tpu_cypher_stage_seconds",
-    "wall seconds per pipeline phase (parse/ir/logical/.../execute)",
+    "wall seconds per pipeline phase (plan_cache/parse/ir/.../execute)",
     labels=("stage",),
 )
 
-_TIMINGS: List[Tuple[str, float]] = []
-
 
 def record_stage(name: str, seconds: float) -> None:
-    """One pipeline-phase timing: registry histogram + the bounded recent
-    list ``last_timings`` reads + the ``TPU_CYPHER_PRINT_TIMINGS`` echo
+    """One pipeline-phase timing (``obs.trace`` calls it as each phase span
+    closes): the registry histogram + the ``TPU_CYPHER_PRINT_TIMINGS`` echo
     (reference ``Measurement.scala:36-56`` / ``PrintTimings``)."""
     STAGE_SECONDS.observe(seconds, stage=name)
-    _TIMINGS.append((name, seconds))
-    del _TIMINGS[:-64]
     if PRINT_TIMINGS.get():
         print(f"[timing] {name}: {seconds * 1000:.2f} ms")
-
-
-def time_stage(name: str, fn: Callable, *args, **kw):
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    record_stage(name, time.perf_counter() - t0)
-    return out
-
-
-def last_timings() -> Dict[str, float]:
-    return dict(_TIMINGS[-16:])
-
-
-def clear_timings() -> None:
-    _TIMINGS.clear()
 
 
 # ---------------------------------------------------------------------------

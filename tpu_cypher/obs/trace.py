@@ -21,10 +21,21 @@ Costs, by design:
   boundaries absorb action time);
 * when no trace is active every instrumentation point is one contextvar
   read returning a shared null span;
-* the device-trace backend rides ``utils/profiling.py``: with
-  ``TPU_CYPHER_PROFILE_DIR`` set, each span also opens a
-  ``jax.profiler.TraceAnnotation`` so the same tree shows up region-named
-  inside TensorBoard/Perfetto device traces.
+* every live span also opens a ``jax.profiler.TraceAnnotation``
+  (``tpu_cypher:<kind>:<name>``): a no-op while no profiler session runs,
+  and inside ANY capture — the operator's own ``jax.profiler.start_trace``,
+  TensorBoard, ``chipbench --keep-trace`` — the same tree shows up
+  region-named beside the device's operations.
+
+The clock: a ``QueryTrace`` stamps its root when it is made
+(``perf_counter`` and ``time_ns``), every span keeps its start and end on
+that ``perf_counter``, and ``to_dict()`` gives each span ``start_s``, its
+offset from the root's start. A served request is ONE tree
+(``serve/server.py``): root ``request``, the serving stages as closed
+spans of kind ``serve``, the engine's own tree grafted under ``dispatch``
+as ``engine``. Finished request trees go to a bounded in-process log
+(``finish`` / ``recent``) that the benchmark's readers read; a tree holds
+numbers and strings only.
 
 Context-locality: the active trace/span ride ``contextvars``, so
 interleaved queries (threads, asyncio, nested view execution) each grow
@@ -34,40 +45,83 @@ the execution guard.
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import itertools
 import json
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
-from ..utils.profiling import PROFILE_DIR
 from . import metrics as M
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
+
+# finished request trees kept by ``finish`` for ``recent`` (about 4 KB each)
+RECENT_CAPACITY = 4096
+
+HOST_SYNCS = M.REGISTRY.counter(
+    "tpu_cypher_host_syncs_total",
+    "blocking device->host reads, by the enclosing fault site",
+    labels=("site",),
+)
 
 
 class Span:
     """One node of the tree: a named, timed region with attributes."""
 
-    __slots__ = ("span_id", "name", "kind", "attrs", "t0", "seconds",
-                 "status", "children")
+    __slots__ = ("span_id", "name", "kind", "attrs", "t0", "t1", "seconds",
+                 "status", "children", "remote")
 
     def __init__(self, span_id: int, name: str, kind: str,
                  attrs: Optional[Dict[str, Any]] = None):
         self.span_id = span_id
         self.name = name
-        self.kind = kind  # "query" | "phase" | "operator" | "kernel" | "span"
+        # "query" | "phase" | "operator" | "kernel" | "serve" | "sync" |
+        # "build" | "span"
+        self.kind = kind
         self.attrs: Dict[str, Any] = dict(attrs or {})
+        # start and end on the process's perf_counter (None: never timed)
         self.t0: Optional[float] = None
+        self.t1: Optional[float] = None
         self.seconds: float = 0.0
         self.status = "ok"
         self.children: List["Span"] = []
+        # a tree rendered in ANOTHER process (cluster mode: the worker's
+        # profile), emitted as a last child with its own offsets
+        self.remote: Optional[Dict[str, Any]] = None
 
     @property
     def self_seconds(self) -> float:
         """Wall time minus child spans — the per-operator cost that sums
         (within tolerance) to the parent's total."""
         return max(self.seconds - sum(c.seconds for c in self.children), 0.0)
+
+    def close(self, t1: Optional[float] = None) -> None:
+        """Stamp the end (now, unless measured by the caller)."""
+        self.t1 = time.perf_counter() if t1 is None else t1
+        self.seconds = max(self.t1 - self.t0, 0.0)
+
+    def add(self, name: str, kind: str, t0: float, t1: Optional[float] = None,
+            **attrs) -> "Span":
+        """Append a child whose ends the caller measured itself (the
+        serving stages: their code runs across ``await``s and threads, where
+        no ``with`` block can stand). ``t1=None`` leaves it open for a later
+        ``close``. Span ids are per tree; such children take none (0)."""
+        sp = Span(0, name, kind, attrs)
+        sp.t0 = t0
+        if t1 is not None:
+            sp.close(t1)
+        self.children.append(sp)
+        return sp
+
+    def absorb(self, t0: float, t1: float) -> None:
+        """Fold a LATER interval into this closed span: its seconds add up,
+        its end moves, attr ``pages`` counts what it holds. How a result of
+        many pages leaves a tree of bounded size (``seconds`` is then a
+        sum, not the span's extent)."""
+        self.attrs["pages"] = self.attrs.get("pages", 1) + 1
+        self.seconds += max(t1 - t0, 0.0)
+        self.t1 = t1
 
     def note(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -111,19 +165,29 @@ class Span:
             if len(spairs) < self.ROWS_PAIRS_CAP:
                 spairs.append([int(local_true), int(local_padded)])
 
-    def to_dict(self) -> Dict[str, Any]:
+    def to_dict(self, origin: Optional[float] = None) -> Dict[str, Any]:
+        """JSON form; ``start_s`` is the offset from ``origin`` (the root's
+        start; this span's own where none is given)."""
+        if origin is None:
+            origin = self.t0
         out: Dict[str, Any] = {
             "span_id": self.span_id,
             "name": self.name,
             "kind": self.kind,
+            "start_s": (
+                round(self.t0 - origin, 6) if self.t0 is not None else 0.0
+            ),
             "seconds": round(self.seconds, 6),
             "self_seconds": round(self.self_seconds, 6),
             "status": self.status,
         }
         if self.attrs:
             out["attrs"] = dict(self.attrs)
-        if self.children:
-            out["children"] = [c.to_dict() for c in self.children]
+        children = [c.to_dict(origin) for c in self.children]
+        if self.remote is not None:
+            children.append(self.remote)
+        if children:
+            out["children"] = children
         return out
 
 
@@ -152,9 +216,13 @@ class QueryTrace:
     sit unpulled for minutes between planning and execution — idle wall
     time between phases is not query time)."""
 
-    def __init__(self, name: str = "query", **attrs):
+    def __init__(self, name: str = "query", kind: str = "query", **attrs):
         self._ids = itertools.count(1)
-        self.root = Span(0, name, "query", attrs)
+        self.root = Span(0, name, kind, attrs)
+        # the tree's clock: every span's t0/t1 is on this perf_counter;
+        # unix_ns places the root on the wall clock
+        self.root.t0 = time.perf_counter()
+        self.unix_ns = time.time_ns()
         # deepest span open when the current execution attempt failed —
         # reset per ladder attempt, read into ``execution_log`` entries
         self.failed_span_id: Optional[int] = None
@@ -183,11 +251,23 @@ class QueryTrace:
             stack.extend(reversed(s.children))
         return out
 
+    def close(self) -> None:
+        """Stamp the root's end. A root never closed (a lazy result)
+        renders with the extent of its children instead."""
+        self.root.close()
+
     def to_dict(self) -> Dict[str, Any]:
+        root = self.root
+        if root.t1 is None:
+            ends = [c.t1 for c in root.children if c.t1 is not None]
+            if ends:
+                root.seconds = max(max(ends) - root.t0, 0.0)
         return {
             "schema_version": SCHEMA_VERSION,
             "total_seconds": round(self.total_seconds, 6),
-            "root": self.root.to_dict(),
+            "start_perf_s": root.t0,
+            "start_unix_ns": self.unix_ns,
+            "root": root.to_dict(),
         }
 
 
@@ -265,6 +345,30 @@ class activate:
         _TRACE.reset(self._t1)
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, or False: unavailable
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation`` (None where JAX has none). While no
+    profiler session runs it records nothing."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            import jax
+
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        except Exception:  # fault-ok: profiling must never fail a query
+            _ANNOTATION = False
+    if not _ANNOTATION:
+        return None
+    try:
+        dev = _ANNOTATION(name)
+        dev.__enter__()
+        return dev
+    except Exception:  # fault-ok: profiling must never fail a query
+        return None
+
+
 class span:
     """``with span(name, kind=..., **attrs) as sp:`` — open a child of the
     innermost span. Returns ``NULL_SPAN`` (and records nothing) when no
@@ -289,18 +393,9 @@ class span:
         sp = Span(next(tr._ids), self._name, self._kind, self._attrs)
         parent.children.append(sp)
         self._tok = _SPAN.set(sp)
-        if PROFILE_DIR.get():
-            # device-trace backend: the same region, named inside the
-            # jax.profiler timeline (utils/profiling.py)
-            try:
-                import jax
-
-                self._dev = jax.profiler.TraceAnnotation(
-                    f"tpu_cypher:{self._kind}:{self._name}"
-                )
-                self._dev.__enter__()
-            except Exception:  # fault-ok: profiling must never fail a query
-                self._dev = None
+        # the same region, named inside whatever jax.profiler capture is
+        # running (the operator's own included)
+        self._dev = _annotation(f"tpu_cypher:{self._kind}:{self._name}")
         sp.t0 = time.perf_counter()
         self._span = sp
         return sp
@@ -309,7 +404,7 @@ class span:
         sp = self._span
         if sp is None:
             return False
-        sp.seconds = time.perf_counter() - sp.t0
+        sp.close()
         if self._dev is not None:
             try:
                 self._dev.__exit__(exc_type, exc, tb)
@@ -326,6 +421,40 @@ class span:
         if self._kind == "phase":
             M.record_stage(self._name, sp.seconds)
         return False
+
+
+def sync(site: str) -> span:
+    """``with sync(site): n = int(device_scalar)`` — the span (kind
+    ``sync``) round ONE blocking device->host read, named for the enclosing
+    fault site. It wraps the read that blocks, not the dispatch before it,
+    and reads nothing itself. The count survives outside a traced run in
+    ``tpu_cypher_host_syncs_total{site=}``."""
+    HOST_SYNCS.inc(site=site)
+    return span(site, kind="sync")
+
+
+# ---------------------------------------------------------------------------
+# the log of finished request trees
+# ---------------------------------------------------------------------------
+
+_RECENT: Deque[QueryTrace] = collections.deque(maxlen=RECENT_CAPACITY)
+
+
+def finish(trace: QueryTrace) -> QueryTrace:
+    """Close a request's tree and keep it in the bounded log. Nothing is
+    rendered here — this runs before the request's last message goes out;
+    whoever reads the tree (``recent``, ``/queries/<id>``) pays for its
+    rendering. A tree holds numbers and strings only, never a table or a
+    device buffer."""
+    trace.close()
+    _RECENT.append(trace)
+    return trace
+
+
+def recent() -> List[Dict[str, Any]]:
+    """The last ``RECENT_CAPACITY`` finished request trees, rendered
+    (``QueryTrace.to_dict``), newest last."""
+    return [trace.to_dict() for trace in list(_RECENT)]
 
 
 # ---------------------------------------------------------------------------

@@ -295,8 +295,11 @@ class CypherResult:
                     entry["seconds"] = round(dt, 6)
                     entry["duration_ms"] = round(dt * 1000, 3)
                     self.execution_log.append(entry)
-                    self._emit_query_event(True, scope)
-                    self._observe_feedback(trace)
+                    # what a finished attempt still pays on the host: the
+                    # JSON-lines event and the optimizer's calibration
+                    with OT.span("feedback", kind="phase"):
+                        self._emit_query_event(True, scope)
+                        self._observe_feedback(trace)
                     return recs
                 except Exception as exc:  # classified below; see errors.py
                     typed = ERR.classify(exc)
@@ -1054,9 +1057,14 @@ class CypherSession:
         if graph is not None and isinstance(graph._graph, _MG):
             mutable = graph._graph
             graph = PropertyGraph(self, mutable.snapshot())
-        cache_key = self._plan_cache_key(query, graph, parameters, driving_table)
-        if cache_key is not None:
-            hit = self._plan_cache.get(cache_key)
+        trace = OT.QueryTrace("query")
+        # one phase for what every request pays before planning or instead
+        # of it: key building, the lookup and, on a hit, the plan's clone
+        with OT.activate(trace), OT.span("plan_cache", kind="phase"):
+            cache_key = self._plan_cache_key(
+                query, graph, parameters, driving_table
+            )
+            hit = self._plan_cache.get(cache_key) if cache_key is not None else None
             if hit is not None and hit[0] is graph._graph:
                 self._plan_cache.move_to_end(cache_key)
                 _, logical, relational, returns = hit
@@ -1065,12 +1073,13 @@ class CypherSession:
                     self._clone_plan(relational, parameters), returns,
                 )
                 # a plan-cache hit skips every planning phase: its trace
-                # starts empty and says so
-                result._trace = OT.QueryTrace("query", plan_cache="hit")
+                # holds none and says so
+                trace.root.attrs["plan_cache"] = "hit"
+                result._trace = trace
                 result._source = (query, parameters, graph, driving_table)
                 return result
-        trace = OT.QueryTrace(
-            "query", plan_cache="miss" if cache_key is not None else "bypass"
+        trace.root.attrs["plan_cache"] = (
+            "miss" if cache_key is not None else "bypass"
         )
         ambient = graph._graph if graph is not None else EmptyGraph()
         ambient_qgn = f"{AMBIENT_NS}.q{next(self._counter)}"

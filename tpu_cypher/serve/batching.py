@@ -61,7 +61,8 @@ def batch_key(session, query: str, graph, parameters: Dict[str, Any]):
 class Batch:  # shared-by: loop
     """One open coalescing group: the leader executes, members share."""
 
-    __slots__ = ("key", "leader_id", "members", "done", "result", "error")
+    __slots__ = ("key", "leader_id", "members", "done", "result", "error",
+                 "span")
 
     def __init__(self, key, leader_id: str):
         self.key = key
@@ -70,6 +71,9 @@ class Batch:  # shared-by: loop
         self.done = asyncio.Event()
         self.result: Optional[Any] = None
         self.error: Optional[BaseException] = None
+        # the leader's ``dispatch`` span (obs/trace.py): the followers'
+        # request trees hang the one shared execution under their wait
+        self.span: Optional[Any] = None
 
     @property
     def size(self) -> int:

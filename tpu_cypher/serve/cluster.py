@@ -176,16 +176,21 @@ class ClusterServer(QueryServer):  # shared-by: loop
     # -- the execution hook ----------------------------------------------
 
     async def _execute_payload(self, t: _Ticket, graph) -> Dict[str, Any]:
-        remaining = None
-        if t.deadline_s:
-            remaining = max(
-                t.deadline_s - (time.monotonic() - t.submitted_at), 1e-6
-            )
-        return await self.router.submit(
+        t0 = time.perf_counter()
+        payload = await self.router.submit(
             graph=t.graph_name, query=t.query, parameters=t.parameters,
-            tenant=t.tenant, deadline_s=remaining, faults=t.faults,
+            tenant=t.tenant, deadline_s=self._remaining_s(t), faults=t.faults,
             qid=t.qid,
         )
+        # ONE ``route`` span round the whole trip to the worker and back.
+        # The engine ran in another process on another perf_counter: its
+        # rendered tree hangs under the span with its own offsets, and no
+        # clock is claimed across the two processes
+        route = self._stage(t.dispatch, "route", t0)
+        remote = (payload.get("profile") or {}).get("root")
+        if remote:
+            route.remote = {**remote, "clock": "worker"}
+        return payload
 
     async def _open_stream(self, t: _Ticket, graph):
         """Cursor streaming over the cluster: route the query like any

@@ -65,6 +65,7 @@ from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
 from .. import errors as ERR
+from ..obs import trace as OT
 from ..obs.metrics import REGISTRY as _REGISTRY
 from ..relational.session import CypherSession, PropertyGraph
 from ..utils.config import (
@@ -85,6 +86,9 @@ from .session_pool import SessionPool
 PROTOCOL_VERSION = 1
 PAGE_ROWS = 256  # rows per streamed "rows" message
 _QUERY_LOG_MAX = 512  # bounded /queries/<id> history
+# page deliveries that keep a span pair of their own on the request's tree;
+# a longer result's later pages add up in the last pair
+_PAGE_SPANS_MAX = 16
 
 QUERIES_TOTAL = _REGISTRY.counter(
     "tpu_cypher_serve_queries_total",
@@ -116,7 +120,7 @@ class _Ticket:
     __slots__ = (
         "qid", "query", "graph_name", "parameters", "tenant", "deadline_s",
         "faults", "conn", "status", "cancelled", "task", "submitted_at",
-        "stream", "cursor",
+        "stream", "cursor", "trace", "dispatch",
     )
 
     def __init__(self, qid, query, graph_name, parameters, tenant,
@@ -135,6 +139,13 @@ class _Ticket:
         self.cancelled = False
         self.task: Optional[asyncio.Task] = None
         self.submitted_at = time.monotonic()
+        # the request's ONE span tree, opened here and closed in _terminal:
+        # the serving stages as spans of kind "serve", the engine's own
+        # tree grafted under ``dispatch`` (obs/trace.py)
+        self.trace = OT.QueryTrace(
+            "request", kind="serve", id=qid, graph=graph_name, tenant=tenant
+        )
+        self.dispatch: Optional[OT.Span] = None
 
 
 class _Cursor:  # shared-by: loop
@@ -214,9 +225,10 @@ class QueryServer:  # shared-by: loop
         self.batcher = BatchWindow(window)
         self.cache = ResultCache(cache_bytes)
         self._fingerprints: Dict[str, str] = {}
-        # accumulated per-stage wall seconds (queue_wait / route /
-        # dispatch / demux / serialize) — the soak harness's latency
-        # attribution reads this
+        # accumulated per-stage wall seconds (cache / batch_window /
+        # queue_wait / dispatch / route / serialize / demux): the running
+        # sum of the ``serve`` spans of every request's tree (``_stage``) —
+        # the soak harness's latency attribution reads this
         self.stages: Dict[str, float] = {}
         self._graphs: Dict[str, PropertyGraph] = {}
         self._tickets: Dict[str, _Ticket] = {}
@@ -456,8 +468,10 @@ class QueryServer:  # shared-by: loop
             and t.deadline_s is None
             and not wire.is_write_query(t.query)
         ):
+            tc0 = time.perf_counter()
             key = batch_key(self.session, t.query, graph, t.parameters)
             hit = self.cache.lookup(key, self._fingerprints.get(t.graph_name, ""))
+            self._stage(t.trace.root, "cache", tc0, hit=hit is not None)
             if hit is not None:
                 # zero-dispatch fast path: no batch window, no admission
                 # wait, no device work — the stored payload is served
@@ -470,15 +484,24 @@ class QueryServer:  # shared-by: loop
                     await self._fail(t, exc)
                 return
         batch, is_leader = self.batcher.lead_or_join(key, t.qid)
+        tb0 = time.perf_counter()
         try:
             if is_leader:
                 await self.batcher.window()
                 self.batcher.close(batch)
+                self._stage(t.trace.root, "batch_window", tb0)
                 if t.cancelled and batch.size == 1:
                     raise asyncio.CancelledError
                 await self._dispatch(t, graph, batch)
             else:
                 await batch.done.wait()
+                wait = self._stage(
+                    t.trace.root, "batch_wait", tb0, leader=batch.leader_id
+                )
+                if batch.span is not None:
+                    # the one execution that served this member too: the
+                    # leader's dispatch, the same objects
+                    wait.children.append(batch.span)
             await self._finish(t, batch)
         except asyncio.CancelledError:
             if is_leader:
@@ -488,34 +511,61 @@ class QueryServer:  # shared-by: loop
         except Exception as exc:  # fault-ok: surfaced as a typed error reply
             await self._fail(t, exc)
 
-    def _stage(self, name: str, seconds: float) -> None:
-        """Accumulate per-stage wall seconds (queue_wait / route /
-        dispatch / demux / serialize) for latency attribution."""
-        self.stages[name] = self.stages.get(name, 0.0) + max(seconds, 0.0)
+    def _stage(self, parent: OT.Span, name: str, t0: float,
+               t1: Optional[float] = None, **attrs) -> OT.Span:
+        """One serving stage, measured ONCE: a closed span of kind
+        ``serve`` under ``parent`` with its real start and end (``t1``:
+        now), and its seconds into ``self.stages`` — two views of one
+        measurement. Event loop only."""
+        sp = parent.add(
+            name, "serve", t0, time.perf_counter() if t1 is None else t1,
+            **attrs,
+        )
+        self._sum_stage(sp)
+        return sp
+
+    def _sum_stage(self, sp: OT.Span) -> None:
+        self.stages[sp.name] = self.stages.get(sp.name, 0.0) + sp.seconds
+
+    def _page_stage(self, root: OT.Span, name: str, t0: float,
+                    t1: float) -> None:
+        """``_stage`` for a page delivery (``serialize`` / ``demux``). A
+        tree keeps a span of its own for the first ``_PAGE_SPANS_MAX``
+        of each; a longer result's later pages add their seconds to the
+        last one (attr ``pages``: how many it holds), so a cursor over
+        millions of rows still leaves a tree of bounded size."""
+        held = [c for c in root.children if c.name == name]
+        if len(held) < _PAGE_SPANS_MAX:
+            self._stage(root, name, t0, t1)
+            return
+        held[-1].absorb(t0, t1)
+        self.stages[name] = self.stages.get(name, 0.0) + max(t1 - t0, 0.0)
+
+    async def _admit_and_run(self, t: _Ticket, graph, run):
+        """Admission, then ``run()`` under the slot: the ``queue_wait`` and
+        ``dispatch`` stages shared by the eager and the streamed path."""
+        cost = preflight_admit(graph, t.query, t.tenant)
+        deadline_at = t.submitted_at + t.deadline_s if t.deadline_s else None
+        tq0 = time.perf_counter()
+        await self.scheduler.acquire(cost, t.tenant, deadline_at)
+        self._stage(t.trace.root, "queue_wait", tq0)
+        t.status = "running"
+        # opened before the engine runs: its tree is grafted under it
+        t.dispatch = t.trace.root.add("dispatch", "serve", time.perf_counter())
+        try:
+            return await run()
+        finally:
+            self.scheduler.release(t.tenant)
+            t.dispatch.close()
+            self._sum_stage(t.dispatch)
 
     async def _dispatch(self, t: _Ticket, graph, batch) -> None:
         """The leader's path: admission, one isolated execution, publish."""
         try:
-            cost = preflight_admit(graph, t.query, t.tenant)
-            deadline_at = (
-                t.submitted_at + t.deadline_s if t.deadline_s else None
+            payload = await self._admit_and_run(
+                t, graph, lambda: self._execute_payload(t, graph)
             )
-            tq0 = time.perf_counter()
-            await self.scheduler.acquire(cost, t.tenant, deadline_at)
-            self._stage("queue_wait", time.perf_counter() - tq0)
-            t.status = "running"
-            td0 = time.perf_counter()
-            try:
-                payload = await self._execute_payload(t, graph)
-            finally:
-                self.scheduler.release(t.tenant)
-            wall = time.perf_counter() - td0
-            self._stage("dispatch", wall)
-            # route = everything around the engine seconds: lane hop in
-            # one process, connect/serialize/worker hop in cluster mode
-            self._stage(
-                "route", wall - float(payload.get("seconds") or 0.0)
-            )
+            batch.span = t.dispatch
             self.batcher.publish(batch, result=payload)
             write_stats = payload.get("write")
             if write_stats and write_stats.get("fingerprint"):
@@ -538,21 +588,48 @@ class QueryServer:  # shared-by: loop
         admission) is shared with the multi-process tier, which overrides
         this one method to route to an engine-worker process instead
         (``serve/cluster.py``)."""
-        return await self.pool.run(lambda: self._execute(graph, t))
+        return await self._on_lane(t, lambda: self._execute(graph, t))
+
+    async def _on_lane(self, t: _Ticket, fn):
+        """``fn`` on a worker lane, with the hop out to the lane's thread
+        and the hop back to the loop as two ``route`` spans round what ran
+        there (the engine's tree, grafted under ``dispatch`` by ``fn``)."""
+
+        hops: Dict[str, Any] = {}
+
+        def on_lane():
+            # appended on the lane, before the engine's tree: the children
+            # of ``dispatch`` stay in the order of the clock
+            hops["out"] = t.dispatch.add(
+                "route", "serve", t.dispatch.t0, time.perf_counter(), hop="out"
+            )
+            try:
+                return fn()
+            finally:
+                hops["left"] = time.perf_counter()
+
+        try:
+            return await self.pool.run(on_lane)
+        finally:
+            if "left" in hops:  # summed here: ``stages`` is the loop's
+                self._sum_stage(hops["out"])
+                self._stage(t.dispatch, "route", hops["left"], hop="back")
+
+    def _remaining_s(self, t: _Ticket) -> Optional[float]:
+        """What is left of the request's deadline: queue wait already
+        consumed part of it."""
+        if not t.deadline_s:
+            return None
+        return max(t.deadline_s - (time.monotonic() - t.submitted_at), 1e-6)
 
     def _execute(self, graph, t: _Ticket) -> Dict[str, Any]:
         """One engine execution — runs on a pool worker thread inside a
         FRESH contextvars.Context; everything scoped here dies with the
         query."""
-        remaining = None
-        if t.deadline_s:
-            # remaining budget: queue wait already consumed part of it
-            remaining = max(
-                t.deadline_s - (time.monotonic() - t.submitted_at), 1e-6
-            )
         return wire.execute_payload(
             self.session, graph, t.query, t.parameters,
-            deadline_s=remaining, faults=t.faults,
+            deadline_s=self._remaining_s(t), faults=t.faults,
+            parent=t.dispatch,
         )
 
     # -- cursor streaming ------------------------------------------------
@@ -560,16 +637,13 @@ class QueryServer:  # shared-by: loop
     async def _open_stream(self, t: _Ticket, graph):
         """Streamed-execution hook: ``(meta, page source)``. The cluster
         tier overrides this to route through an engine worker."""
-        remaining = None
-        if t.deadline_s:
-            remaining = max(
-                t.deadline_s - (time.monotonic() - t.submitted_at), 1e-6
-            )
-        return await self.pool.run(
+        return await self._on_lane(
+            t,
             lambda: wire.open_stream(
                 self.session, graph, t.query, t.parameters,
-                deadline_s=remaining, faults=t.faults, page_rows=PAGE_ROWS,
-            )
+                deadline_s=self._remaining_s(t), faults=t.faults,
+                page_rows=PAGE_ROWS, parent=t.dispatch,
+            ),
         )
 
     async def _run_stream(self, t: _Ticket, graph) -> None:
@@ -584,20 +658,9 @@ class QueryServer:  # shared-by: loop
         client grants credit (``next``), closes, cancels, or disconnects.
         Streamed queries never batch and never touch the result cache —
         their value is precisely the results too big to hold whole."""
-        cost = preflight_admit(graph, t.query, t.tenant)
-        deadline_at = t.submitted_at + t.deadline_s if t.deadline_s else None
-        tq0 = time.perf_counter()
-        await self.scheduler.acquire(cost, t.tenant, deadline_at)
-        self._stage("queue_wait", time.perf_counter() - tq0)
-        t.status = "running"
-        td0 = time.perf_counter()
-        try:
-            meta, source = await self._open_stream(t, graph)
-        finally:
-            self.scheduler.release(t.tenant)
-        wall = time.perf_counter() - td0
-        self._stage("dispatch", wall)
-        self._stage("route", wall - float(meta.get("seconds") or 0.0))
+        meta, source = await self._admit_and_run(
+            t, graph, lambda: self._open_stream(t, graph)
+        )
         cur = _Cursor(int(SERVE_STREAM_WINDOW.get()))
         t.cursor = cur
         CURSORS_OPEN.set(CURSORS_OPEN.value() + 1)
@@ -615,12 +678,14 @@ class QueryServer:  # shared-by: loop
                 if page is None:
                     break
                 msg = {"type": "rows", "id": t.qid, "seq": seq, "rows": page}
+                root = t.trace.root
                 ts0 = time.perf_counter()
+                self._page_stage(root, "demux", tp0, ts0)  # the page's pull
                 data = (json.dumps(msg) + "\n").encode()
-                tser = time.perf_counter() - ts0
-                self._stage("serialize", tser)
+                ts1 = time.perf_counter()
+                self._page_stage(root, "serialize", ts0, ts1)
                 await t.conn.send_raw(data)
-                self._stage("demux", time.perf_counter() - tp0 - tser)
+                self._page_stage(root, "demux", ts1, time.perf_counter())
                 cur.sent += 1
                 seq += 1
                 streamed += len(page)
@@ -663,8 +728,7 @@ class QueryServer:  # shared-by: loop
             await t.conn.send({"type": "cancelled", "id": t.qid})
             return
         rows = payload["rows"]
-        td0 = time.perf_counter()
-        ser = 0.0
+        root = t.trace.root
         for seq in range(0, max(len(rows), 1), PAGE_ROWS):
             page = rows[seq : seq + PAGE_ROWS]
             if page or seq == 0:
@@ -672,10 +736,10 @@ class QueryServer:  # shared-by: loop
                        "rows": page}
                 ts0 = time.perf_counter()
                 data = (json.dumps(msg) + "\n").encode()
-                ser += time.perf_counter() - ts0
+                ts1 = time.perf_counter()
+                self._page_stage(root, "serialize", ts0, ts1)
                 await t.conn.send_raw(data)
-        self._stage("serialize", ser)
-        self._stage("demux", time.perf_counter() - td0 - ser)
+                self._page_stage(root, "demux", ts1, time.perf_counter())
         done = {
             "type": "done",
             "id": t.qid,
@@ -703,8 +767,13 @@ class QueryServer:  # shared-by: loop
     def _terminal(self, t: _Ticket, status: str, message: Dict[str, Any],
                   payload: Optional[Dict[str, Any]] = None,
                   batch=None) -> None:
-        """Record the query's terminal state for ``GET /queries/<id>``."""
+        """Record the query's terminal state for ``GET /queries/<id>`` and
+        close its span tree: every path ends here — done, cached, error,
+        cancelled — and the record's ``profile`` is the whole request, the
+        serving stages beside the engine's tree (rendered when asked for:
+        this runs before the terminal message goes out)."""
         t.status = status
+        t.trace.root.status = "ok" if status == "done" else status
         QUERIES_TOTAL.inc(status=status)
         QUERY_SECONDS.observe(time.monotonic() - t.submitted_at)
         record: Dict[str, Any] = {
@@ -714,6 +783,7 @@ class QueryServer:  # shared-by: loop
             "graph": t.graph_name,
             "tenant": t.tenant,
             "message": {k: v for k, v in message.items() if k != "type"},
+            "profile": OT.finish(t.trace),
         }
         if payload is not None:
             record.update(
@@ -724,7 +794,6 @@ class QueryServer:  # shared-by: loop
                 degraded=payload["degraded"],
                 compile_stats=payload["compile_stats"],
                 fallbacks=payload.get("fallbacks"),
-                profile=payload["profile"],
                 cached=bool(payload.get("cached", False)),
             )
         if batch is not None:
@@ -811,6 +880,8 @@ class QueryServer:  # shared-by: loop
                     "404 Not Found", "application/json",
                     json.dumps({"error": f"unknown query {qid!r}"}).encode(),
                 )
+            if "profile" in rec:
+                rec = {**rec, "profile": rec["profile"].to_dict()}
             return ("200 OK", "application/json", json.dumps(rec).encode())
         if path == "/cache":
             return (

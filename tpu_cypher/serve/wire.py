@@ -36,6 +36,7 @@ from typing import Any, Dict, List, Optional
 
 from .. import errors as ERR
 from ..api import values as V
+from ..obs import trace as OT
 from ..runtime import faults as F
 from ..runtime import guard as G
 
@@ -61,6 +62,18 @@ def encode_rows(rows, columns) -> List[Dict[str, Any]]:
     return [{c: json_value(r.get(c)) for c in columns} for r in rows]
 
 
+def _engine_trace(result, parent: Optional[OT.Span]) -> OT.QueryTrace:
+    """The result's own span tree; under ``parent`` (a span of the
+    request's tree, one process, one ``perf_counter``) it is grafted as
+    ``engine`` — the objects, not a copy, so what execution and collection
+    add later shows there too."""
+    trace = result.profile(execute=False).trace
+    if parent is not None:
+        trace.root.name = "engine"
+        parent.children.append(trace.root)
+    return trace
+
+
 def execute_payload(
     session,
     graph,
@@ -69,27 +82,41 @@ def execute_payload(
     *,
     deadline_s: Optional[float] = None,
     faults: Optional[str] = None,
+    parent: Optional[OT.Span] = None,
 ) -> Dict[str, Any]:
     """One engine execution -> the wire payload. Runs BLOCKING engine work;
     callers put it on a worker lane (``SessionPool.run``) inside a fresh
     ``contextvars.Context``. ``deadline_s`` is the REMAINING budget (queue
-    wait already deducted); ``faults`` is a client-scoped chaos schedule."""
+    wait already deducted); ``faults`` is a client-scoped chaos schedule;
+    ``parent`` is the span of the request's tree that the engine's tree
+    hangs under (the one-process server's ``dispatch``)."""
     t0 = time.perf_counter()
-    with contextlib.ExitStack() as stack:
-        if deadline_s:
-            stack.enter_context(G.request_deadline(deadline_s))
-        if faults is not None:
-            stack.enter_context(F.scoped_spec(faults))
-        result = session.cypher(query, parameters or {}, graph=graph)
-        records = result.records
-        rows = records.collect() if records is not None else []
-        columns = list(records.columns) if records is not None else []
+    trace = None
+    try:
+        with contextlib.ExitStack() as stack:
+            if deadline_s:
+                stack.enter_context(G.request_deadline(deadline_s))
+            if faults is not None:
+                stack.enter_context(F.scoped_spec(faults))
+            result = session.cypher(query, parameters or {}, graph=graph)
+            trace = _engine_trace(result, parent)
+            records = result.records
+            rows = records.collect() if records is not None else []
+            columns = list(records.columns) if records is not None else []
+        with OT.activate(trace), OT.span(
+            "encode", kind="phase", rows=len(rows)
+        ):
+            encoded = encode_rows(rows, columns)
+        seconds = round(time.perf_counter() - t0, 6)
+    finally:
+        if trace is not None:
+            trace.close()
     log = list(result.execution_log)
     rungs = [e["rung"] for e in log]
     payload = {
-        "rows": encode_rows(rows, columns),
+        "rows": encoded,
         "columns": columns,
-        "seconds": round(time.perf_counter() - t0, 6),
+        "seconds": seconds,
         "execution_log": log,
         "rungs": rungs,
         "degraded": bool(rungs and rungs[-1] != G.RUNG_DEVICE),
@@ -97,7 +124,9 @@ def execute_payload(
         # {reason: count} of host-oracle fallbacks / host islands; None
         # unless the session records them (``session.record_fallbacks``)
         "fallbacks": result.fallbacks,
-        "profile": result.profile(execute=False).to_dict(),
+        # the engine's tree alone: what the result cache stores and what an
+        # engine worker sends over the wire
+        "profile": trace.to_dict(),
     }
     write_stats = getattr(result, "write_stats", None)
     if write_stats is not None:
@@ -114,6 +143,7 @@ def open_stream(
     deadline_s: Optional[float] = None,
     faults: Optional[str] = None,
     page_rows: int = 256,
+    parent: Optional[OT.Span] = None,
 ) -> "tuple[Dict[str, Any], RowStream]":
     """One engine execution -> ``(meta, RowStream)`` WITHOUT materializing
     the result rows: device execution runs here (inside the deadline and
@@ -124,13 +154,19 @@ def open_stream(
     this call and every ``next_page`` on a worker lane
     (``SessionPool.run``)."""
     t0 = time.perf_counter()
-    with contextlib.ExitStack() as stack:
-        if deadline_s:
-            stack.enter_context(G.request_deadline(deadline_s))
-        if faults is not None:
-            stack.enter_context(F.scoped_spec(faults))
-        result = session.cypher(query, parameters or {}, graph=graph)
-        records = result.records
+    trace = None
+    try:
+        with contextlib.ExitStack() as stack:
+            if deadline_s:
+                stack.enter_context(G.request_deadline(deadline_s))
+            if faults is not None:
+                stack.enter_context(F.scoped_spec(faults))
+            result = session.cypher(query, parameters or {}, graph=graph)
+            trace = _engine_trace(result, parent)
+            records = result.records
+    finally:
+        if trace is not None:
+            trace.close()
     columns = list(records.columns) if records is not None else []
     log = list(result.execution_log)
     rungs = [e["rung"] for e in log]
@@ -142,9 +178,9 @@ def open_stream(
         "rungs": rungs,
         "degraded": bool(rungs and rungs[-1] != G.RUNG_DEVICE),
         "compile_stats": result.compile_stats,
-        "profile": result.profile(execute=False).to_dict(),
+        "profile": trace.to_dict(),
     }
-    return meta, RowStream(records, columns, page_rows=page_rows)
+    return meta, RowStream(records, columns, page_rows=page_rows, trace=trace)
 
 
 class RowStream:
@@ -157,9 +193,13 @@ class RowStream:
     10M-row result stream under a fixed ceiling. Decode is BLOCKING host
     work: drive ``next_page`` from a worker lane, never the event loop."""
 
-    def __init__(self, records, columns: List[str], *, page_rows: int = 256):
+    def __init__(self, records, columns: List[str], *, page_rows: int = 256,
+                 trace: Optional[OT.QueryTrace] = None):
         self._columns = list(columns)
         self._page_rows = max(int(page_rows), 1)
+        # the engine's tree, where the pages' encoding shows as a phase
+        self._trace = trace
+        self._encode: Optional[OT.Span] = None
         self._chunks = (
             records.iter_chunks(G.stream_chunk_rows())
             if records is not None
@@ -178,10 +218,24 @@ class RowStream:
             self._buf = nxt
             self._pos = 0
         hi = min(self._pos + self._page_rows, len(self._buf))
+        t0 = time.perf_counter()
         page = encode_rows(self._buf[self._pos:hi], self._columns)
+        if self._trace is not None:
+            self._note_encode(t0, time.perf_counter())
         self.rows_sent += len(page)
         self._pos = hi
         return page
+
+    def _note_encode(self, t0: float, t1: float) -> None:
+        """The pages' encoding as ONE phase ``encode`` of the engine's tree
+        (the later pages add up in it: a cursor over millions of rows
+        leaves a tree of bounded size), the engine's extent moved to it."""
+        root = self._trace.root
+        if self._encode is None:
+            self._encode = root.add("encode", "phase", t0, t1)
+        else:
+            self._encode.absorb(t0, t1)
+        root.close(t1)
 
     def close(self) -> None:
         """Drop the buffered chunk and the underlying iterator (early

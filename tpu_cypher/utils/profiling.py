@@ -3,12 +3,11 @@
 The reference delegates engine-level profiling to Spark UI /
 ``tableEnv.explain`` (used in ``flink-cypher/.../Demo.scala:84``); the TPU
 equivalents are the XLA profiler (TensorBoard-compatible traces) and the
-compiled HLO of the jitted kernels. Gated by ``TPU_CYPHER_PROFILE_DIR``:
-when set, ``CypherSession.cypher`` executions are wrapped in a profiler
-trace automatically, AND the ``obs.trace`` span tree uses this module as
-its device-trace backend — every engine span opens a matching
-``jax.profiler.TraceAnnotation``, so the phase/operator/kernel tree shows
-up region-named inside the TensorBoard/Perfetto timeline
+compiled HLO of the jitted kernels. ``TPU_CYPHER_PROFILE_DIR`` has one
+use: when set, every ``CypherSession.cypher`` execution is wrapped in a
+profiler capture of its own (``relational/session.py``). The ``obs.trace``
+spans need no knob: each opens a ``jax.profiler.TraceAnnotation`` whatever
+capture is running, this one or the operator's own
 (``docs/observability.md``).
 """
 
