@@ -4,8 +4,10 @@ The reference executes a k-hop ``MATCH ... RETURN count(*)`` as 2k hash
 joins followed by a global aggregate. This engine recognizes the shape at
 the physical level and runs the WHOLE plan as one XLA program:
 
-* ``count(*)`` over an expand chain -> a right-to-left scatter-free CSR
-  SpMV (``path_count_chain``), one dispatch + one scalar fetch;
+* ``count(*)`` over an expand chain -> one right-to-left pass over
+  per-node completion counts (``path_count_chain``): degrees from
+  ``row_ptr``, a gather and a sum under a whole frontier, a scatter-free
+  CSR SpMV elsewhere; one dispatch + one scalar fetch;
 * ``WITH DISTINCT a, c RETURN count(*)`` -> per-hop (key, position)
   programs ending in a packed values-only sort count;
 * ``ORDER BY ... LIMIT k`` -> one ``lax.top_k`` over a packed rank.

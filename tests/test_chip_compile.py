@@ -107,8 +107,9 @@ def _csr(shape, nodes=NODES, edges=EDGES):
 
 @pytest.mark.parametrize("graph", GRAPHS)
 def test_fused_count_chain_compiles(one_chip, graph):
-    """``path_count_chain`` over the whole graph: the 2-hop count(*) as one
-    program, two scatter-free SpMVs over every edge."""
+    """``path_count_chain`` over a frontier of ids and partial labels: the
+    2-hop count(*) as one program, two scatter-free SpMVs (gather, 64-bit
+    prefix scan, boundary reads) over every edge."""
     nodes, edges = graph
     rp, ci, _ = _csr(one_chip, nodes, edges)
     mask = one_chip((nodes,), BOOL)
@@ -119,6 +120,26 @@ def test_fused_count_chain_compiles(one_chip, graph):
     ).compile()
     # the working set is the edge arrays, far inside one chip's 16 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+@pytest.mark.parametrize("hops", [1, 2, 3])
+def test_scan_free_count_chain_compiles(one_chip, hops):
+    """The chain over a whole frontier with no partial label — up to two
+    hops at the FULL size, its compile is short without a scan: row_ptr
+    differences, then one gather and one 64-bit sum over the edge lanes;
+    the third hop brings one scan back, between the two."""
+    # the third hop's scan takes minutes to compile at 45M edges
+    nodes, edges = (NODES, EDGES) if hops < 3 else (100_000, 4_500_000)
+    rp, ci, _ = _csr(one_chip, nodes, edges)
+    hop = (rp, ci, None, None, None, None)
+    compiled = J.path_count_chain.lower(
+        None, None, None, (hop,) * hops, num_nodes=nodes, whole=True
+    ).compile()
+    text = compiled.as_text()
+    assert ("gather" in text) == (hops > 1)  # one hop: no edge is read
+    # XLA's TPU scan is a reduce-window; none up to two hops
+    assert ("reduce-window" in text) == (hops == 3)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_expand_materialize_counted_compiles(one_chip):
@@ -252,3 +273,21 @@ def test_sharded_count_chain_compiles_for_four_chips(four_chips, graph):
     ).compile()
     assert "all-reduce" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_sharded_scan_free_count_chain_compiles_for_four_chips(four_chips):
+    """The mesh chain over a whole frontier, full size: degrees from the
+    replicated row_ptr, each shard's masked sum of its own lanes, and a
+    ``psum`` of one scalar — no vector of ``num_nodes`` crosses the mesh."""
+    mesh, shape = four_chips
+    rp = shape((NODES + 1,), I32, P())
+    ci = shape((EDGES,), I32, P("rows"))
+    hop = (rp, ci, None, None, None, None)
+    run = J.path_count_chain_on_mesh(mesh, "rows")
+    compiled = run.lower(
+        None, None, None, (hop, hop), num_nodes=NODES, whole=True
+    ).compile()
+    text = compiled.as_text()
+    reduces = [l for l in text.splitlines() if " all-reduce(" in l or " all-reduce-start(" in l]
+    assert reduces and all(f"[{NODES}]" not in l for l in reduces)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

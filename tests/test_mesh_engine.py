@@ -61,8 +61,10 @@ def _build(session, ids, src, dst, ages):
 
 
 QUERIES = [
-    # fused CSR expand (2-hop) under sharding
+    # fused CSR expand (2-hop) under sharding: a whole frontier (degrees,
+    # then the sharded gather-and-sum), and a filtered one (the sharded scan)
     "MATCH (a:Person)-[:KNOWS]->(b)-[:KNOWS]->(c) RETURN count(*) AS c",
+    "MATCH (a:Person) WHERE a.age > 40 WITH a MATCH (a)-[:KNOWS]->(b)-[:KNOWS]->(c) RETURN count(*) AS c",
     # filter + projection over sharded scan columns
     "MATCH (a:Person) WHERE a.age > 40 RETURN count(*) AS n, sum(a.age) AS s",
     # sort-probe join path (value join) + distinct
@@ -123,6 +125,25 @@ def test_differential_on_mesh_nondivisible(meshed_odd, query):
     with use_mesh(mesh):
         got = g_tpu.cypher(query).records.to_bag()
     assert got == expected, f"\nquery: {query}\ntpu: {got!r}\nlocal: {expected!r}"
+
+
+@pytest.mark.parametrize(
+    "query,forms",
+    [(QUERIES[0], {"degree": 1, "reduce": 1, "scan": 0}),
+     (QUERIES[1], {"degree": 1, "reduce": 0, "scan": 1})],
+    ids=["whole", "filtered"],
+)
+def test_count_chain_forms_on_mesh(meshed_odd, query, forms):
+    """The mesh chain shares the one-chip chain's algebra: the same forms
+    for the same frontier, over the shard_map programs (``expand_shards``
+    on the operator's span says they ran)."""
+    mesh, _, g_tpu = meshed_odd
+    with use_mesh(mesh):
+        result = g_tpu.cypher(query)
+        result.records.collect()
+    noted = [s.attrs for s in result._trace.spans() if "chain_hops" in s.attrs]
+    assert len(noted) == 1 and noted[0]["chain_hops"] == forms
+    assert noted[0]["expand_shards"] == 8
 
 
 def test_nondivisible_columns_padded_and_sharded(meshed_odd):
@@ -254,8 +275,10 @@ def test_sharded_programs_emit_xla_collectives(meshed):
         ids = shard_rows(jnp.asarray(np.arange(n, dtype=np.int64)))
         rd = shard_rows(jnp.asarray(rng.integers(0, 50, e).astype(np.int64)))
     dev_ids = jnp.asarray(np.arange(n, dtype=np.int64))
-    # fused count chain over a sharded CSR + sharded frontier ids
-    hops = ((rp, ci, None, None, None, None),)
+    # fused count chain over a sharded CSR + sharded frontier ids (a
+    # partial label on the far node, or the hop is row_ptr differences and
+    # reads no edge)
+    hops = ((rp, ci, None, None, None, jnp.asarray(np.arange(n) % 2 == 0)),)
     txt = (
         J.path_count_chain.lower(dev_ids, ids, None, hops, num_nodes=n)
         .compile()

@@ -155,6 +155,16 @@ def _mxu_tiled_common(gi, ctx, hops):
     return t1, t2, t1.max_row_sum * max(t2.max_entry, 1), m_b, m_c
 
 
+def _note_chain_forms(forms) -> None:
+    """Count a fused count chain's hops by the form each takes
+    (``jit_ops.chain_forms``): in the registry, and as ``chain_hops`` on
+    the operator's span."""
+    counts = {f: forms.count(f) for f in ("degree", "reduce", "scan")}
+    for form, n in counts.items():  # a 0 seeds the series: it exports
+        _obs_trace.COUNT_CHAIN_HOPS.inc(n, form=form)
+    _obs_trace.note("chain_hops", counts)
+
+
 def _pad_mask(mask, npad: int):
     """Optional bool[num_nodes] label mask -> bf16 0/1[(npad,)] or None."""
     if mask is None:
@@ -575,6 +585,7 @@ class CsrExpandOp(_FusedExpandBase):
         undirected: bool,
         backwards: bool,
         far_labels: Tuple[str, ...],
+        frontier_scan: Optional[Tuple[str, ...]] = None,
         enforced_pairs: Tuple[Tuple[str, str], ...] = (),
     ):
         super().__init__(in_plan, classic, graph_obj)
@@ -585,6 +596,10 @@ class CsrExpandOp(_FusedExpandBase):
         self.undirected = undirected
         self.backwards = backwards
         self.far_labels = far_labels
+        # the label set whose plain node scan of this graph IS the input
+        # (nothing joined, filtered or unwound on the way), else None: what
+        # lets the count chain know its frontier without reading it
+        self.frontier_scan = frontier_scan
         self.enforced_pairs = enforced_pairs
 
     def _ctor_kwargs(self) -> Dict[str, Any]:
@@ -596,6 +611,7 @@ class CsrExpandOp(_FusedExpandBase):
             undirected=self.undirected,
             backwards=self.backwards,
             far_labels=self.far_labels,
+            frontier_scan=self.frontier_scan,
         )
 
     def _show_inner(self) -> str:
@@ -709,12 +725,23 @@ class CsrExpandOp(_FusedExpandBase):
             )
         if len(hops) == 1 and not self.undirected and not self.far_labels:
             # single unrestricted hop: the O(frontier) degree sum beats
-            # the chain's O(edges) SpMV
+            # the chain's O(nodes) degree vector
             pos, present = gi.compact_of(id_col, ctx)
             rp, _, _ = gi.csr(self.types_key, self.backwards, ctx)
             n_dev = J.frontier_degree_sum(rp, pos, present)
+            _note_chain_forms(("degree",))
             with _obs_trace.sync("expand"):
                 return int(n_dev)
+        # the frontier holds every node exactly once: the input is the
+        # graph's own scan of a label set that every node carries, row for
+        # row (facts of the plan and of the index build; nothing is read)
+        whole = (
+            base.frontier_scan is not None
+            # no null id: no validity, or one that marks the pad tail alone
+            and (id_col.valid is None or id_col.pad_synth)
+            and in_t.size == id_col.logical_len == gi.num_logical_nodes
+            and gi.scan_is_whole(base.frontier_scan, ctx)
+        )
         hop_data = []
         for hop in reversed(hops):  # deepest (first executed) hop first
             mask = gi.label_mask(hop.far_labels, ctx)
@@ -744,12 +771,13 @@ class CsrExpandOp(_FusedExpandBase):
             if divisible and size > 1:
                 chain = J.path_count_chain_on_mesh(mesh, axis)
                 _obs_trace.note("expand_shards", size)
+        _note_chain_forms(
+            J.chain_forms([h[5] is not None for h in reversed(hop_data)], whole)
+        )
+        # a whole frontier is not read: none is handed to the program
+        frontier = (None,) * 3 if whole else (dev_ids, id_col.data, id_col.valid)
         n_dev = chain(
-            dev_ids,
-            id_col.data,
-            id_col.valid,
-            tuple(hop_data),
-            num_nodes=gi.num_nodes,
+            *frontier, tuple(hop_data), num_nodes=gi.num_nodes, whole=whole
         )
         with _obs_trace.sync("expand"):  # the read that waits for the chain
             return int(n_dev)
@@ -1813,6 +1841,16 @@ def plan_expand_fastpath(planner, op, lhs, rhs, classic) -> Optional[RelationalO
     m = op.rhs.node_type.material
     far_labels = tuple(sorted(getattr(m, "labels", ()) or ()))
     types = getattr(op.rel_type.material, "types", frozenset()) or frozenset()
+    frontier_scan = None
+    if (
+        isinstance(op.lhs, L.NodeScan)
+        and not op.lhs.in_op.fields
+        and lhs.graph is rhs.graph
+    ):
+        # ``lhs`` is then ``graph.scan_operator`` of this type and nothing
+        # else (``RelationalPlanner._plan_NodeScan``)
+        fm = op.lhs.node_type.material
+        frontier_scan = tuple(sorted(getattr(fm, "labels", ()) or ()))
     return CsrExpandOp(
         lhs,
         classic,
@@ -1824,6 +1862,7 @@ def plan_expand_fastpath(planner, op, lhs, rhs, classic) -> Optional[RelationalO
         undirected=op.direction == "-",
         backwards=backwards,
         far_labels=far_labels,
+        frontier_scan=frontier_scan,
     )
 
 

@@ -169,10 +169,12 @@ class GraphIndex:
         # count chains subtract the double-counted loop contribution)
         self._loop_count: Dict[Tuple[str, ...], Any] = {}
         # labels_key -> device bool[num_nodes] (node carries the labels) or
-        # None for the unrestricted set
+        # None where every logical node does (the unrestricted set among them)
         self._label_mask: Dict[Tuple[str, ...], Optional[Any]] = {}
         # labels_key -> host row_map copy (mask building without a D2H sync)
         self._row_map_np: Dict[Tuple[str, ...], np.ndarray] = {}
+        # labels_key -> logical row count of that node scan
+        self._scan_rows: Dict[Tuple[str, ...], int] = {}
         # types_key -> (sorted global ids, scan-row perm) device arrays:
         # global-rel-id -> canonical scan row (isomorphism forbid masks)
         self._rel_id_index: Dict[Tuple[str, ...], Tuple[Any, Any]] = {}
@@ -197,6 +199,14 @@ class GraphIndex:
         if self._node_ids is None:
             raise GraphIndexError("node ids not built yet")
         return int(self._node_ids[0].shape[0])
+
+    @property
+    def num_logical_nodes(self) -> int:
+        """The graph's own node count: ``num_nodes`` less the bucket's pad
+        ids."""
+        if self._node_ids is None:
+            raise GraphIndexError("node ids not built yet")
+        return len(self._node_ids[1])
 
     def node_scan(self, labels: Tuple[str, ...], ctx):
         """Canonical node scan for a label set: (columns, header, row_map).
@@ -242,22 +252,40 @@ class GraphIndex:
         row_map = np.full(self.num_nodes, -1, dtype=np.int64)
         row_map[pos] = np.arange(len(ids_np), dtype=np.int64)
         self._row_map_np[key] = row_map
+        self._scan_rows[key] = len(ids_np)
         out = (table._cols, header, jnp.asarray(row_map))
         self._node_scans[key] = out
         return out
 
     def label_mask(self, labels: Tuple[str, ...], ctx) -> Optional[Any]:
-        """Device bool[num_nodes]: node carries the label set. ``None`` for
-        the empty set (every node qualifies — structurally skips the mask
-        multiply in fused count chains)."""
+        """Device bool[num_nodes]: node carries the label set. ``None``
+        when every LOGICAL node does — the empty set, or a label the whole
+        graph carries (decided on the host copy of the row map, no device
+        read): every node qualifies, so callers skip the mask multiply and
+        the count chain knows its weights are still constant. Exact under
+        bucketing: a pad node has degree 0, no edge points at it and no
+        frontier holds it, so what weight it carries is never read."""
         key = tuple(sorted(labels))
         if not key:
             return None
         if key not in self._label_mask:
             with _build("label_mask", labels=":".join(key)):
                 self.node_scan(key, ctx)
-                self._label_mask[key] = jnp.asarray(self._row_map_np[key] >= 0)
+                carries = self._row_map_np[key] >= 0
+                every = bool(carries[: self.num_logical_nodes].all())
+                self._label_mask[key] = None if every else jnp.asarray(carries)
         return self._label_mask[key]
+
+    def scan_is_whole(self, labels: Tuple[str, ...], ctx) -> bool:
+        """True when the node scan of ``labels`` holds every logical node
+        exactly once: as many rows as the graph has nodes, and every node
+        mapped to one of them (host facts of the build, no device read)."""
+        key = tuple(sorted(labels))
+        self.node_scan(key, ctx)
+        return (
+            self._scan_rows[key] == self.num_logical_nodes
+            and self.label_mask(key, ctx) is None
+        )
 
     # -- relationships -----------------------------------------------------
 

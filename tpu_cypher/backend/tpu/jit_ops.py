@@ -21,7 +21,7 @@ select the right compiled variant automatically.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -605,6 +605,34 @@ def gather_swapped(a_data, b_data, a_valid, b_valid, orig, swapped):
 # ---------------------------------------------------------------------------
 
 
+def chain_forms(masked: Sequence[bool], whole: bool) -> Tuple[str, ...]:
+    """The form each hop of a count chain takes, in the order executed
+    (far end first); ``masked[i]`` says whether that hop's far node
+    carries a label mask, ``whole`` whether the frontier holds every node
+    exactly once. Chosen from those alone, at trace time and — by the same
+    function — on the host that counts them:
+
+    * ``degree``: the weights are still the constant 1, so the hop is the
+      degree vector, ``row_ptr`` differences; no edge is read;
+    * ``reduce``: the hop next to a whole frontier feeds a plain sum over
+      every node, which is ``sum_e w[ci[e]]``: one gather over the edge
+      lanes and one reduction, no prefix sums;
+    * ``scan``: per-node sums of non-constant weights, the prefix-scan
+      SpMV (``_csr_spmv``)."""
+    forms = []
+    constant = True
+    for i, m in enumerate(masked):
+        constant = constant and not m
+        if constant:
+            forms.append("degree")
+        elif whole and i == len(masked) - 1:
+            forms.append("reduce")
+        else:
+            forms.append("scan")
+        constant = False
+    return tuple(forms)
+
+
 def _csr_spmv(rp, ci, w):
     """(A w)[n] = sum of w[ci[e]] over n's CSR edge range — computed as a
     cumsum difference at row_ptr boundaries: gathers + one scan, ZERO
@@ -616,6 +644,56 @@ def _csr_spmv(rp, ci, w):
         ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
     rp64 = rp.astype(jnp.int64)
     return jnp.take(ps, rp64[1:]) - jnp.take(ps, rp64[:-1])
+
+
+# edge lanes a step of ``_edge_sum`` gathers (my chip runs, PR 27, 2**26
+# lanes of which 40M real: 2**16 514 ms, 2**18 288 ms, 2**20 293 ms, 2**22
+# 301 ms, against 578 ms for the whole array in one gather)
+_EDGE_CHUNK = 1 << 18
+
+
+def _lanes_sum(ci, w):
+    """64-bit sum of ``w[ci[e]]`` over these lanes, the pad tail (``ci`` =
+    -1) counting 0. The gather runs at ``w``'s own width with the 32-bit
+    index ``col_idx`` is stored in (a 64-bit lane costs the chip three
+    times a 32-bit one)."""
+    t = jnp.take(w, jnp.clip(ci, 0), mode="clip")
+    return jnp.sum(jnp.where(ci >= 0, t, jnp.zeros((), w.dtype)), dtype=jnp.int64)
+
+
+def _edge_sum(ci, w, stop):
+    """sum over the real edges of ``w[ci[e]]`` = the sum over every node of
+    (A w)[n]: one gather and one reduction, no prefix sums. ``stop`` is the
+    number of lanes that hold real edges (traced; every lane from there on
+    is pad): the lanes are walked in steps of ``_EDGE_CHUNK`` and the walk
+    ends with the real edges, so a bucket's pad tail costs nothing."""
+    lanes = ci.shape[0]
+    steps = lanes // _EDGE_CHUNK
+    with jax.named_scope("reduce"):
+        if steps < 2:
+            return _lanes_sum(ci, w)
+
+        def more(state):
+            return (state[0] < steps) & (state[0] * _EDGE_CHUNK < stop)
+
+        def step(state):
+            i, acc = state
+            chunk = lax.dynamic_slice(ci, (i * _EDGE_CHUNK,), (_EDGE_CHUNK,))
+            return i + 1, acc + _lanes_sum(chunk, w)
+
+        # the first step is taken outright: its sum is the carry's type
+        # (inside a shard_map, one that varies over the mesh)
+        first = _lanes_sum(ci[:_EDGE_CHUNK], w)
+        _, total = lax.while_loop(more, step, (jnp.int32(1), first))
+        if lanes % _EDGE_CHUNK:
+            total = total + _lanes_sum(ci[steps * _EDGE_CHUNK:], w)
+        return total
+
+
+def _csr_edge_sum(rp, ci, w):
+    """``_edge_sum`` over one device's whole edge array: ``rp[-1]`` real
+    edges lead it."""
+    return _edge_sum(ci, w, rp[-1])
 
 
 def _sharded_spmv(mesh, axis: str):
@@ -654,48 +732,110 @@ def _sharded_spmv(mesh, axis: str):
     return spmv
 
 
-def _chain_body(dev_ids, ids, valid, hops, num_nodes: int, spmv):
+def _sharded_edge_sum(mesh, axis: str):
+    """``_csr_edge_sum`` over a row-sharded edge array: per shard the sum
+    of its own real lanes, then a ``psum`` of ONE scalar where the sharded
+    SpMV sums a vector of ``num_nodes``."""
+    from ...parallel.mesh import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def kernel(rp_r, ci_shard, w_r):
+        first = lax.axis_index(axis).astype(jnp.int64) * ci_shard.shape[0]
+        return lax.psum(_edge_sum(ci_shard, w_r, rp_r[-1] - first), axis)
+
+    def edge_sum(rp, ci, w):
+        return shard_map(
+            kernel, mesh, in_specs=(P(), P(axis), P()), out_specs=P()
+        )(rp, ci, w)
+
+    return edge_sum
+
+
+def _degrees(rp):
+    """Per-node edge count of one CSR orientation, 32-bit: differences of
+    the int32 ``row_ptr``, each at most ``rp[-1]``, so they fit."""
+    return rp[1:] - rp[:-1]
+
+
+def _chain_body(dev_ids, ids, valid, hops, num_nodes: int, whole: bool,
+                spmv, edge_sum):
     """Shared traced body of the fused count chain (see
-    ``path_count_chain``); ``spmv`` is the single-device or sharded SpMV.
-    Each hop's operations carry a ``hop<i>`` scope (``i`` counts in the
-    order executed; metadata only), so a kept device trace tells the hops
-    of one program apart."""
-    w = jnp.ones(num_nodes, jnp.int64)
-    for i, (rp_a, ci_a, rp_b, ci_b, loop_cnt, mask) in enumerate(reversed(hops)):
+    ``path_count_chain``); ``spmv`` / ``edge_sum`` are the single-device or
+    the sharded forms. Each hop's operations carry a ``hop<i>`` scope
+    (``i`` counts in the order executed; metadata only), so a kept device
+    trace tells the hops of one program apart.
+
+    ``w`` is ``None`` while it is the constant 1 on every node, and 32-bit
+    only as one orientation's degrees, which are proven to fit; every sum
+    is 64-bit."""
+    executed = tuple(reversed(hops))
+    forms = chain_forms([h[5] is not None for h in executed], whole)
+    w = None
+    for i, (hop, form) in enumerate(zip(executed, forms)):
+        rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hop
         with jax.named_scope(f"hop{i}"):
+            if form == "degree":
+                w = _degrees(rp_a)
+                if rp_b is not None:
+                    w = w.astype(jnp.int64) + _degrees(rp_b) - loop_cnt
+                continue
             if mask is not None:  # far-label filter of this hop
-                w = jnp.where(mask, w, 0)
+                w = (
+                    mask.astype(jnp.int32)
+                    if w is None
+                    else jnp.where(mask, w, jnp.zeros((), w.dtype))
+                )
+            if form == "reduce":
+                total = edge_sum(rp_a, ci_a, w)
+                if rp_b is not None:
+                    total = total + edge_sum(rp_b, ci_b, w) - jnp.sum(
+                        loop_cnt * w, dtype=jnp.int64
+                    )
+                return total
+            w = w.astype(jnp.int64)
             nw = spmv(rp_a, ci_a, w)
             if rp_b is not None:
                 nw = nw + spmv(rp_b, ci_b, w) - loop_cnt * w
             w = nw
-    # base frontier: one completion-count gather per input row
     with jax.named_scope("frontier"):
+        if whole:  # every node once: the plain sum (a pad node's w is 0)
+            return jnp.sum(w, dtype=jnp.int64)
+        # one completion-count gather per input row
         pos = jnp.clip(jnp.searchsorted(dev_ids, ids), 0, num_nodes - 1)
         present = jnp.take(dev_ids, pos) == ids
         if valid is not None:
             present = present & valid
-        return jnp.sum(jnp.where(present, jnp.take(w, pos), 0))
+        return jnp.sum(
+            jnp.where(present, jnp.take(w, pos), 0), dtype=jnp.int64
+        )
 
 
-@partial(jax.jit, static_argnames=("num_nodes",))
-def path_count_chain(dev_ids, ids, valid, hops, num_nodes: int):
+@partial(jax.jit, static_argnames=("num_nodes", "whole"))
+def path_count_chain(dev_ids, ids, valid, hops, num_nodes: int,
+                     whole: bool = False):
     """Total path count of a typed expand chain WITHOUT materializing any
     intermediate row set — ONE program replacing the whole 2k-join cascade.
 
     Evaluated RIGHT-TO-LEFT: ``w[n]`` = number of chain completions
-    starting at node n; each hop is a scatter-free CSR SpMV (cumsum form);
-    far-label filters multiply ``w`` by a node mask; the base frontier
-    multiplicities collapse to one gather+sum over the input id column.
+    starting at node n; far-label filters multiply ``w`` by a node mask;
+    the base frontier multiplicities collapse to one gather+sum over the
+    input id column. A hop costs an edge pass only where the algebra needs
+    one and a prefix scan only where per-node sums of non-constant weights
+    are needed (``chain_forms``). ``whole``: the frontier holds every node
+    of the graph exactly once (a host fact, ``GraphIndex.scan_is_whole``);
+    ``dev_ids`` / ``ids`` / ``valid`` are then not read and may be None.
 
-    ``hops`` (deepest/first-executed hop first): per hop a tuple
+    ``hops`` (in pattern order, the hop next to the frontier first; the
+    LAST is executed first): per hop a tuple
     ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask)`` —
     fwd: (rp_fwd, ci_fwd, None, None, None, mask);
     bwd: (rp_rev, ci_rev, None, None, None, mask);
     und: both orientations + per-node self-loop counts (primary half counts
     loops once, the opposite half excludes them — subtracting loop_cnt*w
     reproduces exactly the two CsrExpandOp halves)."""
-    return _chain_body(dev_ids, ids, valid, hops, num_nodes, _csr_spmv)
+    return _chain_body(
+        dev_ids, ids, valid, hops, num_nodes, whole, _csr_spmv, _csr_edge_sum
+    )
 
 
 _MESH_CHAIN_CACHE: Dict[Any, Any] = {}
@@ -703,15 +843,18 @@ _MESH_CHAIN_CACHE: Dict[Any, Any] = {}
 
 def path_count_chain_on_mesh(mesh, axis: str):
     """Mesh-active variant of ``path_count_chain``: same chain body with
-    the shard_map SpMV. Jitted once per mesh (cached)."""
+    the shard_map SpMV and edge sum. Jitted once per mesh (cached)."""
     got = _MESH_CHAIN_CACHE.get((mesh, axis))
     if got is not None:
         return got
     spmv = _sharded_spmv(mesh, axis)
+    edge_sum = _sharded_edge_sum(mesh, axis)
 
-    @partial(jax.jit, static_argnames=("num_nodes",))
-    def run(dev_ids, ids, valid, hops, num_nodes: int):
-        return _chain_body(dev_ids, ids, valid, hops, num_nodes, spmv)
+    @partial(jax.jit, static_argnames=("num_nodes", "whole"))
+    def run(dev_ids, ids, valid, hops, num_nodes: int, whole: bool = False):
+        return _chain_body(
+            dev_ids, ids, valid, hops, num_nodes, whole, spmv, edge_sum
+        )
 
     _MESH_CHAIN_CACHE[(mesh, axis)] = run
     return run

@@ -65,6 +65,14 @@ HOST_SYNCS = M.REGISTRY.counter(
     labels=("site",),
 )
 
+COUNT_CHAIN_HOPS = M.REGISTRY.counter(
+    "tpu_cypher_count_chain_hops_total",
+    "hops of fused count chains by the form taken: degree (row_ptr "
+    "differences, no edge read), reduce (one gather and one sum over the "
+    "edges), scan (gather, prefix scan, boundary reads)",
+    labels=("form",),
+)
+
 
 class Span:
     """One node of the tree: a named, timed region with attributes."""
