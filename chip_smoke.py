@@ -24,7 +24,8 @@ because a chip belongs to one process at a time:
 
 ``--chips 4`` (the builder's run) runs only ``mesh`` (one process,
 ``CypherSession.tpu(mesh=4)``: four-way sharded columns and CSR, balanced
-per-device bytes, sharded tiers taken) and ``cluster4`` (four workers, one
+per-device bytes, its queries sent through ``QueryServer`` and the wire, every
+sharded tier taken and counted, none declined) and ``cluster4`` (four workers, one
 chip each, assigned by the supervisor). ``--only`` keeps a subset of the
 mode's phases (four-chip time costs four times as much).
 
@@ -636,7 +637,7 @@ async def cluster_phase(args, n_workers: int):
 # -- phase: mesh -------------------------------------------------------------
 
 
-def mesh_phase(args, device):
+async def mesh_phase(args, device):
     import jax
 
     from tpu_cypher import CypherSession
@@ -677,38 +678,44 @@ def mesh_phase(args, device):
         "MATCH (a:Person) WITH DISTINCT a.birthday AS b RETURN count(*) AS c",
         {}, [{"c": len(set(arrays["birthday"].tolist()))}],
     )]
+    # through QueryServer and the wire, as the one-chip serve phase sends
+    # its queries: worker lanes, admission and the record of each request
+    # meet the process-global mesh here
+    from tpu_cypher.serve import QueryServer
+
+    server = QueryServer(session, port=0, cache_bytes=0)
+    server.register_graph("snb", graph)
     before = REGISTRY.flat()
-    shards_seen = set()
-    for name, query, params, want in queries:
-        secs = []
-        for _ in ("cold", "warm"):
-            t0 = time.perf_counter()
-            rows, res = run_rows(graph, query, params)
-            secs.append(round(time.perf_counter() - t0, 3))
-            check(rows == want, f"{name}: got {str(rows)[:300]} want "
-                  f"{str(want)[:300]}")
-            log = res.execution_log
-            check(len(log) == 1 and log[0]["rung"] == "device",
-                  f"{name}: not the first rung alone: {log}")
-            check(not res.fallbacks, f"{name}: fallbacks {res.fallbacks}")
-            prof = json.dumps(res.profile(execute=False).to_dict())
-            for note in ("expand_shards", "agg_shards", "distinct_shards"):
-                if f'"{note}"' in prof:
-                    shards_seen.add(note)
-        say(f"query {name}: equal to reference; cold {secs[0]}s warm "
-            f"{secs[1]}s; first rung only")
+    async with server:
+        say(f"QueryServer on {server.host}:{server.port}, mesh of {n}")
+        for name, query, params, want in queries:
+            await run_query(
+                server.host, server.port, name, "snb", query, params, want
+            )
     after = REGISTRY.flat()
     moved = {
         k: after[k] - before.get(k, 0) for k in after
         if k.startswith("tpu_cypher_mesh_") and after[k] != before.get(k, 0)
     }
-    say(f"sharded tiers: counters moved {moved}; span notes {sorted(shards_seen)}")
-    check("expand_shards" in shards_seen,
-          "the count chain took the global path, not the sharded SpMV")
-    check(any(k.startswith("tpu_cypher_mesh_agg_total") for k in moved),
-          "grouped aggregation took the global path, not parallel/agg.py")
-    check(any(k.startswith("tpu_cypher_mesh_distinct_total") for k in moved),
-          "DISTINCT took the global path, not parallel/shuffle.py")
+    say(f"sharded tiers: counters moved {moved}")
+    for series, what in (
+        ("tpu_cypher_mesh_expand_total",
+         "the count chain took the global path, not the sharded SpMV"),
+        ("tpu_cypher_mesh_agg_total",
+         "grouped aggregation took the global path, not parallel/agg.py"),
+        ("tpu_cypher_mesh_distinct_total",
+         "DISTINCT took the global path, not parallel/shuffle.py"),
+        ("tpu_cypher_mesh_join_total",
+         "the value join took the global sort-probe join, not a tier of "
+         "parallel/shuffle.py"),
+    ):
+        check(any(k.startswith(series) for k in moved), what)
+    declined = {
+        k: v for k, v in moved.items()
+        if k.startswith("tpu_cypher_mesh_declines_total")
+    }
+    check(not declined,
+          f"a sharded tier handed back to the global path: {declined}")
     return {"ok": True, "device": device}
 
 
@@ -723,10 +730,8 @@ def child(args) -> int:
         )
     else:
         device = claim_device(args)
-        if args.phase == "serve":
-            report = asyncio.run(serve_phase(args, device))
-        else:
-            report = mesh_phase(args, device)
+        phase = serve_phase if args.phase == "serve" else mesh_phase
+        report = asyncio.run(phase(args, device))
     write_report(args.phase, report)
     say(f"phase {args.phase} passed")
     return 0

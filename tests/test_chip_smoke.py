@@ -72,6 +72,28 @@ def test_rehearsal_can_never_print_the_tpu_success_line(rehearsal):
     assert '"platform": "tpu"' not in rehearsal.stdout
 
 
+def test_mesh_phase_sends_its_queries_through_the_server():
+    """``--chips 4``'s mesh phase on four virtual devices: the queries go
+    through ``QueryServer`` and the wire, every sharded tier's counter
+    moves (the join's among them) and nothing declines."""
+    proc = _run(["--rehearse-cpu", "--scale", "0.5", "--chips", "4",
+                 "--only", "mesh"])
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    out = proc.stdout
+    assert "phase mesh passed" in out, out
+    assert "QueryServer on " in out and "mesh of 4" in out
+    for name in ("two_hop_count", "grouped_aggregate", "sort_probe_join",
+                 "distinct_values"):
+        assert f"query {name}: rows=" in out, name
+    moved = next(l for l in out.splitlines() if "counters moved" in l)
+    for series in ("tpu_cypher_mesh_expand_total", "tpu_cypher_mesh_agg_total",
+                   "tpu_cypher_mesh_distinct_total",
+                   "tpu_cypher_mesh_join_total",
+                   "tpu_cypher_mesh_exchange_bytes_total"):
+        assert series in moved, (series, moved)
+    assert "tpu_cypher_mesh_declines_total" not in moved, moved
+
+
 def test_no_accelerator_fails_before_loading_anything():
     proc = _run(["--scale", "0.05"])
     assert proc.returncode not in (0, None)

@@ -33,6 +33,15 @@ def _ground_truth(lk, lv, rk, rv):
     return want
 
 
+def _live_pairs(got):
+    """A sharded join's (left rows, right rows) as lists: the ``total``
+    live pairs that lead the arrays, without the lattice's pad lanes."""
+    assert got is not None
+    l_rows, r_rows, total = got
+    assert len(l_rows) == len(r_rows) >= total
+    return np.asarray(l_rows)[:total].tolist(), np.asarray(r_rows)[:total].tolist()
+
+
 @pytest.mark.parametrize(
     "seed,n_l,n_r,lo,hi",
     [
@@ -52,10 +61,7 @@ def test_hash_repartition_join_matches_ground_truth(seed, n_l, n_r, lo, hi):
         got = SH.hash_repartition_join(
             jnp.asarray(lk), jnp.asarray(lv), jnp.asarray(rk), jnp.asarray(rv)
         )
-    assert got is not None
-    got_c = Counter(
-        zip(np.asarray(got[0]).tolist(), np.asarray(got[1]).tolist())
-    )
+    got_c = Counter(zip(*_live_pairs(got)))
     assert got_c == _ground_truth(lk, lv, rk, rv)
 
 
@@ -69,10 +75,7 @@ def test_negative_keys_join_correctly():
         got = SH.hash_repartition_join(
             jnp.asarray(lk), None, jnp.asarray(rk), None
         )
-    assert got is not None
-    got_c = Counter(
-        zip(np.asarray(got[0]).tolist(), np.asarray(got[1]).tolist())
-    )
+    got_c = Counter(zip(*_live_pairs(got)))
     assert got_c == _ground_truth(lk, [True] * 5, rk, [True] * 5)
 
 
@@ -89,8 +92,7 @@ def test_strided_keys_use_all_shards(stride):
         got = SH.hash_repartition_join(
             jnp.asarray(keys), None, jnp.asarray(keys), None
         )
-    assert got is not None
-    l_rows, r_rows = (np.asarray(a) for a in got)
+    l_rows, r_rows = (np.asarray(a) for a in _live_pairs(got))
     assert len(l_rows) == n
     assert (l_rows == r_rows).all()
 
@@ -112,7 +114,20 @@ def test_skew_overflow_falls_back_to_none():
     assert got is None
 
 
-def test_engine_join_on_mesh_uses_shuffle(monkeypatch):
+@pytest.mark.parametrize("bucket", ["off", "pow2"])
+@pytest.mark.parametrize(
+    "q",
+    [
+        "MATCH (a:P)-[:K]->(b:P) "
+        "RETURN b.age AS g, count(*) AS c ORDER BY g, c",
+        # an outer shape: the tiers' pairs come tail-padded on the bucket
+        # lattice and are cut to their true count before the unmatched rows
+        "MATCH (a:P) OPTIONAL MATCH (a)-[:K]->(b:P) "
+        "RETURN a.age AS g, count(b) AS c ORDER BY g, c",
+    ],
+    ids=["inner", "optional"],
+)
+def test_engine_join_on_mesh_uses_shuffle(monkeypatch, bucket, q):
     """An engine query whose plan genuinely JOINS (dangling edge endpoints
     make the CSR index bail, so Expand runs as the classic scan+join
     cascade) routes the mesh join through hash_repartition_join and
@@ -173,15 +188,17 @@ def test_engine_join_on_mesh_uses_shuffle(monkeypatch):
         )
         return session.read_from(ElementTable(nm, nt), ElementTable(rm, rt))
 
-    q = (
-        "MATCH (a:P)-[:K]->(b:P) "
-        "RETURN b.age AS g, count(*) AS c ORDER BY g, c"
-    )
+    from tpu_cypher.backend.tpu import bucketing
+
     g_local = build(CypherSession.local())
     want = [dict(r) for r in g_local.cypher(q).records.collect()]
-    with use_mesh(make_row_mesh()):
-        g_tpu = build(CypherSession.tpu())
-        got = [dict(r) for r in g_tpu.cypher(q).records.collect()]
+    bucketing.MODE.set(bucket)
+    try:
+        with use_mesh(make_row_mesh()):
+            g_tpu = build(CypherSession.tpu())
+            got = [dict(r) for r in g_tpu.cypher(q).records.collect()]
+    finally:
+        bucketing.MODE.reset()
     assert got == want
     assert calls["n"] >= 1, "mesh join did not route through a deliberate tier"
 
@@ -211,9 +228,7 @@ def test_broadcast_join_differential(seed, n_l, n_r, lo, hi):
         got = SH.broadcast_join(
             jnp.asarray(lk), jnp.asarray(lv), jnp.asarray(rk), jnp.asarray(rv)
         )
-    assert got is not None
-    l_rows, r_rows = got
-    have = Counter(zip(np.asarray(l_rows).tolist(), np.asarray(r_rows).tolist()))
+    have = Counter(zip(*_live_pairs(got)))
     assert have == want
 
 

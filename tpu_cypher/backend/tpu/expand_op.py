@@ -22,6 +22,8 @@ Semantics are bag-identical to the classic cascade by construction:
 
 from __future__ import annotations
 
+import contextlib
+
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -30,7 +32,12 @@ import numpy as np
 
 from ...ir import expr as E
 from ...obs import trace as _obs_trace
-from ...parallel.mesh import current_mesh, mesh_size
+from ...parallel.mesh import (
+    current_mesh,
+    mesh_size,
+    note_decline,
+    note_exchange,
+)
 from ...runtime.faults import fault_point
 from ...relational.header import RecordHeader
 from ...relational.ops import RelationalOperator
@@ -124,6 +131,12 @@ MXU_TIER_COUNTS = CounterView(
     ),
     "tier",
     ("dense", "tiled"),
+)
+
+_MESH_EXPAND_TOTAL = _OBS_REGISTRY.counter(
+    "tpu_cypher_mesh_expand_total",
+    "fused count chains executed as the explicit shard_map program over "
+    "the row-sharded CSR",
 )
 
 # which NATIVE (C++ stamping/DFS) kernels answered — same purpose
@@ -770,15 +783,34 @@ class CsrExpandOp(_FusedExpandBase):
             )
             if divisible and size > 1:
                 chain = J.path_count_chain_on_mesh(mesh, axis)
-                _obs_trace.note("expand_shards", size)
-        _note_chain_forms(
-            J.chain_forms([h[5] is not None for h in reversed(hop_data)], whole)
+            elif size > 1:
+                note_decline("expand", "unpadded_edges")
+        forms = J.chain_forms(
+            [h[5] is not None for h in reversed(hop_data)], whole
         )
+        _note_chain_forms(forms)
         # a whole frontier is not read: none is handed to the program
         frontier = (None,) * 3 if whole else (dev_ids, id_col.data, id_col.valid)
-        n_dev = chain(
-            *frontier, tuple(hop_data), num_nodes=gi.num_nodes, whole=whole
-        )
+        on_mesh = chain is not J.path_count_chain
+        if on_mesh:
+            _obs_trace.note("expand_shards", size)
+        with (
+            _obs_trace.span("mesh_expand", kind="mesh") if on_mesh
+            else contextlib.nullcontext()
+        ):
+            n_dev = chain(
+                *frontier, tuple(hop_data), num_nodes=gi.num_nodes, whole=whole
+            )
+        if on_mesh:
+            # per edge pass (two for an undirected hop) every shard hands
+            # the mesh one 64-bit scalar in the reduce form and one per
+            # node in the scan form; a degree hop reads no edge
+            words = {"degree": 0, "reduce": 1, "scan": gi.num_nodes}
+            note_exchange("expand", size * 8 * sum(
+                words[form] * (2 if h[2] is not None else 1)
+                for h, form in zip(reversed(hop_data), forms)
+            ))
+            _MESH_EXPAND_TOTAL.inc()
         with _obs_trace.sync("expand"):  # the read that waits for the chain
             return int(n_dev)
 

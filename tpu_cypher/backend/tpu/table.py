@@ -69,6 +69,8 @@ from .compiler import TpuEvaluator, TpuUnsupportedExpr
 
 from ...obs import trace as _obs_trace
 from ...obs.metrics import REGISTRY as _OBS_REGISTRY
+from ...parallel.mesh import mesh_size as _mesh_size
+from ...parallel.mesh import note_decline as _note_mesh_decline
 
 _FALLBACKS = _OBS_REGISTRY.counter(
     "tpu_cypher_fallbacks_total",
@@ -646,11 +648,18 @@ class TpuTable(Table):
             if got is None:
                 got = hash_repartition_join(lkd, lv, rkd, rv)
             if got is not None:
-                left_rows, right_rows = got
-                total = int(left_rows.shape[0])
+                left_rows, right_rows, total = got
+                match_bucketed = int(left_rows.shape[0]) != total
                 bucketing.admit(total, join_row_bytes, "join")
             else:
                 packed_all_keys = False
+        elif _mesh_size() > 1:
+            # the sharded tiers join inner and left/full outer shapes on
+            # 64-bit integer keys only
+            _note_mesh_decline(
+                "join",
+                "key_kind" if lk.kind != I64 or rk.kind != I64 else "join_kind",
+            )
         if left_rows is None:
             is_f64 = lk.kind == F64
             is_bool = lk.kind == BOOL
@@ -1109,6 +1118,9 @@ class TpuTable(Table):
                 return sharded
             cnt = J.distinct_count_packed(datas, valids, (), kinds, pack)
         else:
+            if _mesh_size() > 1:
+                # the exchange routes ONE packed 63-bit key per row
+                _note_mesh_decline("distinct", "unpackable")
             # unpackable keys: sort unpacked directly — re-probing min/max
             # via _first_occurrence_index would repeat the device round trip
             _, _, cnt = J.equivalence_sort(datas, valids, (), kinds, pack=None)
@@ -1122,13 +1134,12 @@ class TpuTable(Table):
         multi-device mesh is active, the ``TPU_CYPHER_MESH_AGG`` gate is
         off, or the shuffle declines (skew overflow / non-addressable
         rows) — the global values-only sort stays the fallback."""
-        from ...parallel import mesh as PM
-
-        if PM.mesh_size() <= 1:
+        if _mesh_size() <= 1:
             return None
         from ...utils.config import MESH_AGG
 
         if MESH_AGG.get().strip().lower() != "auto":
+            _note_mesh_decline("distinct", "gate")
             return None
         from ...parallel.shuffle import sharded_distinct_count
 
@@ -1385,6 +1396,9 @@ class TpuTable(Table):
                     return Column(I64, out_data, None)
                 out_kind = F64 if name == "avg" else kind
                 return Column(out_kind, out_data, out_valid, vocab)
+        elif _mesh_size() > 1:
+            # a float combine is not associative: the global path keeps it
+            _note_mesh_decline("agg", "not_integer")
         # kernel tier: the Pallas masked segment reduce when eligible
         # (dispatch falls back to the jax.ops scatter formulation; see
         # backend/tpu/pallas/aggregate.py)

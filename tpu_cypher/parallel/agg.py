@@ -31,8 +31,14 @@ from jax.sharding import PartitionSpec as P
 from ..obs import trace as _obs_trace
 from ..obs.metrics import REGISTRY as _REGISTRY
 from ..runtime.faults import fault_point
-from .mesh import current_mesh, mesh_size, shard_map
-from .shuffle import _pad_sharded
+from .mesh import (
+    current_mesh,
+    mesh_size,
+    note_decline,
+    note_exchange,
+    shard_map,
+)
+from .shuffle import _addressable, _pad_sharded, _to_host
 
 _MESH_AGG_TOTAL = _REGISTRY.counter(
     "tpu_cypher_mesh_agg_total",
@@ -126,27 +132,38 @@ def sharded_segment_agg(
     the caller keeps the global path."""
     mesh = current_mesh()
     nsh = mesh_size()
-    if mesh is None or nsh <= 1 or name not in _INT_NAMES or k <= 0:
+    if mesh is None or nsh <= 1:
+        return None
+    if name not in _INT_NAMES or k <= 0:
+        note_decline("agg", "not_integer_exact")
         return None
     if not _gate_open():
+        note_decline("agg", "gate")
         return None
-    for arr in (data, valid, seg_j):
-        if arr is not None and not getattr(arr, "is_fully_addressable", True):
-            return None
+    if not _addressable(data, valid, seg_j):
+        note_decline("agg", "not_addressable")
+        return None
     fault_point("agg")  # staging rows to host for resharding syncs here
-    d_np = np.asarray(data)
-    n = d_np.shape[0]
-    if n == 0:
-        return None
-    v_np = (
-        np.ones(n, bool) if valid is None else np.asarray(valid, dtype=bool)
-    )
-    s_np = np.asarray(seg_j, dtype=np.int64)
+    with _obs_trace.span("mesh_agg:stage", kind="mesh"):
+        d_np = _to_host("agg", data)
+        n = d_np.shape[0]
+        if n == 0:
+            return None  # nothing to shard: the global path's empty groups
+        v_np = (
+            np.ones(n, bool) if valid is None
+            else _to_host("agg", valid, bool)
+        )
+        s_np = _to_host("agg", seg_j, np.int64)
     axis = mesh.axis_names[0]
-    d = _pad_sharded(d_np, nsh, 0, mesh, axis)
-    v = _pad_sharded(v_np, nsh, False, mesh, axis)
-    s = _pad_sharded(s_np, nsh, 0, mesh, axis)
-    out, cnt = _agg_fn(mesh, axis, name, bool(is_bool), int(k))(d, v, s)
+    with _obs_trace.span("mesh_agg:combine", kind="mesh", agg=name):
+        d = _pad_sharded(d_np, nsh, 0, mesh, axis)
+        v = _pad_sharded(v_np, nsh, False, mesh, axis)
+        s = _pad_sharded(s_np, nsh, 0, mesh, axis)
+        out, cnt = _agg_fn(mesh, axis, name, bool(is_bool), int(k))(d, v, s)
+        # every shard hands the mesh its k counts, and k partials more: as
+        # they are for a sum, placed in a stack of nsh for a min or a max
+        partials = {"count": 0, "sum": k, "avg": k}.get(name, nsh * k)
+        note_exchange("agg", nsh * (k + partials) * 8)
     _MESH_AGG_TOTAL.inc()
     _obs_trace.note("agg_shards", nsh)
     if name == "count":

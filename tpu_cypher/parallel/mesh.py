@@ -29,7 +29,46 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import trace as _obs_trace
+from ..obs.metrics import REGISTRY as _REGISTRY
 from ..utils import config as _config
+
+# Every hand-back from a sharded tier to the global (GSPMD-partitioned)
+# path while a multi-device mesh is active: the answer stays exact, the
+# layout is not the one the deployment asked for, so it is counted.
+_MESH_DECLINES = _REGISTRY.counter(
+    "tpu_cypher_mesh_declines_total",
+    "operations that a sharded tier handed back to the global path while "
+    "a multi-device mesh was active",
+    labels=("op", "reason"),
+)
+# the declines a healthy mesh deployment reads 0 on, exported as zeros
+for _op, _reason in (
+    ("join", "overflow"), ("distinct", "overflow"), ("agg", "gate"),
+    ("expand", "unpadded_edges"),
+):
+    _MESH_DECLINES.inc(0, op=_op, reason=_reason)
+
+_MESH_EXCHANGE_BYTES = _REGISTRY.counter(
+    "tpu_cypher_mesh_exchange_bytes_total",
+    "bytes the sharded tiers' exchanges hand to the mesh, reckoned from "
+    "their static capacities: all_to_all blocks that leave their chip, a "
+    "replicated build side once per chip, the operands of a psum once per "
+    "shard",
+    labels=("op",),
+)
+
+
+def note_decline(op: str, reason: str) -> None:
+    """Count one hand-back to the global path, on ``/metrics`` and on the
+    innermost open span."""
+    _MESH_DECLINES.inc(op=op, reason=reason)
+    _obs_trace.note("mesh_decline", f"{op}:{reason}")
+
+
+def note_exchange(op: str, nbytes: int) -> None:
+    _MESH_EXCHANGE_BYTES.inc(int(nbytes), op=op)
+    _obs_trace.note("exchange_bytes", int(nbytes))
 
 
 def shard_map(f, mesh, in_specs, out_specs):

@@ -41,14 +41,26 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..backend.tpu.bucketing import round_up_pow2
+from ..backend.tpu.bucketing import round_size, round_up_pow2
 from ..obs import trace as _obs_trace
 from ..obs.metrics import REGISTRY as _REGISTRY
-from .mesh import current_mesh, mesh_size, shard_map
+from .mesh import (
+    current_mesh,
+    mesh_size,
+    note_decline,
+    note_exchange,
+    shard_map,
+)
 
 _MESH_DISTINCT_TOTAL = _REGISTRY.counter(
     "tpu_cypher_mesh_distinct_total",
     "DISTINCT counts executed on the sharded hash-repartition tier",
+)
+_MESH_JOIN_TOTAL = _REGISTRY.counter(
+    "tpu_cypher_mesh_join_total",
+    "equi-joins executed on a sharded tier: broadcast (replicated build "
+    "side, local probe) or shuffle (hash repartition of both sides)",
+    labels=("tier",),
 )
 
 # Key namespace: real keys ship DOUBLED (even numbers — injective, equality
@@ -219,6 +231,60 @@ def _pad_sharded(arr_np: np.ndarray, nsh: int, fill, mesh, axis):
     return jax.device_put(arr_np, NamedSharding(mesh, P(axis)))
 
 
+def _no_pairs():
+    z = jnp.zeros(0, jnp.int64)
+    return z, z, 0
+
+
+def _compact_pairs(l_out, r_out, valid, total: int):
+    """The ``total`` live pairs of a materialize's per-shard blocks, moved
+    to the front of arrays of ``round_size(total)`` lanes: the same
+    tail-padded form (and the same lattice) as the one-device join's
+    ``join_materialize_counted``, so data of another size or seed reuses
+    the compiled compact and every gather after it. With bucketing off the
+    size is the total itself."""
+    from ..backend.tpu.jit_ops import mask_nonzero, tree_take
+
+    idx = mask_nonzero(valid, size=round_size(total))
+    l_rows, r_rows = tree_take((l_out, r_out), idx)
+    return l_rows, r_rows, total
+
+
+def _addressable(*arrays) -> bool:
+    """Multi-process meshes hold row-sharded GLOBAL arrays whose remote
+    shards this process cannot read: host staging would raise."""
+    return all(
+        a is None or getattr(a, "is_fully_addressable", True) for a in arrays
+    )
+
+
+def _to_host(site: str, arr, dtype=None) -> np.ndarray:
+    """One blocking device->host copy of a staged column, seen by
+    ``obs.trace.sync`` under ``site``."""
+    with _obs_trace.sync(site):
+        # tpulint: allow[host-sync] reason=the one staging read of the sharded tiers; every caller passes its own fault_point (shuffle / agg) before it stages a column
+        return np.asarray(arr, dtype=dtype)
+
+
+def _stage_join_sides(l_key, l_valid, r_key, r_valid):
+    """Host staging of both join sides: keys and their global row numbers
+    with the invalid rows dropped (null keys never match). Returns
+    ``(lk, lrow, rk, rrow)`` as NumPy arrays, or None where a key is too
+    large to double into the even namespace."""
+    with _obs_trace.span("mesh_join:stage", kind="mesh"):
+        sides = []
+        for key, valid in ((l_key, l_valid), (r_key, r_valid)):
+            k_np = _to_host("shuffle", key, np.int64)
+            row_np = np.arange(len(k_np), dtype=np.int64)
+            if valid is not None:
+                keep = _to_host("shuffle", valid)
+                k_np, row_np = k_np[keep], row_np[keep]
+            if np.abs(k_np).max(initial=0) >= _KEY_LIMIT:
+                return None  # doubling would overflow int64
+            sides += [k_np, row_np]
+        return tuple(sides)
+
+
 _BCAST_COUNT_CACHE: Dict[Any, Any] = {}
 _BCAST_MAT_CACHE: Dict[Any, Any] = {}
 
@@ -290,14 +356,15 @@ def _bcast_materialize_fn(mesh, axis, out_cap):
 
 def broadcast_join(
     l_key, l_valid, r_key, r_valid
-) -> Optional[Tuple[Any, Any]]:
+) -> Optional[Tuple[Any, Any, int]]:
     """Broadcast (replicated-build) equi-join over the active mesh: when
     the build (right) side is small, shuffling it through ``all_to_all`` is
     the wrong plan — replicate it to every device and probe the row-sharded
     left side LOCALLY, with NO collective in the join at all (the engines'
     broadcast join, delegated to Catalyst in the reference; SURVEY §2.3
-    "broadcast small relations"). Returns matching global row-index pairs,
-    or None when no mesh is active or the build side exceeds the cost
+    "broadcast small relations"). Returns matching global row-index pairs
+    and their number, as ``hash_repartition_join`` does, or None when no
+    mesh is active or the build side exceeds the cost
     model's broadcast window (``optimizer.cost.broadcast_build_limit`` —
     at least ``TPU_CYPHER_BROADCAST_LIMIT`` rows, default 4096, extended
     past it when replication still beats repartitioning both sides; a
@@ -321,64 +388,53 @@ def broadcast_join(
     from ..runtime.faults import fault_point
 
     fault_point("shuffle")
-    for arr in (l_key, l_valid, r_key, r_valid):
-        if arr is not None and not getattr(arr, "is_fully_addressable", True):
-            return None
+    if not _addressable(l_key, l_valid, r_key, r_valid):
+        return None  # hash_repartition_join declines next, and counts it
     axis = mesh.axis_names[0]
 
-    lk_np = np.asarray(l_key, dtype=np.int64)
-    rk_np = np.asarray(r_key, dtype=np.int64)
-    lrow_np = np.arange(n_l, dtype=np.int64)
-    rrow_np = np.arange(n_r, dtype=np.int64)
-    if l_valid is not None:
-        keep = np.asarray(l_valid)
-        lk_np, lrow_np = lk_np[keep], lrow_np[keep]
-    if r_valid is not None:
-        keep = np.asarray(r_valid)
-        rk_np, rrow_np = rk_np[keep], rrow_np[keep]
+    staged = _stage_join_sides(l_key, l_valid, r_key, r_valid)
+    if staged is None:
+        return None  # as above
+    lk_np, lrow_np, rk_np, rrow_np = staged
     if len(lk_np) == 0 or len(rk_np) == 0:
-        z = jnp.zeros(0, jnp.int64)
-        return z, z
-    if (
-        np.abs(lk_np).max(initial=0) >= _KEY_LIMIT
-        or np.abs(rk_np).max(initial=0) >= _KEY_LIMIT
-    ):
-        return None
-    lk = _pad_sharded(lk_np * 2, nsh, _L_PAD, mesh, axis)
-    lrow = _pad_sharded(lrow_np, nsh, 0, mesh, axis)
-    repl = NamedSharding(mesh, P(None))
-    rk = jax.device_put(rk_np * 2, repl)
-    rrow = jax.device_put(rrow_np, repl)
-
-    counts = _bcast_count_fn(mesh, axis)(lk, rk)
-    counts_np = np.asarray(counts)
+        return _no_pairs()
+    with _obs_trace.span("mesh_join:count", kind="mesh", tier="broadcast"):
+        lk = _pad_sharded(lk_np * 2, nsh, _L_PAD, mesh, axis)
+        lrow = _pad_sharded(lrow_np, nsh, 0, mesh, axis)
+        repl = NamedSharding(mesh, P(None))
+        rk = jax.device_put(rk_np * 2, repl)
+        rrow = jax.device_put(rrow_np, repl)
+        # the build side's keys and row numbers, once to every chip
+        note_exchange("broadcast_join", nsh * len(rk_np) * 16)
+        counts = _bcast_count_fn(mesh, axis)(lk, rk)
+        counts_np = _to_host("shuffle", counts)
+    _MESH_JOIN_TOTAL.inc(tier="broadcast")
+    _obs_trace.note("join_shards", nsh)
     out_cap = int(counts_np.max()) if counts_np.size else 0
     if out_cap == 0:
-        z = jnp.zeros(0, jnp.int64)
-        return z, z
+        return _no_pairs()
     # shared pow2 lattice (see hash_repartition_join): one compiled
     # broadcast-materialize per bucket instead of one per match count
     out_cap = round_up_pow2(out_cap, 16)
-    l_out, r_out, valid = _bcast_materialize_fn(mesh, axis, out_cap)(
-        lk, lrow, rk, rrow
-    )
-    from ..backend.tpu.jit_ops import mask_nonzero, tree_take
-
-    total = int(counts_np.sum())
-    # tpulint: allow[pad-invariant] reason=final exact compact of the broadcast-join result (callers take every returned row as live); the materialize capacity above is already on the pow2 lattice
-    idx = mask_nonzero(valid, size=total)
-    return tree_take((l_out, r_out), idx)
+    with _obs_trace.span("mesh_join:materialize", kind="mesh",
+                         tier="broadcast"):
+        l_out, r_out, valid = _bcast_materialize_fn(mesh, axis, out_cap)(
+            lk, lrow, rk, rrow
+        )
+        return _compact_pairs(l_out, r_out, valid, int(counts_np.sum()))
 
 
 def hash_repartition_join(
     l_key, l_valid, r_key, r_valid, cap_factor: float = 2.0
-) -> Optional[Tuple[Any, Any]]:
+) -> Optional[Tuple[Any, Any, int]]:
     """Inner equi-join row pairs over the active mesh via explicit hash
     shuffle. ``l_key``/``r_key``: int64 device arrays (element ids); valid
-    masks may be None. Returns (left_rows, right_rows) int64 arrays of
-    matching GLOBAL row indices (compacted), or None when no multi-device
-    mesh is active or a hash bucket overflows its static capacity — the
-    caller keeps the global sort-probe join."""
+    masks may be None. Returns (left_rows, right_rows, total): int64 arrays
+    of matching GLOBAL row indices, the ``total`` pairs first and the lanes
+    up to ``bucketing.round_size(total)`` pad (``_compact_pairs``), or None
+    when no multi-device mesh is active or a hash bucket overflows its
+    static capacity — the caller keeps the global sort-probe join, and the
+    decline is counted."""
     mesh = current_mesh()
     nsh = mesh_size()
     if mesh is None or nsh <= 1:
@@ -390,73 +446,67 @@ def hash_repartition_join(
     n_l, n_r = int(l_key.shape[0]), int(r_key.shape[0])
     if n_l == 0 or n_r == 0:
         return None  # trivial; the default join handles empties cheaply
-    for arr in (l_key, l_valid, r_key, r_valid):
-        # multi-process meshes hold row-sharded GLOBAL arrays whose remote
-        # shards this process cannot read — np.asarray staging would raise,
-        # so keep the default (GSPMD-partitioned) sort-probe join
-        if arr is not None and not getattr(arr, "is_fully_addressable", True):
-            return None
+    if not _addressable(l_key, l_valid, r_key, r_valid):
+        # np.asarray staging would raise, so keep the default
+        # (GSPMD-partitioned) sort-probe join
+        note_decline("join", "not_addressable")
+        return None
 
-    # host staging: drop invalid rows (null keys never match), double the
-    # keys into the even namespace, pad to shard multiples with odd pad
-    # sentinels. (join() depads its inputs, so the clean row sharding must
-    # be rebuilt anyway.)
-    lk_np = np.asarray(l_key, dtype=np.int64)
-    rk_np = np.asarray(r_key, dtype=np.int64)
-    lrow_np = np.arange(n_l, dtype=np.int64)
-    rrow_np = np.arange(n_r, dtype=np.int64)
-    if l_valid is not None:
-        keep = np.asarray(l_valid)
-        lk_np, lrow_np = lk_np[keep], lrow_np[keep]
-    if r_valid is not None:
-        keep = np.asarray(r_valid)
-        rk_np, rrow_np = rk_np[keep], rrow_np[keep]
+    # host staging: drop invalid rows, double the keys into the even
+    # namespace, pad to shard multiples with odd pad sentinels. (join()
+    # depads its inputs, so the clean row sharding must be rebuilt anyway.)
+    staged = _stage_join_sides(l_key, l_valid, r_key, r_valid)
+    if staged is None:
+        note_decline("join", "key_limit")
+        return None
+    lk_np, lrow_np, rk_np, rrow_np = staged
     if len(lk_np) == 0 or len(rk_np) == 0:
-        z = jnp.zeros(0, jnp.int64)
-        return z, z
-    if (
-        np.abs(lk_np).max(initial=0) >= _KEY_LIMIT
-        or np.abs(rk_np).max(initial=0) >= _KEY_LIMIT
-    ):
-        return None  # doubling would overflow int64
-    lk = _pad_sharded(lk_np * 2, nsh, _L_PAD, mesh, axis)
-    rk = _pad_sharded(rk_np * 2, nsh, _R_PAD, mesh, axis)
-    lrow = _pad_sharded(lrow_np, nsh, 0, mesh, axis)
-    rrow = _pad_sharded(rrow_np, nsh, 0, mesh, axis)
+        return _no_pairs()
+    with _obs_trace.span("mesh_join:count", kind="mesh", tier="shuffle"):
+        lk = _pad_sharded(lk_np * 2, nsh, _L_PAD, mesh, axis)
+        rk = _pad_sharded(rk_np * 2, nsh, _R_PAD, mesh, axis)
+        lrow = _pad_sharded(lrow_np, nsh, 0, mesh, axis)
+        rrow = _pad_sharded(rrow_np, nsh, 0, mesh, axis)
 
-    bl = int(lk.shape[0]) // nsh
-    br = int(rk.shape[0]) // nsh
-    # capacities snap to the SHARED power-of-two lattice
-    # (``bucketing.round_up_pow2`` — same helper as the shape buckets): the
-    # static cap is baked into the shard_map programs, so rounding makes
-    # nearby input sizes reuse one compiled exchange instead of compiling
-    # per size. Overflow detection keeps correctness; <=2x buffer slack.
-    cap_l = round_up_pow2(int(bl / nsh * cap_factor) + 16, 16)
-    cap_r = round_up_pow2(int(br / nsh * cap_factor) + 16, 16)
+        bl = int(lk.shape[0]) // nsh
+        br = int(rk.shape[0]) // nsh
+        # capacities snap to the SHARED power-of-two lattice
+        # (``bucketing.round_up_pow2`` — same helper as the shape buckets):
+        # the static cap is baked into the shard_map programs, so rounding
+        # makes nearby input sizes reuse one compiled exchange instead of
+        # compiling per size. Overflow detection keeps correctness; <=2x
+        # buffer slack.
+        cap_l = round_up_pow2(int(bl / nsh * cap_factor) + 16, 16)
+        cap_r = round_up_pow2(int(br / nsh * cap_factor) + 16, 16)
+        # what leaves each chip: nsh - 1 of its nsh blocks, per side; the
+        # count exchanges the keys, the materialize keys and row numbers
+        moved = nsh * (nsh - 1) * (cap_l + cap_r) * 8
 
-    counts, overflow = _count_fn(mesh, axis, nsh, cap_l, cap_r)(
-        lk, lrow, rk, rrow
-    )
-    counts_np = np.asarray(counts)
-    if bool(np.asarray(overflow).any()):
-        return None  # skewed keys: fall back to the global sort-probe join
+        counts, overflow = _count_fn(mesh, axis, nsh, cap_l, cap_r)(
+            lk, lrow, rk, rrow
+        )
+        note_exchange("shuffle_join", moved)
+        counts_np = _to_host("shuffle", counts)
+        overflowed = bool(_to_host("shuffle", overflow).any())
+    if overflowed:
+        # skewed keys: fall back to the global sort-probe join
+        note_decline("join", "overflow")
+        return None
+    _MESH_JOIN_TOTAL.inc(tier="shuffle")
+    _obs_trace.note("join_shards", nsh)
     out_cap = int(counts_np.max()) if counts_np.size else 0
     if out_cap == 0:
-        z = jnp.zeros(0, jnp.int64)
-        return z, z
+        return _no_pairs()
     # same lattice for the output capacity (slots past the true per-shard
     # total come out valid=False and are compacted away below)
     out_cap = round_up_pow2(out_cap, 16)
-    l_out, r_out, valid = _materialize_fn(
-        mesh, axis, nsh, cap_l, cap_r, out_cap
-    )(lk, lrow, rk, rrow)
-    from ..backend.tpu.jit_ops import mask_nonzero, tree_take
-
-    total = int(counts_np.sum())
-    # tpulint: allow[pad-invariant] reason=final exact compact of the shuffle-join result (callers take every returned row as live); the per-shard capacities above are already on the pow2 lattice
-    idx = mask_nonzero(valid, size=total)
-    l_rows, r_rows = tree_take((l_out, r_out), idx)
-    return l_rows, r_rows
+    with _obs_trace.span("mesh_join:materialize", kind="mesh",
+                         tier="shuffle"):
+        l_out, r_out, valid = _materialize_fn(
+            mesh, axis, nsh, cap_l, cap_r, out_cap
+        )(lk, lrow, rk, rrow)
+        note_exchange("shuffle_join", 2 * moved)
+        return _compact_pairs(l_out, r_out, valid, int(counts_np.sum()))
 
 
 # ---------------------------------------------------------------------------
@@ -543,26 +593,31 @@ def sharded_distinct_count(
     nsh = mesh_size()
     if mesh is None or nsh <= 1:
         return None
-    for arr in (keys, valid):
-        if arr is not None and not getattr(arr, "is_fully_addressable", True):
-            return None
+    if not _addressable(keys, valid):
+        note_decline("distinct", "not_addressable")
+        return None
     from ..runtime.faults import fault_point
 
     fault_point("shuffle")
     axis = mesh.axis_names[0]
-    k_np = np.asarray(keys, dtype=np.int64)
-    if valid is not None:
-        k_np = k_np[np.asarray(valid)]
+    with _obs_trace.span("mesh_distinct:stage", kind="mesh"):
+        k_np = _to_host("shuffle", keys, np.int64)
+        if valid is not None:
+            k_np = k_np[_to_host("shuffle", valid)]
     n = len(k_np)
     if n == 0:
         return 0
-    k = _pad_sharded(k_np, nsh, 0, mesh, axis)
-    live = _pad_sharded(np.ones(n, dtype=np.int64), nsh, 0, mesh, axis)
-    b = int(k.shape[0]) // nsh
-    cap = round_up_pow2(int(b / nsh * cap_factor) + 16, 16)
-    counts, overflow = _distinct_fn(mesh, axis, nsh, cap)(k, live)
-    if bool(np.asarray(overflow).any()):
-        return None
-    _MESH_DISTINCT_TOTAL.inc()
-    _obs_trace.note("distinct_shards", nsh)
-    return int(np.asarray(counts)[0])
+    with _obs_trace.span("mesh_distinct:count", kind="mesh"):
+        k = _pad_sharded(k_np, nsh, 0, mesh, axis)
+        live = _pad_sharded(np.ones(n, dtype=np.int64), nsh, 0, mesh, axis)
+        b = int(k.shape[0]) // nsh
+        cap = round_up_pow2(int(b / nsh * cap_factor) + 16, 16)
+        counts, overflow = _distinct_fn(mesh, axis, nsh, cap)(k, live)
+        # keys and their liveness lane: nsh - 1 of each chip's nsh blocks
+        note_exchange("distinct", nsh * (nsh - 1) * cap * 16)
+        if bool(_to_host("shuffle", overflow).any()):
+            note_decline("distinct", "overflow")
+            return None
+        _MESH_DISTINCT_TOTAL.inc()
+        _obs_trace.note("distinct_shards", nsh)
+        return int(_to_host("shuffle", counts)[0])
