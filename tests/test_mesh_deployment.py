@@ -47,6 +47,14 @@ TIER_OF = {
     "sort_probe_join": 'tpu_cypher_mesh_join_total{tier=shuffle}',
 }
 DECLINES = "tpu_cypher_mesh_declines_total"
+# the ungrouped count(*) of these shapes is answered by the operator under
+# it without its rows (``count_pushdowns.analytic`` reads 3 a pass here)
+PUSHDOWN = "tpu_cypher_count_pushdown_total"
+PUSHDOWN_OF = {
+    "scan_filter": "filter",
+    "distinct_values": "distinct",
+    "sort_probe_join": "join",
+}
 
 # every person's birthday lies in one 10**12 ms, so this key is the same
 # for all of them: every row of both sides goes to one shard's bucket
@@ -181,6 +189,13 @@ def test_shape_over_the_wire_on_its_sharded_tier(deployment, shape):
             or shape == "one_hop_count"  # a sum of degrees: nothing to exchange
     # every host read of the mesh path is one that obs.trace.sync counts
     assert _moved(before, after, "tpu_cypher_host_syncs_total") >= 1
+    asked = {
+        k[len(PUSHDOWN):]: v - before.get(k, 0.0)
+        for k, v in after.items()
+        if k.startswith(PUSHDOWN) and v != before.get(k, 0.0)
+    }
+    op = PUSHDOWN_OF.get(shape)
+    assert asked == ({"{op=%s,outcome=count}" % op: 1.0} if op else {})
 
 
 def test_overflowing_join_is_counted_and_still_right(deployment):
@@ -193,6 +208,8 @@ def test_overflowing_join_is_counted_and_still_right(deployment):
     ) == 1
     assert _moved(before, after, "tpu_cypher_mesh_join_total") == 0
     assert _moved(before, after, "tpu_cypher_fallbacks_total") == 0
+    # the one-device probe counts after the decline: still no rows
+    assert _moved(before, after, PUSHDOWN + "{op=join,outcome=count}") == 1
 
 
 def test_healthy_declines_are_exported_as_zeros():

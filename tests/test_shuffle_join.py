@@ -133,8 +133,8 @@ def test_engine_join_on_mesh_uses_shuffle(monkeypatch, bucket, q):
     cascade) routes the mesh join through hash_repartition_join and
     matches the oracle."""
     calls = {"n": 0}
-    orig = SH.hash_repartition_join
-    orig_b = SH.broadcast_join
+    orig = SH.hash_repartition_join_count
+    orig_b = SH.broadcast_join_count
 
     def spy(*a, **k):
         out = orig(*a, **k)
@@ -148,8 +148,8 @@ def test_engine_join_on_mesh_uses_shuffle(monkeypatch, bucket, q):
             calls["n"] += 1
         return out
 
-    monkeypatch.setattr(SH, "hash_repartition_join", spy)
-    monkeypatch.setattr(SH, "broadcast_join", spy_b)
+    monkeypatch.setattr(SH, "hash_repartition_join_count", spy)
+    monkeypatch.setattr(SH, "broadcast_join_count", spy_b)
 
     rng = np.random.default_rng(5)
     n, e = 120, 400
@@ -267,7 +267,7 @@ def test_optional_match_rides_mesh_join(monkeypatch):
     tiers: match pairs from broadcast/shuffle, unmatched-row padding on
     top."""
     calls = {"bcast": 0, "shuffle": 0}
-    orig_b, orig_s = SH.broadcast_join, SH.hash_repartition_join
+    orig_b, orig_s = SH.broadcast_join_count, SH.hash_repartition_join_count
 
     def spy_b(*a, **k):
         out = orig_b(*a, **k)
@@ -281,8 +281,8 @@ def test_optional_match_rides_mesh_join(monkeypatch):
             calls["shuffle"] += 1
         return out
 
-    monkeypatch.setattr(SH, "broadcast_join", spy_b)
-    monkeypatch.setattr(SH, "hash_repartition_join", spy_s)
+    monkeypatch.setattr(SH, "broadcast_join_count", spy_b)
+    monkeypatch.setattr(SH, "hash_repartition_join_count", spy_s)
 
     create = (
         "CREATE (a:P {v: 1})-[:K]->(:Q {w: 10}), (:P {v: 2}), "
@@ -310,7 +310,7 @@ def test_composite_key_join_rides_mesh(monkeypatch):
     """Multi-column join keys pack into ONE mixed key for the mesh tiers;
     every key column is post-verified (hash-collision screen)."""
     calls = {"n": 0}
-    orig_b = SH.broadcast_join
+    orig_b = SH.broadcast_join_count
 
     def spy_b(*a, **k):
         out = orig_b(*a, **k)
@@ -318,7 +318,7 @@ def test_composite_key_join_rides_mesh(monkeypatch):
             calls["n"] += 1
         return out
 
-    monkeypatch.setattr(SH, "broadcast_join", spy_b)
+    monkeypatch.setattr(SH, "broadcast_join_count", spy_b)
     create = (
         "CREATE (:L {a: 1, b: 1, s: 'x'}), (:L {a: 1, b: 2, s: 'y'}), "
         "(:L {a: 2, b: 1, s: 'z'}), (:R {a: 1, b: 1, t: 'p'}), "

@@ -70,6 +70,23 @@ class Table(ABC):
         (count-over-distinct aggregate pushdown)."""
         return None
 
+    def filter_count(self, expr, header, parameters) -> Optional[int]:
+        """``filter(expr, header, parameters).size`` without the filtered
+        rows, or None when this backend has to build them to know
+        (count-over-filter aggregate pushdown)."""
+        return None
+
+    def join_count(
+        self,
+        other: "Table",
+        kind: JoinType,
+        join_cols: Sequence[Tuple[str, str]],
+    ) -> Optional[int]:
+        """``join(other, kind, join_cols).size`` without the joined rows,
+        or None when the number is not exact before the pairs are built
+        (count-over-join aggregate pushdown)."""
+        return None
+
     # -- algebra ----------------------------------------------------------
 
     @abstractmethod

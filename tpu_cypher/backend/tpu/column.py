@@ -749,14 +749,10 @@ def mask_to_idx_bucketed(mask) -> Tuple[Any, int]:
     lanes hold index 0 (duplicates of a real row) — consumers mark lanes at
     or past ``count`` invalid (``jit_ops.cols_take_counted``), keeping the
     tail-pad invariant. One scalar sync, same as the exact form."""
-    from ...runtime.faults import fault_point
     from .bucketing import round_size
-    from .jit_ops import mask_nonzero, mask_sum
+    from .jit_ops import mask_count, mask_nonzero
 
-    fault_point("compact")
-    n_dev = mask_sum(mask)
-    with _obs_trace.sync("compact"):
-        count = int(n_dev)
+    count = mask_count(mask)
     return mask_nonzero(mask, size=round_size(count)), count
 
 

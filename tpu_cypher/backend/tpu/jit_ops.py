@@ -116,14 +116,22 @@ def mask_nonzero(mask, size: int):
     return jnp.nonzero(mask, size=size)[0]
 
 
-def mask_to_idx(mask) -> Tuple[Any, int]:
-    """Boolean device mask -> (index array, count); one scalar sync."""
+def mask_count(mask) -> int:
+    """True lanes of a boolean device mask, on the host: one dispatch and
+    ONE scalar sync (site ``compact``). The first phase of every
+    compaction, and all of a pushed-down ``count(*)``
+    (``TpuTable.filter_count``)."""
     from ...runtime.faults import fault_point
 
     fault_point("compact")
     n_dev = mask_sum(mask)
     with _obs_trace.sync("compact"):
-        count = int(n_dev)
+        return int(n_dev)
+
+
+def mask_to_idx(mask) -> Tuple[Any, int]:
+    """Boolean device mask -> (index array, count); one scalar sync."""
+    count = mask_count(mask)
     # tpulint: allow[pad-invariant] reason=the exact-compact primitive itself; bucketed callers go through mask_to_idx_bucketed, and the ladder's bucket-exact rung NEEDS the unrounded size
     return mask_nonzero(mask, size=count), count
 

@@ -35,7 +35,18 @@ backend per expression, keeping full Cypher semantics."""
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    Iterator,
+    List,
+    Mapping,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -201,6 +212,22 @@ def ensure_flat(t):
     so an over-budget decompress surfaces as ``AdmissionRejected``."""
     to_flat = getattr(t, "to_flat_table", None)
     return to_flat() if to_flat is not None else t
+
+
+class _JoinProbe(NamedTuple):
+    """The device equi-join after its count phase (``TpuTable._join_probe``):
+    ``total`` match pairs — exact unless ``packed_all_keys`` (a composite
+    key packed by ``combine_keys``: hash collisions are screened on the
+    pairs); ``pairs()`` materializes ``(left_rows, right_rows)``, tail-padded
+    past ``total`` where the lattice rounds."""
+
+    total: int
+    pairs: Callable[[], Tuple[Any, Any]]
+    packed_all_keys: bool
+
+
+# key kinds whose one-column probe total needs no look at the pairs
+_EXACT_PROBE_KINDS = (I64, BOOL, DATE, LDT)
 
 
 class TpuTable(Table):
@@ -458,14 +485,44 @@ class TpuTable(Table):
         t = self._depad()
         if t is not self:
             return t.filter(expr, header, parameters)
-        try:
-            c = TpuEvaluator(self, header, parameters).eval(expr)
-        except TpuUnsupportedExpr:
-            return self._from_local(self._to_local('filter:expr').filter(expr, header, parameters))
-        if c.kind == OBJ:
-            return self._from_local(self._to_local('filter:obj-mask').filter(expr, header, parameters))
-        idx, _ = self._mask_to_idx(J.and_valid_mask(c.data, c.valid))
+        keep = self._filter_keep(expr, header, parameters)
+        if isinstance(keep, str):
+            return self._filter_local(keep, expr, header, parameters)
+        idx, _ = self._mask_to_idx(keep)
         return self._take(idx)
+
+    def _filter_local(self, reason: str, expr, header, parameters) -> "TpuTable":
+        """The local oracle's filter, counted as a fallback under ``reason``."""
+        return self._from_local(
+            self._to_local(reason).filter(expr, header, parameters)
+        )
+
+    def _filter_keep(self, expr, header, parameters):
+        """The rows a filter keeps, as a device mask over the PHYSICAL rows
+        (predicate AND its validity; under bucketing AND the row tail, so a
+        pad row is never kept) — or, where the predicate has no device
+        form, the reason as the fallback counter names it (a ``str``).
+        Shared by the rows paths (``filter`` / ``_filter_bucketed``) and
+        ``filter_count``."""
+        try:
+            ev = TpuEvaluator(self, header, parameters)
+            if bucketing.enabled():
+                ev.n = self._phys
+            c = ev.eval(expr)
+        except TpuUnsupportedExpr:
+            return 'filter:expr'
+        if c.kind == OBJ:
+            return 'filter:obj-mask'
+        if bucketing.enabled():
+            return J.filter_keep_mask(c.data, c.valid, self._nrows)
+        return J.and_valid_mask(c.data, c.valid)
+
+    def _obj_under_pad(self) -> bool:
+        """OBJ columns are host arrays of logical length: a padded table
+        carrying one cannot evaluate over its physical rows."""
+        return self._phys > self._nrows and any(
+            col.kind == OBJ for col in self._cols.values()
+        )
 
     def _filter_bucketed(self, expr, header, parameters) -> "TpuTable":
         """Pad-aware filter: the predicate evaluates over the PHYSICAL
@@ -475,27 +532,33 @@ class TpuTable(Table):
         them), and the survivor set compacts to a BUCKETED size. OBJ
         columns are host arrays of logical length, so a table carrying one
         takes the exact (depadded) path instead."""
-        phys = self._phys
-        if phys > self._nrows and any(
-            col.kind == OBJ for col in self._cols.values()
-        ):
+        if self._obj_under_pad():
             t = self._depad()
             return TpuTable.filter(t, expr, header, parameters)
-        try:
-            ev = TpuEvaluator(self, header, parameters)
-            ev.n = phys
-            c = ev.eval(expr)
-        except TpuUnsupportedExpr:
-            return self._from_local(
-                self._to_local('filter:expr').filter(expr, header, parameters)
-            )
-        if c.kind == OBJ:
-            return self._from_local(
-                self._to_local('filter:obj-mask').filter(expr, header, parameters)
-            )
-        keep = J.filter_keep_mask(c.data, c.valid, self._nrows)
+        keep = self._filter_keep(expr, header, parameters)
+        if isinstance(keep, str):
+            return self._filter_local(keep, expr, header, parameters)
         idx, count = mask_to_idx_bucketed(keep)
         return self._take_counted(idx, count)
+
+    def filter_count(self, expr, header, parameters) -> Optional[int]:
+        """Rows ``filter`` would keep, from the keep mask alone: the same
+        predicate, validity and row tail, one ``mask_sum`` and its one host
+        read — no compaction and no gather of the kept rows. None where
+        ``filter`` leaves the device (unsupported expression, OBJ mask, OBJ
+        columns under a padded table): the rows path answers unchanged."""
+        if bucketing.enabled():
+            if self._obj_under_pad():
+                return None
+        else:
+            t = self._depad()
+            if t is not self:
+                return t.filter_count(expr, header, parameters)
+        fault_point("filter")
+        keep = self._filter_keep(expr, header, parameters)
+        if isinstance(keep, str):
+            return None
+        return J.mask_count(keep)
 
     # -- join --------------------------------------------------------------
 
@@ -551,21 +614,35 @@ class TpuTable(Table):
             return self._from_local(lt)
         return self._join_device(other, kind, join_cols)
 
-    def _join_device(self, other, kind, join_cols) -> "TpuTable":
-        """Device sort-probe equi-join (the TPU analog of the engines'
-        shuffled hash join, ``SparkTable.scala:178``): the build (right) side
-        is lexsorted valid-first-by-key once, the probe side binary-searches
-        it; matches materialize via fixed-size repeat+gather. Multi-key joins
-        probe on the first key and post-filter the rest on device."""
+    def join_count(self, other, kind, join_cols) -> Optional[int]:
+        """Rows of an inner equi-join on ONE key column, from the join's
+        count phase (``_join_probe``): the sharded tiers stop after their
+        first exchange, the one-device join after its probe — no pairs, no
+        gathers, no admission for rows that are not built. None wherever
+        the number is not exact at that point or the keys leave the device
+        path: outer kinds, composite keys (packed keys need the pairs
+        post-verified), string, float, mixed and OBJ keys."""
+        if kind != "inner" or len(join_cols) != 1:
+            return None
+        other = ensure_flat(other)
+        lk, rk = self._cols[join_cols[0][0]], other._cols[join_cols[0][1]]
+        if lk.kind != rk.kind or lk.kind not in _EXACT_PROBE_KINDS:
+            return None
+        if not bucketing.enabled():
+            t, o = self._depad(), other._depad()
+            if t is not self or o is not other:
+                return t.join_count(o, kind, join_cols)
         fault_point("join")
-        # padded per-output-row cost of the match-pair arrays + the
-        # gathered output columns (8B data + 1B mask per column, 2 int64
-        # index lanes) — the admission estimate for every join materialize
-        join_row_bytes = 16 + 9 * max(len(self._cols) + len(other._cols), 1)
+        return self._join_probe(other, kind, join_cols).total
+
+    def _join_probe(self, other, kind, join_cols) -> Optional["_JoinProbe"]:
+        """Key preparation and count phase of the device equi-join, shared
+        by ``_join_device`` (which goes on to the pairs) and ``join_count``
+        (which stops here). None where the key kinds can never be equal."""
         lk, rk = self._cols[join_cols[0][0]], other._cols[join_cols[0][1]]
         if lk.kind == STR or rk.kind == STR:
             if lk.kind != STR or rk.kind != STR:
-                return self._join_empty_result(other, kind)
+                return None
             from .column import _unify_vocab
 
             lk, rk = _unify_vocab(lk, rk)
@@ -580,7 +657,7 @@ class TpuTable(Table):
                 else:
                     rk = _float_as_exact_int(rk)
             else:  # cross-kind keys never match
-                return self._join_empty_result(other, kind)
+                return None
         # validity masks beyond the probe key's own (extra key columns must
         # be non-null to match) — folded on device inside the jitted phases
         l_extra_valid = tuple(
@@ -604,9 +681,6 @@ class TpuTable(Table):
                 lvalids = lvalids + (J.row_tail_mask(lk.data, self._nrows),)
             if int(rk.data.shape[0]) > other._nrows:
                 rvalids = rvalids + (J.row_tail_mask(rk.data, other._nrows),)
-        left_rows = right_rows = None
-        match_bucketed = False  # match-pair arrays padded past ``total``
-        packed_all_keys = False
         if (
             kind in ("inner", "left_outer", "full_outer")
             and lk.kind == I64
@@ -621,14 +695,15 @@ class TpuTable(Table):
             # match pairs: the unmatched-row padding downstream is
             # tier-independent.
             from ...parallel.shuffle import (
-                broadcast_join,
+                broadcast_join_count,
                 combine_keys,
-                hash_repartition_join,
+                hash_repartition_join_count,
             )
 
             lv = _fold_valids(lvalids)
             rv = _fold_valids(rvalids)
             lkd, rkd = lk.data, rk.data
+            packed_all_keys = False
             if len(join_cols) > 1 and all(
                 self._cols[l].kind == I64 and other._cols[r].kind == I64
                 for l, r in join_cols[1:]
@@ -644,15 +719,13 @@ class TpuTable(Table):
                     (rkd,) + tuple(other._cols[r].data for _, r in join_cols[1:])
                 )
                 packed_all_keys = True
-            got = broadcast_join(lkd, lv, rkd, rv)
-            if got is None:
-                got = hash_repartition_join(lkd, lv, rkd, rv)
-            if got is not None:
-                left_rows, right_rows, total = got
-                match_bucketed = int(left_rows.shape[0]) != total
-                bucketing.admit(total, join_row_bytes, "join")
-            else:
-                packed_all_keys = False
+            counted = broadcast_join_count(lkd, lv, rkd, rv)
+            if counted is None:
+                counted = hash_repartition_join_count(lkd, lv, rkd, rv)
+            if counted is not None:
+                return _JoinProbe(
+                    counted.total, lambda: counted.pairs()[:2], packed_all_keys
+                )
         elif _mesh_size() > 1:
             # the sharded tiers join inner and left/full outer shapes on
             # 64-bit integer keys only
@@ -660,40 +733,65 @@ class TpuTable(Table):
                 "join",
                 "key_kind" if lk.kind != I64 or rk.kind != I64 else "join_kind",
             )
-        if left_rows is None:
-            is_f64 = lk.kind == F64
-            is_bool = lk.kind == BOOL
-            # phase 1: build side sorted valid-first (one jitted dispatch,
-            # one scalar sync for the valid count)
-            rd_s, r_order, nvalid_dev = J.join_build(rk.data, rvalids, is_f64=is_f64, is_bool=is_bool)
-            nvalid = int(nvalid_dev)
-            if bucketed:
-                # phases 2+3 at BUCKETED static sizes: the valid count and
-                # the match total ride as traced operands, so any inputs
-                # whose counts share buckets reuse these compiled programs
-                cap = min(
-                    bucketing.round_size(nvalid), int(r_order.shape[0])
-                )
-                r_idx_valid, lo, counts, total_dev = J.join_probe_bucketed(
-                    rd_s, r_order, lk.data, lvalids, nvalid_dev,
-                    nvalid_cap=cap, is_f64=is_f64, is_bool=is_bool,
-                )
-                total = int(total_dev)
-                bucketing.admit(total, join_row_bytes, "join")
-                size = bucketing.round_size(total)
-                left_rows, right_rows, _ = J.join_materialize_counted(
-                    r_idx_valid, lo, counts, total_dev, size=size
-                )
-                match_bucketed = size != total
-            else:
-                # phase 2: probe by binary search (one dispatch, one sync)
-                r_idx_valid, lo, counts, total_dev = J.join_probe(
-                    rd_s, r_order, lk.data, lvalids, nvalid=nvalid, is_f64=is_f64, is_bool=is_bool
-                )
-                total = int(total_dev)
-                bucketing.admit(total, join_row_bytes, "join")
-                # phase 3: materialize match pairs (one dispatch, static total)
-                left_rows, right_rows = J.join_materialize(r_idx_valid, lo, counts, total=total)
+        is_f64 = lk.kind == F64
+        is_bool = lk.kind == BOOL
+        # phase 1: build side sorted valid-first (one jitted dispatch,
+        # one scalar sync for the valid count)
+        rd_s, r_order, nvalid_dev = J.join_build(rk.data, rvalids, is_f64=is_f64, is_bool=is_bool)
+        nvalid = int(nvalid_dev)
+        if bucketed:
+            # phases 2+3 at BUCKETED static sizes: the valid count and
+            # the match total ride as traced operands, so any inputs
+            # whose counts share buckets reuse these compiled programs
+            cap = min(
+                bucketing.round_size(nvalid), int(r_order.shape[0])
+            )
+            r_idx_valid, lo, counts, total_dev = J.join_probe_bucketed(
+                rd_s, r_order, lk.data, lvalids, nvalid_dev,
+                nvalid_cap=cap, is_f64=is_f64, is_bool=is_bool,
+            )
+            total = int(total_dev)
+            return _JoinProbe(
+                total,
+                lambda: J.join_materialize_counted(
+                    r_idx_valid, lo, counts, total_dev,
+                    size=bucketing.round_size(total),
+                )[:2],
+                False,
+            )
+        # phase 2: probe by binary search (one dispatch, one sync)
+        r_idx_valid, lo, counts, total_dev = J.join_probe(
+            rd_s, r_order, lk.data, lvalids, nvalid=nvalid, is_f64=is_f64, is_bool=is_bool
+        )
+        total = int(total_dev)
+        # phase 3: materialize match pairs (one dispatch, static total)
+        return _JoinProbe(
+            total,
+            lambda: J.join_materialize(r_idx_valid, lo, counts, total=total),
+            False,
+        )
+
+    def _join_device(self, other, kind, join_cols) -> "TpuTable":
+        """Device sort-probe equi-join (the TPU analog of the engines'
+        shuffled hash join, ``SparkTable.scala:178``): the build (right) side
+        is lexsorted valid-first-by-key once, the probe side binary-searches
+        it (``_join_probe``: the count phase, on the mesh a sharded tier's);
+        matches materialize via fixed-size repeat+gather. Multi-key joins
+        probe on the first key and post-filter the rest on device."""
+        fault_point("join")
+        probe = self._join_probe(other, kind, join_cols)
+        if probe is None:
+            return self._join_empty_result(other, kind)
+        total, packed_all_keys = probe.total, probe.packed_all_keys
+        bucketed = bucketing.enabled()
+        # padded per-output-row cost of the match-pair arrays + the
+        # gathered output columns (8B data + 1B mask per column, 2 int64
+        # index lanes) — the admission estimate for every join materialize
+        join_row_bytes = 16 + 9 * max(len(self._cols) + len(other._cols), 1)
+        bucketing.admit(total, join_row_bytes, "join")
+        left_rows, right_rows = probe.pairs()
+        # match-pair arrays padded past ``total``
+        match_bucketed = int(left_rows.shape[0]) != total
         # packed-key matches verify EVERY key column (hash collisions);
         # otherwise the probe key matched exactly and only extras need it
         post_cols = join_cols if packed_all_keys else join_cols[1:]

@@ -32,7 +32,7 @@ pod — only the device list changes."""
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -236,6 +236,19 @@ def _no_pairs():
     return z, z, 0
 
 
+class CountedJoin(NamedTuple):
+    """A sharded join after its count phase: ``total`` is the exact number
+    of matching pairs (on the host already), ``pairs()`` goes on to the
+    materialize and returns ``(left_rows, right_rows, total)``. A
+    ``count(*)`` over the join stops at ``total``."""
+
+    total: int
+    pairs: Callable[[], Tuple[Any, Any, int]]
+
+
+_NO_PAIRS = CountedJoin(0, _no_pairs)
+
+
 def _compact_pairs(l_out, r_out, valid, total: int):
     """The ``total`` live pairs of a materialize's per-shard blocks, moved
     to the front of arrays of ``round_size(total)`` lanes: the same
@@ -354,21 +367,22 @@ def _bcast_materialize_fn(mesh, axis, out_cap):
     return fn
 
 
-def broadcast_join(
+def broadcast_join_count(
     l_key, l_valid, r_key, r_valid
-) -> Optional[Tuple[Any, Any, int]]:
-    """Broadcast (replicated-build) equi-join over the active mesh: when
-    the build (right) side is small, shuffling it through ``all_to_all`` is
-    the wrong plan — replicate it to every device and probe the row-sharded
-    left side LOCALLY, with NO collective in the join at all (the engines'
-    broadcast join, delegated to Catalyst in the reference; SURVEY §2.3
-    "broadcast small relations"). Returns matching global row-index pairs
-    and their number, as ``hash_repartition_join`` does, or None when no
-    mesh is active or the build side exceeds the cost
-    model's broadcast window (``optimizer.cost.broadcast_build_limit`` —
-    at least ``TPU_CYPHER_BROADCAST_LIMIT`` rows, default 4096, extended
-    past it when replication still beats repartitioning both sides; a
-    pinned env knob is honoured verbatim)."""
+) -> Optional[CountedJoin]:
+    """Count phase of the broadcast (replicated-build) equi-join over the
+    active mesh: when the build (right) side is small, shuffling it
+    through ``all_to_all`` is the wrong plan — replicate it to every device
+    and probe the row-sharded left side LOCALLY, with NO collective in the
+    join at all (the engines' broadcast join, delegated to Catalyst in the
+    reference; SURVEY §2.3 "broadcast small relations"). Returns the
+    ``CountedJoin`` — the pairs' number, and the materialize still to run
+    for a caller that wants the pairs — or None when no mesh is active or
+    the build side exceeds the cost model's broadcast window
+    (``optimizer.cost.broadcast_build_limit`` — at least
+    ``TPU_CYPHER_BROADCAST_LIMIT`` rows, default 4096, extended past it
+    when replication still beats repartitioning both sides; a pinned env
+    knob is honoured verbatim)."""
     mesh = current_mesh()
     nsh = mesh_size()
     if mesh is None or nsh <= 1:
@@ -389,7 +403,7 @@ def broadcast_join(
 
     fault_point("shuffle")
     if not _addressable(l_key, l_valid, r_key, r_valid):
-        return None  # hash_repartition_join declines next, and counts it
+        return None  # hash_repartition_join_count declines next, and counts it
     axis = mesh.axis_names[0]
 
     staged = _stage_join_sides(l_key, l_valid, r_key, r_valid)
@@ -397,7 +411,7 @@ def broadcast_join(
         return None  # as above
     lk_np, lrow_np, rk_np, rrow_np = staged
     if len(lk_np) == 0 or len(rk_np) == 0:
-        return _no_pairs()
+        return _NO_PAIRS
     with _obs_trace.span("mesh_join:count", kind="mesh", tier="broadcast"):
         lk = _pad_sharded(lk_np * 2, nsh, _L_PAD, mesh, axis)
         lrow = _pad_sharded(lrow_np, nsh, 0, mesh, axis)
@@ -412,29 +426,45 @@ def broadcast_join(
     _obs_trace.note("join_shards", nsh)
     out_cap = int(counts_np.max()) if counts_np.size else 0
     if out_cap == 0:
-        return _no_pairs()
-    # shared pow2 lattice (see hash_repartition_join): one compiled
+        return _NO_PAIRS
+    # shared pow2 lattice (see hash_repartition_join_count): one compiled
     # broadcast-materialize per bucket instead of one per match count
     out_cap = round_up_pow2(out_cap, 16)
-    with _obs_trace.span("mesh_join:materialize", kind="mesh",
-                         tier="broadcast"):
-        l_out, r_out, valid = _bcast_materialize_fn(mesh, axis, out_cap)(
-            lk, lrow, rk, rrow
-        )
-        return _compact_pairs(l_out, r_out, valid, int(counts_np.sum()))
+    total = int(counts_np.sum())
+
+    def pairs():
+        with _obs_trace.span("mesh_join:materialize", kind="mesh",
+                             tier="broadcast"):
+            l_out, r_out, valid = _bcast_materialize_fn(mesh, axis, out_cap)(
+                lk, lrow, rk, rrow
+            )
+            return _compact_pairs(l_out, r_out, valid, total)
+
+    return CountedJoin(total, pairs)
 
 
-def hash_repartition_join(
-    l_key, l_valid, r_key, r_valid, cap_factor: float = 2.0
+def broadcast_join(
+    l_key, l_valid, r_key, r_valid
 ) -> Optional[Tuple[Any, Any, int]]:
-    """Inner equi-join row pairs over the active mesh via explicit hash
-    shuffle. ``l_key``/``r_key``: int64 device arrays (element ids); valid
-    masks may be None. Returns (left_rows, right_rows, total): int64 arrays
-    of matching GLOBAL row indices, the ``total`` pairs first and the lanes
-    up to ``bucketing.round_size(total)`` pad (``_compact_pairs``), or None
-    when no multi-device mesh is active or a hash bucket overflows its
-    static capacity — the caller keeps the global sort-probe join, and the
-    decline is counted."""
+    """``broadcast_join_count`` and then its materialize: the matching
+    global row-index pairs and their number, as ``hash_repartition_join``
+    returns them, or None where the count phase declines."""
+    counted = broadcast_join_count(l_key, l_valid, r_key, r_valid)
+    return None if counted is None else counted.pairs()
+
+
+def hash_repartition_join_count(
+    l_key, l_valid, r_key, r_valid, cap_factor: float = 2.0
+) -> Optional[CountedJoin]:
+    """Count phase of the inner equi-join over the active mesh via explicit
+    hash shuffle: ONE exchange of the keys, a local probe per shard, the
+    per-shard pair counts read to the host. ``l_key``/``r_key``: int64
+    device arrays (element ids); valid masks may be None. Returns the
+    ``CountedJoin`` — the exact number of pairs, and the materialize (a
+    second exchange of keys and row numbers, then ``_compact_pairs``) still
+    to run for a caller that wants the pairs — or None when no multi-device
+    mesh is active or a hash bucket overflows its static capacity: the
+    caller keeps the global sort-probe join, and the decline is counted."""
     mesh = current_mesh()
     nsh = mesh_size()
     if mesh is None or nsh <= 1:
@@ -461,7 +491,7 @@ def hash_repartition_join(
         return None
     lk_np, lrow_np, rk_np, rrow_np = staged
     if len(lk_np) == 0 or len(rk_np) == 0:
-        return _no_pairs()
+        return _NO_PAIRS
     with _obs_trace.span("mesh_join:count", kind="mesh", tier="shuffle"):
         lk = _pad_sharded(lk_np * 2, nsh, _L_PAD, mesh, axis)
         rk = _pad_sharded(rk_np * 2, nsh, _R_PAD, mesh, axis)
@@ -496,17 +526,36 @@ def hash_repartition_join(
     _obs_trace.note("join_shards", nsh)
     out_cap = int(counts_np.max()) if counts_np.size else 0
     if out_cap == 0:
-        return _no_pairs()
+        return _NO_PAIRS
     # same lattice for the output capacity (slots past the true per-shard
     # total come out valid=False and are compacted away below)
     out_cap = round_up_pow2(out_cap, 16)
-    with _obs_trace.span("mesh_join:materialize", kind="mesh",
-                         tier="shuffle"):
-        l_out, r_out, valid = _materialize_fn(
-            mesh, axis, nsh, cap_l, cap_r, out_cap
-        )(lk, lrow, rk, rrow)
-        note_exchange("shuffle_join", 2 * moved)
-        return _compact_pairs(l_out, r_out, valid, int(counts_np.sum()))
+    total = int(counts_np.sum())
+
+    def pairs():
+        with _obs_trace.span("mesh_join:materialize", kind="mesh",
+                             tier="shuffle"):
+            l_out, r_out, valid = _materialize_fn(
+                mesh, axis, nsh, cap_l, cap_r, out_cap
+            )(lk, lrow, rk, rrow)
+            note_exchange("shuffle_join", 2 * moved)
+            return _compact_pairs(l_out, r_out, valid, total)
+
+    return CountedJoin(total, pairs)
+
+
+def hash_repartition_join(
+    l_key, l_valid, r_key, r_valid, cap_factor: float = 2.0
+) -> Optional[Tuple[Any, Any, int]]:
+    """``hash_repartition_join_count`` and then its materialize. Returns
+    (left_rows, right_rows, total): int64 arrays of matching GLOBAL row
+    indices, the ``total`` pairs first and the lanes up to
+    ``bucketing.round_size(total)`` pad (``_compact_pairs``), or None where
+    the count phase declines."""
+    counted = hash_repartition_join_count(
+        l_key, l_valid, r_key, r_valid, cap_factor
+    )
+    return None if counted is None else counted.pairs()
 
 
 # ---------------------------------------------------------------------------

@@ -20,11 +20,23 @@ from ..api import types as T
 from ..api.table import Table
 from ..ir import expr as E
 from ..obs import trace as _obs_trace
+from ..obs.metrics import REGISTRY as _OBS_REGISTRY
 from .header import RecordHeader
 
 
 class RelationalError(Exception):
     pass
+
+
+# how often an ungrouped count(*) got its number from the operator under it
+# (``count``) and how often that operator had to build its rows after all
+# (``rows``); ``AggregateOp`` is the one place that counts
+COUNT_PUSHDOWN = _OBS_REGISTRY.counter(
+    "tpu_cypher_count_pushdown_total",
+    "ungrouped count(*) over a filter, an inner join or a DISTINCT: "
+    "answered from the operator's count phase (count) or from its rows (rows)",
+    labels=("op", "outcome"),
+)
 
 
 @dataclass
@@ -286,6 +298,15 @@ class FilterOp(RelationalOperator):
         in_op = self.children[0]
         return in_op.table.filter(self.predicate, in_op.header, self.context.parameters)
 
+    def row_count(self) -> Optional[int]:
+        """Rows the filter keeps, without them (``Table.filter_count``: the
+        mask's sum); None where the backend must build them to know. The
+        input of the filter is built as ever."""
+        in_op = self.children[0]
+        return in_op.table.filter_count(
+            self.predicate, in_op.header, self.context.parameters
+        )
+
     def _show_inner(self) -> str:
         return self.predicate.pretty_expr()
 
@@ -380,50 +401,78 @@ class AggregateOp(RelationalOperator):
             out_col = self.header.column(E.Var(name))
             aggs.append((out_col, agg))
         # plain count(*) and nothing else: only the NUMBER of input rows
-        # is read, so two inputs can give it without their rows
+        # is read, so the operator below is asked for that number first
         count_star_only = not by and all(
             getattr(agg, "expr", None) is None and not getattr(agg, "distinct", False)
             for _, agg in self.aggregations
         )
-        # count-over-distinct pushdown: WITH DISTINCT a, b ... RETURN
-        # count(*) never materializes the deduped rows — the count is the
-        # number of first-occurrence groups (the engines get the same from
-        # their optimizers' aggregate pushdown)
-        if count_star_only and isinstance(in_op, DistinctOp):
-            # deepest pushdown first: a fused expand chain can count its
-            # DISTINCT endpoints without materializing ANY row set (the
-            # backend op advertises `distinct_endpoints_count`). Column
-            # projections keep the row multiset, so peel SelectOps as long
-            # as the distinct fields survive them.
-            inner = in_op.children[0]
-            while (
-                isinstance(inner, SelectOp)
-                and set(in_op.fields) <= set(inner.fields)
-            ) or isinstance(inner, CacheOp):
-                inner = inner.children[0]
-            fused = getattr(inner, "distinct_endpoints_count", None)
-            if fused is not None:
-                n = fused(in_op.fields)
-                if n is not None:
-                    cols = {out_col: [n] for out_col, _ in aggs}
-                    return self.context.table_cls.from_columns(cols)
-            src = in_op.children[0].table
-            n = src.distinct_count(in_op.distinct_columns())
+        if count_star_only:
+            n = self._input_row_count()
             if n is not None:
                 cols = {out_col: [n] for out_col, _ in aggs}
-                return type(src).from_columns(cols)
-        if count_star_only:
-            # count-over-join pushdown: the pairs are counted from the key
-            # columns alone (``JoinOp.row_count``)
-            inner = in_op
-            while isinstance(inner, (SelectOp, CacheOp)):
-                inner = inner.children[0]  # projections keep the multiset
-            if isinstance(inner, JoinOp):
-                n = inner.row_count()
-                if n is not None:
-                    cols = {out_col: [n] for out_col, _ in aggs}
-                    return self.context.table_cls.from_columns(cols)
+                return self.context.table_cls.from_columns(cols)
         return in_op.table.group(by, aggs, in_h, self.context.parameters)
+
+    def _input_row_count(self) -> Optional[int]:
+        """The number of input rows from the phase of the input operator
+        that already knows it — a DISTINCT's first-occurrence count, a
+        filter's mask, an inner join's count phase, a table a CSE-shared
+        sibling built — or None: the rows are built and grouped. Every ask
+        is counted in ``tpu_cypher_count_pushdown_total{op,outcome}`` and
+        named on this operator's span (``count_from``)."""
+        in_op = self.children[0]
+        if isinstance(in_op, DistinctOp) and in_op._table is None:
+            # count-over-distinct: WITH DISTINCT a, b ... RETURN count(*)
+            # never materializes the deduped rows — the count is the
+            # number of first-occurrence groups (the engines get the same
+            # from their optimizers' aggregate pushdown)
+            n = self._distinct_row_count(in_op)
+            return self._note_pushdown("distinct", n)
+        # projections keep the multiset: look through them
+        inner = in_op
+        while inner._table is None and isinstance(inner, (SelectOp, CacheOp)):
+            inner = inner.children[0]
+        if inner._table is not None:
+            _obs_trace.note("count_from", "table")
+            return inner._table.size
+        if not isinstance(inner, (FilterOp, JoinOp)):
+            return None
+        op = "filter" if isinstance(inner, FilterOp) else "join"
+        # the operator's span, as ``table`` would have opened it
+        with _obs_trace.span(type(inner).__name__, kind="operator", count_only=True):
+            n = inner.row_count()
+            # no count phase for this kind of key: the pairs are built, but
+            # still from the key columns alone
+            rows = inner.key_join_size() if n is None and op == "join" else None
+        self._note_pushdown(op, n)
+        return n if n is not None else rows
+
+    @staticmethod
+    def _note_pushdown(op: str, n: Optional[int]) -> Optional[int]:
+        outcome = "rows" if n is None else "count"
+        COUNT_PUSHDOWN.inc(op=op, outcome=outcome)
+        _obs_trace.note("count_from", op if n is not None else f"{op}:rows")
+        return n
+
+    @staticmethod
+    def _distinct_row_count(in_op: "DistinctOp") -> Optional[int]:
+        # deepest pushdown first: a fused expand chain can count its
+        # DISTINCT endpoints without materializing ANY row set (the
+        # backend op advertises `distinct_endpoints_count`). Column
+        # projections keep the row multiset, so peel SelectOps as long
+        # as the distinct fields survive them.
+        inner = in_op.children[0]
+        while (
+            isinstance(inner, SelectOp)
+            and set(in_op.fields) <= set(inner.fields)
+        ) or isinstance(inner, CacheOp):
+            inner = inner.children[0]
+        fused = getattr(inner, "distinct_endpoints_count", None)
+        if fused is not None:
+            n = fused(in_op.fields)
+            if n is not None:
+                return n
+        return in_op.children[0].table.distinct_count(in_op.distinct_columns())
 
     def _show_inner(self) -> str:
         return f"group={self.group_fields}"
@@ -635,15 +684,9 @@ class JoinOp(RelationalOperator):
             joined = joined.drop(to_drop)
         return joined
 
-    def row_count(self) -> Optional[int]:
-        """Rows of an inner equi-join, from its key columns alone; None for
-        the other kinds. ``table`` gathers every column of both sides for
-        every pair, and a ``count(*)`` over the join reads none of them:
-        in ``snb-sf100-mesh4.analytic-mesh`` those gathers (ten columns a
-        side, 3.2M pairs) were 1.9 of the pass's 3.2 seconds on every chip,
-        and ran on into the next request (PR 28)."""
-        if self.kind != "inner" or not self.join_exprs:
-            return None
+    def _key_sides(self) -> Tuple[Table, Table, List[Tuple[str, str]]]:
+        """Both sides cut to their key columns, the right side renamed as
+        ``_compute_table`` renames it, and the column pairs to join on."""
         lhs, rhs = self.children
         renames = self._analyze()[0]
         l_cols: List[str] = []
@@ -655,6 +698,33 @@ class JoinOp(RelationalOperator):
         rt = rt.rename({c: renames[c] for c in set(r_cols) if c in renames})
         pairs = [(lc, renames.get(rc, rc)) for lc, rc in zip(l_cols, r_cols)]
         lt = lhs.table.select(list(dict.fromkeys(l_cols)))
+        return lt, rt, pairs
+
+    def row_count(self) -> Optional[int]:
+        """Rows of an inner equi-join from its count phase alone
+        (``Table.join_count``): a sharded tier's first exchange, the
+        one-device join's probe. Nothing of the pairs is built — not the
+        materialize (on the mesh a second exchange of keys and row numbers
+        of both sides), not their compaction, not a gather of any column.
+        In ``snb-sf100-mesh4.analytic-mesh`` that tail was about a second a
+        chip of a 2.12 s pass, run after the answer had left, inside the
+        next request (PR 28's trace; taken out in PR 29). None for the other
+        kinds, and where the backend cannot count before it has the pairs
+        (composite, string, float or mixed keys): ``key_join_size``."""
+        if self.kind != "inner" or not self.join_exprs:
+            return None
+        lt, rt, pairs = self._key_sides()
+        return lt.join_count(rt, "inner", pairs)
+
+    def key_join_size(self) -> Optional[int]:
+        """Rows of an inner equi-join from a join of its key columns alone,
+        where ``row_count`` has no answer; None for the other kinds.
+        ``table`` gathers every column of both sides for every pair, and a
+        ``count(*)`` over the join reads none of them (PR 28: ten columns a
+        side, 3.2M pairs, 1.9 of a pass's 3.2 seconds on every chip)."""
+        if self.kind != "inner" or not self.join_exprs:
+            return None
+        lt, rt, pairs = self._key_sides()
         return lt.join(rt, "inner", pairs).size
 
     def _show_inner(self) -> str:
