@@ -203,10 +203,9 @@ class Reference:
 
     def windows(self, budget_rows: int):
         """Two anchor windows [lo, hi) of compact positions, from the middle
-        of the id range (away from the Zipf hubs at the low ids), as
-        ``bench.py`` anchors its materializing shapes: the first sized so
-        its 2-hop walk count stays within ``budget_rows``, the second so
-        its <=3-hop walk count does."""
+        of the id range (away from the Zipf hubs at the low ids): the
+        first sized so its 2-hop walk count stays within ``budget_rows``,
+        the second so its <=3-hop walk count does."""
         w1 = self.outdeg.astype(np.float64)
         w2 = np.bincount(self.s, weights=w1[self.d], minlength=self.n)
         w3 = np.bincount(self.s, weights=w2[self.d], minlength=self.n)

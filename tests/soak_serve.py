@@ -66,8 +66,6 @@ line prefixed ``SERVE_SOAK``:
 * ``stage_breakdown`` — accumulated wall seconds per serving stage
   (queue_wait / route / dispatch / serialize / demux), the latency
   attribution table in docs/serving.md.
-
-``bench.py`` imports ``main()`` for its ``serve_soak`` summary field.
 """
 
 import asyncio

@@ -411,7 +411,6 @@ def test_config_registry_enumerates_engine_surface():
         "TPU_CYPHER_FAULTS",
         "TPU_CYPHER_PALLAS",
         "TPU_CYPHER_MXU_DENSE",
-        "TPU_CYPHER_MXU_TILED_MAX",
         "TPU_CYPHER_BROADCAST_LIMIT",
         "TPU_CYPHER_ISLAND_WARN_ROWS",
         "TPU_CYPHER_METRICS_FILE",
@@ -561,7 +560,7 @@ def test_json_output_carries_suppressions_inventory():
 
 
 def test_engine_lint_summary_reports_per_rule_counts():
-    """the bench.py ``lint_clean`` payload: per-rule counts, never raises"""
+    """the lint summary: per-rule counts, never raises"""
     from tpu_cypher.analysis import engine_lint_summary
 
     s = engine_lint_summary()

@@ -1,4 +1,4 @@
-"""``chip_smoke.py`` and ``bench.py`` off the chip: what must fail, fails.
+"""``chip_smoke.py`` off the chip: what must fail, fails.
 
 The chip check itself runs through the chip tool. Here, on the CPU:
 
@@ -8,9 +8,7 @@ The chip check itself runs through the chip tool. Here, on the CPU:
   and can never print the TPU success line;
 * NO FALLBACK — without the rehearsal flag a machine without an accelerator
   gets a non-zero exit and no result line, before anything is loaded; so
-  does a directory that holds the script and nothing else of the repo;
-* ``bench.py`` — one process that raises off the chip, a peaks table keyed
-  by ``device_kind`` where an unknown device is an error.
+  does a directory that holds the script and nothing else of the repo.
 """
 
 import json
@@ -111,37 +109,3 @@ def test_script_alone_in_a_directory_fails(tmp_path):
         assert proc.returncode != 0
         assert _last_json(proc.stdout) is None, proc.stdout
 
-
-# ---------------------------------------------------------------------------
-# bench.py
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def bench():
-    sys.path.insert(0, REPO)
-    import bench
-
-    return bench
-
-
-def test_bench_raises_off_the_chip(bench, monkeypatch):
-    monkeypatch.delenv("TPU_CYPHER_BENCH_FORCE_CPU", raising=False)
-    with pytest.raises(RuntimeError, match="no CPU fallback"):
-        bench.main()
-
-
-def test_bench_peaks_are_keyed_by_device_kind(bench):
-    v5e = bench.device_peaks("TPU v5 lite")
-    assert v5e == {"flops": 197e12, "bytes": 819e9}
-    with pytest.raises(KeyError, match="no peak table entry"):
-        bench.device_peaks("TPU v99")
-    with pytest.raises(KeyError):
-        bench.device_peaks("cpu")
-
-
-def test_bench_roofline_reports_no_utilization_without_peaks(bench):
-    model = bench._roofline(100, 1000, 5000, 0.5, None)
-    assert set(model) == {"est_bytes", "est_flops", "arith_intensity"}
-    on_chip = bench._roofline(100, 1000, 5000, 0.5, bench.PEAKS["TPU v5 lite"])
-    assert {"bandwidth_util", "mfu", "bound", "roofline_frac"} <= set(on_chip)

@@ -69,16 +69,12 @@ def test_close_count_self_loops_and_cycles():
 def test_close_count_uses_fused_program(monkeypatch, seed, loopy):
     """The triangle count(*) must go through a fused close-count program
     (no chain materialization) WHETHER OR NOT the graph has self-loops:
-    loop-free graphs drop the uniqueness filters by proof and run the
-    native stamping kernel (or into_close_count without it); loopy graphs
-    enforce the filters in-kernel via into_close_count_unique. The
-    materializing into_probe must not run."""
-    from tpu_cypher import native
-
-    calls = {"close": 0, "unique": 0, "native": 0, "probe": 0}
+    loop-free graphs drop the uniqueness filters by proof and run
+    into_close_count; loopy graphs enforce the filters in-kernel via
+    into_close_count_unique. The materializing into_probe must not run."""
+    calls = {"close": 0, "unique": 0, "probe": 0}
     orig_close = J.into_close_count
     orig_unique = J.into_close_count_unique
-    orig_native = native.two_hop_close_count_native
     orig_probe = J.into_probe
 
     def spy_close(*a, **k):
@@ -89,19 +85,12 @@ def test_close_count_uses_fused_program(monkeypatch, seed, loopy):
         calls["unique"] += 1
         return orig_unique(*a, **k)
 
-    def spy_native(*a, **k):
-        got = orig_native(*a, **k)
-        if got is not None:  # None falls through to the device kernel
-            calls["native"] += 1
-        return got
-
     def spy_probe(*a, **k):
         calls["probe"] += 1
         return orig_probe(*a, **k)
 
     monkeypatch.setattr(J, "into_close_count", spy_close)
     monkeypatch.setattr(J, "into_close_count_unique", spy_unique)
-    monkeypatch.setattr(native, "two_hop_close_count_native", spy_native)
     monkeypatch.setattr(J, "into_probe", spy_probe)
     create = _random_create(seed, 20, 80)
     if not loopy:
@@ -111,13 +100,9 @@ def test_close_count_uses_fused_program(monkeypatch, seed, loopy):
     expected = [dict(r) for r in g_local.cypher(TRIANGLE).records.collect()]
     got = [dict(r) for r in g_tpu.cypher(TRIANGLE).records.collect()]
     assert got == expected
-    # loop-free graphs drop the filters by PROOF (native/plain kernel);
-    # loopy graphs must route through the in-kernel enforcement variant
-    if loopy:
-        assert (calls["close"], calls["unique"], calls["native"]) == (0, 1, 0)
-    else:
-        assert calls["unique"] == 0
-        assert calls["close"] + calls["native"] == 1
+    # loop-free graphs drop the filters by PROOF (plain kernel); loopy
+    # graphs must route through the in-kernel enforcement variant
+    assert (calls["close"], calls["unique"]) == ((0, 1) if loopy else (1, 0))
     assert calls["probe"] == 0
 
 

@@ -1,7 +1,7 @@
 """MXU dense tier (SURVEY §2.2⚙ / tpu-first design): 2-hop close counts and
 DISTINCT-endpoint counts as blocked bf16 ``A @ A`` on the systolic array —
 the count becomes a matmul chain, which is where a TPU's FLOPs live. On CPU
-the tier is off by default (the native stamping kernels win); these tests
+the tier is off by default (dense N^3 does not win there); these tests
 FORCE it (``TPU_CYPHER_MXU_DENSE=force``) to pin exactness differentially:
 bf16 entries are small exact integers, accumulation is f32 with f64/int64
 reductions, so the counts must be bit-equal to the oracle."""
@@ -121,88 +121,3 @@ def test_mxu_disabled_on_cpu_by_default(monkeypatch):
     g.cypher(TRIANGLE).records.collect()
     assert calls["mxu"] == 0
 
-
-# ---------------------------------------------------------------------------
-# TILED tier: no full (Npad, Npad) matrix — row blocks densified from the
-# edge list per contraction step (graphs past dense_adj's node cap, e.g.
-# SF10's 100k nodes, stay on the MXU). Forced here by nulling dense_adj.
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture
-def _tiled_only(monkeypatch):
-    from tpu_cypher.backend.tpu.graph_index import GraphIndex
-
-    monkeypatch.setattr(
-        GraphIndex, "dense_adj", lambda self, *a, **k: None
-    )
-
-
-TILED_QUERIES = [
-    TRIANGLE,
-    "MATCH (a:N)-[:K]->(b:M)-[:K]->(c:N)-[:K]->(a) RETURN count(*) AS t",
-    "MATCH (a)<-[:K]-(b)-[:K]->(c)-[:K]->(a) RETURN count(*) AS t",
-    "MATCH (a)-[:K]->(b)-[:K]->(c) WITH DISTINCT a, c RETURN count(*) AS t",
-    "MATCH (a:N)-[:K]->(b:M)-[:K]->(c) WITH DISTINCT a, c RETURN count(*) AS t",
-]
-
-
-@pytest.mark.parametrize("query", TILED_QUERIES)
-def test_mxu_tiled_differential(query, _tiled_only):
-    from tpu_cypher.backend.tpu import expand_op as X
-
-    create = _random_create(11, 40, 200, labels=("N", "M"))
-    gl = CypherSession.local().create_graph_from_create_query(create)
-    gt = CypherSession.tpu().create_graph_from_create_query(create)
-    before = X.MXU_TIER_COUNTS["tiled"]
-    lv = [dict(r) for r in gl.cypher(query).records.collect()]
-    tv = [dict(r) for r in gt.cypher(query).records.collect()]
-    assert tv == lv, f"{query}: {tv} vs {lv}"
-    assert X.MXU_TIER_COUNTS["tiled"] > before  # the tiled tier answered
-
-
-def test_mxu_tiled_multi_block(_tiled_only):
-    """More nodes than one 256-wide block: the contraction loops over
-    several (block, block) @ (block, Npad) steps."""
-    create = _random_create(13, 300, 900)
-    gl = CypherSession.local().create_graph_from_create_query(create)
-    gt = CypherSession.tpu().create_graph_from_create_query(create)
-    lv = [dict(r) for r in gl.cypher(TRIANGLE).records.collect()]
-    tv = [dict(r) for r in gt.cypher(TRIANGLE).records.collect()]
-    assert tv == lv
-
-
-def test_mxu_tiled_matches_full_kernel():
-    """Kernel-level equivalence: tiled == full dense on random adjacencies."""
-    import jax.numpy as jnp
-
-    from tpu_cypher.backend.tpu.graph_index import DenseTiles
-
-    rng = np.random.default_rng(5)
-    n, e = 70, 400
-    s = rng.integers(0, n, e).astype(np.int64)
-    d = rng.integers(0, n, e).astype(np.int64)
-    block = 256
-
-    def tiles_of(a, b):
-        order = np.argsort(a, kind="stable")
-        keys = a * np.int64(n) + b
-        _, counts = np.unique(keys, return_counts=True)
-        return DenseTiles(
-            n, block, a[order], b[order], int(counts.max()),
-            int(np.bincount(a, minlength=n).max()),
-        )
-
-    t = tiles_of(s, d)
-    npad = t.npad
-    dense = np.zeros((npad, npad), np.int32)
-    np.add.at(dense, (s, d), 1)
-    a_bf = jnp.asarray(dense).astype(jnp.bfloat16)
-    mult = jnp.ones(npad, jnp.int64)
-    pres = jnp.ones(npad, bool)
-    full_close = int(J.mxu_close_count(a_bf, a_bf, a_bf, mult, None, None, block=block))
-    tiled_close = J.mxu_close_count_tiled(t, t, t, mult, None, None)
-    assert tiled_close == full_close
-    full_dist = int(J.mxu_distinct_pairs(a_bf, a_bf, pres, None, None, block=block))
-    tiled_dist = J.mxu_distinct_pairs_tiled(t, t, pres, None, None)
-    assert tiled_dist == full_dist

@@ -695,7 +695,6 @@ def test_session_metrics_text_covers_the_engine():
         "tpu_cypher_stage_seconds",
         "tpu_cypher_pallas_launch_total",
         "tpu_cypher_mxu_tier_total",
-        "tpu_cypher_native_tier_total",
         "tpu_cypher_fallbacks_total",
     ):
         assert f"# TYPE {name}" in text, name

@@ -236,7 +236,7 @@ def test_facts_sites_and_summary(tmp_path):
 
 
 def test_engine_shape_summary_never_raises():
-    """the bench.py ``shape_facts`` payload"""
+    """the facts summary over the engine"""
     s = shapes.engine_shape_summary()
     assert set(s) >= {
         "facts_emitted", "data_dependent_sites", "bucketed_sites",

@@ -290,13 +290,28 @@ def test_auto_mode_routes_past_threshold():
     assert after["count"] == before["count"]
 
 
-def test_auto_mode_hands_pure_count_back_to_fused_binary():
-    """Pure counts hand back to the classic plan in auto mode whenever a
-    fused binary counting tier is in reach (always true on the CPU
-    backend these tests run on): the count lands on the shadow tier,
-    never the sum(min-deg) probing tier — and the shadow child is the
-    PRUNED fused expand-into, so it costs what ``off`` mode costs.
-    ``force`` keeps the pure WCOJ path (the wcoj-vs-binary bench legs)."""
+@pytest.mark.parametrize(
+    "dense,dense_max,tier",
+    [
+        # the dense MXU tier in reach: the count lands on the shadow tier,
+        # and the shadow child is the PRUNED fused expand-into, so it
+        # costs what ``off`` mode costs
+        ("force", None, "shadow"),
+        # not in reach — off (the CPU's default), or on with its node cap
+        # pinned under the graph's 40 nodes: WCOJ's own count tier answers
+        ("auto", None, "count"),
+        ("force", 39, "count"),
+    ],
+)
+def test_auto_mode_hands_pure_count_to_the_dense_tier_in_reach(
+    monkeypatch, dense, dense_max, tier
+):
+    """Pure counts hand back to the classic plan in auto mode exactly when
+    its dense MXU counting tier will take them (``_mxu_dense_mode`` under
+    ``optimizer.cost.mxu_dense_node_cap()``, the gate ``dense_adj`` asks)."""
+    monkeypatch.setenv("TPU_CYPHER_MXU_DENSE", dense)
+    if dense_max is not None:
+        monkeypatch.setenv("TPU_CYPHER_MXU_DENSE_MAX", str(dense_max))
     WCOJ_MIN_ROWS.set(1)
     create = _loop_free_create()
     g = CypherSession.tpu().create_graph_from_create_query(create)
@@ -305,9 +320,8 @@ def test_auto_mode_hands_pure_count_back_to_fused_binary():
     got = g.cypher(TRIANGLE).records.to_bag()
     after = _tiers()
     assert got == g_loc.cypher(TRIANGLE).records.to_bag()
-    assert after["shadow"] == before["shadow"] + 1
-    assert after["count"] == before["count"]
-    assert after["materialize"] == before["materialize"]
+    moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
+    assert moved == {tier: 1}
 
 
 # ---------------------------------------------------------------------------
@@ -430,107 +444,3 @@ def test_csr_build_violation_raises(monkeypatch):
     with pytest.raises(GraphIndexError, match="sorted-by-neighbor"):
         GraphIndex._sorted_csr(a, b, 6)
 
-
-# ---------------------------------------------------------------------------
-# bench rung: wcoj_vs_binary emits both legs and they agree
-# ---------------------------------------------------------------------------
-
-
-def test_bench_wcoj_vs_binary_rung():
-    import sys
-
-    sys.path.insert(
-        0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    import bench
-
-    # bench's queries match (:Person)-[:KNOWS]-> — build a graph in that
-    # vocabulary (the generic _loopy_create fixture would match nothing
-    # and pass vacuously)
-    rng = np.random.default_rng(11)
-    n, e = 12, 50
-    src = rng.integers(0, n, e)
-    dst = (src + 1 + rng.integers(0, n - 1, e)) % n
-    parts = [f"(p{i}:Person)" for i in range(n)]
-    parts += [f"(p{s})-[:KNOWS]->(p{d})" for s, d in zip(src, dst)]
-    g = CypherSession.tpu().create_graph_from_create_query(
-        "CREATE " + ", ".join(parts)
-    )
-    tiny = {"triangle": e, "clique4": e}
-    out = bench._wcoj_vs_binary(
-        g, feasible_binary=True, est_rows=tiny, budget_rows=1_000_000
-    )
-    for leg in ("triangle", "clique4"):
-        entry = out[leg]
-        assert entry["counts_match"] is True, entry
-        assert entry["wcoj_seconds"] > 0 and entry["binary_seconds"] > 0
-        assert "wcoj_speedup" in entry
-        # each leg replans (the plan cache keys on TPU_CYPHER_WCOJ): the
-        # force leg answers from a wcoj tier, the off leg never touches one
-        assert "wcoj" in entry["wcoj_tier"], entry
-        assert "wcoj" not in entry["binary_tier"], entry
-    # the factorized materialize leg: measured (not skipped), answered
-    # from the factorized tier, flat comparison agrees and yields the
-    # speedup field
-    mat = out["clique4_materialize"]
-    assert mat["factorized_seconds"] > 0, mat
-    assert "wcoj_factorized" in mat["factorized_tier"], mat
-    assert mat["flat_seconds"] > 0 and mat["counts_match"] is True, mat
-    assert "factorized_vs_flat" in mat
-    skipped = bench._wcoj_vs_binary(
-        g, feasible_binary=False, est_rows=tiny, budget_rows=1_000_000
-    )
-    assert skipped["triangle"]["binary_skipped"]
-    assert skipped["triangle"]["count"] == out["triangle"]["count"]
-    # the per-shape transient gate: an over-budget estimate degrades the
-    # whole leg to a skip note (the clique4 force leg was the OOM that
-    # killed every bench round since r04)
-    gated = bench._wcoj_vs_binary(
-        g,
-        feasible_binary=True,
-        est_rows={"triangle": e, "clique4": 10_000_001},
-        budget_rows=1_000_000,
-    )
-    assert gated["triangle"]["counts_match"] is True
-    assert gated["clique4"]["wcoj_seconds"] is None
-    assert gated["clique4"]["binary_seconds"] is None
-    assert "over budget" in gated["clique4"]["skipped"]
-    # both WCOJ count legs are lean count-tier lanes now (the multi-close
-    # count tier answers clique4 without materializing the 3-walk set),
-    # so both get the x8 slack — but clique4's BINARY sub-leg still
-    # materializes fat 3-walk rows and keeps the no-slack bound
-    near = bench._wcoj_vs_binary(
-        g,
-        feasible_binary=True,
-        est_rows={"triangle": 3_000_000, "clique4": 3_000_000},
-        budget_rows=1_000_000,
-    )
-    assert near["triangle"]["wcoj_seconds"] > 0
-    assert near["clique4"]["wcoj_seconds"] > 0
-    assert near["clique4"]["binary_seconds"] is None
-    assert near["clique4"]["binary_skipped"]
-    # the materialize leg's gates are FACTORIZED-shaped: an over-budget
-    # LANE estimate is the only typed skip, and an over-budget flat
-    # estimate only drops the comparison sub-leg (the factorized leg
-    # still measures — the old unconditional clique4 skip is gone)
-    big = 10_000_001  # over budget*8: skips the count legs, which these
-    # two cases don't look at — they probe the materialize leg's gates
-    lane_gated = bench._wcoj_vs_binary(
-        g,
-        feasible_binary=False,
-        est_rows={"triangle": big, "clique4": big, "clique4_lanes": big},
-        budget_rows=1_000_000,
-    )
-    m = lane_gated["clique4_materialize"]
-    assert m["factorized_seconds"] is None
-    assert "over budget" in m["skipped"]
-    flat_gated = bench._wcoj_vs_binary(
-        g,
-        feasible_binary=False,
-        est_rows={"triangle": big, "clique4": big, "clique4_lanes": e},
-        budget_rows=1_000_000,
-    )
-    m = flat_gated["clique4_materialize"]
-    assert m["factorized_seconds"] > 0, m
-    assert m["flat_seconds"] is None
-    assert "over budget" in m["flat_skipped"]

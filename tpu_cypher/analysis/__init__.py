@@ -40,7 +40,6 @@ from .core import FileContext, Finding, Rule
 from .runner import (
     ENGINE_ROOT,
     check_engine,
-    engine_is_clean,
     engine_lint_summary,
     run_paths,
 )
@@ -53,7 +52,6 @@ __all__ = [
     "Finding",
     "Rule",
     "check_engine",
-    "engine_is_clean",
     "engine_lint_summary",
     "run_paths",
 ]

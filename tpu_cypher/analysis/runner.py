@@ -64,7 +64,7 @@ class Report:
         return not self.blocking
 
     def counts_by_rule(self) -> Dict[str, int]:
-        """{rule id: blocking finding count} — the bench.py lint field."""
+        """{rule id: blocking finding count}."""
         out: Dict[str, int] = {}
         for f in self.blocking:
             out[f.rule] = out.get(f.rule, 0) + 1
@@ -304,24 +304,15 @@ def check_engine(
     baseline_path: Optional[str] = DEFAULT_BASELINE,
 ) -> Report:
     """Lint the installed ``tpu_cypher`` package — the thin invocation the
-    test suite (and bench.py's ``lint_clean``) uses."""
+    test suite uses."""
     return run_paths([ENGINE_ROOT], rules=rules, baseline_path=baseline_path)
 
 
-def engine_is_clean() -> bool:
-    """True when the engine lints clean. Never raises — bench.py records
-    this on its one guaranteed JSON line even mid-incident."""
-    try:
-        return check_engine().clean
-    except Exception:  # fault-ok: a lint crash must not fail the bench line
-        return False
-
-
 def engine_lint_summary() -> Dict[str, object]:
-    """The bench.py ``lint_clean`` payload: verdict plus per-rule blocking
-    finding counts, so a regressed invariant names itself on the JSON line
-    instead of flipping an opaque boolean. Never raises — an analyzer
-    crash reports ``{"clean": False, "error": ...}``."""
+    """The lint verdict plus per-rule blocking finding counts, so a
+    regressed invariant names itself instead of flipping an opaque
+    boolean. Never raises — an analyzer crash reports
+    ``{"clean": False, "error": ...}``."""
     try:
         report = check_engine()
         return {
@@ -330,7 +321,7 @@ def engine_lint_summary() -> Dict[str, object]:
             "suppressed": len(report.suppressed),
             "files_checked": report.files_checked,
         }
-    except Exception as exc:  # fault-ok: a lint crash must not fail the bench line
+    except Exception as exc:  # fault-ok: a lint crash reports itself in the summary
         return {"clean": False, "findings_by_rule": {}, "error": str(exc)[:200]}
 
 

@@ -954,15 +954,15 @@ def collect_facts(project) -> Dict[str, object]:
 
 
 def engine_shape_summary() -> Dict[str, object]:
-    """The bench.py ``shape_facts`` payload: the facts summary over the
-    installed engine. Never raises — a crash reports itself on the line."""
+    """The facts summary over the installed engine. Never raises — a
+    crash reports itself in the summary."""
     try:
         from .runner import ENGINE_ROOT, run_paths
 
         report = run_paths([ENGINE_ROOT], rules=[])
         facts = collect_facts(report.project)
         return dict(facts["summary"])
-    except Exception as exc:  # fault-ok: a facts crash must not fail the bench line
+    except Exception as exc:  # fault-ok: a facts crash reports itself in the summary
         return {
             "facts_emitted": 0,
             "data_dependent_sites": -1,

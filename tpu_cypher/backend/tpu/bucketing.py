@@ -21,8 +21,7 @@ This module is the shared policy for the fix:
   ``backend_compile`` event per real compilation, persistent-cache
   hit/miss events per disk-tier lookup), served by the unified obs
   registry (``tpu_cypher_xla_compiles_total`` etc.) — surfaced as
-  ``result.compile_stats``, ``session.warmup(..)`` deltas, and the
-  ``compile_count`` metrics in ``benchmarks/micro.py``.
+  ``result.compile_stats`` and ``session.warmup(..)`` deltas.
 * the persistent compilation cache wiring (``enable_persistent_cache``), so
   warm caches survive process restarts.
 

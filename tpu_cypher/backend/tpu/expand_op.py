@@ -82,7 +82,7 @@ def _mxu_dense_mode() -> bool:
     """Route 2-hop counts through the MXU dense tier (blocked bf16 A @ A,
     ``jit_ops.mxu_close_count``/``mxu_distinct_pairs``)? Defaults to ON for
     accelerator backends (matmuls are where the TPU's FLOPs live) and OFF
-    for CPU (the native stamping kernels win there; dense N^3 does not).
+    for CPU (dense N^3 does not win there).
     ``TPU_CYPHER_MXU_DENSE=force`` enables it anywhere (correctness tests),
     ``=0`` disables."""
     from ...utils.config import MXU_DENSE
@@ -95,31 +95,8 @@ def _mxu_dense_mode() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _mxu_tiled_enabled() -> bool:
-    """The TILED MXU tier (no full dense matrix; ``jit_ops.mxu_*_tiled``)
-    engages only on EXPLICIT request (``TPU_CYPHER_MXU_DENSE=1|force``) —
-    deliberately NOT on auto: a dense product is Theta(N^3) FLOPs, so past
-    ``dense_adj``'s cap the sparse walk/stamping tiers win by orders of
-    magnitude (100k nodes ~ 1e15 bf16 FLOPs ~ minutes on one chip vs
-    sub-second sparse). The tier exists to run dense-eligible counts on
-    the systolic array at ANY node count with bit-identical results —
-    proven by the forced differential tests — not to outrace the sparse
-    tiers at scale. Node gate: ``TPU_CYPHER_MXU_TILED_MAX`` (default
-    131072, covers SF10's 100k nodes)."""
-    from ...utils.config import MXU_DENSE
-
-    return MXU_DENSE.get() in ("1", "force")
-
-
-def _mxu_tiled_max() -> int:
-    from ...optimizer.cost import mxu_tiled_node_cap
-
-    return mxu_tiled_node_cap()
-
-
-# which MXU tier answered each dense-eligible count — bench.py reports the
-# per-rung tier so a perf run shows WHERE the FLOPs went. Served by the
-# unified obs registry; these views keep the dict-shaped read path.
+# how many dense-eligible counts the MXU tier answered. Served by the
+# unified obs registry; the view keeps the dict-shaped read path.
 from ...obs.metrics import REGISTRY as _OBS_REGISTRY  # noqa: E402
 from ...obs.metrics import CounterView  # noqa: E402
 
@@ -130,7 +107,7 @@ MXU_TIER_COUNTS = CounterView(
         labels=("tier",),
     ),
     "tier",
-    ("dense", "tiled"),
+    ("dense",),
 )
 
 _MESH_EXPAND_TOTAL = _OBS_REGISTRY.counter(
@@ -138,34 +115,6 @@ _MESH_EXPAND_TOTAL = _OBS_REGISTRY.counter(
     "fused count chains executed as the explicit shard_map program over "
     "the row-sharded CSR",
 )
-
-# which NATIVE (C++ stamping/DFS) kernels answered — same purpose
-NATIVE_TIER_COUNTS = CounterView(
-    _OBS_REGISTRY.counter(
-        "tpu_cypher_native_tier_total",
-        "counts answered per native C++ stamping/DFS kernel",
-        labels=("tier",),
-    ),
-    "tier",
-    ("two_hop", "close", "varlen"),
-)
-
-
-def _mxu_tiled_common(gi, ctx, hops):
-    """Shared preamble of the tiled MXU tier: gate, hop tile providers,
-    f32-exactness product term, label masks. None when the tier does not
-    apply."""
-    if not _mxu_tiled_enabled() or gi.num_nodes > _mxu_tiled_max():
-        return None
-    base, final_hop = hops[1], hops[0]
-    t1 = gi.dense_tiles(base.types_key, base.backwards, ctx)
-    t2 = gi.dense_tiles(final_hop.types_key, final_hop.backwards, ctx)
-    if t1 is None or t2 is None:
-        return None
-    npad = t1.npad
-    m_b = _pad_mask(gi.label_mask(base.far_labels, ctx), npad)
-    m_c = _pad_mask(gi.label_mask(final_hop.far_labels, ctx), npad)
-    return t1, t2, t1.max_row_sum * max(t2.max_entry, 1), m_b, m_c
 
 
 def _note_chain_forms(forms) -> None:
@@ -859,19 +808,13 @@ class CsrExpandOp(_FusedExpandBase):
                 if spec is None:
                     return None  # materialized path enforces via row masks
                 carry, mask_pairs, _ = spec
-            elif len(hops) == 2 and current_mesh() is None:
-                got = None
-                if use_a and use_c and _mxu_dense_mode():
-                    # MXU tier: nonzero count of the blocked bf16 boolean
-                    # product — one matmul chain instead of 20M-row state
-                    got = self._mxu_distinct_pairs(gi, ctx, hops, id_col)
-                if got is None and jax.default_backend() == "cpu":
-                    # host tier: stamped one-pass count in C++ (native/) —
-                    # no 20M-row materialize, no sort, O(N) cache-resident
-                    # state
-                    got = self._native_two_hop(
-                        gi, ctx, hops, id_col, use_a=use_a, use_c=use_c
-                    )
+            elif (
+                len(hops) == 2 and current_mesh() is None
+                and use_a and use_c and _mxu_dense_mode()
+            ):
+                # MXU tier: nonzero count of the blocked bf16 boolean
+                # product — one matmul chain instead of 20M-row state
+                got = self._mxu_distinct_pairs(gi, ctx, hops, id_col)
                 if got is not None:
                     return got
 
@@ -885,18 +828,6 @@ class CsrExpandOp(_FusedExpandBase):
                             total=total, use_a=use_a, use_c=use_c,
                             num_nodes=gi.num_nodes, mask_idx=midx,
                             nvalid=nvalid,
-                        )
-                    )
-                n = gi.num_nodes
-                cells = n * n if (use_a and use_c) else n
-                if jax.default_backend() == "cpu" and cells <= (1 << 30):
-                    # host: presence-bitmap scatter + popcount beats the
-                    # 20M-row sort by ~7x; TPU keeps the values-only sort
-                    return int(
-                        J.distinct_bitmap_final(
-                            rp, ci, pos, deg, akey, mask,
-                            total=total, use_a=use_a, use_c=use_c,
-                            num_nodes=n, nvalid=nvalid,
                         )
                     )
                 return int(
@@ -921,7 +852,7 @@ class CsrExpandOp(_FusedExpandBase):
         got1 = gi.dense_adj(base.types_key, base.backwards, ctx)
         got2 = gi.dense_adj(final_hop.types_key, final_hop.backwards, ctx)
         if got1 is None or got2 is None:
-            return self._mxu_distinct_pairs_tiled(gi, ctx, hops, id_col)
+            return None
         a1, _, rowsum1 = got1
         a2, entry2, _ = got2
         if rowsum1 * entry2 > (1 << 24):
@@ -938,47 +869,6 @@ class CsrExpandOp(_FusedExpandBase):
                 a1, a2, pres, m_b, m_c, block=GraphIndex.DENSE_BLOCK
             )
         )
-
-    def _mxu_distinct_pairs_tiled(self, gi, ctx, hops, id_col):
-        """count(DISTINCT a, c) on the TILED MXU tier: densified row blocks
-        straight from the edge lists, no (Npad, Npad) matrix — the path
-        that keeps SF10-scale graphs (100k nodes) on the systolic array."""
-        got = _mxu_tiled_common(gi, ctx, hops)
-        if got is None:
-            return None
-        t1, t2, cell_bound, m_b, m_c = got
-        if cell_bound > (1 << 24):
-            return None
-        pos, present = gi.compact_of(id_col, ctx)
-        pres = J.frontier_multiplicity(pos, present, n=t1.npad) > 0
-        fault_point("expand")  # the tiled-tier count sync below
-        MXU_TIER_COUNTS.inc("tiled")
-        return int(J.mxu_distinct_pairs_tiled(t1, t2, pres, m_b, m_c))
-
-    def _native_two_hop(self, gi, ctx, hops, id_col, *, use_a, use_c):
-        """Host-tier 2-hop DISTINCT count via the C++ stamping kernel
-        (``native/csr_builder.cpp``); None when the lib is unavailable or
-        the frontier isn't grouped by source."""
-        from ... import native
-
-        if native.get_lib() is None:
-            return None
-        pos, present = gi.compact_of(id_col, ctx)
-        fr = np.asarray(pos)[np.asarray(present)]
-        base, final_hop = hops[1], hops[0]
-        rp1, ci1, _ = gi.csr(base.types_key, base.backwards, ctx)
-        rp2, ci2, _ = gi.csr(final_hop.types_key, final_hop.backwards, ctx)
-        m1 = gi.label_mask(base.far_labels, ctx)
-        m2 = gi.label_mask(final_hop.far_labels, ctx)
-        got = native.two_hop_distinct_native(
-            np.asarray(rp1), np.asarray(ci1), np.asarray(rp2), np.asarray(ci2),
-            fr, fr, gi.num_nodes, use_a, use_c,
-            None if m1 is None else np.asarray(m1),
-            None if m2 is None else np.asarray(m2),
-        )
-        if got is not None:
-            NATIVE_TIER_COUNTS.inc("two_hop")
-        return got
 
     def _factorized_expand(self, gi: GraphIndex, ctx, in_op, in_t, pos, present):
         """The expand output as a ``FactorizedTable`` — input rows are the
@@ -1269,13 +1159,6 @@ class CsrExpandIntoOp(_FusedExpandBase):
             fault_point("expand")
             keys = gi.edge_keys(self.types_key, ctx)
             src_is_base = self.source_fld == base.frontier_fld
-            dense = False
-            if jax.default_backend() == "cpu":
-                # host: one bitmap gather per probe replaces two binary
-                # searches over the sorted keys (~6x on the SF1 triangle)
-                bm = gi.edge_bitmap(self.types_key, ctx)
-                if bm is not None:
-                    keys, dense = bm, True
             pairs = _collected_pairs(hops, (self,))
             if pairs:
                 if self.undirected:
@@ -1305,7 +1188,7 @@ class CsrExpandIntoOp(_FusedExpandBase):
                             total=total, src_is_base=src_is_base,
                             num_nodes=gi.num_nodes,
                             mask_idx=midx, sub_idx=sub_idx, sub_cur=sub_cur,
-                            dense=dense, nvalid=nvalid,
+                            nvalid=nvalid,
                         )
                     )
 
@@ -1317,19 +1200,11 @@ class CsrExpandIntoOp(_FusedExpandBase):
                 len(hops) == 2
                 and not self.undirected
                 and current_mesh() is None
+                and _mxu_dense_mode()
             ):
-                if _mxu_dense_mode():
-                    got = self._mxu_close_count(
-                        gi, ctx, hops, id_col, src_is_base
-                    )
-                    if got is not None:
-                        return got
-                if jax.default_backend() == "cpu":
-                    got = self._native_close_count(
-                        gi, ctx, hops, id_col, src_is_base
-                    )
-                    if got is not None:
-                        return got
+                got = self._mxu_close_count(gi, ctx, hops, id_col, src_is_base)
+                if got is not None:
+                    return got
 
             def final(rp, ci, eo, pos, deg, akey, mask, prevs, order, midx,
                       total, nvalid=None):
@@ -1338,8 +1213,7 @@ class CsrExpandIntoOp(_FusedExpandBase):
                         rp, ci, pos, deg, akey, mask, keys,
                         total=total, src_is_base=src_is_base,
                         num_nodes=gi.num_nodes,
-                        undirected=self.undirected, dense=dense,
-                        nvalid=nvalid,
+                        undirected=self.undirected, nvalid=nvalid,
                     )
                 )
 
@@ -1359,7 +1233,7 @@ class CsrExpandIntoOp(_FusedExpandBase):
         got2 = gi.dense_adj(final_hop.types_key, final_hop.backwards, ctx)
         gotc = gi.dense_adj(self.types_key, not src_is_base, ctx)
         if got1 is None or got2 is None or gotc is None:
-            return self._mxu_close_count_tiled(gi, ctx, hops, id_col, src_is_base)
+            return None
         a1, _, rowsum1 = got1
         a2, entry2, _ = got2
         cm, entry_c, _ = gotc
@@ -1380,51 +1254,6 @@ class CsrExpandIntoOp(_FusedExpandBase):
                 a1, a2, cm, mult, m_b, m_c, block=GraphIndex.DENSE_BLOCK
             )
         )
-
-    def _mxu_close_count_tiled(self, gi, ctx, hops, id_col, src_is_base):
-        """Triangle/cycle close count on the TILED MXU tier (see
-        ``_mxu_distinct_pairs_tiled``)."""
-        got = _mxu_tiled_common(gi, ctx, hops)
-        if got is None:
-            return None
-        t1, t2, cell_bound, m_b, m_c = got
-        tc = gi.dense_tiles(self.types_key, not src_is_base, ctx)
-        if tc is None or cell_bound * max(tc.max_entry, 1) > (1 << 24):
-            return None
-        pos, present = gi.compact_of(id_col, ctx)
-        mult = J.frontier_multiplicity(pos, present, n=t1.npad)
-        fault_point("expand")  # the tiled-tier count sync below
-        MXU_TIER_COUNTS.inc("tiled")
-        return int(J.mxu_close_count_tiled(t1, t2, tc, mult, m_b, m_c))
-
-    def _native_close_count(self, gi, ctx, hops, id_col, src_is_base):
-        """Host-tier triangle/cycle close count via the C++ stamping kernel
-        (``native/csr_builder.cpp``): pre-stamp each source's closing
-        endpoints, one multiplicity lookup per 2-hop path."""
-        from ... import native
-
-        if native.get_lib() is None:
-            return None
-        pos, present = gi.compact_of(id_col, ctx)
-        fr = np.asarray(pos)[np.asarray(present)]
-        base, final_hop = hops[1], hops[0]
-        rp1, ci1, _ = gi.csr(base.types_key, base.backwards, ctx)
-        rp2, ci2, _ = gi.csr(final_hop.types_key, final_hop.backwards, ctx)
-        # close CSR oriented FROM the walk's base endpoint a: probe (a, c)
-        # stamps a's forward close row, probe (c, a) its in-neighbors
-        rpc, cic, _ = gi.csr(self.types_key, not src_is_base, ctx)
-        m1 = gi.label_mask(base.far_labels, ctx)
-        m2 = gi.label_mask(final_hop.far_labels, ctx)
-        got = native.two_hop_close_count_native(
-            np.asarray(rp1), np.asarray(ci1), np.asarray(rp2), np.asarray(ci2),
-            np.asarray(rpc), np.asarray(cic),
-            fr, fr, gi.num_nodes,
-            None if m1 is None else np.asarray(m1),
-            None if m2 is None else np.asarray(m2),
-        )
-        if got is not None:
-            NATIVE_TIER_COUNTS.inc("close")
-        return got
 
     def _fused_table(self):
         if not self.header.expressions:
@@ -1677,39 +1506,6 @@ class CsrVarExpandOp(_FusedExpandBase):
             return self.upper
         return max(int(np.asarray(ci).shape[0]), self.lower, 1)
 
-    def _native_varlen_count(self, rp, ci, eo, pos, present, row_map, forbid):
-        """count(*) of bounded var-length walks via the C++ DFS kernel;
-        None when unavailable (callers keep the device frontier loop)."""
-        from ... import native
-
-        if native.get_lib() is None:
-            return None
-        pres = np.asarray(present)
-        fr = np.asarray(pos)[pres]
-        rm = np.asarray(row_map)
-        mask = (rm >= 0).astype(np.uint8) if self.far_labels else None
-        total = 0
-        if self.lower == 0:
-            keep = np.ones(len(fr), bool) if mask is None else (
-                mask[fr].astype(bool)
-            )
-            total += int(keep.sum())
-        fb = (
-            np.ascontiguousarray(
-                np.stack([np.asarray(f)[pres] for f in forbid], axis=1)
-            )
-            if forbid
-            else None
-        )
-        got = native.varlen_count_native(
-            np.asarray(rp), np.asarray(ci), np.asarray(eo), fr,
-            max(1, self.lower), self._resolved_upper(ci), mask, fb,
-        )
-        if got is None:
-            return None
-        NATIVE_TIER_COUNTS.inc("varlen")
-        return total + got
-
     def _fused_table(self):
         from .table import TpuTable
 
@@ -1740,18 +1536,6 @@ class CsrVarExpandOp(_FusedExpandBase):
             rp, ci, eo = gi.csr(self.types_key, False, ctx)
         _, _, row_map = gi.node_scan(self.far_labels, ctx)
         forbid = self._forbid_arrays(gi, ctx)
-        if (
-            count_only
-            and jax.default_backend() == "cpu"
-            and current_mesh() is None
-        ):
-            # host tier: DFS with a register-resident walked-edge stack
-            # (native/csr_builder.cpp) — no per-level materialization
-            got = self._native_varlen_count(
-                rp, ci, eo, pos, present, row_map, forbid
-            )
-            if got is not None:
-                return TpuTable({}, got)
         row0 = None
         # forbidden edges seed the walked-edge masks: the loop's existing
         # ``orig != prev`` checks then enforce fixed-vs-var-length

@@ -159,9 +159,6 @@ class GraphIndex:
         # types_key -> int64[num_rels] (src*N + dst) key per canonical
         # rel-scan row (relationship-uniqueness probe subtraction)
         self._keys_by_orig: Dict[Tuple[str, ...], Any] = {}
-        # types_key -> Optional dense bool[N*N] edge-presence bitmap (host
-        # backends probe closes by one gather instead of a binary search)
-        self._edge_bitmap: Dict[Tuple[str, ...], Optional[Any]] = {}
         # (types_key, reverse) -> Optional (Npad, Npad) bf16 dense adjacency
         # with edge MULTIPLICITY entries (MXU matmul tier; Npad = block pad)
         self._dense_adj: Dict[Tuple[Tuple[str, ...], bool], Optional[Any]] = {}
@@ -498,27 +495,6 @@ class GraphIndex:
             )
         return got
 
-    def edge_bitmap(self, types_key: Tuple[str, ...], ctx) -> Optional[Any]:
-        """Dense int16[N*N] edge-MULTIPLICITY array for one type set (0 =
-        absent; parallel edges count), or None when N*N exceeds ~half a
-        billion cells (1GB int16) or a multiplicity overflows. Host
-        backends close triangles by ONE gather per probe instead of a 2x
-        binary search over the sorted edge keys (~12x on 20M probes); the
-        TPU keeps the searchsorted form (scatter-built dense state is the
-        slow path there, and HBM is better spent on the CSR)."""
-        if types_key not in self._edge_bitmap:
-            s, d, n = self._edge_endpoints(types_key, ctx)
-            out = None
-            if n and n * n <= (1 << 29):
-                keys = s.astype(np.int64) * n + d.astype(np.int64)
-                uniq, counts = np.unique(keys, return_counts=True)
-                if not len(counts) or counts.max() <= np.iinfo(np.int16).max:
-                    bm = np.zeros(n * n, dtype=np.int16)
-                    bm[uniq] = counts.astype(np.int16)
-                    out = jnp.asarray(bm)
-            self._edge_bitmap[types_key] = out
-        return self._edge_bitmap[types_key]
-
     DENSE_BLOCK = 256  # MXU tile-friendly row-block / pad quantum
 
     def dense_adj(
@@ -568,54 +544,6 @@ class GraphIndex:
             self._dense_adj[key] = out
         return self._dense_adj[key]
 
-    # total cached-tile budget for the tiled MXU tier: below this many
-    # matrix CELLS the densified row-blocks are kept on device across the
-    # contraction loop; above it each k-tile is re-densified on demand
-    TILE_CACHE_CELLS = 1 << 30  # 2 GiB of bf16
-
-    def dense_tiles(
-        self, types_key: Tuple[str, ...], reverse: bool, ctx,
-        block: Optional[int] = None,
-    ) -> Optional["DenseTiles"]:
-        """Row-block tile provider for the TILED MXU tier: (block, Npad)
-        bf16 slices of the dense multiplicity adjacency densified from the
-        edge list on demand — the full (Npad, Npad) matrix is never
-        materialized, lifting ``dense_adj``'s node-count cap (the 16,384-node
-        gate kept SF10 off the MXU). Returns None
-        when a multiplicity exceeds bf16's exact-integer range."""
-        b = block or self.DENSE_BLOCK
-        key = (types_key, reverse, b)
-        cache = getattr(self, "_dense_tiles", None)
-        if cache is None:
-            cache = self._dense_tiles = {}
-        if key not in cache:
-            self.node_ids(ctx)
-            n = self.num_nodes
-            if n == 0:
-                cache[key] = None
-                return None
-            s, d, _ = self._edge_endpoints(types_key, ctx)
-            a, bb = (d, s) if reverse else (s, d)
-            out = None
-            if len(a) == 0:
-                out = DenseTiles(n, b, np.zeros(0, np.int64), np.zeros(0, np.int64), 0, 0)
-            else:
-                # exactness metadata WITHOUT densifying: multiplicity =
-                # duplicate (row, col) count; row sum = out-degree
-                keys = a * np.int64(n) + bb
-                uniq, counts = np.unique(keys, return_counts=True)
-                max_entry = int(counts.max())
-                max_row_sum = int(np.bincount(a, minlength=n).max())
-                if max_entry > 256:
-                    out = None  # beyond bf16's exact-integer range
-                else:
-                    order = np.argsort(a, kind="stable")
-                    out = DenseTiles(
-                        n, b, a[order], bb[order], max_entry, max_row_sum
-                    )
-            cache[key] = out
-        return cache[key]
-
     def csr_max_degree(self, types_key: Tuple[str, ...], reverse: bool, ctx) -> int:
         """Host-cached max degree of one CSR orientation (computed at
         build)."""
@@ -636,40 +564,3 @@ class GraphIndex:
             return z, jnp.zeros(ids.shape[0], bool)
         return J.compact_lookup(dev_ids, ids, id_col.valid)
 
-
-class DenseTiles:
-    """On-demand (block, Npad) bf16 row-block slices of a dense
-    multiplicity adjacency, densified from the row-sorted edge list — the
-    tiled MXU tier's matrix view. Tiles are cached on device when the full
-    matrix stays under ``GraphIndex.TILE_CACHE_CELLS``; larger graphs
-    re-densify per request (the tier is then a correctness/force path)."""
-
-    def __init__(self, n, block, rows_sorted, cols_sorted, max_entry, max_row_sum):
-        self.n = int(n)
-        self.block = int(block)
-        self.npad = -(-self.n // self.block) * self.block
-        self.nblocks = self.npad // self.block
-        self._rows = rows_sorted
-        self._cols = cols_sorted
-        self.max_entry = max_entry
-        self.max_row_sum = max_row_sum
-        self._cache = (
-            {} if self.npad * self.npad <= GraphIndex.TILE_CACHE_CELLS else None
-        )
-
-    def tile(self, i: int):
-        if self._cache is not None and i in self._cache:
-            return self._cache[i]
-        lo = int(np.searchsorted(self._rows, i * self.block))
-        hi = int(np.searchsorted(self._rows, (i + 1) * self.block))
-        dense = np.zeros((self.block, self.npad), dtype=np.int32)
-        if hi > lo:
-            np.add.at(
-                dense,
-                (self._rows[lo:hi] - i * self.block, self._cols[lo:hi]),
-                1,
-            )
-        out = jnp.asarray(dense).astype(jnp.bfloat16)
-        if self._cache is not None:
-            self._cache[i] = out
-        return out

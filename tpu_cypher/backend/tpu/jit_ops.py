@@ -425,12 +425,12 @@ def into_probe(keys, s_pos, t_pos, ok, n, drop_loops: bool):
 
 @partial(
     jax.jit,
-    static_argnames=("total", "src_is_base", "num_nodes", "undirected", "dense"),
+    static_argnames=("total", "src_is_base", "num_nodes", "undirected"),
 )
 def into_close_count(
     rp, ci, pos, deg, akey, mask, keys,
     total: int, src_is_base: bool, num_nodes: int, undirected: bool,
-    dense: bool = False, nvalid=None,
+    nvalid=None,
 ):
     """Final hop of a count(*) triangle/cycle chain: expand the last hop's
     (base key, far position) pairs and, INSTEAD of materializing columns,
@@ -440,12 +440,6 @@ def into_close_count(
     full 2-hop row set on device first). Mirrors ``into_probe`` semantics
     exactly, including the swapped-orientation half with loops dropped for
     undirected closes.
-
-    ``dense``: ``keys`` is an int16[N*N] edge-MULTIPLICITY array instead of
-    the sorted key array (``GraphIndex.edge_bitmap``) — one gather per probe
-    replaces two binary searches on host backends. Parallel edges are
-    supported: the gathered value IS the count, summed exactly like the
-    searchsorted hi-lo range.
 
     ``nvalid`` (traced, optional): true emission count when ``total`` is a
     BUCKETED static size — pad lanes are sanitized and counted dead."""
@@ -463,9 +457,6 @@ def into_close_count(
 
     def probe_count(s, t, ok):
         probe = s * num_nodes + t
-        if dense:
-            got = jnp.take(keys, probe).astype(jnp.int64)
-            return jnp.sum(jnp.where(ok, got, 0))
         lo = jnp.searchsorted(keys, probe, side="left")
         hi = jnp.searchsorted(keys, probe, side="right")
         return jnp.sum(jnp.where(ok, hi - lo, 0).astype(jnp.int64))
@@ -480,14 +471,12 @@ def into_close_count(
     jax.jit,
     static_argnames=(
         "total", "src_is_base", "num_nodes", "mask_idx", "sub_idx", "sub_cur",
-        "dense",
     ),
 )
 def into_close_count_unique(
     rp, ci, eo, pos, deg, akey, mask, keys, keys_by_orig, prevs,
     total: int, src_is_base: bool, num_nodes: int,
-    mask_idx: tuple, sub_idx: tuple, sub_cur: bool, dense: bool = False,
-    nvalid=None,
+    mask_idx: tuple, sub_idx: tuple, sub_cur: bool, nvalid=None,
 ):
     """``into_close_count`` with openCypher relationship-uniqueness enforced
     IN the fused program (the reference gets the same semantics from explicit
@@ -522,12 +511,9 @@ def into_close_count_unique(
         ok = ok & (orig != prevs_r[i])
     s, t = (a, nbr) if src_is_base else (nbr, a)
     probe = s * num_nodes + t
-    if dense:
-        cnt = jnp.take(keys, probe).astype(jnp.int64)
-    else:
-        lo = jnp.searchsorted(keys, probe, side="left")
-        hi = jnp.searchsorted(keys, probe, side="right")
-        cnt = (hi - lo).astype(jnp.int64)
+    lo = jnp.searchsorted(keys, probe, side="left")
+    hi = jnp.searchsorted(keys, probe, side="right")
+    cnt = (hi - lo).astype(jnp.int64)
     subbed = []
     if sub_cur:
         cnt = cnt - (jnp.take(keys_by_orig, orig) == probe).astype(jnp.int64)
@@ -1014,41 +1000,6 @@ def distinct_pairs_count_final(
     return bounds + (valid_n > 0).astype(jnp.int64)
 
 
-@partial(jax.jit, static_argnames=("total", "use_a", "use_c", "num_nodes"))
-def distinct_bitmap_final(
-    rp, ci, pos, deg, akey, mask,
-    total: int, use_a: bool, use_c: bool, num_nodes: int, nvalid=None,
-):
-    """Host-backend variant of ``distinct_pairs_count_final``: scatter the
-    packed endpoint keys into a presence bitmap and popcount — one random
-    write per row beats the values-only sort's log(n) compare-exchange
-    passes on CPU (SF1: ~20M rows sorted in ~2s vs ~0.3s scattered). The
-    TPU keeps the sort form (``lax.sort`` is fast there, scatter is not).
-    Masked rows land in a spill slot past the counted range."""
-    row, edge = _expand_rows(jnp.take(rp, pos), deg, total)
-    if nvalid is not None:
-        live = _live_lanes(total, nvalid)
-        row = jnp.where(live, row, 0)
-        edge = jnp.where(live, edge, 0)
-    nbr = jnp.take(ci, edge).astype(jnp.int64)
-    if use_a and use_c:
-        key = jnp.take(akey, row) * num_nodes + nbr
-        size = num_nodes * num_nodes
-    elif use_a:
-        key = jnp.take(akey, row)
-        size = num_nodes
-    else:
-        key = nbr
-        size = num_nodes
-    present = jnp.take(mask, nbr) if mask is not None else None
-    if nvalid is not None:
-        present = live if present is None else (present & live)
-    if present is not None:
-        key = jnp.where(present, key, size)
-    bitmap = jnp.zeros(size + 1, bool).at[key].set(True)
-    return jnp.sum(bitmap[:size].astype(jnp.int64))
-
-
 @partial(jax.jit, static_argnames=("total", "mask_idx"))
 def unique_hop_materialize(
     rp, ci, eo, pos, deg, akey, mask, prevs, total: int, mask_idx: tuple,
@@ -1289,71 +1240,6 @@ def mxu_distinct_pairs(a1, a2, present, mask_b, mask_c, block: int):
     return lax.fori_loop(
         0, n // block, body, jnp.asarray(0, jnp.int64)
     )
-
-
-@jax.jit
-def _mxu_tile_acc(p2, a1_slice, a2_k):
-    """One (block, block) @ (block, Npad) contraction step, f32 accumulate."""
-    return p2 + jnp.dot(a1_slice, a2_k, preferred_element_type=jnp.float32)
-
-
-@jax.jit
-def _mxu_close_finish(p2, c_i, mask_c, mult_i):
-    prod = p2 * c_i.astype(jnp.float32) * mask_c[None, :].astype(jnp.float32)
-    row = jnp.sum(prod.astype(jnp.float64), axis=1)
-    return jnp.sum(jnp.round(row).astype(jnp.int64) * mult_i)
-
-
-@jax.jit
-def _mxu_distinct_finish(p2, mask_c, pres_i):
-    hit = (p2 > 0.5) & (mask_c[None, :] > 0.5) & pres_i[:, None]
-    return jnp.sum(hit.astype(jnp.int64))
-
-
-def _mxu_tiled_p2(t1, t2, mask_b):
-    """Shared tiled contraction: yields each row block's (i, P2_i) where
-    P2_i = (A1[Bi, :] masked) @ A2 accumulated in f32, one (block, block)
-    @ (block, Npad) MXU matmul per k — no (Npad, Npad) matrix resident."""
-    block, npad, nb = t1.block, t1.npad, t1.nblocks
-    mb = jnp.ones(npad, jnp.bfloat16) if mask_b is None else mask_b
-    for i in range(nb):
-        a1_i = t1.tile(i) * mb[None, :]
-        p2 = jnp.zeros((block, npad), jnp.float32)
-        for k in range(nb):
-            a1_slice = lax.dynamic_slice_in_dim(a1_i, k * block, block, 1)
-            p2 = _mxu_tile_acc(p2, a1_slice, t2.tile(k))
-        yield i, p2
-
-
-def mxu_close_count_tiled(t1, t2, tc, mult, mask_b, mask_c):
-    """Tiled variant of ``mxu_close_count``: the three adjacencies arrive
-    as ``DenseTiles`` row-block providers. Lifts the dense tier's
-    node-count cap (graphs larger than ``dense_adj``'s limit still ride
-    the MXU)."""
-    from ...runtime.faults import fault_point
-
-    block = t1.block
-    mc = jnp.ones(t1.npad, jnp.bfloat16) if mask_c is None else mask_c
-    acc = 0
-    for i, p2 in _mxu_tiled_p2(t1, t2, mask_b):
-        mult_i = lax.dynamic_slice_in_dim(mult, i * block, block, 0)
-        fault_point("mxu_tile")  # per-row-block scalar sync below
-        acc += int(_mxu_close_finish(p2, tc.tile(i), mc, mult_i))
-    return acc
-
-
-def mxu_distinct_pairs_tiled(t1, t2, present, mask_b, mask_c):
-    """Tiled variant of ``mxu_distinct_pairs`` (see above)."""
-    from ...runtime.faults import fault_point
-
-    block = t1.block
-    mc = jnp.ones(t1.npad, jnp.bfloat16) if mask_c is None else mask_c
-    acc = 0
-    for i, p2 in _mxu_tiled_p2(t1, t2, mask_b):
-        pres_i = lax.dynamic_slice_in_dim(present, i * block, block, 0)
-        fault_point("mxu_tile")  # per-row-block scalar sync below
-        acc += int(_mxu_distinct_finish(p2, mc, pres_i))
-    return acc
 
 
 @partial(jax.jit, static_argnames=("k", "name"))

@@ -80,8 +80,7 @@ from .graph_index import (
     rekey_element_expr,
 )
 
-# which tier answered each multiway-intersect pull — bench.py reports these
-# per rung (wcoj_count / wcoj_materialize / wcoj_factorized / wcoj_shadow)
+# which tier answered each multiway-intersect pull
 WCOJ_TIER_COUNTS = CounterView(
     _OBS_REGISTRY.counter(
         "tpu_cypher_wcoj_tier_total",
@@ -829,12 +828,10 @@ class MultiwayIntersectOp(_FusedExpandBase):
         ):
             # WCOJ's edge is avoiding the MATERIALIZED intermediate. A
             # pure count never materializes on the binary side either
-            # when a fused counting tier is in reach (the CPU native
-            # stamping kernels, or the dense MXU A@A tier under its node
-            # cap) — those count the blowup without ever building it, and
-            # measure faster than sum(min-deg) probing. Auto mode hands
-            # the count back to the classic plan; force keeps the pure
-            # WCOJ path (the bench's wcoj-vs-binary rung, differentials).
+            # when a fused counting tier is in reach (the dense MXU A@A
+            # tier under its node cap) — it counts the blowup without ever
+            # building it. Auto mode hands the count back to the classic
+            # plan; force keeps the pure WCOJ path (differentials).
             # ONLY single-close shapes hand back: the classic fused tiers
             # count one cycle close, so a multi-close count (clique4+)
             # would shadow into the materialized blowup (the 878M-row
@@ -873,21 +870,19 @@ class MultiwayIntersectOp(_FusedExpandBase):
 
 def _fused_binary_count_available(gi: GraphIndex) -> bool:
     """Will the CLASSIC plan answer a pure cycle-close count through a
-    fused counting tier that never materializes the intermediate? True on
-    the CPU backend (the native stamping kernels in ``expand_op`` — the
-    0.06s-at-SF1 path) and whenever the dense MXU ``A @ A`` tier is live
-    under ``dense_adj``'s node cap. In both cases the binary side dodges
-    the blowup WCOJ exists to avoid, and its per-edge stamping/matmul
-    beats per-lane sorted probing — so auto mode should not steal the
-    count. Materializing shapes are untouched: there the binary plan
-    really does build the blowup and the multiway intersection wins."""
+    fused counting tier that never materializes the intermediate? True
+    whenever the dense MXU ``A @ A`` tier is live under ``dense_adj``'s
+    node cap: the binary side then dodges the blowup WCOJ exists to
+    avoid, and its matmul beats per-lane sorted probing — so auto mode
+    should not steal the count. Materializing shapes are untouched: there
+    the binary plan really does build the blowup and the multiway
+    intersection wins."""
+    from ...optimizer.cost import mxu_dense_node_cap
     from .expand_op import _mxu_dense_mode
 
-    if jax.default_backend() == "cpu":
-        return True
-    # dense_adj's size gate (max_nodes=16384): past it the dense form is
-    # declined and the binary plan falls back to materializing frontiers
-    return _mxu_dense_mode() and 0 < gi.num_nodes <= 16384
+    # dense_adj's size gate: past it the dense form is declined and the
+    # binary plan falls back to materializing frontiers
+    return _mxu_dense_mode() and 0 < gi.num_nodes <= mxu_dense_node_cap()
 
 
 def _est_binary_blowup(gi: GraphIndex, ctx, types_key, rev: bool) -> int:

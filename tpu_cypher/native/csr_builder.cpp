@@ -134,163 +134,4 @@ int32_t build_csr(const int64_t* node_ids, int64_t n,
     return 0;
 }
 
-// Stamped 2-hop DISTINCT-endpoints count: the host-tier replacement for
-// materialize-20M-rows-then-sort (engine analog of a merge-free boolean
-// SpGEMM row count). One pass over the path space with an O(N) timestamp
-// array that lives in cache: stamp[c] == a marks pair (a, c) as seen.
-// PRECONDITION (checked by the ctypes wrapper): equal akeys are contiguous
-// (each source one run), so a stamp from an earlier source can never be
-// confused with the current one.
-//   rp1/ci1: hop-1 CSR (frontier -> b), rp2/ci2: hop-2 CSR (b -> c)
-//   frontier/akeys: compact position + distinct-group key per input row
-//   mask1/mask2: optional bool masks on b / c (null = unrestricted)
-//   use_a/use_c: which endpoints the DISTINCT covers
-int64_t two_hop_distinct(const int32_t* rp1, const int32_t* ci1,
-                         const int32_t* rp2, const int32_t* ci2,
-                         const int64_t* frontier, const int64_t* akeys,
-                         int64_t nf, int64_t n, int32_t use_a, int32_t use_c,
-                         const uint8_t* mask1, const uint8_t* mask2) {
-    std::vector<int64_t> stamp(n, -1);
-    int64_t cnt = 0;
-    int64_t last_counted_a = -1;
-    for (int64_t i = 0; i < nf; i++) {
-        int64_t a = use_a ? akeys[i] : 0;  // !use_a: one global dedup group
-        if (!use_c && use_a && a == last_counted_a) continue;
-        int64_t p = frontier[i];
-        bool found = false;
-        for (int32_t e1 = rp1[p]; e1 < rp1[p + 1] && !(found && !use_c); e1++) {
-            int32_t b = ci1[e1];
-            if (mask1 && !mask1[b]) continue;
-            for (int32_t e2 = rp2[b]; e2 < rp2[b + 1]; e2++) {
-                int32_t c = ci2[e2];
-                if (mask2 && !mask2[c]) continue;
-                if (!use_c) { found = true; break; }
-                if (stamp[c] != a) {
-                    stamp[c] = a;
-                    cnt++;
-                }
-            }
-        }
-        if (!use_c && found) {
-            cnt++;
-            last_counted_a = a;
-        }
-    }
-    return cnt;
-}
-
-// Stamped 2-hop + ExpandInto close count (directed triangles / 2-hop
-// cycles): per source a, pre-stamp the closing endpoints x reachable by a
-// closing edge (rpc/cic = the close CSR oriented FROM a) with their edge
-// multiplicities, then every surviving 2-hop path (a, b, c) adds the
-// multiplicity of closing edges at c. Matches the searchsorted probe's
-// hi-lo semantics exactly, parallel edges included. Same grouped-akeys
-// precondition as two_hop_distinct.
-int64_t two_hop_close_count(const int32_t* rp1, const int32_t* ci1,
-                            const int32_t* rp2, const int32_t* ci2,
-                            const int32_t* rpc, const int32_t* cic,
-                            const int64_t* frontier, const int64_t* akeys,
-                            int64_t nf, int64_t n,
-                            const uint8_t* mask1, const uint8_t* mask2) {
-    std::vector<int64_t> stamp(n, -1);
-    std::vector<int32_t> mult(n, 0);
-    int64_t cnt = 0;
-    int64_t stamped_a = -1;
-    for (int64_t i = 0; i < nf; i++) {
-        int64_t a = akeys[i];
-        if (a != stamped_a) {
-            for (int32_t e = rpc[a]; e < rpc[a + 1]; e++) {
-                int32_t x = cic[e];
-                if (stamp[x] != a) {
-                    stamp[x] = a;
-                    mult[x] = 0;
-                }
-                mult[x]++;
-            }
-            stamped_a = a;
-        }
-        int64_t p = frontier[i];
-        for (int32_t e1 = rp1[p]; e1 < rp1[p + 1]; e1++) {
-            int32_t b = ci1[e1];
-            if (mask1 && !mask1[b]) continue;
-            for (int32_t e2 = rp2[b]; e2 < rp2[b + 1]; e2++) {
-                int32_t c = ci2[e2];
-                if (mask2 && !mask2[c]) continue;
-                if (stamp[c] == a) cnt += mult[c];
-            }
-        }
-    }
-    return cnt;
-}
-
-// Bounded var-length walk count with relationship-distinctness (openCypher
-// path isomorphism): iterative DFS per frontier row over the CSR, counting
-// walks of length in [lo, hi] whose far node passes the label mask. The
-// walked-edge stack holds canonical scan rows (eo) — undirected walks share
-// one scan row per relationship, so reuse checks are direction-agnostic —
-// and is at most `hi` deep, so the distinctness check is a linear scan of a
-// register-resident array. Replaces materializing every partial-walk level
-// on host backends (the device frontier loop keeps TPU/mesh paths).
-int64_t varlen_count_forbid(const int32_t* rp, const int32_t* ci,
-                            const int64_t* eo, const int64_t* frontier,
-                            int64_t nf, int64_t lo, int64_t hi,
-                            const uint8_t* far_mask,
-                            const int64_t* forbid, int64_t nfb);
-
-int64_t varlen_count(const int32_t* rp, const int32_t* ci, const int64_t* eo,
-                     const int64_t* frontier, int64_t nf,
-                     int64_t lo, int64_t hi, const uint8_t* far_mask) {
-    return varlen_count_forbid(rp, ci, eo, frontier, nf, lo, hi, far_mask,
-                               nullptr, 0);
-}
-
-// varlen_count with per-frontier-row forbidden edges: forbid is row-major
-// [nf x nfb] canonical scan rows (-1 = unconstrained) that row i's walks may
-// not use — the openCypher isomorphism between a var-length and the fixed
-// relationships already bound in its input row (the device tier seeds the
-// same values into the walked-edge masks).
-int64_t varlen_count_forbid(const int32_t* rp, const int32_t* ci,
-                            const int64_t* eo, const int64_t* frontier,
-                            int64_t nf, int64_t lo, int64_t hi,
-                            const uint8_t* far_mask,
-                            const int64_t* forbid, int64_t nfb) {
-    if (hi < 1 || hi > 64 || nfb < 0) return -1;  // caller falls back
-    int64_t count = 0;
-    std::vector<int64_t> estack(hi + 1);
-    std::vector<int32_t> vstack(hi + 1);
-    std::vector<int32_t> epos(hi + 1);
-    for (int64_t i = 0; i < nf; i++) {
-        int32_t s = (int32_t)frontier[i];
-        const int64_t* fb = forbid ? forbid + i * nfb : nullptr;
-        int depth = 0;
-        vstack[0] = s;
-        epos[0] = rp[s];
-        while (depth >= 0) {
-            if (epos[depth] < rp[vstack[depth] + 1]) {
-                int32_t e = epos[depth]++;
-                int64_t orig = eo[e];
-                bool dup = false;
-                for (int64_t k = 0; k < nfb; k++)
-                    if (fb[k] == orig) { dup = true; break; }
-                if (!dup)
-                    for (int k = 0; k < depth; k++)
-                        if (estack[k] == orig) { dup = true; break; }
-                if (dup) continue;
-                int32_t nb = ci[e];
-                int d1 = depth + 1;
-                if (d1 >= lo && (!far_mask || far_mask[nb])) count++;
-                if (d1 < hi) {
-                    estack[depth] = orig;
-                    vstack[d1] = nb;
-                    epos[d1] = rp[nb];
-                    depth = d1;
-                }
-            } else {
-                depth--;
-            }
-        }
-    }
-    return count;
-}
-
 }  // extern "C"

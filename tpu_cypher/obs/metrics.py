@@ -365,8 +365,8 @@ class MetricsRegistry:  # shared-by: lanes
         return out
 
     def flat(self) -> Dict[str, float]:
-        """One flat {"name{a=b}": number} dict — the bench.py JSON-line
-        shape (histograms flatten to _count/_sum/_p50/_p95/_max keys)."""
+        """One flat {"name{a=b}": number} dict (histograms flatten to
+        _count/_sum/_p50/_p95/_max keys)."""
         out: Dict[str, float] = {}
         with self._lock:
             metrics = list(self._metrics.values())

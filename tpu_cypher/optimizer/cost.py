@@ -27,8 +27,8 @@ hand override, detected via ``ConfigOption.overridden``):
 * join-order search (``joinorder.py``) composes :class:`CostModel` steps
   instead of trusting syntax order;
 * MXU tier gating — :func:`mxu_dense_node_cap` (modelled from the HBM
-  budget when one is set) and :func:`mxu_tiled_node_cap` replace the
-  fixed node caps in ``graph_index.dense_adj`` / ``expand_op``.
+  budget when one is set) replaces the fixed node cap in
+  ``graph_index.dense_adj``.
 """
 
 from __future__ import annotations
@@ -39,7 +39,6 @@ from ..utils.config import (
     BROADCAST_LIMIT,
     MEM_BUDGET,
     MXU_DENSE_MAX,
-    MXU_TILED_MAX,
     WCOJ_MIN_ROWS,
 )
 from .stats import GraphStatistics
@@ -278,7 +277,7 @@ def broadcast_build_limit(n_l: int, nsh: int) -> int:
     return max(limit, min(crossover, 1 << 20))
 
 
-# -- MXU tier node caps (backend/tpu/graph_index.py, expand_op.py) --------
+# -- MXU tier node cap (backend/tpu/graph_index.py) ------------------------
 
 
 def mxu_dense_node_cap() -> int:
@@ -300,15 +299,6 @@ def mxu_dense_node_cap() -> int:
     # Npad^2 * 2 B (bf16) <= budget / 4, Npad a block multiple
     npad = int((budget / 8) ** 0.5) // block * block
     return max(block, min(npad, 1 << 16))
-
-
-def mxu_tiled_node_cap() -> int:
-    """Node-count ceiling for the TILED MXU close-count tier (row-block
-    tiles, no full dense matrix — the cap bounds total FLOPs, not memory).
-    ``TPU_CYPHER_MXU_TILED_MAX`` is honored whether pinned or defaulted;
-    routing through the cost model keeps the gate a single decision
-    point beside the dense cap it backstops."""
-    return int(MXU_TILED_MAX.get())
 
 
 # -- serve admission (serve/scheduler.estimate_cost_bytes) ----------------

@@ -177,18 +177,12 @@ PALLAS_MODE = declare(
     "TPU_CYPHER_PALLAS", "auto", str, help="kernel tier: auto | interpret | off"
 )
 
-# MXU dense-expand tiers (backend/tpu/expand_op.py)
+# MXU dense-expand tier (backend/tpu/expand_op.py)
 MXU_DENSE = declare(
     "TPU_CYPHER_MXU_DENSE",
     "auto",
     str,
     help="dense MXU expand: auto | 1 | force | off",
-)
-MXU_TILED_MAX = declare(
-    "TPU_CYPHER_MXU_TILED_MAX",
-    1 << 17,
-    int,
-    help="node-count ceiling for the tiled MXU close-count tier",
 )
 
 # MXU dense-adjacency node cap (backend/tpu/graph_index.py dense_adj).
