@@ -221,6 +221,20 @@ def test_segment_aggregate_drop_in_compiles(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("k", [5, J.SEGMENT_DENSE_MAX_GROUPS])
+@pytest.mark.parametrize("name", ["min", "sum"])
+def test_dense_segment_aggregate_compiles_without_a_scatter(one_chip, name, k):
+    """The 64-bit aggregates the kernel cannot take: up to
+    ``SEGMENT_DENSE_MAX_GROUPS`` groups the chip's compiler is handed one
+    fused compare-select-reduce, no scatter and no ``k x n`` temporary."""
+    compiled = J.segment_aggregate.lower(
+        one_chip((NODES,), I64), one_chip((NODES,), BOOL), None,
+        one_chip((NODES,), I64), name=name, kind=J.I64, k=k,
+    ).compile()
+    assert " scatter(" not in compiled.as_text()  # the op, not a name
+    assert compiled.memory_analysis().temp_size_in_bytes < NODES * 8
+
+
 def test_sixty_four_bit_planes_are_refused(one_chip):
     """Why int64/float64 aggregates decline by eligibility instead of
     riding the kernel: a custom call's 64-bit operand cannot be lowered.
@@ -237,22 +251,28 @@ def test_sixty_four_bit_planes_are_refused(one_chip):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize(
+    "k,scatters", [(7, False), (J.SEGMENT_DENSE_MAX_GROUPS + 1, True)],
+    ids=["dense", "scatter"],
+)
 @pytest.mark.parametrize("name", ["count", "sum", "max"])
-def test_sharded_segment_agg_compiles_for_four_chips(four_chips, name):
+def test_sharded_segment_agg_compiles_for_four_chips(four_chips, name, k, scatters):
     """``parallel/agg.py``: per-shard segment partials combined over the
-    mesh — the collective is in the compiled text. (``max`` once used
-    ``lax.pmax``, which this lowering refuses for int64: only SUM
-    all-reduces of 64-bit integers are lowered.)"""
+    mesh — the collective is in the compiled text, and a scatter only past
+    ``SEGMENT_DENSE_MAX_GROUPS``. (``max`` once used ``lax.pmax``, which
+    this lowering refuses for int64: only SUM all-reduces of 64-bit
+    integers are lowered.)"""
     from tpu_cypher.parallel.agg import _agg_fn
 
     mesh, shape = four_chips
     rows = NODES  # divisible by 4, as ingest pads it
-    fn = _agg_fn(mesh, "rows", name, False, 7)
-    compiled = fn.lower(
+    fn = _agg_fn(mesh, "rows", name, False, k)
+    text = fn.lower(
         shape((rows,), I64, P("rows")), shape((rows,), BOOL, P("rows")),
         shape((rows,), I64, P("rows")),
-    ).compile()
-    assert "all-reduce" in compiled.as_text()
+    ).compile().as_text()
+    assert "all-reduce" in text
+    assert (" scatter(" in text) == scatters  # the op, not a name
 
 
 @pytest.mark.parametrize("graph", GRAPHS)

@@ -327,6 +327,85 @@ def test_sharded_agg_tier_runs_and_matches(meshed_odd):
     assert _counter("tpu_cypher_mesh_agg_total") > before
 
 
+def _declines():
+    return {
+        k: v for k, v in _OBS.flat().items()
+        if k.startswith("tpu_cypher_mesh_declines_total")
+    }
+
+
+def _valued_persons(session, n):
+    """``n`` persons: ``uid`` unique, ``age`` one of 60, ``v`` near 2^62
+    with every ninth null, ``ok`` a BOOL."""
+    i = np.arange(n, dtype=np.int64)
+    v = ((1 << 62) - i * 977) * np.where(i % 2, 1, -1)
+    table = session.table_cls.from_columns({
+        "nid": (i * 7 + 3).tolist(),
+        "uid": (i * 11 + (5 << 41)).tolist(),
+        "age": (i * 13 % 60 + 20).tolist(),
+        "v": [None if j % 9 == 4 else int(x) for j, x in enumerate(v)],
+        "ok": (i % 3 == 0).tolist(),
+    })
+    mapping = (
+        NodeMappingBuilder.on("nid").with_implied_label("Person")
+        .with_property_key("uid").with_property_key("age")
+        .with_property_key("v").with_property_key("ok").build()
+    )
+    return session.read_from(ElementTable(mapping, table))
+
+
+@pytest.fixture(scope="module")
+def valued():
+    """The same persons on one device and row-sharded over eight; more of
+    them than ``SEGMENT_DENSE_MAX_GROUPS``, and not a multiple of eight."""
+    import jax
+
+    from tpu_cypher.backend.tpu import jit_ops as J
+
+    n = J.SEGMENT_DENSE_MAX_GROUPS + 203
+    mesh = make_row_mesh(jax.devices()[:8])
+    single = _valued_persons(CypherSession.tpu(), n)
+    with use_mesh(mesh):
+        sharded = _valued_persons(CypherSession.tpu(), n)
+    return mesh, single, sharded
+
+
+@pytest.mark.parametrize(
+    "key,form", [("a.age", "dense"), ("a.uid", "scatter")],
+    ids=["under_the_constant", "past_the_constant"],
+)
+def test_sharded_aggregates_equal_single_device_bit_for_bit(valued, key, form):
+    """On both sides of ``SEGMENT_DENSE_MAX_GROUPS`` the per-shard partials
+    (dense compare-and-reduce under it, the scatter past it) combine to
+    the single-device columns, value for value; six aggregators ride the
+    sharded tier, each counted by its form, and nothing is handed back."""
+    from tpu_cypher.obs import trace as obs_trace
+
+    mesh, single, sharded = valued
+    q = (
+        f"MATCH (a:Person) RETURN {key} AS k, count(a.v) AS c, sum(a.v) AS s, "
+        "min(a.v) AS lo, max(a.v) AS hi, avg(a.age) AS m, max(a.ok) AS any "
+        "ORDER BY k"
+    )
+    want = [dict(r) for r in single.cypher(q).records.collect()]
+    aggs = _counter("tpu_cypher_mesh_agg_total")
+    declines = _declines()
+    forms = {
+        f: obs_trace.SEGMENT_REDUCE.value(form=f) for f in ("dense", "scatter")
+    }
+    with use_mesh(mesh):
+        got = [dict(r) for r in sharded.cypher(q).records.collect()]
+    assert got == want
+    assert [type(r["m"]) for r in got] == [type(r["m"]) for r in want]
+    assert len(got) == (60 if form == "dense" else len(want)) > 0
+    assert _counter("tpu_cypher_mesh_agg_total") == aggs + 6
+    assert _declines() == declines
+    for f, before in forms.items():
+        assert obs_trace.SEGMENT_REDUCE.value(form=f) == before + (
+            6 if f == form else 0
+        )
+
+
 def test_sharded_distinct_count_tier():
     """Table-level DISTINCT count under the mesh hash-repartitions the
     packed equivalence keys across shards (``tpu_cypher_mesh_distinct_total``

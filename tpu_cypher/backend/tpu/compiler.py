@@ -153,16 +153,19 @@ class TpuEvaluator:
         sub_vars: List[E.Expr] = []
         subs: List[E.Expr] = []
 
-        def walk(e):
+        # a loop, not a closure that calls itself: such a closure is a
+        # reference cycle, and what it holds (here the evaluator, so its
+        # table's device columns) then outlives the call until the cyclic
+        # collector happens to run
+        stack = [expr]
+        while stack:  # pre-order, children left to right
+            e = stack.pop()
             subs.append(e)
             if isinstance(e, E.Param):
                 param_names.append(e.name)
             if isinstance(e, E.Var):
                 sub_vars.append(e)
-            for c in getattr(e, "children", ()) or ():
-                walk(c)
-
-        walk(expr)
+            stack.extend(reversed(getattr(e, "children", ()) or ()))
         # only the REFERENCED params feed the key and the closure (a cached
         # entry must not pin an unrelated 100MB parameter for the process
         # lifetime)
@@ -334,26 +337,25 @@ class TpuEvaluator:
         out: Dict[str, None] = {}
         tcols = self.table._cols
 
-        def visit(e):
+        stack = [expr]  # a loop for ``_jit_cache_key``'s reason
+        while stack:  # pre-order, children left to right
+            e = stack.pop()
             col = self.header.get(e) if self.header is not None else None
             if col is not None and col in tcols:
                 out[col] = None
                 if not isinstance(e, E.Var):
-                    return  # mapped non-var: children irrelevant
+                    continue  # mapped non-var: children irrelevant
             if isinstance(e, E.Var) and self.header is not None:
                 if self.header.has_path(e.name):
                     # path materialization walks entity columns; decode all
                     for c in tcols:
                         out[c] = None
-                    return
+                    continue
                 for sub in self.header.expressions_for(e):
                     c = self.header.get(sub)
                     if c is not None and c in tcols:
                         out[c] = None
-            for child in getattr(e, "children", ()) or ():
-                visit(child)
-
-        visit(expr)
+            stack.extend(reversed(getattr(e, "children", ()) or ()))
         return list(out)
 
     def _eval_device(self, expr: E.Expr) -> Column:

@@ -1395,6 +1395,81 @@ def order_permutation(datas, valids, kinds, ascs):
 # grouped aggregation (count/sum/avg/stdev/min/max) as one program per agg
 # ---------------------------------------------------------------------------
 
+# the largest number of groups a segment reduction takes as a dense
+# compare-and-reduce: the largest k measured at which it beats the scatter
+# twice over at both sizes (my chip run, PR 31, TPU v5 lite, device ms of
+# one int64 reduction, sum / min, the trace's median of five):
+#               n = 448,626                 n = 2^22
+#   scatter     32.8 / 33.1 (k = 5),        251.0 / 252.6,
+#               34.3 / 33.4 (k = 1,024)     262.6 / 263.1   61-73 ns a row
+#   dense k=5   0.042 / 0.026               0.376 / 0.223
+#         64    0.095 / 0.099               0.778 / 0.605
+#         256   0.679 / 0.680               2.615 / 2.081
+#         1,024 1.385 / 1.390               10.24 / 8.10
+#         4,096 5.519 / 5.538 (6.0x)        41.10 / 32.38 (6.1x / 7.8x)
+#        16,384 22.05 / 22.12 (1.5x)        164.2 / 129.3 (1.5x / 2.0x)
+SEGMENT_DENSE_MAX_GROUPS = 4096
+
+
+def segment_reduce_form(k: int, dtype, op: str) -> str:
+    """The form ``segment_reduce`` takes for ``k`` groups of ``dtype``
+    values under ``op`` (``sum | min | max``), chosen from those alone, at
+    trace time and — by the same function — on the host that counts:
+
+    * ``dense``: every group compares its id against every row and reduces
+      what matches; no scatter, ``k`` compare-selects a row. Taken where
+      the combine is free of order (integer sums, every min and max), up
+      to ``SEGMENT_DENSE_MAX_GROUPS`` groups;
+    * ``scatter``: ``jax.ops.segment_<op>``, which the chip serialises row
+      by row whatever ``k``. A float sum keeps it at every ``k``: its
+      order of addition is part of the result's bits."""
+    if op == "sum" and not jnp.issubdtype(dtype, jnp.integer):
+        return "scatter"
+    return "dense" if k <= SEGMENT_DENSE_MAX_GROUPS else "scatter"
+
+
+def segment_aggregate_form(name: str, dtype, k: int) -> str:
+    """The form of the reduction that is aggregator ``name``'s own in
+    ``segment_aggregate``, over values of ``dtype``: what the host counts
+    (``tpu_cypher_segment_reduce_total``)."""
+    if name in ("stdev", "stdevp"):
+        return "scatter"  # the sum of the squared differences is a float's
+    if name == "count":
+        return segment_reduce_form(k, jnp.int64, "sum")
+    return segment_reduce_form(
+        k, dtype, name if name in ("min", "max") else "sum"
+    )
+
+
+def _reduce_identity(dtype, op: str):
+    """What ``jax.ops.segment_<op>`` leaves in an empty group."""
+    if op == "sum":
+        return jnp.zeros((), dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.max if op == "min" else info.min, dtype)
+
+
+def segment_reduce(values, seg, k: int, op: str):
+    """``jax.ops.segment_<op>(values, seg, num_segments=k)``, bit for bit,
+    in the form ``segment_reduce_form`` names (traced helper). A row whose
+    id lies outside ``[0, k)`` contributes nothing; an empty group holds the
+    op's identity."""
+    if segment_reduce_form(k, values.dtype, op) == "scatter":
+        return getattr(jax.ops, f"segment_{op}")(values, seg, num_segments=k)
+    # 32-bit ids for the k compares of a row (a 64-bit compare costs the
+    # chip two), cut to that width as the scatter cuts its indices
+    seg32 = seg.astype(jnp.int32)
+    hit = seg32[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]
+    identity = _reduce_identity(values.dtype, op)
+    picked = jnp.where(hit, values[None, :], identity)
+    # one fused compare-select-reduce: no (k, n) array is ever stored
+    if op == "sum":
+        return jnp.sum(picked, axis=1, dtype=values.dtype)
+    reduce = jnp.min if op == "min" else jnp.max
+    return reduce(picked, axis=1, initial=identity)
+
 
 @partial(jax.jit, static_argnames=("name", "kind", "k"))
 def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
@@ -1408,15 +1483,14 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
     # the phases carry scopes of their own (metadata only: a kept device
     # trace tells them apart): count, sum, minmax, intness
     with jax.named_scope("count"):
-        cnt = jax.ops.segment_sum(v.astype(jnp.int64), seg_j, num_segments=k)
+        cnt = segment_reduce(v.astype(jnp.int64), seg_j, k, "sum")
     if name == "count":
         return cnt, None, None, None
     if name in ("sum", "avg", "stdev", "stdevp"):
         zero = jnp.zeros((), data.dtype)
         with jax.named_scope("sum"):
-            ssum = jax.ops.segment_sum(
-                jnp.where(v, data, zero), seg_j, num_segments=k
-            )
+            # a float sum stays the scatter (``segment_reduce_form``)
+            ssum = segment_reduce(jnp.where(v, data, zero), seg_j, k, "sum")
         if name == "sum":
             if kind == F64:
                 # Cypher sum over no values is the INTEGER 0, and the sum
@@ -1426,8 +1500,8 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
                 if iflag is not None:
                     int_if_valid = jnp.where(v, iflag, True)
                     all_int = (
-                        jax.ops.segment_min(
-                            int_if_valid.astype(jnp.int8), seg_j, num_segments=k
+                        segment_reduce(
+                            int_if_valid.astype(jnp.int8), seg_j, k, "min"
                         )
                         == 1
                     )
@@ -1456,9 +1530,7 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
     if kind == F64:
         isnan = jnp.isnan(d) & v
         nn_valid = v & ~isnan
-        nan_cnt = jax.ops.segment_sum(
-            isnan.astype(jnp.int64), seg_j, num_segments=k
-        )
+        nan_cnt = segment_reduce(isnan.astype(jnp.int64), seg_j, k, "sum")
     else:
         nn_valid = v
         nan_cnt = None
@@ -1469,8 +1541,8 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
     )
     if name == "min":
         with jax.named_scope("minmax"):
-            agged = jax.ops.segment_min(
-                jnp.where(nn_valid, d, big), seg_j, num_segments=k
+            agged = segment_reduce(
+                jnp.where(nn_valid, d, big), seg_j, k, "min"
             )
         if nan_cnt is not None:
             # all-NaN group: min is NaN (NaN sorts above numbers)
@@ -1478,8 +1550,8 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
     else:
         low = -big if kind != STR else -jnp.ones((), d.dtype)
         with jax.named_scope("minmax"):
-            agged = jax.ops.segment_max(
-                jnp.where(nn_valid, d, low), seg_j, num_segments=k
+            agged = segment_reduce(
+                jnp.where(nn_valid, d, low), seg_j, k, "max"
             )
         if nan_cnt is not None:
             # any NaN: NaN is the maximum under Cypher orderability
@@ -1494,10 +1566,9 @@ def segment_aggregate(data, valid, iflag, seg_j, name: str, kind: str, k: int):
         # int_flag of the first row matching the aggregate
         with jax.named_scope("intness"):
             cand = nn_valid & (d == jnp.take(agged, seg_j))
-            first_row = jax.ops.segment_min(
+            first_row = segment_reduce(
                 jnp.where(cand, jnp.arange(n, dtype=jnp.int64), n),
-                seg_j,
-                num_segments=k,
+                seg_j, k, "min",
             )
             safe_row = jnp.clip(first_row, 0, max(n - 1, 0))
             out_iflag = jnp.take(iflag, safe_row) & (first_row < n)

@@ -1,10 +1,11 @@
 """Pallas masked segment-reduce kernel for grouped aggregation.
 
-``jit_ops.segment_aggregate`` reduces a (value column, group index) pair
-with ``jax.ops.segment_sum``/``segment_min``/``segment_max`` — XLA lowers
-those as scatter-reduces, which the TPU serializes (SURVEY: scatter is the
-one primitive the VPU cannot vectorize). The hand-scheduled version never
-scatters: the rows stream through VMEM in ``(_TILE_ROWS, 128)`` tiles, and
+``jax.ops.segment_sum``/``segment_min``/``segment_max`` lower as
+scatter-reduces, which the TPU serializes (SURVEY: scatter is the one
+primitive the VPU cannot vectorize); ``jit_ops.segment_aggregate`` takes
+them only past ``SEGMENT_DENSE_MAX_GROUPS`` groups and is a plain dense
+compare-and-reduce under it. The hand-scheduled version never scatters
+either: the rows stream through VMEM in ``(_TILE_ROWS, 128)`` tiles, and
 each 128-lane row is compared against a group iota broadcast along
 sublanes, folding into ONE VMEM-resident ``(k_pad, 128)`` accumulator of
 per-(group, lane) partials that every grid step revisits. The 128 lane
@@ -16,7 +17,7 @@ Lane width: Mosaic has no 64-bit lanes (and XLA's x64 rewriter cannot
 split a custom call's int64 operand), so the kernel takes 32-bit planes
 only. That covers ``count`` over any column (a 0/1 int32 mask) and
 min/max over BOOL (0/1 int32); int64 / float64 / dict-coded values keep
-the scatter formulation by eligibility — a 64-bit kernel is never handed
+the plain formulation by eligibility — a 64-bit kernel is never handed
 to the compiler.
 
 Masking discipline (docs/pad-invariants.md): kernel tile pad lanes carry
@@ -154,13 +155,16 @@ def _segment_aggregate_pallas(
     return agged.astype(bool), cnt > 0, None, None
 
 
-def segment_aggregate(data, valid, iflag, seg_j, *, name: str, kind: str, k: int):
+def segment_aggregate(
+    data, valid, iflag, seg_j, *, name: str, kind: str, k: int, plain=None
+):
     """Dispatching drop-in for ``jit_ops.segment_aggregate`` (same 4-tuple
-    contract). Eligible: count over anything; min/max over BOOL — the
+    contract; ``plain``: the caller's own call of it, for one that wants to
+    know that it ran). Eligible: count over anything; min/max over BOOL — the
     aggregates whose working planes are 32-bit (see the module docstring).
     GROUP BY cardinality is capped (``TPU_CYPHER_PALLAS_MAX_GROUPS``): the
     (k_pad, 128) int32 accumulator is 128 KiB (32 vregs) at the declared
-    default; larger GROUP BYs keep the scatter formulation."""
+    default; larger GROUP BYs keep the plain formulation."""
     eligible = (
         0 < k <= int(PALLAS_MAX_GROUPS.get())
         and data.ndim == 1
@@ -174,8 +178,8 @@ def segment_aggregate(data, valid, iflag, seg_j, *, name: str, kind: str, k: int
         lambda interpret: _segment_aggregate_pallas(
             data, valid, seg_j, name=name, k=k, interpret=interpret
         ),
-        lambda: J.segment_aggregate(
+        plain or (lambda: J.segment_aggregate(
             data, valid, iflag, seg_j, name=name, kind=kind, k=k
-        ),
+        )),
         eligible=eligible,
     )

@@ -22,7 +22,7 @@ fused kernels use under jit:
 * distinct      = stable device lexsort + neighbour-difference flags ->
                   first-occurrence gather
 * group         = device lexsort factorization (same equivalence classes as
-                  distinct) + ``jax.ops.segment_*`` aggregation
+                  distinct) + segment reductions (``jit_ops.segment_reduce``)
 * skip/limit    = contiguous device slices (no gather)
 * with_columns  = compiled expressions
 
@@ -1335,11 +1335,10 @@ class TpuTable(Table):
     def _group_device(self, by, aggregations, header, parameters) -> "TpuTable":
         """Grouped aggregation as device segment ops: group assignment reuses
         ``distinct``'s device lexsort factorization (null/NaN equivalence
-        classes), then count/sum/avg/min/max run as ``jax.ops.segment_*``
-        over the group index — the TPU replacement for the engines' shuffle
-        aggregate (reference ``Table.group``)."""
-        import jax
-
+        classes), then count/sum/avg/min/max run as segment reductions
+        over the group index (``jit_ops.segment_reduce``: dense over a small
+        number of groups, a scatter past it) — the TPU replacement for the
+        engines' shuffle aggregate (reference ``Table.group``)."""
         from ...ir import expr as E
 
         for _, agg in aggregations:
@@ -1395,13 +1394,15 @@ class TpuTable(Table):
         for out_col, agg in aggregations:
             name = agg.name.lower()
             if agg.expr is None:  # count(*): every row counts
-                out_cols[out_col] = Column(
-                    I64,
-                    jax.ops.segment_sum(
-                        jnp.ones(n, jnp.int64), seg_j, num_segments=k
-                    ),
-                    None,
+                _obs_trace.note_agg_form(
+                    J.segment_aggregate_form("count", seg_j.dtype, k)
                 )
+                # the jitted count over the group index alone (the values
+                # give it their length, no validity: every row is counted)
+                cnt, _, _, _ = J.segment_aggregate(
+                    seg_j, None, None, seg_j, name="count", kind=I64, k=k
+                )
+                out_cols[out_col] = Column(I64, cnt, None)
                 continue
             col = ev.eval(agg.expr)
             if col.kind == OBJ:
@@ -1498,13 +1499,26 @@ class TpuTable(Table):
             # a float combine is not associative: the global path keeps it
             _note_mesh_decline("agg", "not_integer")
         # kernel tier: the Pallas masked segment reduce when eligible
-        # (dispatch falls back to the jax.ops scatter formulation; see
+        # (dispatch falls back to the plain ``segment_aggregate``; see
         # backend/tpu/pallas/aggregate.py)
         from .pallas import segment_aggregate
 
+        plain_ran = []
+
+        def plain():
+            plain_ran.append(True)
+            return J.segment_aggregate(
+                data, col.valid, col.int_flag, seg_j, name=name, kind=kind, k=k
+            )
+
         out_data, out_valid, out_iflag, iflag_any = segment_aggregate(
-            data, col.valid, col.int_flag, seg_j, name=name, kind=kind, k=k
+            data, col.valid, col.int_flag, seg_j, name=name, kind=kind, k=k,
+            plain=plain,
         )
+        if plain_ran:  # an aggregator the kernel took is in its own counter
+            _obs_trace.note_agg_form(
+                J.segment_aggregate_form(name, data.dtype, k)
+            )
         if name == "count":
             return Column(I64, out_data, None)
         if out_iflag is not None and not bool(iflag_any):

@@ -73,6 +73,16 @@ COUNT_CHAIN_HOPS = M.REGISTRY.counter(
     labels=("form",),
 )
 
+SEGMENT_REDUCE = M.REGISTRY.counter(
+    "tpu_cypher_segment_reduce_total",
+    "aggregators dispatched on the device by the form of their segment "
+    "reduction: dense (compare-and-reduce over a small number of groups, "
+    "no scatter) or scatter (jax.ops.segment_*)",
+    labels=("form",),
+)
+for _form in ("dense", "scatter"):  # both series export from the start
+    SEGMENT_REDUCE.inc(0, form=_form)
+
 
 class Span:
     """One node of the tree: a named, timed region with attributes."""
@@ -321,6 +331,17 @@ def note_rows(
             true_rows, padded_rows,
             shards=shards, local_true=local_true, local_padded=local_padded,
         )
+
+
+def note_agg_form(form: str) -> None:
+    """Count one aggregator dispatched on the device by the form of its
+    segment reduction (``jit_ops.segment_aggregate_form``): in the registry,
+    and as ``agg_form`` (form -> aggregators) on the innermost open span."""
+    SEGMENT_REDUCE.inc(form=form)
+    sp = _SPAN.get()
+    if sp is not None:
+        forms = sp.attrs.setdefault("agg_form", {})
+        forms[form] = forms.get(form, 0) + 1
 
 
 def note_site(site: str) -> None:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..backend.tpu.jit_ops import segment_aggregate_form, segment_reduce
 from ..obs import trace as _obs_trace
 from ..obs.metrics import REGISTRY as _REGISTRY
 from ..runtime.faults import fault_point
@@ -74,17 +75,13 @@ def _agg_fn(mesh, axis: str, name: str, is_bool: bool, k: int):
 
     def local(data, valid, seg):
         # pad rows staged valid=False: they contribute the combine identity
-        cnt = jax.ops.segment_sum(
-            valid.astype(jnp.int64), seg, num_segments=k
-        )
+        cnt = segment_reduce(valid.astype(jnp.int64), seg, k, "sum")
         cnt = lax.psum(cnt, axis)
         if name == "count":
             return cnt, cnt
         if name in ("sum", "avg"):
-            ssum = jax.ops.segment_sum(
-                jnp.where(valid, data, jnp.zeros((), data.dtype)),
-                seg,
-                num_segments=k,
+            ssum = segment_reduce(
+                jnp.where(valid, data, jnp.zeros((), data.dtype)), seg, k, "sum"
             )
             return lax.psum(ssum, axis), cnt
         # min / max: same sentinels as the global segment_aggregate so
@@ -92,14 +89,10 @@ def _agg_fn(mesh, axis: str, name: str, is_bool: bool, k: int):
         d = data.astype(jnp.int8) if is_bool else data
         big = jnp.asarray(jnp.iinfo(d.dtype).max, d.dtype)
         if name == "min":
-            agged = jax.ops.segment_min(
-                jnp.where(valid, d, big), seg, num_segments=k
-            )
+            agged = segment_reduce(jnp.where(valid, d, big), seg, k, "min")
             agged = jnp.min(_every_shard(agged), axis=0)
         else:
-            agged = jax.ops.segment_max(
-                jnp.where(valid, d, -big), seg, num_segments=k
-            )
+            agged = segment_reduce(jnp.where(valid, d, -big), seg, k, "max")
             agged = jnp.max(_every_shard(agged), axis=0)
         return agged, cnt
 
@@ -166,6 +159,7 @@ def sharded_segment_agg(
         note_exchange("agg", nsh * (k + partials) * 8)
     _MESH_AGG_TOTAL.inc()
     _obs_trace.note("agg_shards", nsh)
+    _obs_trace.note_agg_form(segment_aggregate_form(name, d_np.dtype, k))
     if name == "count":
         return out, None
     if name == "sum":
@@ -197,10 +191,10 @@ def _weighted_premultiply(data, valid, weight):
 
 @partial(jax.jit, static_argnames=("k",))
 def _weighted_segment_sums(pre_sum, pre_cnt, seg_j, k: int):
-    wcnt = jax.ops.segment_sum(pre_cnt, seg_j, num_segments=k)
+    wcnt = segment_reduce(pre_cnt, seg_j, k, "sum")
     if pre_sum is None:
         return None, wcnt
-    return jax.ops.segment_sum(pre_sum, seg_j, num_segments=k), wcnt
+    return segment_reduce(pre_sum, seg_j, k, "sum"), wcnt
 
 
 def weighted_segment_partials(data, valid, weight, seg_j, k: int):

@@ -11,6 +11,7 @@ graphs and views, and supports driving tables (``readFrom``)."""
 
 from __future__ import annotations
 
+import copy
 import itertools
 import os
 import re
@@ -956,29 +957,32 @@ class CypherSession:
         sharing the immutable pieces (headers, expressions, source tables,
         graph indexes). The cached plan itself is never mutated, so lazy
         CypherResults handed out earlier keep their own state."""
-        import copy
-
         old_ctx = root.context
         new_ctx = RelationalRuntimeContext(
             old_ctx.resolve_graph, dict(parameters), old_ctx.table_cls
         )
-        memo: Dict[int, Any] = {}
+        return CypherSession._clone_op(root, {}, new_ctx)
 
-        def walk(op):
-            got = memo.get(id(op))
-            if got is not None:
-                return got
-            new = copy.copy(op)
-            memo[id(op)] = new  # before children: DAG sharing preserved
-            new.children = tuple(walk(c) for c in op.children)
-            new._table = None
-            if hasattr(new, "_plan"):
-                new._plan = None
-            if getattr(new, "_ctx", None) is not None:
-                new._ctx = new_ctx
-            return new
-
-        return walk(root)
+    @staticmethod
+    def _clone_op(op, memo: Dict[int, Any], new_ctx):
+        """``_clone_plan``'s walk. Not a closure that calls itself: that is
+        a reference cycle, and its ``memo`` would keep every operator of the
+        copy — after the run, each with its table's device columns — alive
+        until the cyclic collector happens to run."""
+        got = memo.get(id(op))
+        if got is not None:
+            return got
+        new = copy.copy(op)
+        memo[id(op)] = new  # before children: DAG sharing preserved
+        new.children = tuple(
+            CypherSession._clone_op(c, memo, new_ctx) for c in op.children
+        )
+        new._table = None
+        if hasattr(new, "_plan"):
+            new._plan = None
+        if getattr(new, "_ctx", None) is not None:
+            new._ctx = new_ctx
+        return new
 
     def cypher(
         self,
