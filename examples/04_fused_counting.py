@@ -8,6 +8,10 @@ the physical level and runs the WHOLE plan as one XLA program:
   per-node completion counts (``path_count_chain``): degrees from
   ``row_ptr``, a gather and a sum under a whole frontier, a scatter-free
   CSR SpMV elsewhere; one dispatch + one scalar fetch;
+* ``count(*)`` over a chain ``WHERE a <> c`` / ``NOT (a)-[:KNOWS]->(c)``
+  between two of its nodes -> the same chain count, less the wedges that
+  break the constraint (a cached count per edge for ``a = c``, an int8
+  matrix product for the closing edge): no row of the chain is built;
 * ``WITH DISTINCT a, c RETURN count(*)`` -> per-hop (key, position)
   programs ending in a packed values-only sort count;
 * ``ORDER BY ... LIMIT k`` -> one ``lax.top_k`` over a packed rank.
@@ -67,6 +71,11 @@ def main():
          "MATCH (a:Person)-[:KNOWS]->(b)-[:KNOWS]->(c) RETURN count(*) AS c"),
         ("3-hop count (fused SpMV chain)",
          "MATCH (a:Person)-[:KNOWS]->(b)-[:KNOWS]->(c)-[:KNOWS]->(d) RETURN count(*) AS c"),
+        ("2-hop count, ends distinct (chain count less the two-cycles)",
+         "MATCH (a:Person)-[:KNOWS]->(b)-[:KNOWS]->(c) WHERE a <> c RETURN count(*) AS c"),
+        ("2-hop count, ends distinct and not friends (closing program on the MXU)",
+         "MATCH (a:Person)-[:KNOWS]->(b)-[:KNOWS]->(c) "
+         "WHERE a <> c AND NOT (a)-[:KNOWS]->(c) RETURN count(*) AS c"),
         ("distinct endpoint pairs (fused sort count)",
          "MATCH (a:Person)-[:KNOWS]->(b)-[:KNOWS]->(c) WITH DISTINCT a, c RETURN count(*) AS pairs"),
         ("var-length walk count (fused frontier loop)",

@@ -193,6 +193,48 @@ def test_wcoj_range_count_compiles(one_chip):
     ).compile()
 
 
+# the constrained count chain at LSQB's Person side of SNB SF10 (the cell
+# lsqb-sf10-person.lsqb-chain under pow2 buckets): 83,179 nodes of four labels
+# in 2**17 ids, 3.9M KNOWS lanes in 2**22, 65,645 persons in a side of 66,048
+LSQB_IDS, LSQB_LANES, LSQB_SIDE = 1 << 17, 1 << 22, 66_048
+
+
+def test_wedge_close_sum_compiles_at_lsqb_sf10(one_chip):
+    """The closing program: 129 blocks of 512 rows, each an int8 product
+    (512 x 66,048) @ (66,048 x 66,048) with int32 sums on the MXU; one
+    block's product (0.14 GB) and its rows are all the program holds beside
+    the 4.4 GB matrix."""
+    rp, ci = one_chip((LSQB_IDS + 1,), I32), one_chip((LSQB_LANES,), I32)
+    ids32, ids64 = one_chip((LSQB_IDS,), I32), one_chip((LSQB_IDS,), I64)
+    compiled = J.wedge_close_sum.lower(
+        one_chip((LSQB_SIDE, LSQB_SIDE), jnp.int8), ids32, rp, ci, ci, ids32,
+        one_chip((130,), I32), one_chip((LSQB_IDS,), BOOL),
+        rp, ci, ci, one_chip((LSQB_LANES,), BOOL), ids64, ids64,
+        block=512, width1=1 << 16, width_c=1 << 16,
+    ).compile()
+    text = compiled.as_text()
+    assert "s8[" in text and "s32[512,66048]" in text  # an int8 product
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_constrained_chain_helpers_compile_at_lsqb_sf10(one_chip):
+    """The matrix build (a 3.9M-lane scatter into 4.3 GB of int8), the
+    per-lane back counts, the two-cycle sum and the chain's node weights."""
+    rp, ci = one_chip((LSQB_IDS + 1,), I32), one_chip((LSQB_LANES,), I32)
+    ids32, ids64 = one_chip((LSQB_IDS,), I32), one_chip((LSQB_IDS,), I64)
+    mask = one_chip((LSQB_IDS,), BOOL)
+    J.dense_adjacency.lower(ci, ci, rp, ids32, size=LSQB_SIDE).compile()
+    J.csr_lane_rows.lower(rp, ci).compile()
+    J.csr_pair_runs.lower(rp, ci, ci).compile()
+    J.csr_back_counts.lower(
+        rp, ci, ci, one_chip((LSQB_LANES,), I64), num_nodes=LSQB_IDS
+    ).compile()
+    J.two_cycle_sum.lower(rp, ci, ci, ci, mask, ids64).compile()
+    J.chain_node_weights.lower(
+        mask, ((rp, ci, mask),), num_nodes=LSQB_IDS
+    ).compile()
+
+
 # ---------------------------------------------------------------------------
 # the Pallas tier
 # ---------------------------------------------------------------------------

@@ -635,8 +635,30 @@ class TpuEvaluator:
             return l.cast_f64(), r.cast_f64()
         raise TpuUnsupportedExpr(f"compare {l.kind} vs {r.kind}")
 
+    def _element_ids(self, e: E.Expr) -> Optional[Column]:
+        """The id column of a node or relationship variable (an element is
+        its id: two variables bind the same element where their ids are
+        equal), or None for any other expression."""
+        if not isinstance(e, E.Var) or self.header is None:
+            return None
+        try:
+            v = self.header.var(e.name)
+        except (KeyError, ValueError):
+            return None
+        m = v.cypher_type.material if v.cypher_type is not None else None
+        if not isinstance(m, (T.CTNodeType, T.CTRelationshipType)):
+            return None
+        if self.header.has_path(e.name):
+            return None
+        col = self.header.get(self.header.id_expr(v))
+        return self.table._cols.get(col) if col is not None else None
+
     def _equality(self, expr) -> Column:
-        l, r = self.eval(expr.lhs), self.eval(expr.rhs)
+        ids = self._element_ids(expr.lhs), self._element_ids(expr.rhs)
+        if None not in ids:
+            l, r = ids
+        else:
+            l, r = self.eval(expr.lhs), self.eval(expr.rhs)
         if OBJ in (l.kind, r.kind):
             raise TpuUnsupportedExpr("equality on object columns")
         if l.kind == DUR and r.kind == DUR:

@@ -268,6 +268,56 @@ def _collected_pairs(hops, extra=()):
     return tuple(seen)
 
 
+_WEDGE_LANES_TOTAL = _OBS_REGISTRY.counter(
+    "tpu_cypher_chain_constraint_wedge_lanes_total",
+    "candidate (a, c) pairs the closing program of constrained count chains "
+    "went over: the cells of the wedge matrix it computed, block by block",
+)
+
+
+# (equality, closing edge) -> (is the whole chain's count a term, the sign
+# of each other term): inclusion and exclusion over ``same`` (wedges with
+# a = c), ``closed`` (wedges an a-c edge closes) and ``same_closed`` (both)
+_CONSTRAINED_TERMS = {
+    ("neq", None): (True, {"same": -1}),
+    ("eq", None): (False, {"same": 1}),
+    (None, "closed"): (False, {"closed": 1}),
+    (None, "open"): (True, {"closed": -1}),
+    ("neq", "closed"): (False, {"closed": 1, "same_closed": -1}),
+    ("neq", "open"): (True, {"same": -1, "closed": -1, "same_closed": 1}),
+    ("eq", "closed"): (False, {"same_closed": 1}),
+    ("eq", "open"): (False, {"same": 1, "same_closed": -1}),
+}
+
+
+def _constrained_pair(names, constraints):
+    """The constraints of a constrained chain count read against the
+    nodes of the pattern's path (``CsrExpandOp._pattern_path``): ``(i,
+    equality, edge)`` — the pair is ``names[i]`` / ``names[i + 2]``,
+    ``equality`` None, ``"neq"`` or ``"eq"``, ``edge`` None or ``(types_key,
+    from_first, negated)`` with ``from_first`` saying whether the closing
+    edge leaves ``names[i]``. None: the constraints are not all on one pair
+    two hops apart, or hold two of a kind."""
+    at = {name: i for i, name in enumerate(names)}
+    pair, equality, edge = None, None, None
+    for kind, x, y, *rest in constraints:
+        if x not in at or y not in at or abs(at[x] - at[y]) != 2:
+            return None
+        if pair not in (None, {x, y}):
+            return None
+        pair = {x, y}
+        if kind == "edge":
+            if edge is not None:
+                return None
+            types_key, negated = rest
+            edge = (types_key, at[x] < at[y], negated)
+        else:
+            if equality is not None:
+                return None
+            equality = kind
+    return min(at[n] for n in pair), equality, edge
+
+
 class _FusedExpandBase(RelationalOperator):
     """Shared machinery: header delegation + fallback + column assembly."""
 
@@ -617,33 +667,38 @@ class CsrExpandOp(_FusedExpandBase):
             row, nbr, orig = J.tree_take((row, nbr, orig), idx)
         return row, nbr, orig, total
 
-    def _chain_hops(self) -> List["CsrExpandOp"]:
-        """Walk the input chain of directly-stacked CsrExpandOps over the
-        same graph (deepest last). Intermediate output columns are
-        irrelevant for counting: each op's row MULTISET is exactly its
-        child's multiset expanded, so a per-node multiplicity vector carries
-        complete information down the chain."""
+    def _stacked_expands(self, linked: bool) -> List["CsrExpandOp"]:
+        """This op and the CsrExpandOps directly stacked under it over the
+        same graph (cache wraps are identity), deepest last. ``linked``:
+        only as far as each hop expands FROM its child's far node —
+        branching patterns ((x)-->(y), (x)-->(z)) stack expands whose
+        frontier is NOT the previous far end."""
         from ...relational.ops import CacheOp
 
         hops: List[CsrExpandOp] = [self]
         node = self
         while True:
             child = node.children[0]
-            while isinstance(child, CacheOp):  # cache wraps are identity
+            while isinstance(child, CacheOp):
                 child = child.children[0]
             if (
                 isinstance(child, CsrExpandOp)
                 and child._graph_obj is self._graph_obj
-                # linkage: this hop must expand FROM the child's far node —
-                # branching patterns ((x)-->(y), (x)-->(z)) stack expands
-                # whose frontier is NOT the previous far end, and composing
-                # their SpMVs would count the wrong paths
-                and node.frontier_fld == child.far_fld
+                and (not linked or node.frontier_fld == child.far_fld)
             ):
                 hops.append(child)
                 node = child
                 continue
             return hops
+
+    def _chain_hops(self) -> List["CsrExpandOp"]:
+        """Walk the input chain of directly-stacked, linked CsrExpandOps
+        (deepest last): composing the SpMVs of hops that are not linked
+        would count the wrong paths. Intermediate output columns are
+        irrelevant for counting: each op's row MULTISET is exactly its
+        child's multiset expanded, so a per-node multiplicity vector carries
+        complete information down the chain."""
+        return self._stacked_expands(linked=True)
 
     def _count_via_chain(self, gi: GraphIndex, ctx) -> int:
         """Whole-chain count as ONE jitted program (``path_count_chain``):
@@ -762,6 +817,180 @@ class CsrExpandOp(_FusedExpandBase):
             _MESH_EXPAND_TOTAL.inc()
         with _obs_trace.sync("expand"):  # the read that waits for the chain
             return int(n_dev)
+
+    def _pattern_path(self):
+        """The expands stacked under this one (same graph, caches looked
+        through) read as a path of the PATTERN, whatever node the plan
+        started from and in whatever order it grew: ``(names, steps,
+        base)`` with ``steps[j] = (types_key, reverse, op)`` the CSR whose
+        rows are ``names[j]`` and whose columns are ``names[j + 1]``, and
+        ``base`` the deepest expand (its input is the rows everything
+        starts from, bound to ``base.frontier_fld``). None: an undirected
+        hop, a node bound twice, a pattern that branches."""
+        ops = self._stacked_expands(linked=False)
+        base = ops[-1]
+        links: Dict[str, list] = {base.frontier_fld: []}
+        for op in reversed(ops):  # the order executed
+            if op.undirected or op.frontier_fld not in links or op.far_fld in links:
+                return None
+            links[op.frontier_fld].append((op.far_fld, op.backwards, op))
+            links[op.far_fld] = [(op.frontier_fld, not op.backwards, op)]
+        ends = [name for name, out in links.items() if len(out) == 1]
+        if len(ends) != 2 or any(len(out) > 2 for out in links.values()):
+            return None
+        names, steps, came_by = [ends[0]], [], None
+        while len(names) <= len(ops):
+            far, reverse, op = next(
+                link for link in links[names[-1]] if link[2] is not came_by
+            )
+            names.append(far)
+            steps.append((op.types_key, reverse, op))
+            came_by = op
+        return names, steps, base
+
+    def chain_constraint_count(self, constraints) -> Optional[int]:
+        """count(*) of this chain's rows under ``constraints`` between two
+        of its nodes two hops apart (``relational.ops._node_pair_constraint``:
+        ``a <> c``, ``a = c``, ``[NOT] (a)-[:T]->(c)``, at most one of each
+        kind), WITHOUT a row of the chain:
+
+            count = sum over wedges (a -> b -> c) of left[a] * right[c] * [constraints]
+
+        with ``left[a]`` the ways the pattern's hops before the pair reach
+        ``a`` and ``right[c]`` the completions of the hops after it from
+        ``c`` (``jit_ops.chain_node_weights``). By inclusion and exclusion
+        over the whole chain's count, the wedges with ``a = c`` (one cached
+        count per first-hop lane, ``GraphIndex.back_counts``) and the
+        wedges an edge between ``a`` and ``c`` closes (EXISTS: parallel
+        closing edges count once; ``jit_ops.wedge_close_sum``, dense, on
+        the MXU). None where the shape does not fit — an undirected hop, a
+        branching pattern, a pair further apart, relationship uniqueness
+        the chain enforces itself (self-loops under a type walked twice),
+        a closing matrix too large for the device, a mesh — and the caller
+        builds the rows."""
+        try:
+            path = self._pattern_path()
+            if path is None:
+                return None
+            names, steps, base = path
+            if _collected_pairs([op for _, _, op in steps]):
+                return None
+            read = _constrained_pair(names, constraints)
+            if read is None:
+                return None
+            if current_mesh() is not None and mesh_size() > 1:
+                note_decline("expand", "chain_constraint")
+                return None
+            gi = GraphIndex.of(self.graph)
+            with _obs_trace.span(
+                "chain_constraint", kind="kernel", constraints=len(constraints)
+            ):
+                return self._constrained_count(gi, names, steps, base, *read)
+        except (GraphIndexError, TpuBackendError):
+            return None
+
+    def _constrained_count(self, gi, names, steps, base, i, equality, edge):
+        ctx = self.context
+        gi.node_ids(ctx)
+        n = gi.num_nodes
+        if n == 0:
+            return 0
+        fault_point("expand")
+        # what each node of the pattern weighs: its label mask (None: every
+        # node passes), and for the node the plan starts from the input's
+        # rows — the label mask again where the input is a plain node scan,
+        # else their number per node
+        weight = {
+            op.far_fld: gi.label_mask(op.far_labels, ctx) for _, _, op in steps
+        }
+        if base.frontier_scan is not None:
+            weight[base.frontier_fld] = gi.label_mask(base.frontier_scan, ctx)
+        elif base.frontier_fld == names[i + 1]:
+            return None  # the middle of the wedges has to weigh 0 or 1
+        else:
+            in_op = base.children[0]
+            in_t = _flat_in(in_op.table)
+            h = in_op.header
+            id_col = in_t._cols[h.column(h.id_expr(h.var(base.frontier_fld)))]
+            pos, present = gi.compact_of(id_col, ctx)
+            weight[base.frontier_fld] = J.frontier_multiplicity(pos, present, n=n)
+        first, second = steps[i][:2], steps[i + 1][:2]
+        whole, signs = _CONSTRAINED_TERMS[
+            equality, edge and ("open" if edge[2] else "closed")
+        ]
+        if "same_closed" in signs and _graph_loop_free(self._graph_obj, edge[0], ctx):
+            # no node closes on itself: that term is 0 (a host fact)
+            signs = {name: sign for name, sign in signs.items() if name != "same_closed"}
+        if "closed" in signs:
+            types_key, from_first, _ = edge
+            closing = (types_key, not from_first)
+            adjacency = gi.wedge_adjacency(second, ctx)
+            if adjacency is None:
+                return None
+            blocks = gi.wedge_blocks(first, closing, ctx)
+            if (
+                blocks.longest_run > 127  # a block's rows are int8 too
+                # the wedge matrix's cells are int32 sums of int8 products
+                or gi.csr_max_degree(*first, ctx) * adjacency.longest_run
+                >= (1 << 31)
+            ):
+                return None
+        # left: the pattern's first node carried to node i, each step over
+        # the CSR whose rows are the node reached; right: its last node
+        # carried back to node i + 2
+        left = J.chain_node_weights(
+            weight[names[0]],
+            tuple(
+                gi.csr(types, not reverse, ctx)[:2] + (weight[names[j + 1]],)
+                for j, (types, reverse, _) in enumerate(steps[:i])
+            ),
+            num_nodes=n,
+        )
+        right = J.chain_node_weights(
+            weight[names[-1]],
+            tuple(
+                gi.csr(types, reverse, ctx)[:2] + (weight[names[j]],)
+                for j, (types, reverse, _) in reversed(
+                    list(enumerate(steps))[i + 2:]
+                )
+            ),
+            num_nodes=n,
+        )
+        mid_mask = weight[names[i + 1]]
+        rp1, ci1, _ = gi.csr(*first, ctx)
+        rows1 = gi.csr_rows(*first, ctx)
+        terms = {}
+        if "same" in signs or "same_closed" in signs:
+            back = gi.back_counts(first, second, ctx)
+            both = left * right
+            if "same_closed" in signs:  # a = c under a closing edge: a's loop
+                looped = gi.loop_count(edge[0], ctx) > 0
+                terms["same_closed"] = J.two_cycle_sum(
+                    rp1, ci1, rows1, back, mid_mask, jnp.where(looped, both, 0)
+                )
+            if "same" in signs:
+                terms["same"] = J.two_cycle_sum(
+                    rp1, ci1, rows1, back, mid_mask, both
+                )
+        if "closed" in signs:
+            rp_c, ci_c, _ = gi.csr(*closing, ctx)
+            terms["closed"] = J.wedge_close_sum(
+                adjacency.matrix, adjacency.rank, rp1, ci1, rows1,
+                blocks.rank, blocks.block_rows, mid_mask,
+                rp_c, ci_c, gi.csr_rows(*closing, ctx), blocks.first_closing,
+                left, right,
+                block=blocks.block, width1=blocks.width1,
+                width_c=blocks.width_closing,
+            )
+            lanes = blocks.blocks * blocks.block * int(adjacency.matrix.shape[0])
+            _WEDGE_LANES_TOTAL.inc(lanes)
+            _obs_trace.note("form", "dense")
+            _obs_trace.note("wedge_lanes", lanes)
+        n_dev = sum(sign * terms[name] for name, sign in signs.items())
+        # the whole chain's count is the chain's own program, as ever
+        count = self._count_via_chain(gi, ctx) if whole else 0
+        with _obs_trace.sync("expand"):
+            return count + int(n_dev)
 
     def distinct_endpoints_count(self, fields) -> Optional[int]:
         """count(DISTINCT endpoints) over a fused expand chain WITHOUT
@@ -1095,6 +1324,15 @@ class CsrExpandIntoOp(_FusedExpandBase):
             f"({self.source_fld})-[{self.rel_fld}:{t}]{arrow}"
             f"({self.target_fld}) into{uniq}"
         )
+
+    def closing_edge(self) -> Optional[Tuple[str, str, Tuple[str, ...]]]:
+        """``(source, target, types)`` where this is one plain directed
+        relationship between two bound nodes — what a count chain can take
+        as a constraint (``CsrExpandOp.chain_constraint_count``) — else
+        None."""
+        if self.undirected or self.enforced_pairs:
+            return None
+        return self.source_fld, self.target_fld, self.types_key
 
     def _probe(self, gi: GraphIndex, keys, s_pos, t_pos, ok, drop_loops: bool):
         """Closing-edge probe + materialize. Returns ``(row, orig, count)``;

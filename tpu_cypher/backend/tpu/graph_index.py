@@ -27,6 +27,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import time
+from dataclasses import dataclass
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -37,6 +38,7 @@ from ...api import types as T
 from ...ir import expr as E
 from ...obs import trace as _obs_trace
 from ...obs.metrics import REGISTRY as _REGISTRY
+from ...runtime.faults import fault_point
 from .bucketing import ID_SENTINEL, bucket_pad_host
 from .column import Column, TpuBackendError, device_padded
 
@@ -115,6 +117,29 @@ def rekey_element_expr(e: E.Expr, canon: E.Var) -> Optional[E.Expr]:
     return None
 
 
+@dataclass(frozen=True)
+class WedgeAdjacency:
+    """One CSR as a dense matrix (``GraphIndex.wedge_adjacency``)."""
+
+    matrix: Any  # int8[size, size], entries = parallel lanes
+    rank: Any  # int32[num_nodes]: a node's row and column, -1 = has none
+    longest_run: int  # the most parallel lanes any pair has
+
+
+@dataclass(frozen=True)
+class WedgeBlocks:
+    """A first hop's rows in blocks (``GraphIndex.wedge_blocks``)."""
+
+    rank: Any  # int32[num_nodes]: a node's number among the rows, -1 = none
+    block_rows: Any  # int32[blocks + 1]: the node each block starts at
+    first_closing: Any  # bool per closing lane: first of its (a, c) pair
+    longest_run: int  # the most parallel first-hop lanes any pair has
+    block: int
+    blocks: int
+    width1: int  # lanes: the most first-hop lanes of a block, bucketed
+    width_closing: int
+
+
 class GraphIndex:
     """CSR + canonical-scan cache for one RelationalCypherGraph."""
 
@@ -175,6 +200,17 @@ class GraphIndex:
         # types_key -> (sorted global ids, scan-row perm) device arrays:
         # global-rel-id -> canonical scan row (isomorphism forbid masks)
         self._rel_id_index: Dict[Tuple[str, ...], Tuple[Any, Any]] = {}
+        # (types_key, reverse) -> int32 row (node) of each lane of that CSR
+        self._csr_rows: Dict[Tuple[Tuple[str, ...], bool], Any] = {}
+        # (types_key, reverse) -> (bool per lane: first of its (row, col)
+        # pair, the most parallel lanes any pair has)
+        self._pair_runs: Dict[Tuple[Tuple[str, ...], bool], Tuple[Any, int]] = {}
+        # (CSR key, CSR key) -> int32 per lane a -> b of the first CSR: the
+        # lanes b -> a of the second (constrained count chains)
+        self._back_counts: Dict[Tuple[Any, Any], Any] = {}
+        # CSR key(s) -> the dense forms of a constrained count chain
+        self._wedge_adj: Dict[Any, Optional["WedgeAdjacency"]] = {}
+        self._wedge_blocks: Dict[Any, "WedgeBlocks"] = {}
 
     # -- nodes -------------------------------------------------------------
 
@@ -543,6 +579,138 @@ class GraphIndex:
                 )
             self._dense_adj[key] = out
         return self._dense_adj[key]
+
+    # -- constrained count chains (expand_op.chain_constraint_count) -------
+
+    def csr_rows(self, types_key: Tuple[str, ...], reverse: bool, ctx):
+        """Device int32 per lane of ``csr(types_key, reverse)``: its row."""
+        key = (types_key, reverse)
+        if key not in self._csr_rows:
+            from . import jit_ops as J
+
+            rp, ci, _ = self.csr(types_key, reverse, ctx)
+            self._csr_rows[key] = J.csr_lane_rows(rp, ci)
+        return self._csr_rows[key]
+
+    def pair_runs(self, key, ctx) -> Tuple[Any, int]:
+        """Of the CSR ``key``: (device bool per lane, the first lane of its
+        (row, col) pair; the most parallel lanes any pair has)."""
+        if key not in self._pair_runs:
+            from . import jit_ops as J
+
+            fault_point("expand")  # the scalar read below
+            rp, ci, _ = self.csr(*key, ctx)
+            first, longest = J.csr_pair_runs(rp, ci, self.csr_rows(*key, ctx))
+            with _obs_trace.sync("expand"):
+                self._pair_runs[key] = (first, int(longest))
+        return self._pair_runs[key]
+
+    def back_counts(self, first, second, ctx):
+        """Device int32 per lane ``a -> b`` of the CSR ``first`` (a
+        ``(types_key, reverse)``): how many lanes ``b -> a`` the CSR
+        ``second`` holds. A fact of the graph, probed once (two binary
+        searches a lane over ``second``'s sorted keys) and kept."""
+        got = self._back_counts.get((first, second))
+        if got is None:
+            from . import jit_ops as J
+
+            with _build("back_counts"):
+                rp, ci, _ = self.csr(*first, ctx)
+                got = J.csr_back_counts(
+                    rp, ci, self.csr_rows(*first, ctx),
+                    self.edge_keys(second[0], ctx, reverse=second[1]),
+                    num_nodes=self.num_nodes,
+                )
+            self._back_counts[(first, second)] = got
+        return got
+
+    # cells of the dense matrix a constrained count may hold on the device
+    # (int8: 6 GiB of a chip's 16 GB; LDBC SNB SF10's persons take 4.3e9)
+    WEDGE_MAX_CELLS = 6 << 30
+    # first-hop rows a matrix product takes at a time, and the multiple the
+    # matrix's side is padded to (my chip run, PR 32, SNB SF10's KNOWS, int8:
+    # 1.99 s in blocks of 512 over a side of 66,048, 2.09 s at 1,024 over
+    # 66,560, 2.19 s at 2,048 over 67,584; a side of 65,664 = 513 x 128 under
+    # blocks of 1,024 took 2.47 s)
+    WEDGE_BLOCK = 512
+
+    @classmethod
+    def _wedge_side(cls, count: int) -> int:
+        """``count`` rows or columns padded to whole blocks (a small graph:
+        to the MXU's 128)."""
+        step = cls.WEDGE_BLOCK if count > cls.WEDGE_BLOCK else 128
+        return max(-(-count // step) * step, step)
+
+    def wedge_adjacency(self, key, ctx) -> Optional["WedgeAdjacency"]:
+        """The CSR ``key`` as an int8 matrix over the nodes that touch one
+        of its edges, or None: too many of them for ``WEDGE_MAX_CELLS``, or
+        more than 127 parallel edges somewhere (int8)."""
+        if key not in self._wedge_adj:
+            from . import jit_ops as J
+
+            fault_point("expand")  # the build reads a count back
+            with _build("wedge_adjacency") as sp:
+                rp, ci, _ = self.csr(*key, ctx)
+                rp_in, _, _ = self.csr(key[0], not key[1], ctx)
+                rows = self.csr_rows(*key, ctx)
+                touched = (rp[1:] > rp[:-1]) | (rp_in[1:] > rp_in[:-1])
+                rank = jnp.where(
+                    touched, jnp.cumsum(touched, dtype=jnp.int32) - 1, -1
+                )
+                longest = self.pair_runs(key, ctx)[1]
+                with _obs_trace.sync("expand"):
+                    count = int(jnp.sum(touched))
+                size = self._wedge_side(count)
+                sp.note("nodes", count)
+                out = None
+                if size * size <= self.WEDGE_MAX_CELLS and longest <= 127:
+                    out = WedgeAdjacency(
+                        # tpulint: allow[pad-invariant] reason=the matrix's side is padded to whole blocks of WEDGE_BLOCK (_wedge_side), the MXU's lattice, not the row buckets'; one program per graph, as dense_adj's
+                        J.dense_adjacency(rows, ci, rp, rank, size=size),
+                        rank, longest,
+                    )
+                self._wedge_adj[key] = out
+        return self._wedge_adj[key]
+
+    def wedge_blocks(self, first, closing, ctx) -> "WedgeBlocks":
+        """The first hop's rows cut into blocks of ``WEDGE_BLOCK`` for
+        ``jit_ops.wedge_close_sum``, with the closing CSR's lanes of each
+        block beside them (both CSRs are sorted by the same node)."""
+        got = self._wedge_blocks.get((first, closing))
+        if got is None:
+            from .bucketing import round_size
+
+            fault_point("expand")  # the build reads counts back
+            with _build("wedge_blocks"):
+                rp1, ci1, _ = self.csr(*first, ctx)
+                rp_c, ci_c, _ = self.csr(*closing, ctx)
+                n = self.num_nodes
+                has = rp1[1:] > rp1[:-1]
+                rank1 = jnp.where(has, jnp.cumsum(has, dtype=jnp.int32) - 1, -1)
+                with _obs_trace.sync("expand"):
+                    count = int(jnp.sum(has))
+                block = min(self.WEDGE_BLOCK, self._wedge_side(count))
+                nblocks = max(-(-count // block), 1)
+                # the node each block starts at; past the last row: n
+                # tpulint: allow[pad-invariant] reason=whole blocks of first-hop rows (nblocks * block >= count), the closing program's own lattice
+                starts = jnp.nonzero(has, size=nblocks * block, fill_value=n)[0]
+                block_rows = jnp.concatenate([
+                    starts[::block].astype(jnp.int32),
+                    jnp.full(1, n, jnp.int32),
+                ])
+                with _obs_trace.sync("expand"):
+                    w1, wc = (
+                        int(jnp.max(jnp.diff(jnp.take(rp, block_rows))))
+                        for rp in (rp1, rp_c)
+                    )
+                got = WedgeBlocks(
+                    rank1, block_rows, self.pair_runs(closing, ctx)[0],
+                    self.pair_runs(first, ctx)[1], block, nblocks,
+                    min(max(round_size(w1), 8), int(ci1.shape[0])),
+                    min(max(round_size(wc), 8), int(ci_c.shape[0])),
+                )
+            self._wedge_blocks[(first, closing)] = got
+        return got
 
     def csr_max_degree(self, types_key: Tuple[str, ...], reverse: bool, ctx) -> int:
         """Host-cached max degree of one CSR orientation (computed at

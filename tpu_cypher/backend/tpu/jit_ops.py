@@ -855,6 +855,161 @@ def path_count_chain_on_mesh(mesh, axis: str):
 
 
 # ---------------------------------------------------------------------------
+# count chain under a constraint between two of its nodes, two hops apart:
+#   count = sum over wedges (a -> b -> c) of left[a] * right[c] * [constraint]
+# ``left`` / ``right`` are the chain's per-node weights on either side of the
+# pair (``chain_node_weights``); the wedges with a = c are counted from one
+# cached count per edge lane (``two_cycle_sum``), the wedges closed by an
+# edge between a and c on the MXU (``wedge_close_sum``). No row of the chain
+# is built.
+# ---------------------------------------------------------------------------
+
+
+@partial(jax.jit, static_argnames=("num_nodes",))
+def chain_node_weights(start, steps, num_nodes: int):
+    """int64[num_nodes]: ``start`` (None: 1 on every node) carried through
+    ``steps``, each ``(rp, ci, weight)`` — the sum over a node's CSR edges
+    of the far ends' weights, times the node's own: a label mask, a number
+    per node, or None (1)."""
+    w = jnp.ones(num_nodes, jnp.int64) if start is None else start.astype(jnp.int64)
+    for rp, ci, weight in steps:
+        w = _csr_spmv(rp, ci, w)
+        if weight is not None:
+            w = w * weight.astype(jnp.int64)
+    return w
+
+
+def _real_lanes(rp, ci):
+    """bool per edge lane of a CSR: it holds an edge (``rp[-1]`` of them
+    lead the array; the rest is the bucket's pad)."""
+    return jnp.arange(ci.shape[0], dtype=jnp.int32) < rp[-1]
+
+
+@jax.jit
+def csr_lane_rows(rp, ci):
+    """int32 per edge lane of a CSR: the row (node) the lane belongs to; pad
+    lanes past ``rp[-1]`` read the last node and are never live."""
+    lanes = jnp.arange(ci.shape[0], dtype=jnp.int32)
+    rows = jnp.searchsorted(rp[1:], lanes, side="right")
+    return jnp.minimum(rows, rp.shape[0] - 2).astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("num_nodes",))
+def csr_back_counts(rp, ci, rows, keys2, num_nodes: int):
+    """int32 per lane ``a -> b`` of one CSR: how many lanes ``b -> a`` the
+    CSR whose sorted ``row * N + col`` keys are ``keys2`` holds (parallel
+    edges each count). Pad lanes read 0."""
+    live = _real_lanes(rp, ci)
+    q = jnp.clip(ci, 0).astype(jnp.int64) * num_nodes + rows.astype(jnp.int64)
+    return range_count(keys2, q, live)[1].astype(jnp.int32)
+
+
+@jax.jit
+def two_cycle_sum(rp, ci, rows, back, mid_mask, w):
+    """sum over the lanes ``a -> b`` of ``w[a] * [mid_mask[b]] * back``: the
+    weighted number of wedges ``a -> b -> a`` (``back`` from
+    ``csr_back_counts``). One 64-bit gather a lane."""
+    live = _real_lanes(rp, ci)
+    if mid_mask is not None:
+        live = live & jnp.take(mid_mask, jnp.clip(ci, 0))
+    t = jnp.take(w, rows) * back.astype(jnp.int64)
+    return jnp.sum(jnp.where(live, t, 0), dtype=jnp.int64)
+
+
+@jax.jit
+def csr_pair_runs(rp, ci, rows):
+    """(bool per lane: the first lane of its (row, col) pair; the most
+    lanes any pair has). The lanes of a pair are neighbours: the CSR is
+    sorted by (row, col)."""
+    lanes = jnp.arange(ci.shape[0], dtype=jnp.int32)
+    live = _real_lanes(rp, ci)
+    prev_same = jnp.concatenate([
+        jnp.zeros(1, bool), (ci[1:] == ci[:-1]) & (rows[1:] == rows[:-1])
+    ])
+    first = live & ~prev_same
+    run_start = lax.cummax(jnp.where(first, lanes, 0))
+    longest = jnp.max(jnp.where(live, lanes - run_start + 1, 0), initial=0)
+    return first, longest
+
+
+@partial(jax.jit, static_argnames=("size",))
+def dense_adjacency(rows, ci, rp, rank, size: int):
+    """int8[size, size]: the CSR's edges as a matrix over the nodes ``rank``
+    numbers (-1: not among them), each entry the number of parallel lanes
+    (the caller has seen that none passes 127)."""
+    live = _real_lanes(rp, ci)
+    r = jnp.take(rank, rows)
+    c = jnp.take(rank, jnp.clip(ci, 0))
+    r = jnp.where(live & (r >= 0) & (c >= 0), r, size)  # dropped
+    return jnp.zeros((size, size), jnp.int8).at[r, c].add(
+        jnp.ones((), jnp.int8), mode="drop"
+    )
+
+
+def _lane_window(length: int, lo, hi, width: int):
+    """``width`` consecutive lanes of an array of ``length`` that hold
+    [lo, hi) (``hi - lo <= width <= length``), and which of them are in it:
+    the slice may not run off the array, so it starts early where it
+    must."""
+    start = jnp.clip(lo, 0, length - width)
+    lanes = start + jnp.arange(width, dtype=jnp.int32)
+    return start, (lanes >= lo) & (lanes < hi)
+
+
+@partial(jax.jit, static_argnames=("block", "width1", "width_c"))
+def wedge_close_sum(
+    a2, rank2, rp1, ci1, rows1, rank1, block_rows, mid_mask,
+    rp_c, ci_c, rows_c, first_c, left, right,
+    block: int, width1: int, width_c: int,
+):
+    """sum over the closing pairs (a, c) — each once, however many parallel
+    closing lanes — of ``left[a] * right[c] *`` the number of wedges
+    ``a -> b -> c`` (parallel lanes each count, ``mid_mask[b]`` holds).
+
+    Dense: per block of ``block`` first-hop rows the rows' 0/1.. matrix is
+    scattered from the first hop's CSR lanes, multiplied on the MXU by the
+    second hop's whole matrix ``a2`` (int8, int32 sums, exact), and the
+    product is read at the block's closing pairs alone; the product of one
+    block is all that is ever held. ``rank1`` numbers the nodes that have a
+    first-hop lane (the blocks' rows), ``rank2`` those ``a2`` is over;
+    ``block_rows[i]`` is the first node of block ``i``; ``width1`` /
+    ``width_c`` bound a block's first-hop and closing lanes."""
+    size = a2.shape[0]
+    nblocks = block_rows.shape[0] - 1
+
+    def body(i, acc):
+        i = i.astype(jnp.int32)
+        a_lo, a_hi = block_rows[i], block_rows[i + 1]
+        start, live = _lane_window(ci1.shape[0], rp1[a_lo], rp1[a_hi], width1)
+        b = jnp.clip(lax.dynamic_slice(ci1, (start,), (width1,)), 0)
+        r = jnp.take(rank1, lax.dynamic_slice(rows1, (start,), (width1,)))
+        kb = jnp.take(rank2, b)
+        live = live & (kb >= 0)
+        if mid_mask is not None:
+            live = live & jnp.take(mid_mask, b)
+        rows = jnp.zeros((block, size), jnp.int8).at[
+            jnp.where(live, r - i * block, block), jnp.where(live, kb, 0)
+        ].add(jnp.ones((), jnp.int8), mode="drop")
+        with jax.named_scope("wedge_matmul"):
+            wedges = jnp.dot(rows, a2, preferred_element_type=jnp.int32)
+        start, live = _lane_window(ci_c.shape[0], rp_c[a_lo], rp_c[a_hi], width_c)
+        a = lax.dynamic_slice(rows_c, (start,), (width_c,))
+        c = jnp.clip(lax.dynamic_slice(ci_c, (start,), (width_c,)), 0)
+        ra = jnp.take(rank1, a)
+        kc = jnp.take(rank2, c)
+        live = (
+            live & lax.dynamic_slice(first_c, (start,), (width_c,))
+            & (ra >= 0) & (kc >= 0)
+        )
+        at = jnp.where(live, (ra - i * block) * size + kc, 0)
+        found = jnp.take(wedges.reshape(-1), at).astype(jnp.int64)
+        weight = jnp.take(left, a) * jnp.take(right, c)
+        return acc + jnp.sum(jnp.where(live, weight * found, 0), dtype=jnp.int64)
+
+    return lax.fori_loop(0, nblocks, body, jnp.zeros((), jnp.int64))
+
+
+# ---------------------------------------------------------------------------
 # fused var-length expand: per-hop frontier materialize with edge-distinct
 # (isomorphism) masks — SURVEY §5's frontier loop, engine-integrated
 # ---------------------------------------------------------------------------

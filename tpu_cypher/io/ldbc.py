@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import csv
 import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
@@ -92,14 +92,21 @@ def load_snb_csv(directory: str, session, delimiter: str = "|") -> ScanGraph:
     src = [int(r[s_i]) for r in krows]
     dst = [int(r[t_i]) for r in krows]
 
-    return _graph_from_arrays(
+    # LDBC datagen stores KNOWS once per unordered pair; Cypher's SNB queries
+    # traverse it both ways, so both orientations are stored (the reference
+    # models undirected traversal as a union of orientations at plan time;
+    # storing both keeps every hop a plain directed expand)
+    return graph_from_tables(
         session,
-        np.asarray(ids, dtype=np.int64),
-        person_cols,
-        prop_types,
-        np.asarray(src, dtype=np.int64),
-        np.asarray(dst, dtype=np.int64),
-        undirected_knows=True,
+        {PERSON_LABEL: (
+            np.asarray(ids, dtype=np.int64),
+            {k: (person_cols[k], t) for k, t in prop_types.items()},
+        )},
+        {KNOWS_TYPE: (
+            np.asarray(src + dst, dtype=np.int64),
+            np.asarray(dst + src, dtype=np.int64),
+            {},
+        )},
     )
 
 
@@ -141,85 +148,101 @@ def generate_snb(
 def graph_from_snb_arrays(session, arrays: Dict[str, np.ndarray]) -> ScanGraph:
     """Ingest ``snb_arrays`` output. Columns stay numpy so the bulk
     ingestion path is one H2D copy per column at SF10 scale and beyond."""
-    person_cols: Dict[str, List] = {
-        "id": arrays["ids"],
-        "birthday": arrays["birthday"],
-    }
     # expose the id column as a property too (LDBC queries anchor on
     # ``a.id`` ranges; the bench's var-length source filter does the same)
-    prop_types: Dict[str, T.CypherType] = {
-        "id": T.CTInteger.nullable,
-        "birthday": T.CTInteger.nullable,
+    props: Dict[str, Tuple[Any, T.CypherType]] = {
+        "id": (arrays["ids"], T.CTInteger.nullable),
+        "birthday": (arrays["birthday"], T.CTInteger.nullable),
     }
     if "firstname" in arrays:
-        person_cols["firstname"] = arrays["firstname"].tolist()
-        prop_types["firstname"] = T.CTString.nullable
-    return _graph_from_arrays(
+        props["firstname"] = (arrays["firstname"].tolist(), T.CTString.nullable)
+    return graph_from_tables(
         session,
-        arrays["ids"],
-        person_cols,
-        prop_types,
-        arrays["src"],
-        arrays["dst"],
-        undirected_knows=False,
+        {PERSON_LABEL: (arrays["ids"], props)},
+        {KNOWS_TYPE: (arrays["src"], arrays["dst"], {})},
     )
 
 
-def _graph_from_arrays(
+def graph_from_tables(
     session,
-    ids: np.ndarray,
-    person_cols: Dict[str, List],
-    prop_types: Dict[str, T.CypherType],
-    src: np.ndarray,
-    dst: np.ndarray,
-    undirected_knows: bool,
+    nodes: Mapping[str, Tuple[Any, Mapping[str, Tuple[Any, T.CypherType]]]],
+    relationships: Mapping[
+        str, Tuple[Any, Any, Mapping[str, Tuple[Any, T.CypherType]]]
+    ],
 ) -> ScanGraph:
-    """Assemble the Person/KNOWS ScanGraph. LDBC datagen stores KNOWS once
-    per unordered pair; Cypher's SNB queries traverse it both ways, so
-    ``undirected_knows=True`` materializes both orientations (the reference
-    models undirected traversal as a union of orientations at plan time; for
-    a benchmark-focused loader, storing both directions keeps every hop a
-    plain directed expand)."""
-    if undirected_knows:
-        src, dst = (
-            np.concatenate([src, dst]),
-            np.concatenate([dst, src]),
-        )
-    edge_ids = np.arange(len(src), dtype=np.int64) + EDGE_ID_OFFSET
-    if len(ids) and int(ids.max(initial=0)) >= EDGE_ID_OFFSET:
-        raise DataSourceError("LDBC ids exceed the supported id range")
+    """A ``ScanGraph`` of any number of node and relationship tables, one
+    per label and per relationship type, from host arrays:
 
-    node_table = session.table_cls.from_arrays(person_cols)
-    rel_table = session.table_cls.from_arrays(
-        {"id": edge_ids, "source": src, "target": dst}
-    )
-    schema = (
-        PropertyGraphSchema.empty()
-        .with_node_combination(frozenset({PERSON_LABEL}), prop_types)
-        .with_relationship_type(KNOWS_TYPE, {})
-    )
-    return ScanGraph(
-        [
+    * ``nodes[label] = (ids, {property: (column, cypher_type)})`` — int64
+      element ids, unique over ALL labels (the graph has one id space), and
+      the label's property columns. A property named ``id`` is the id column
+      itself, exposed to queries as ``n.id``;
+    * ``relationships[rel_type] = (source_ids, target_ids, {property:
+      (column, cypher_type)})`` — the endpoints as node ids, one row per
+      stored direction (a loader that wants an undirected edge walkable both
+      ways stores both rows). Relationship ids are assigned here, in a range
+      no node id reaches.
+
+    Numeric and boolean NumPy columns take the bulk path (one copy to the
+    device each); any other column (a list of strings) is decoded per
+    value."""
+    tables: List[ElementTable] = []
+    schema = PropertyGraphSchema.empty()
+    for label, (ids, props) in nodes.items():
+        ids = np.asarray(ids, dtype=np.int64)
+        if len(ids) and int(ids.max()) >= EDGE_ID_OFFSET:
+            raise DataSourceError(
+                f"{label} ids exceed the supported id range (< 2**53)"
+            )
+        cols: Dict[str, Any] = {"id": ids}
+        for key, (column, _) in props.items():
+            if key != "id":
+                cols[key] = column
+        schema = schema.with_node_combination(
+            frozenset({label}), {k: t for k, (_, t) in props.items()}
+        )
+        tables.append(
             ElementTable(
                 NodeMapping(
                     id_key="id",
-                    implied_labels=frozenset({PERSON_LABEL}),
-                    property_mapping=tuple((k, k) for k in prop_types),
+                    implied_labels=frozenset({label}),
+                    property_mapping=tuple((k, k) for k in props),
                 ),
-                node_table,
-            ),
+                session.table_cls.from_arrays(cols),
+            )
+        )
+    next_rel_id = EDGE_ID_OFFSET
+    for rel_type, (src, dst, props) in relationships.items():
+        src = np.asarray(src, dtype=np.int64)
+        dst = np.asarray(dst, dtype=np.int64)
+        if len(src) != len(dst):
+            raise DataSourceError(
+                f"{rel_type}: {len(src)} sources for {len(dst)} targets"
+            )
+        cols = {
+            "id": np.arange(len(src), dtype=np.int64) + next_rel_id,
+            "source": src,
+            "target": dst,
+        }
+        next_rel_id += len(src)
+        for key, (column, _) in props.items():
+            cols[f"p_{key}"] = column  # clear of id / source / target
+        schema = schema.with_relationship_type(
+            rel_type, {k: t for k, (_, t) in props.items()}
+        )
+        tables.append(
             ElementTable(
                 RelationshipMapping(
                     id_key="id",
                     source_key="source",
                     target_key="target",
-                    rel_type=KNOWS_TYPE,
+                    rel_type=rel_type,
+                    property_mapping=tuple((k, f"p_{k}") for k in props),
                 ),
-                rel_table,
-            ),
-        ],
-        schema,
-    )
+                session.table_cls.from_arrays(cols),
+            )
+        )
+    return ScanGraph(tables, schema)
 
 
 # The SNB query shapes the benchmark ladder runs (BASELINE.md configs 2-4)

@@ -45,7 +45,7 @@ _MESH_DECLINES = _REGISTRY.counter(
 # the declines a healthy mesh deployment reads 0 on, exported as zeros
 for _op, _reason in (
     ("join", "overflow"), ("distinct", "overflow"), ("agg", "gate"),
-    ("expand", "unpadded_edges"),
+    ("expand", "unpadded_edges"), ("expand", "chain_constraint"),
 ):
     _MESH_DECLINES.inc(0, op=_op, reason=_reason)
 

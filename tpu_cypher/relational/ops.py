@@ -33,10 +33,14 @@ class RelationalError(Exception):
 # (``rows``); ``AggregateOp`` is the one place that counts
 COUNT_PUSHDOWN = _OBS_REGISTRY.counter(
     "tpu_cypher_count_pushdown_total",
-    "ungrouped count(*) over a filter, an inner join or a DISTINCT: "
-    "answered from the operator's count phase (count) or from its rows (rows)",
+    "ungrouped count(*) over a filter, an inner join, a DISTINCT or an "
+    "expand chain under constraints between two of its nodes (one a "
+    "constraint): answered from the operator's count phase (count) or "
+    "from its rows (rows)",
     labels=("op", "outcome"),
 )
+for _outcome in ("count", "rows"):  # a sound deployment reads 0 rows: exported
+    COUNT_PUSHDOWN.inc(0, op="chain_constraint", outcome=_outcome)
 
 
 @dataclass
@@ -289,6 +293,42 @@ class DropOp(RelationalOperator):
         return self.children[0].table.select(keep)
 
 
+def _peel_cache(op: RelationalOperator) -> RelationalOperator:
+    """``op`` without the CacheOps over it (a cache is the identity)."""
+    while isinstance(op, CacheOp):
+        op = op.children[0]
+    return op
+
+
+class ExistsFlagOp(DropOp):
+    """The top of a planned ``ExistsSubQuery``
+    (``RelationalPlanner._plan_ExistsSubQuery``): the DropOp it is, that
+    also names the plan's parts — the outer rows (``outer``) and the
+    pattern whose existence ``target_field`` reports per outer row
+    (``pattern``) — so that a count over a filter on the flag can ask the
+    pattern instead of building the join (``_chain_constraints``)."""
+
+    def __init__(self, in_op: RelationalOperator, exprs, target_field: str):
+        super().__init__(in_op, exprs)
+        self.target_field = target_field
+
+    @property
+    def _join(self) -> RelationalOperator:
+        with_target = _peel_cache(self.children[0])
+        return _peel_cache(with_target.children[0])
+
+    @property
+    def outer(self) -> RelationalOperator:
+        return _peel_cache(self._join.children[0])
+
+    @property
+    def pattern(self) -> RelationalOperator:
+        op = self._join.children[1]  # flag := true over DISTINCT over SELECT
+        for _ in range(3):
+            op = _peel_cache(op).children[0]
+        return _peel_cache(op)
+
+
 class FilterOp(RelationalOperator):
     def __init__(self, in_op: RelationalOperator, predicate: E.Expr):
         super().__init__(in_op)
@@ -309,6 +349,71 @@ class FilterOp(RelationalOperator):
 
     def _show_inner(self) -> str:
         return self.predicate.pretty_expr()
+
+
+def _is_node_var(header: RecordHeader, name: str) -> bool:
+    try:
+        v = header.var(name)
+    except (KeyError, ValueError):
+        return False
+    m = v.cypher_type.material if v.cypher_type is not None else None
+    return isinstance(m, T.CTNodeType) and not header.has_path(name)
+
+
+def _node_pair_constraint(f: FilterOp):
+    """``f`` read as one constraint between two node variables of its
+    input, with the operator the next filter of the stack would sit on:
+    ``("neq" | "eq", a, c)`` for ``a <> c`` / ``a = c``, ``("edge", source,
+    target, types, negated)`` for a (negated) flag of an ``ExistsFlagOp``
+    whose pattern is one directed relationship between two bound nodes of
+    the very rows the flag is joined to. None: not such a filter."""
+    p, child = f.predicate, f.children[0]
+    if isinstance(p, (E.Neq, E.Equals)):
+        a, c = p.lhs, p.rhs
+        if (
+            isinstance(a, E.Var) and isinstance(c, E.Var)
+            and _is_node_var(child.header, a.name)
+            and _is_node_var(child.header, c.name)
+        ):
+            kind = "neq" if isinstance(p, E.Neq) else "eq"
+            return (kind, a.name, c.name), child
+        return None
+    negated = isinstance(p, E.Not)
+    flag = p.expr if negated else p
+    exists = _peel_cache(child)
+    if (
+        not isinstance(flag, E.Var)
+        or not isinstance(exists, ExistsFlagOp)
+        or exists.target_field != flag.name
+        or exists._table is not None
+    ):
+        return None
+    pattern = exists.pattern
+    closing = getattr(pattern, "closing_edge", None)
+    edge = closing() if closing is not None else None
+    if edge is None or _peel_cache(pattern.children[0]) is not exists.outer:
+        return None
+    return ("edge", *edge, negated), exists.outer
+
+
+def _chain_constraints(op: RelationalOperator):
+    """The stack of filters from ``op`` down, each read as a constraint
+    between two node variables (``_node_pair_constraint``), and the operator
+    under the stack when it can count under such constraints
+    (``chain_constraint_count``, the fused expand chain's); else None."""
+    constraints = []
+    while True:
+        op = _peel_cache(op)
+        if not isinstance(op, FilterOp):
+            break
+        got = None if op._table is not None else _node_pair_constraint(op)
+        if got is None:
+            return None
+        constraints.append(got[0])
+        op = got[1]
+    if not constraints or not hasattr(op, "chain_constraint_count"):
+        return None
+    return constraints, op
 
 
 class SelectOp(RelationalOperator):
@@ -437,6 +542,10 @@ class AggregateOp(RelationalOperator):
             return inner._table.size
         if not isinstance(inner, (FilterOp, JoinOp)):
             return None
+        if isinstance(inner, FilterOp):
+            n = self._chain_constraint_count(inner)
+            if n is not None:
+                return n
         op = "filter" if isinstance(inner, FilterOp) else "join"
         # the operator's span, as ``table`` would have opened it
         with _obs_trace.span(type(inner).__name__, kind="operator", count_only=True):
@@ -446,6 +555,28 @@ class AggregateOp(RelationalOperator):
             rows = inner.key_join_size() if n is None and op == "join" else None
         self._note_pushdown(op, n)
         return n if n is not None else rows
+
+    @staticmethod
+    def _chain_constraint_count(top: "FilterOp") -> Optional[int]:
+        """count(*) over filters that each relate two nodes of the expand
+        chain under them (``a <> c``, ``a = c``, ``[NOT] (a)-[:T]->(c)``):
+        the chain counts under the constraints without a row of its own
+        (``CsrExpandOp.chain_constraint_count``). None — the filters are
+        not all of that kind, or the chain declines (the pair further apart
+        than two hops, ...) — and the generic path builds the rows; the
+        constraints are counted either way, one each."""
+        found = _chain_constraints(top)
+        if found is None:
+            return None
+        constraints, chain = found
+        n = chain.chain_constraint_count(constraints)
+        COUNT_PUSHDOWN.inc(
+            len(constraints), op="chain_constraint",
+            outcome="rows" if n is None else "count",
+        )
+        if n is not None:
+            _obs_trace.note("count_from", "chain_constraint")
+        return n
 
     @staticmethod
     def _note_pushdown(op: str, n: Optional[int]) -> Optional[int]:
@@ -517,12 +648,6 @@ class LimitOp(RelationalOperator):
         super().__init__(in_op)
         self.expr = expr
 
-    @staticmethod
-    def _peel_cache(op: "RelationalOperator") -> "RelationalOperator":
-        while isinstance(op, CacheOp):
-            op = op.children[0]
-        return op
-
     def _compute_table(self) -> Table:
         v = _static_value(self.expr, self.context.parameters)
         if not isinstance(v, int) or isinstance(v, bool) or v < 0:
@@ -530,13 +655,13 @@ class LimitOp(RelationalOperator):
         # top-k fusion: LIMIT k (with optional SKIP s) directly over ORDER BY
         # asks the backend for the first s+k sorted rows instead of a full
         # sort (TpuTable answers with one lax.top_k when the keys allow it)
-        node = self._peel_cache(self.children[0])
+        node = _peel_cache(self.children[0])
         skip = 0
         ob = None
         if isinstance(node, SkipOp):
             try:
                 skip = node._count()
-                inner = self._peel_cache(node.children[0])
+                inner = _peel_cache(node.children[0])
                 if isinstance(inner, OrderByOp):
                     ob = inner
             except RelationalError:

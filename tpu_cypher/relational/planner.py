@@ -34,6 +34,7 @@ from .ops import (
     DistinctOp,
     DropOp,
     EmptyRecordsOp,
+    ExistsFlagOp,
     FilterOp,
     JoinOp,
     LimitOp,
@@ -280,7 +281,7 @@ class RelationalPlanner:
         with_target = AddOp(
             joined, E.IsNotNull(flag_var).with_type(T.CTBoolean), op.target_field
         )
-        return DropOp(with_target, [flag_var])
+        return ExistsFlagOp(with_target, [flag_var], op.target_field)
 
     def _plan_PatternComprehension(
         self, op: L.PatternComprehension
