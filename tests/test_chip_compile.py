@@ -277,6 +277,39 @@ def test_dense_segment_aggregate_compiles_without_a_scatter(one_chip, name, k):
     assert compiled.memory_analysis().temp_size_in_bytes < NODES * 8
 
 
+@pytest.mark.parametrize("rows", [65_645, 448_626], ids=["sf10", "sf100"])
+@pytest.mark.parametrize("k", [10, 1_000])
+def test_order_limit_prefix_gather_compiles_at_the_cells_sizes(one_chip, rows, k):
+    """ORDER BY ... LIMIT k over the Person scan of the two SNB cells: the
+    gather is handed the whole permutation and a static ``k``, and what it
+    reads, holds and returns is ``k`` rows of the 12 columns, never a sorted
+    table. (The sort before it is ``order_permutation`` as it was: a
+    64-bit multi-key sort takes minutes to compile at these sizes, which
+    is why the prefix is cut here and not there.)"""
+    cols = {
+        f"c{i}": (one_chip((rows,), I64), one_chip((rows,), BOOL) if i % 2 else None, None)
+        for i in range(12)
+    }
+    compiled = J.cols_take.lower(cols, one_chip((rows,), I64), first=k).compile()
+    memory = compiled.memory_analysis()
+    # all 18 outputs and every temporary together: under one column's bytes
+    assert memory.output_size_in_bytes + memory.temp_size_in_bytes < rows * 8
+
+
+def test_order_limit_top_k_compiles_where_it_is_first_chosen(one_chip):
+    """``ORDER_TOPK_MIN_ROWS`` rows, two integral keys, the ranges traced
+    (64-bit shifts by a traced width go through the chip's 64-bit
+    lowering): one program for every range and a binade of LIMITs."""
+    rows = J.ORDER_TOPK_MIN_ROWS
+    pack = one_chip((2,), I64)
+    compiled = J.order_topk.lower(
+        (one_chip((rows,), I64), one_chip((rows,), I64)),
+        (one_chip((rows,), BOOL), None),
+        ascs=(False, True), los=pack, spans=pack, bits=pack, k=16,
+    ).compile()
+    assert compiled.memory_analysis().output_size_in_bytes < 4096
+
+
 def test_sixty_four_bit_planes_are_refused(one_chip):
     """Why int64/float64 aggregates decline by eligibility instead of
     riding the kernel: a custom call's 64-bit operand cannot be lowered.

@@ -312,6 +312,8 @@ def test_order_by_limit_topk_matches_oracle(monkeypatch):
         return orig(*a, **kw)
 
     monkeypatch.setattr(jit_ops, "order_topk", spy)
+    # the top-k is chosen from this many rows up; forty rows take it here
+    monkeypatch.setattr(jit_ops, "ORDER_TOPK_MIN_ROWS", 0)
 
     rng = np.random.default_rng(9)
     parts = []

@@ -478,7 +478,9 @@ def order_key(v):
             key = (False, v)
         else:
             f = float(v)
-            key = (math.isnan(f), f)  # NaN greater than all numbers
+            # NaN greater than all numbers, and every NaN the same key
+            # (nan != nan would leave their rows in no order at all)
+            key = (True, 0.0) if math.isnan(f) else (False, f)
     elif cls == "boolean":
         key = v
     elif cls == "string":

@@ -624,11 +624,15 @@ class FactorizedTable(Table):
         """ORDER BY + LIMIT without flattening: sort the lanes, then
         decompress only the first ``k`` flat rows. Returns None (caller
         falls back to ``order_by().limit()`` — same result, here) when
-        the keys are not prefix columns."""
+        the keys are not prefix columns. Every lane is still gathered in
+        sorted order (a lane holds any number of flat rows, none included,
+        so the first ``k`` rows are no fixed prefix of the lanes): counted
+        as ``full``."""
         if not items or self._nrows == 0 or k == 0:
             return None
         if not self._orderable_on_prefix(items):
             return None
+        _obs_trace.note_order_limit("full")
         return self.order_by(items).limit(min(k, self._nrows))
 
     def skip(self, n: int) -> Table:

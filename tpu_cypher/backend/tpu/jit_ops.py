@@ -163,8 +163,13 @@ def take_take(a, idx_outer, idx_inner):
 # ---------------------------------------------------------------------------
 
 
-@jax.jit
-def cols_take(cols: Dict[str, Tuple[Any, Any, Any]], idx):
+@partial(jax.jit, static_argnames=("first",))
+def cols_take(cols: Dict[str, Tuple[Any, Any, Any]], idx, first: Optional[int] = None):
+    """Gather every column at ``idx``; a static ``first`` gathers at
+    ``idx[:first]`` alone (ORDER BY ... LIMIT: the prefix of a permutation
+    is cut here, in the program that is cheap to compile for each new
+    LIMIT, and never in the sort that made it)."""
+    idx = idx[:first]
     out = {}
     for c, (data, valid, iflag) in cols.items():
         out[c] = (
@@ -1516,23 +1521,27 @@ def order_permutation(datas, valids, kinds, ascs):
     Items arrive in ORDER BY priority order; keys are appended reversed so
     lexsort's last-key-primary convention sees item 0 as primary."""
     keys = []
-    for d, v, k, asc in zip(
+    for d, v, kind, asc in zip(
         reversed(datas), reversed(valids), reversed(kinds), reversed(ascs)
     ):
         null = (
             ~v if v is not None else jnp.zeros(d.shape[0], bool)
         )
-        if k == DUR:
+        if kind == DUR:
             # average-length key; equal keys keep original order (stable
             # lexsort) — same tie policy as the oracle's order_key
             d = _dur_order_key(d)
-        if k == BOOL:
+        if kind == BOOL:
             d = d.astype(jnp.int8)
-        if k == F64:
-            nan = jnp.isnan(d)
+        if kind == F64:
+            nan = jnp.isnan(d) & ~null
             d = jnp.where(nan, 0.0, d)
         else:
             nan = None
+        if v is not None:
+            # nulls are ties, broken by the next item: the payload under
+            # valid=False is arbitrary (an expression's, an outer join's)
+            d = jnp.where(null, jnp.zeros((), d.dtype), d)
         if asc:
             keys.append(d)
             if nan is not None:
@@ -1776,6 +1785,26 @@ def segment_percentile(data, valid, seg_j, p, name: str, k: int):
 # ORDER BY ... LIMIT k as top-k over one packed key
 # ---------------------------------------------------------------------------
 
+# the fewest rows at which ORDER BY ... LIMIT k over integral keys that pack
+# into 62 bits is one ``lax.top_k`` over the packed rank (``order_minmax``, a
+# blocking read of the ranges, ``order_topk``) and not the prefix of the
+# stable sort (``order_permutation``, the prefix cut in the gather). The
+# top-k is a third of the sort's device time at every size and flat in k,
+# but pays a probe and a blocking read before it can start, 1.9-2.6 ms of
+# host more than the sort: it wins where two thirds of the sort are longer
+# than that (my chip run, PR 33, call a, TPU v5 lite; a 12-bit key with
+# nulls DESC and a 16-bit key ASC; ms, k = 10 / 1,000, the median of seven;
+# device from the trace, wall round indices and a 12-column gather with the
+# device idle before):
+#                    n = 65,645      n = 448,626     n = 2^22
+#   sort, device     0.378           2.201           28.68
+#   top-k, device    0.141 / 0.141   0.768 / 0.768   8.800 / 8.801
+#   sort, wall       1.90 / 2.12     2.89 / 3.19     30.14 / 30.33
+#   top-k, wall      3.99 / 4.49     4.01 / 4.39     12.19 / 12.43
+# Level at about 700,000 rows by the two slopes; 2^20 is the first lattice
+# size past it (the sort 5.9 ms there, the top-k 2.0 and 2 ms of host).
+ORDER_TOPK_MIN_ROWS = 1 << 20
+
 
 @jax.jit
 def order_minmax(datas, valids):
@@ -1795,28 +1824,29 @@ def order_minmax(datas, valids):
     return jnp.stack(mins), jnp.stack(maxs)
 
 
-@partial(jax.jit, static_argnames=("ascs", "pack", "k"))
-def order_topk(datas, valids, ascs, pack, k: int):
+@partial(jax.jit, static_argnames=("ascs", "k"))
+def order_topk(datas, valids, ascs, los, spans, bits, k: int):
     """Row indices of the first ``k`` rows under Cypher orderability,
-    computed as ONE ``lax.top_k`` over a packed int64 rank — O(n log k)
-    instead of a full O(n log^2 n) device sort. Keys arrive in ORDER BY
-    priority order; each contributes (1 null bit | data bits) with DESC
-    keys bit-reversed, so lexicographic order == integer order. All-integer
-    keys only (the caller guarantees the bit budget)."""
+    computed as ONE ``lax.top_k`` over a packed int64 rank. Keys arrive in
+    ORDER BY priority order; each contributes (1 null bit | data bits) with
+    DESC keys bit-reversed, so lexicographic order == integer order.
+    All-integer keys only; ``los`` / ``spans`` / ``bits`` are each key's
+    measured minimum, range and width, traced (a new range is no new
+    program; the caller guarantees the bit budget)."""
     acc = jnp.zeros(datas[0].shape[0], jnp.int64)
-    for d, v, asc, (lo, span, bits) in zip(datas, valids, ascs, pack):
+    for i, (d, v, asc) in enumerate(zip(datas, valids, ascs)):
         d = d.astype(jnp.int64)
-        val = d - lo
+        val = d - los[i]
         if v is not None:
             val = jnp.where(v, val, 0)
             null_rank = (~v).astype(jnp.int64)  # nulls last ascending
         else:
             null_rank = jnp.zeros_like(val)
         if not asc:
-            val = span - val
+            val = spans[i] - val
             null_rank = 1 - null_rank  # nulls first descending
         acc = (acc << 1) | null_rank
-        acc = (acc << bits) | val
+        acc = (acc << bits[i]) | val
     # stable tiebreak: original row index in the lowest bits (matches the
     # oracle's stable sort; the caller budgets these bits)
     n = acc.shape[0]

@@ -18,7 +18,9 @@ fused kernels use under jit:
                   extra key pairs become device post-filters; string keys
                   join on unified dictionary codes
 * union_all     = columnwise concat (string vocabs unified)
-* order_by      = device lexsort over Cypher-orderability keys
+* order_by      = device lexsort over Cypher-orderability keys + gather;
+                  under a LIMIT k the gather reads the rows at the
+                  permutation's first k entries alone (``order_by_limit``)
 * distinct      = stable device lexsort + neighbour-difference flags ->
                   first-occurrence gather
 * group         = device lexsort factorization (same equivalence classes as
@@ -176,16 +178,17 @@ def _fold_valids(valids):
     return out
 
 
-def _cols_take_maybe_chunked(dev, idx):
-    """``jit_ops.cols_take`` — unless the CHUNKED ladder rung is active and
-    the gather is large, in which case the index splits into bounded slices
-    gathered independently and concatenated, so no single device program
-    allocates the whole output at once (the degraded-memory materialize;
-    docs/robustness.md)."""
+def _cols_take_maybe_chunked(dev, idx, first: Optional[int] = None):
+    """``jit_ops.cols_take`` (at ``idx[:first]``, cut inside that program)
+    — unless the CHUNKED ladder rung is active and the gather is large, in
+    which case the index splits into bounded slices gathered independently
+    and concatenated, so no single device program allocates the whole
+    output at once (the degraded-memory materialize; docs/robustness.md)."""
     chunk = _guard.chunk_rows()
-    n = int(idx.shape[0])
+    n = int(idx.shape[0]) if first is None else first
     if chunk is None or n <= chunk:
-        return J.cols_take(dev, idx)
+        return J.cols_take(dev, idx, first=first)
+    idx = idx[:n]
     pieces = [
         J.cols_take(dev, idx[start : min(start + chunk, n)])
         for start in range(0, n, chunk)
@@ -398,20 +401,23 @@ class TpuTable(Table):
             {c: v for c, v in self._cols.items() if c not in d}, self._nrows
         )
 
-    def _take(self, idx) -> "TpuTable":
+    def _take(self, idx, first: Optional[int] = None) -> "TpuTable":
         """Gather all columns' device arrays in ONE jitted dispatch (not
-        one eager gather per column)."""
+        one eager gather per column): the rows at ``idx``, or at its first
+        ``first`` entries alone, cut inside that dispatch."""
         n = int(idx.shape[0]) if hasattr(idx, "shape") else len(idx)
+        if first is not None:
+            n = first = min(first, n)
         dev = {
             c: (col.data, col.valid, col.int_flag)
             for c, col in self._cols.items()
             if col.kind != OBJ
         }
-        taken = _cols_take_maybe_chunked(dev, idx) if dev else {}
+        taken = _cols_take_maybe_chunked(dev, idx, first) if dev else {}
         out: Dict[str, Column] = {}
         for c, col in self._cols.items():
             if col.kind == OBJ:
-                out[c] = col.take(idx)
+                out[c] = col.take(idx[:n])
             else:
                 d, v, i = taken[c]
                 out[c] = Column(col.kind, d, v, col.vocab, int_flag=i)
@@ -1085,60 +1091,92 @@ class TpuTable(Table):
     def order_by_limit(
         self, items: Sequence[Tuple[str, bool]], k: int
     ) -> Optional["TpuTable"]:
+        """First ``k`` rows under ORDER BY, row for row what
+        ``order_by(items).limit(k)`` gives (ties included): the same stable
+        permutation (``jit_ops.order_permutation``), and one batched gather
+        of the rows at its first ``min(k, n)`` entries alone, cut inside
+        the gather's program — never the sorted table. From
+        ``jit_ops.ORDER_TOPK_MIN_ROWS`` rows up, integral keys whose
+        measured ranges pack into 62 bits take one ``lax.top_k`` to the
+        same indices instead (``_order_topk``). None (the caller sorts,
+        then slices) only for what ``order_by`` does not sort on the device
+        (OBJ keys), an empty table, no items, or ``k == 0``."""
         t = self._depad()
         if t is not self:
             return t.order_by_limit(items, k)
-        """First ``k`` rows under ORDER BY as ONE top-k over a packed int64
-        rank — O(n log k) instead of the full device sort. Returns None
-        (caller falls back to sort+limit) unless every sort key is integral
-        (ints, bools, dictionary-coded strings) and the ranges fit the bit
-        budget."""
+        if not items or self._nrows == 0 or k == 0:
+            return None
+        k = min(k, self._nrows)
+        idx, path = self._order_topk(items, k), "topk"
+        if idx is None:
+            idx, path = self._order_permutation(items), "sort_prefix"
+        if idx is None:
+            return None
+        _obs_trace.note_order_limit(path)
+        return self._take(idx, first=k)
+
+    def _order_topk(self, items: Sequence[Tuple[str, bool]], k: int):
+        """The first ``k`` (rounded up to a power of two: one program a
+        binade of LIMITs) row indices in ORDER BY order as one top-k over
+        the keys packed into an int64 rank, after a min/max probe and one
+        blocking read; None under ``jit_ops.ORDER_TOPK_MIN_ROWS`` rows
+        (the sort is sooner there, the table stands above the constant),
+        for a key that is not integral (ints, bools, dictionary-coded
+        strings), or if the ranges, a null bit a key and the row index pass
+        62 bits."""
         n = self._nrows
-        if not items or n == 0 or k == 0:
-            return None
         cols = [self._cols[c] for c, _ in items]
-        if any(c.kind not in INTEGRAL_KINDS for c in cols):
+        if n < J.ORDER_TOPK_MIN_ROWS or any(
+            c.kind not in INTEGRAL_KINDS for c in cols
+        ):
             return None
-        k = min(k, n)
         datas = tuple(c.data for c in cols)
         valids = tuple(c.valid for c in cols)
         mins, maxs = J.order_minmax(datas, valids)
         with _obs_trace.sync("order"):
             mins = np.asarray(mins)
             maxs = np.asarray(maxs)
-        pack = []
-        total_bits = 0
-        for lo, hi in zip(mins, maxs):
-            lo, hi = int(lo), int(hi)
-            if lo > hi:  # all-null key: zero data bits
-                lo, hi = 0, 0
-            span = hi - lo
-            bits = span.bit_length()
-            total_bits += bits + 1  # +1 null bit per key
-            pack.append((lo, span, bits))
-        total_bits += max(n - 1, 0).bit_length()  # stable row-index tiebreak
-        if total_bits > 62:
+        los, spans = zip(*(
+            (lo, hi - lo) if lo <= hi else (0, 0)  # all-null: no data bit
+            for lo, hi in zip(mins.tolist(), maxs.tolist())
+        ))
+        bits = [span.bit_length() for span in spans]
+        # a null bit a key, the row index as the stable tiebreak
+        if sum(bits) + len(cols) + (n - 1).bit_length() > 62:
             return None
-        ascs = tuple(bool(a) for _, a in items)
-        idx = J.order_topk(datas, valids, ascs, tuple(pack), k=k)
-        return self._take(idx)
+        ascs = tuple(bool(asc) for _, asc in items)
+        return J.order_topk(
+            datas, valids, ascs,
+            np.array(los, np.int64), np.array(spans, np.int64), np.array(bits, np.int64),
+            k=min(n, 1 << (k - 1).bit_length()),
+        )
 
     def order_by(self, items: Sequence[Tuple[str, bool]]) -> "TpuTable":
+        """ORDER BY: one jitted stable lexsort under Cypher orderability
+        (``jit_ops.order_permutation``) + one batched gather of every row;
+        OBJ keys sort on the local backend."""
         t = self._depad()
         if t is not self:
             return t.order_by(items)
-        """ORDER BY: one jitted stable lexsort under Cypher orderability
-        (``jit_ops.order_permutation``) + one batched gather."""
-        if any(self._cols[c].kind == OBJ for c, _ in items):
-            return self._from_local(self._to_local('order_by:obj-keys').order_by(items))
         if not items:
             return self
-        datas = tuple(self._cols[c].data for c, _ in items)
-        valids = tuple(self._cols[c].valid for c, _ in items)
-        kinds = tuple(self._cols[c].kind for c, _ in items)
-        ascs = tuple(bool(asc) for _, asc in items)
-        idx = J.order_permutation(datas, valids, kinds, ascs)
+        idx = self._order_permutation(items)
+        if idx is None:
+            return self._from_local(self._to_local('order_by:obj-keys').order_by(items))
         return self._take(idx)
+
+    def _order_permutation(self, items: Sequence[Tuple[str, bool]]):
+        """Row indices in ORDER BY order; None if a key has no device
+        representation."""
+        cols = [self._cols[c] for c, _ in items]
+        if any(c.kind == OBJ for c in cols):
+            return None
+        return J.order_permutation(
+            tuple(c.data for c in cols),
+            tuple(c.valid for c in cols),
+            tuple(c.kind for c in cols),
+            tuple(bool(asc) for _, asc in items),
+        )
 
     # -- distinct / group factorization ------------------------------------
 

@@ -83,6 +83,19 @@ SEGMENT_REDUCE = M.REGISTRY.counter(
 for _form in ("dense", "scatter"):  # both series export from the start
     SEGMENT_REDUCE.inc(0, form=_form)
 
+ORDER_LIMIT = M.REGISTRY.counter(
+    "tpu_cypher_order_limit_total",
+    "LIMIT directly over ORDER BY, by what was gathered: topk (k rows, "
+    "found by one top-k over the keys packed into 62 bits), sort_prefix (k "
+    "rows, at the first k entries of the stable sort's permutation) or "
+    "full (the whole table sorted and gathered, then sliced: the backend "
+    "has no prefix form or declined, or a CSE sibling had built the sorted "
+    "table)",
+    labels=("path",),
+)
+for _path in ("topk", "sort_prefix", "full"):  # a sound deployment: 0 full
+    ORDER_LIMIT.inc(0, path=_path)
+
 
 class Span:
     """One node of the tree: a named, timed region with attributes."""
@@ -342,6 +355,13 @@ def note_agg_form(form: str) -> None:
     if sp is not None:
         forms = sp.attrs.setdefault("agg_form", {})
         forms[form] = forms.get(form, 0) + 1
+
+
+def note_order_limit(path: str) -> None:
+    """Count one LIMIT over ORDER BY by what it gathered: in the registry,
+    and as ``order_limit`` on the innermost open span."""
+    ORDER_LIMIT.inc(path=path)
+    note("order_limit", path)
 
 
 def note_site(site: str) -> None:

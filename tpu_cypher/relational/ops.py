@@ -652,9 +652,10 @@ class LimitOp(RelationalOperator):
         v = _static_value(self.expr, self.context.parameters)
         if not isinstance(v, int) or isinstance(v, bool) or v < 0:
             raise RelationalError(f"LIMIT requires a non-negative integer, got {v!r}")
-        # top-k fusion: LIMIT k (with optional SKIP s) directly over ORDER BY
-        # asks the backend for the first s+k sorted rows instead of a full
-        # sort (TpuTable answers with one lax.top_k when the keys allow it)
+        # the limit goes into the gather: LIMIT k (with optional SKIP s)
+        # directly over ORDER BY asks the backend for the first s+k sorted
+        # rows (TpuTable sorts the keys and gathers the rows at the first
+        # s+k entries of the permutation alone; the hook notes its path)
         node = _peel_cache(self.children[0])
         skip = 0
         ob = None
@@ -677,6 +678,8 @@ class LimitOp(RelationalOperator):
                 t = hook(ob.sort_cols(), skip + v)
                 if t is not None:
                     return t.skip(skip) if skip else t
+        if ob is not None:  # the whole table sorted and gathered, then sliced
+            _obs_trace.note_order_limit("full")
         return self.children[0].table.limit(v)
 
 
