@@ -258,6 +258,10 @@ def big_graph_queries(ref: Reference, win2, win3):
     # walks of length 3 repeat an edge only as a->b->a->b (no self loops)
     walks = len(vb) + len(vc) + len(vd) - int((vc == v0[q1][q2]).sum())
 
+    # a star round b: the anchor's edge, and two OPTIONAL leaves off b
+    indeg = np.bincount(ref.d, minlength=ref.n).astype(np.int64)
+    star = int((np.maximum(ref.outdeg[b], 1) * np.maximum(indeg[b], 1)).sum())
+
     by_bday = np.bincount(bday, minlength=18_000)
     join_rows = int(by_bday[bday[anchors]].sum())
 
@@ -320,6 +324,11 @@ def big_graph_queries(ref: Reference, win2, win3):
          anchor + "MATCH (a)-[:KNOWS*1..3]->(b:Person) "
          "RETURN count(*) AS walks",
          win_v, [{"walks": walks}]),
+        ("star_optional",  # the tree count: no row of the star is built
+         anchor + "MATCH (a)-[:KNOWS]->(b:Person) "
+         "OPTIONAL MATCH (b)-[:KNOWS]->(c:Person) "
+         "OPTIONAL MATCH (b)<-[:KNOWS]-(d:Person) RETURN count(*) AS n",
+         win, [{"n": star}]),
     ]
 
 

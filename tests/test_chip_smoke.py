@@ -50,7 +50,7 @@ def test_rehearsal_passes_every_phase(rehearsal):
         "scan_filter", "two_hop_count", "grouped_aggregate",
         "order_by_limit", "property_projection", "expand_materialize",
         "sort_probe_join", "distinct_two_hop", "triangle_close",
-        "var_length", "string_starts_with",
+        "var_length", "star_optional", "string_starts_with",
     ):
         assert f"query {name}: rows=" in out, name
     assert "read back after attach_wal replay" in out

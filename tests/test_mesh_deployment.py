@@ -48,9 +48,14 @@ TIER_OF = {
 }
 DECLINES = "tpu_cypher_mesh_declines_total"
 # the ungrouped count(*) of these shapes is answered by the operator under
-# it without its rows (``count_pushdowns.analytic`` reads 3 a pass here)
+# it without its rows (``count_pushdowns.analytic`` reads 3 a pass here:
+# it does not read the ``tree`` series)
 PUSHDOWN = "tpu_cypher_count_pushdown_total"
 PUSHDOWN_OF = {
+    # the linked chain is the tree count's case without a branch (PR 34):
+    # asked through ``tree_count``, answered by the chain's sharded program
+    "two_hop_count": "tree",
+    "one_hop_count": "tree",
     "scan_filter": "filter",
     "distinct_values": "distinct",
     "sort_probe_join": "join",

@@ -288,8 +288,10 @@ def test_optional_match_rides_mesh_join(monkeypatch):
         "CREATE (a:P {v: 1})-[:K]->(:Q {w: 10}), (:P {v: 2}), "
         "(c:P {v: 3})-[:K]->(:Q {w: 30})"
     )
+    # the predicate keeps the optional side off the fused left-outer
+    # expand (which takes one hop under far labels the index proves)
     q = (
-        "MATCH (p:P) OPTIONAL MATCH (p)-[:K]->(x:Q) "
+        "MATCH (p:P) OPTIONAL MATCH (p)-[:K]->(x:Q) WHERE x.w > 0 "
         "RETURN p.v AS v, x.w AS w ORDER BY v"
     )
     want = [

@@ -118,13 +118,52 @@ _MESH_EXPAND_TOTAL = _OBS_REGISTRY.counter(
 
 
 def _note_chain_forms(forms) -> None:
-    """Count a fused count chain's hops by the form each takes
-    (``jit_ops.chain_forms``): in the registry, and as ``chain_hops`` on
-    the operator's span."""
+    """Count a fused count chain's (or tree's) hops by the form each takes
+    (``jit_ops.chain_forms`` / ``tree_forms``): in the registry, and as
+    ``chain_hops`` on the operator's span."""
     counts = {f: forms.count(f) for f in ("degree", "reduce", "scan")}
     for form, n in counts.items():  # a 0 seeds the series: it exports
         _obs_trace.COUNT_CHAIN_HOPS.inc(n, form=form)
     _obs_trace.note("chain_hops", counts)
+
+
+_TREE_LANES_TOTAL = _OBS_REGISTRY.counter(
+    "tpu_cypher_tree_count_lanes_total",
+    "edge lanes (a bucket's pad included) the programs of tree counts read: "
+    "every lane of a reduce or scan hop's CSR, both orientations of an "
+    "undirected one; a degree hop reads none",
+)
+
+
+def _note_tree_lanes(forms, hop_data) -> None:
+    """The edge lanes a count's hops read (``hop_data`` as
+    ``path_count_chain`` takes it, ``forms`` beside it), on the counter and
+    as ``edge_lanes`` on the open span."""
+    lanes = sum(
+        int(h[1].shape[0]) + (int(h[3].shape[0]) if h[3] is not None else 0)
+        for h, form in zip(hop_data, forms) if form != "degree"
+    )
+    _TREE_LANES_TOTAL.inc(lanes)
+    _obs_trace.note("edge_lanes", lanes)
+
+
+def _hop_arrays(gi: GraphIndex, hop, ctx):
+    """One hop as ``jit_ops.path_count_chain`` / ``tree_count`` take it:
+    ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask)`` — the CSR whose rows are
+    the hop's near node, for an undirected hop the opposite orientation and
+    the self-loop counts beside it, and the far node's label mask: None
+    where the index build proves the labels of every node the hop's edges
+    reach (every LIKES source is a Person; ``GraphIndex.hop_mask``), in
+    both orientations for an undirected hop. The one place that decides
+    whether a hop of a count needs a mask."""
+    rp, ci, _ = gi.csr(hop.types_key, hop.backwards, ctx)
+    mask = gi.hop_mask(hop.types_key, hop.backwards, hop.far_labels, ctx)
+    if not getattr(hop, "undirected", False):
+        return rp, ci, None, None, None, mask
+    rp_b, ci_b, _ = gi.csr(hop.types_key, not hop.backwards, ctx)
+    if mask is None:
+        mask = gi.hop_mask(hop.types_key, not hop.backwards, hop.far_labels, ctx)
+    return rp, ci, rp_b, ci_b, gi.loop_count(hop.types_key, ctx), mask
 
 
 def _pad_mask(mask, npad: int):
@@ -576,7 +615,47 @@ class _FusedExpandBase(RelationalOperator):
         return TpuTable(out, n_out)
 
 
-class CsrExpandOp(_FusedExpandBase):
+class _TreeCounted:
+    """What ``CsrExpandOp`` and ``CsrOptionalExpandOp`` share: the top of a
+    stack of them answers ``count(*)`` for the whole stack."""
+
+    def tree_count(self) -> Optional[int]:
+        """count(*) of this operator's rows where it and the expands stacked
+        under it are a tree of the pattern — a path, a star, OPTIONAL leaves —
+        WITHOUT a row of it: one 64-bit multiplicity per node from the leaves
+        to the root, vectors that meet in a node multiplied, ``max(branch, 1)``
+        for an OPTIONAL branch (``jit_ops.tree_count``: one program, one
+        blocking read). The linked chain is its case without a branch and keeps
+        its own program (``_count_via_chain``). None — the reason on the span
+        as ``tree_decline`` — and the caller builds the rows."""
+        ops = _tree_ops(self)
+        optional = sum(isinstance(op, CsrOptionalExpandOp) for op in ops)
+        linked = not optional and all(
+            upper.frontier_fld == lower.far_fld for upper, lower in zip(ops, ops[1:])
+        )
+        with _obs_trace.span(
+            "tree_count", kind="kernel", hops=len(ops), optional_branches=optional
+        ):
+            try:
+                gi = GraphIndex.of(self.graph)
+                if linked:
+                    _obs_trace.note("branches", 1)
+                    return self._count_via_chain(gi, self.context)
+                read = _read_tree(ops)
+                if isinstance(read, str):
+                    _obs_trace.note("tree_decline", read)
+                    return None
+                if current_mesh() is not None and mesh_size() > 1:
+                    note_decline("expand", "tree_count")
+                    _obs_trace.note("tree_decline", "mesh")
+                    return None
+                return _tree_count_program(gi, ops[-1], *read)
+            except (GraphIndexError, TpuBackendError) as exc:
+                _obs_trace.note("tree_decline", type(exc).__name__)
+                return None
+
+
+class CsrExpandOp(_TreeCounted, _FusedExpandBase):
     """Fused (frontier)-[rel]->(far) expansion over the graph CSR.
 
     Replaces the scan+2-joins cascade: frontier element ids map to compact
@@ -759,17 +838,9 @@ class CsrExpandOp(_FusedExpandBase):
             and in_t.size == id_col.logical_len == gi.num_logical_nodes
             and gi.scan_is_whole(base.frontier_scan, ctx)
         )
-        hop_data = []
-        for hop in reversed(hops):  # deepest (first executed) hop first
-            mask = gi.label_mask(hop.far_labels, ctx)
-            if hop.undirected:
-                rp_a, ci_a, _ = gi.csr(hop.types_key, hop.backwards, ctx)
-                rp_b, ci_b, _ = gi.csr(hop.types_key, not hop.backwards, ctx)
-                loop_cnt = gi.loop_count(hop.types_key, ctx)
-                hop_data.append((rp_a, ci_a, rp_b, ci_b, loop_cnt, mask))
-            else:
-                rp, ci, _ = gi.csr(hop.types_key, hop.backwards, ctx)
-                hop_data.append((rp, ci, None, None, None, mask))
+        hop_data = [  # deepest (first executed) hop first
+            _hop_arrays(gi, hop, ctx) for hop in reversed(hops)
+        ]
         dev_ids, _ = gi.node_ids(ctx)
         chain = J.path_count_chain
         mesh = current_mesh()
@@ -793,6 +864,7 @@ class CsrExpandOp(_FusedExpandBase):
             [h[5] is not None for h in reversed(hop_data)], whole
         )
         _note_chain_forms(forms)
+        _note_tree_lanes(forms, list(reversed(hop_data)))
         # a whole frontier is not read: none is handed to the program
         frontier = (None,) * 3 if whole else (dev_ids, id_col.data, id_col.valid)
         on_mesh = chain is not J.path_count_chain
@@ -1542,7 +1614,7 @@ class CsrExpandIntoOp(_FusedExpandBase):
         )
 
 
-class CsrOptionalExpandOp(_FusedExpandBase):
+class CsrOptionalExpandOp(_TreeCounted, _FusedExpandBase):
     """Fused OPTIONAL MATCH (frontier)-[rel]->(far): the reference plans
     Optional as a left outer join of the optional subtree
     (``RelationalPlanner.scala:298``); here matched frontier rows emit
@@ -1561,6 +1633,7 @@ class CsrOptionalExpandOp(_FusedExpandBase):
         far_fld: str,
         types_key: Tuple[str, ...],
         backwards: bool,
+        far_labels: Tuple[str, ...] = (),
     ):
         super().__init__(in_plan, classic, graph_obj)
         self.frontier_fld = frontier_fld
@@ -1568,17 +1641,23 @@ class CsrOptionalExpandOp(_FusedExpandBase):
         self.far_fld = far_fld
         self.types_key = types_key
         self.backwards = backwards
+        self.far_labels = far_labels
 
     def _show_inner(self) -> str:
         arrow = "<-" if self.backwards else "->"
         t = "|".join(self.types_key) or "*"
-        return f"optional ({self.frontier_fld}){arrow}[{self.rel_fld}:{t}]({self.far_fld})"
+        far = ":".join((self.far_fld,) + self.far_labels)
+        return f"optional ({self.frontier_fld}){arrow}[{self.rel_fld}:{t}]({far})"
 
     def _fused_table(self):
         from .table import TpuTable
 
         gi = GraphIndex.of(self.graph)
         ctx = self.context
+        if gi.hop_mask(self.types_key, self.backwards, self.far_labels, ctx) is not None:
+            # some neighbour lacks the far labels: which rows match is the
+            # outer join's to say (the count needs no row: ``tree_count``)
+            raise GraphIndexError("optional far labels not proven by the index")
         in_op = self.children[0]
         in_t = _flat_in(in_op.table)
         frontier_var = in_op.header.var(self.frontier_fld)
@@ -1635,6 +1714,99 @@ class CsrOptionalExpandOp(_FusedExpandBase):
             null_mask_by_tag={"orig": matched, "far": matched},
         )
         return TpuTable(out, total)
+
+
+def _tree_ops(top) -> List[RelationalOperator]:
+    """``top`` and the ``CsrExpandOp``s / ``CsrOptionalExpandOp``s stacked
+    under it over the same graph, deepest last. Caches and selects between
+    them keep the row multiset and are looked through."""
+    from ...relational.ops import CacheOp, SelectOp
+
+    ops, node = [top], top
+    while True:
+        child = node.children[0]
+        while isinstance(child, (CacheOp, SelectOp)):
+            child = child.children[0]
+        if (
+            isinstance(child, (CsrExpandOp, CsrOptionalExpandOp))
+            and child._graph_obj is top._graph_obj
+        ):
+            ops.append(child)
+            node = child
+            continue
+        return ops
+
+
+def _read_tree(ops):
+    """The stacked expands read as a tree of the PATTERN rooted in the node
+    the plan started from (``ops[-1].frontier_fld``): ``(tree, order)`` —
+    ``tree`` as ``jit_ops.tree_count`` takes it, ``order`` the ops by their
+    place in its ``hops`` — or the reason it is none: a node bound twice or
+    bound outside the stack, a hop that grows out of an OPTIONAL node (its
+    null rows match nothing: not a product), relationship uniqueness the
+    operators enforce themselves (two hops of one type that can meet)."""
+    if _collected_pairs(ops):
+        return "uniqueness"
+    base = ops[-1]
+    below: Dict[str, list] = {base.frontier_fld: []}
+    optional_nodes = set()
+    order = list(reversed(ops))  # as executed: a hop's place is its index
+    for k, op in enumerate(order):
+        if op.frontier_fld not in below or op.far_fld in below:
+            return "node_bound_twice"
+        if op.frontier_fld in optional_nodes:
+            return "under_optional"
+        optional = isinstance(op, CsrOptionalExpandOp)
+        below[op.far_fld] = []
+        below[op.frontier_fld].append((k, optional, op.far_fld))
+        if optional:
+            optional_nodes.add(op.far_fld)
+
+    def grown(name):
+        return tuple((k, opt, grown(far)) for k, opt, far in below[name])
+
+    return grown(base.frontier_fld), order
+
+
+def _tree_count_program(gi: GraphIndex, base, tree, order) -> int:
+    ctx = base.context
+    gi.node_ids(ctx)
+    if gi.num_nodes == 0:
+        return 0
+    fault_point("expand")
+    # a leaf whose far labels the index proves is a difference of two row
+    # pointers (``_hop_arrays``)
+    hop_data = [_hop_arrays(gi, op, ctx) for op in order]
+    frontier_scan = getattr(base, "frontier_scan", None)  # an expand's fact
+    rows = None
+    if frontier_scan is not None:
+        # the input is the graph's own scan of these labels: their mask
+        root_weight = gi.label_mask(frontier_scan, ctx)
+        whole = root_weight is None
+    else:
+        in_op = base.children[0]
+        in_t = _flat_in(in_op.table)
+        h = in_op.header
+        id_col = in_t._cols[h.column(h.id_expr(h.var(base.frontier_fld)))]
+        pos, present = gi.compact_of(id_col, ctx)
+        root_weight = J.frontier_multiplicity(pos, present, n=gi.num_nodes)
+        whole = False
+        # a row whose root an earlier OPTIONAL MATCH left null is in no
+        # node's weight: the program counts it from the number of rows
+        rows = np.int64(in_t.size)
+    forms = J.tree_forms(tree, [h[5] is not None for h in hop_data], whole)
+    _note_chain_forms(forms)
+    _note_tree_lanes(forms, hop_data)
+    _obs_trace.note(  # the leaves: nodes no hop grows out of
+        "branches",
+        len({op.far_fld for op in order} - {op.frontier_fld for op in order}),
+    )
+    n_dev = J.tree_count(
+        root_weight, np.int32(gi.num_logical_nodes), tuple(hop_data),
+        tree=tree, whole=whole, rows=rows,
+    )
+    with _obs_trace.sync("expand"):  # the one read that waits for the count
+        return int(n_dev)
 
 
 class CsrVarExpandOp(_FusedExpandBase):
@@ -1930,7 +2102,8 @@ def plan_optional_expand_fastpath(planner, op, lhs, rhs_planned, classic) -> Opt
     e = op.rhs
     if not isinstance(e, L.Expand) or e.direction != ">":
         return None
-    if not isinstance(e.lhs, L.NodeScan) or not isinstance(e.rhs, L.NodeScan):
+    # one hop straight off the left side's rows, whatever planned them
+    if e.lhs is not op.lhs or not isinstance(e.rhs, L.NodeScan):
         return None
     lhs_vars = {v.name for v in lhs.header.vars}
     bound = {e.source, e.rel, e.target} & lhs_vars
@@ -1942,27 +2115,10 @@ def plan_optional_expand_fastpath(planner, op, lhs, rhs_planned, classic) -> Opt
         frontier, far, backwards = e.target, e.source, True
     else:
         return None
-    # the logical planner always puts the BOUND side at Expand.lhs and the
-    # newly scanned far side at Expand.rhs, regardless of direction
-    frontier_scan, far_scan = e.lhs, e.rhs
-    # far-side labels change which rows match (keep the classic join);
-    # frontier labels are fine only when the bound variable's TYPE already
-    # guarantees them (the planner stamps the binding's labels onto the
-    # optional scan — semantically redundant there)
-    if getattr(far_scan.node_type.material, "labels", None):
-        return None
-    scan_labels = frozenset(
-        getattr(frontier_scan.node_type.material, "labels", None) or ()
-    )
-    if scan_labels:
-        try:
-            bt = lhs.header.var(frontier).cypher_type.material
-            bound_labels = frozenset(getattr(bt, "labels", None) or ())
-        except Exception:  # fault-ok: plan-time header probe (no device
-            # work); None keeps the classic plan
-            return None
-        if not scan_labels <= bound_labels:
-            return None
+    # far-side labels change which rows match: the operator builds rows
+    # only where the index proves them of every neighbour (else its shadow,
+    # the outer join, does), and counts under them as a mask
+    far_labels = tuple(sorted(getattr(e.rhs.node_type.material, "labels", ()) or ()))
     types = getattr(e.rel_type.material, "types", frozenset()) or frozenset()
     graph_obj = getattr(rhs_planned, "graph", None)
     if graph_obj is None:
@@ -1976,6 +2132,7 @@ def plan_optional_expand_fastpath(planner, op, lhs, rhs_planned, classic) -> Opt
         far_fld=far,
         types_key=GraphIndex.types_key(types),
         backwards=backwards,
+        far_labels=far_labels,
     )
 
 

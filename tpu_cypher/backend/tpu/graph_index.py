@@ -177,6 +177,10 @@ class GraphIndex:
         # (types_key, reverse) -> host max out-degree (Pallas eligibility
         # probe — computed once at build, never synced per query)
         self._csr_max_deg: Dict[Tuple[Tuple[str, ...], bool], int] = {}
+        # per CSR orientation, host bool[num_nodes]: the nodes its edges end
+        # in; per (orientation, labels): do all of those carry the labels
+        self._csr_far_nodes: Dict[Tuple[Tuple[str, ...], bool], np.ndarray] = {}
+        self._hop_label_proven: Dict[Tuple, bool] = {}
         # (types_key, reverse) -> sorted edge keys, device int64: forward
         # keys are (src*N + dst), reverse keys (dst*N + src) — each sorted
         # because its CSR orientation lexsorts by that pair
@@ -309,6 +313,27 @@ class GraphIndex:
                 self._label_mask[key] = None if every else jnp.asarray(carries)
         return self._label_mask[key]
 
+    def hop_mask(
+        self, types_key: Tuple[str, ...], reverse: bool,
+        labels: Tuple[str, ...], ctx,
+    ) -> Optional[Any]:
+        """``label_mask(labels)`` as ONE hop needs it: None also where every
+        node an edge of this CSR orientation points at carries the labels
+        (every LIKES source is a Person) — a fact of the index build, on
+        the host, decided once per hop and label set. A hop reads its far
+        node's mask through its edges alone, so the mask would pass every
+        lane it is read on."""
+        mask = self.label_mask(labels, ctx)
+        if mask is None:
+            return None
+        key = (types_key, reverse, tuple(sorted(labels)))
+        if key not in self._hop_label_proven:
+            self.csr(types_key, reverse, ctx)
+            far = self._csr_far_nodes[(types_key, reverse)]
+            carries = self._row_map_np[key[2]] >= 0
+            self._hop_label_proven[key] = not bool((far & ~carries).any())
+        return None if self._hop_label_proven[key] else mask
+
     def scan_is_whole(self, labels: Tuple[str, ...], ctx) -> bool:
         """True when the node scan of ``labels`` holds every logical node
         exactly once: as many rows as the graph has nodes, and every node
@@ -430,6 +455,9 @@ class GraphIndex:
         row_ptr, order, a_sorted = self._sorted_csr(a, b, n)
         degs = row_ptr[1:] - row_ptr[:-1]
         self._csr_max_deg[(types_key, reverse)] = int(degs.max()) if n else 0
+        pointed_at = np.zeros(n, dtype=bool)
+        pointed_at[b] = True  # the nodes this orientation's edges end in
+        self._csr_far_nodes[(types_key, reverse)] = pointed_at
         out = (
             # row_ptr is node-dim (replicated); the edge-dim arrays pad to
             # the shape bucket and shard over the active mesh (padded to a

@@ -617,19 +617,13 @@ def chain_forms(masked: Sequence[bool], whole: bool) -> Tuple[str, ...]:
       every node, which is ``sum_e w[ci[e]]``: one gather over the edge
       lanes and one reduction, no prefix sums;
     * ``scan``: per-node sums of non-constant weights, the prefix-scan
-      SpMV (``_csr_spmv``)."""
-    forms = []
-    constant = True
-    for i, m in enumerate(masked):
-        constant = constant and not m
-        if constant:
-            forms.append("degree")
-        elif whole and i == len(masked) - 1:
-            forms.append("reduce")
-        else:
-            forms.append("scan")
-        constant = False
-    return tuple(forms)
+      SpMV (``_csr_spmv``).
+
+    A chain is a tree without a branch: the rule is ``tree_forms``'."""
+    tree = ()
+    for k in range(len(masked)):  # the far end is the leaf
+        tree = ((k, False, tree),)
+    return tree_forms(tree, masked, whole)
 
 
 def _csr_spmv(rp, ci, w):
@@ -857,6 +851,110 @@ def path_count_chain_on_mesh(mesh, axis: str):
 
     _MESH_CHAIN_CACHE[(mesh, axis)] = run
     return run
+
+
+# ---------------------------------------------------------------------------
+# count over a pattern that is a TREE of expands: one multiplicity per node
+# from the leaves to the root, vectors that meet in a node multiply. The
+# chain above is its case without a branch.
+#
+# ``tree`` (static) is the root's children; a child is ``(k, optional,
+# children)`` with ``k`` the hop's place in ``hops`` — a hop as
+# ``path_count_chain`` takes it, its CSR's rows the node nearer the root,
+# its mask the far node's labels.
+# ---------------------------------------------------------------------------
+
+
+def tree_forms(tree, masked: Sequence[bool], whole: bool) -> Tuple[str, ...]:
+    """The form each hop of a tree count takes, by the hop's place in
+    ``hops`` — the three ``chain_forms`` describes, and the one rule for
+    both: ``degree`` where everything beyond the hop weighs the constant 1
+    (a leaf without a mask), ``reduce`` for the one required hop under a
+    root that holds every node once, ``scan`` otherwise."""
+    forms: Dict[int, str] = {}
+
+    def visit(children, at_root: bool):
+        for k, optional, below in children:
+            if not below and not masked[k]:
+                forms[k] = "degree"
+            elif at_root and whole and len(children) == 1 and not optional:
+                forms[k] = "reduce"
+            else:
+                forms[k] = "scan"
+            visit(below, False)
+
+    visit(tree, True)
+    return tuple(forms[k] for k in sorted(forms))
+
+
+def _tree_branch(child, hops, forms):
+    """int64[num_nodes] (32-bit for a plain degree): per node of the hop's
+    near end, the matches of everything beyond the hop — 1 at least where
+    the hop is OPTIONAL."""
+    k, optional, below = child
+    rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hops[k]
+    with jax.named_scope(f"hop{k}"):
+        if forms[k] == "degree":
+            got = _degrees(rp_a)
+            if rp_b is not None:
+                got = got.astype(jnp.int64) + _degrees(rp_b) - loop_cnt
+        else:
+            w = _tree_node_weight(below, mask, hops, forms).astype(jnp.int64)
+            got = _csr_spmv(rp_a, ci_a, w)
+            if rp_b is not None:
+                got = got + _csr_spmv(rp_b, ci_b, w) - loop_cnt * w
+        return jnp.maximum(got, 1) if optional else got
+
+
+def _tree_node_weight(children, mask, hops, forms):
+    """The branches that meet in a node multiplied, under the node's mask;
+    None while that is the constant 1."""
+    w = None
+    for child in children:
+        branch = _tree_branch(child, hops, forms).astype(jnp.int64)
+        w = branch if w is None else w * branch
+    if mask is not None:
+        w = (
+            mask.astype(jnp.int32)
+            if w is None
+            else jnp.where(mask, w, jnp.zeros((), w.dtype))
+        )
+    return w
+
+
+@partial(jax.jit, static_argnames=("tree", "whole"))
+def tree_count(root_weight, live_nodes, hops, tree, whole: bool, rows=None):
+    """count(*) of a pattern that is a tree of expands WITHOUT a row of it:
+    ONE program. ``root_weight`` is what a node weighs as the root — its
+    label mask or the number of input rows it holds; None (``whole``): every
+    node once. ``live_nodes`` (traced) is the number of real nodes: a bucket's
+    pad node has no edge, but an OPTIONAL branch makes it weigh 1. ``rows``
+    (traced; with a ``root_weight`` of input rows) is how many input rows
+    there are: one whose root is null, or no node of this graph, is in no
+    node's weight — a required branch drops it, and where every branch at
+    the root is OPTIONAL it stays once, all nulls."""
+    forms = tree_forms(tree, [h[5] is not None for h in hops], whole)
+    if "reduce" in forms:
+        (k, _, below), = tree
+        rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hops[k]
+        with jax.named_scope(f"hop{k}"):
+            w = _tree_node_weight(below, mask, hops, forms)
+            total = _csr_edge_sum(rp_a, ci_a, w)
+            if rp_b is not None:
+                total = total + _csr_edge_sum(rp_b, ci_b, w) - jnp.sum(
+                    loop_cnt * w, dtype=jnp.int64
+                )
+            return total
+    w = _tree_node_weight(tree, None, hops, forms)
+    with jax.named_scope("frontier"):
+        live = jnp.arange(w.shape[0], dtype=jnp.int32) < live_nodes
+        w = jnp.where(live, w, jnp.zeros((), w.dtype))
+        if root_weight is not None:
+            w = w * root_weight.astype(jnp.int64)
+        total = jnp.sum(w, dtype=jnp.int64)
+        if rows is not None and all(optional for _, optional, _ in tree):
+            total = total + rows - jnp.sum(root_weight, dtype=jnp.int64)
+        return total
 
 
 # ---------------------------------------------------------------------------

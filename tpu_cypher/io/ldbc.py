@@ -163,20 +163,28 @@ def graph_from_snb_arrays(session, arrays: Dict[str, np.ndarray]) -> ScanGraph:
     )
 
 
+# ``graph_from_tables`` takes a label SET a node table (``("Message",
+# "Post")``), not one label alone: what a loader of multi-label data asks for
+LABEL_SETS = True
+
+
 def graph_from_tables(
     session,
-    nodes: Mapping[str, Tuple[Any, Mapping[str, Tuple[Any, T.CypherType]]]],
+    nodes: Mapping[Any, Tuple[Any, Mapping[str, Tuple[Any, T.CypherType]]]],
     relationships: Mapping[
         str, Tuple[Any, Any, Mapping[str, Tuple[Any, T.CypherType]]]
     ],
 ) -> ScanGraph:
     """A ``ScanGraph`` of any number of node and relationship tables, one
-    per label and per relationship type, from host arrays:
+    per label combination and per relationship type, from host arrays:
 
-    * ``nodes[label] = (ids, {property: (column, cypher_type)})`` — int64
-      element ids, unique over ALL labels (the graph has one id space), and
-      the label's property columns. A property named ``id`` is the id column
-      itself, exposed to queries as ``n.id``;
+    * ``nodes[labels] = (ids, {property: (column, cypher_type)})`` — int64
+      element ids, unique over ALL tables (the graph has one id space), and
+      the table's property columns. ``labels`` is one label, or a tuple (or
+      frozenset) of the labels every node of the table carries:
+      ``("Message", "Post")`` and ``("Message", "Comment")`` are two tables
+      that ``(:Message)`` scans both of. A property named ``id`` is the id
+      column itself, exposed to queries as ``n.id``;
     * ``relationships[rel_type] = (source_ids, target_ids, {property:
       (column, cypher_type)})`` — the endpoints as node ids, one row per
       stored direction (a loader that wants an undirected edge walkable both
@@ -189,6 +197,11 @@ def graph_from_tables(
     tables: List[ElementTable] = []
     schema = PropertyGraphSchema.empty()
     for label, (ids, props) in nodes.items():
+        labels = frozenset({label} if isinstance(label, str) else label)
+        if not labels or not all(isinstance(x, str) for x in labels):
+            raise DataSourceError(
+                f"a node table's labels are a label or a tuple of labels, got {label!r}"
+            )
         ids = np.asarray(ids, dtype=np.int64)
         if len(ids) and int(ids.max()) >= EDGE_ID_OFFSET:
             raise DataSourceError(
@@ -199,13 +212,13 @@ def graph_from_tables(
             if key != "id":
                 cols[key] = column
         schema = schema.with_node_combination(
-            frozenset({label}), {k: t for k, (_, t) in props.items()}
+            labels, {k: t for k, (_, t) in props.items()}
         )
         tables.append(
             ElementTable(
                 NodeMapping(
                     id_key="id",
-                    implied_labels=frozenset({label}),
+                    implied_labels=labels,
                     property_mapping=tuple((k, k) for k in props),
                 ),
                 session.table_cls.from_arrays(cols),

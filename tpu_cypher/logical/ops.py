@@ -128,6 +128,23 @@ class NodeScan(UnaryOp):
 
 
 @dataclass(frozen=True)
+class RowIndex(UnaryOp):
+    """``in_op``'s rows with their number (0 .. n-1) in ``fld``: the key an
+    ``Optional`` joins its two sides on."""
+
+    fld: str
+
+    @property
+    def fields(self) -> FieldsT:
+        from ..api.types import CTInteger
+
+        return self.in_op.fields + ((self.fld, CTInteger),)
+
+    def _show_inner(self) -> str:
+        return self.fld
+
+
+@dataclass(frozen=True)
 class PatternScan(UnaryOp):
     """Scan a stored composite pattern (NodeRel / Triplet): one table scan
     binds several query fields at once. Produced by the optimizer rule
@@ -323,7 +340,21 @@ class ValueJoin(BinaryOp):
 
 @dataclass(frozen=True)
 class Optional(BinaryOp):
-    """OPTIONAL MATCH: rhs plans the optional part over lhs's fields."""
+    """OPTIONAL MATCH: rhs plans the optional part over lhs's fields.
+
+    ``row_field``: the field of ``lhs`` (a ``RowIndex``) that numbers its
+    rows, carried through ``rhs`` — the one join key. Every left row then
+    comes out once per match or once with nulls, whatever an earlier
+    OPTIONAL MATCH left null (a null key matches nothing) and however many
+    left rows are equal. It is not a field of the result."""
+
+    row_field: str
+
+    @property
+    def fields(self) -> FieldsT:
+        return tuple(
+            (n, t) for n, t in super().fields if n != self.row_field
+        )
 
 
 @dataclass(frozen=True)

@@ -233,13 +233,17 @@ class LogicalPlanner:
     def plan_match(self, blk: B.MatchBlock, plan: L.LogicalOperator) -> L.LogicalOperator:
         # paths bind before predicates so WHERE can reference the path var
         if blk.optional:
+            # the optional side is planned over the left rows NUMBERED, and
+            # joined back on the number alone (``L.Optional.row_field``)
+            row = self.fresh("optrow")
+            plan = L.RowIndex(plan, row)
             rhs = self._plan_pattern(blk.pattern, plan)
             for pname, fields in sorted(blk.pattern.paths.items()):
                 rhs = L.BindPath(rhs, pname, tuple(fields))
                 self._path_entities[pname] = tuple(fields)
             for p in blk.predicates:
                 rhs = self._plan_predicate(p, rhs)
-            return L.Optional(plan, rhs)
+            return L.Optional(plan, rhs, row)
         plan = self._plan_pattern(blk.pattern, plan)
         for pname, fields in sorted(blk.pattern.paths.items()):
             plan = L.BindPath(plan, pname, tuple(fields))

@@ -66,11 +66,20 @@ class GraphStatistics:
         key = self.labels_key(labels)
         got = self._node_counts.get(key)
         if got is None:
-            op = self.graph.scan_operator(
-                _STATS_VAR, T.CTNodeType(frozenset(key)), self._ctx
+            got = self._node_counts[key] = self._scan_rows(
+                T.CTNodeType(frozenset(key))
             )
-            got = self._node_counts[key] = int(op.table.size)
         return got
+
+    def _scan_rows(self, ct) -> int:
+        """The rows of the graph's scan of ``ct``: from the stored tables'
+        sizes where the graph can tell (``ScanGraph.scan_rows``), else by
+        building the scan."""
+        known = getattr(self.graph, "scan_rows", None)
+        n = known(ct) if known is not None else None
+        if n is None:
+            n = self.graph.scan_operator(_STATS_VAR, ct, self._ctx).table.size
+        return int(n)
 
     def rel_count(self, types=()) -> int:
         """Logical row count of the canonical relationship scan for a
@@ -78,10 +87,9 @@ class GraphStatistics:
         key = self.labels_key(types)
         got = self._rel_counts.get(key)
         if got is None:
-            op = self.graph.scan_operator(
-                _STATS_VAR, T.CTRelationshipType(frozenset(key)), self._ctx
+            got = self._rel_counts[key] = self._scan_rows(
+                T.CTRelationshipType(frozenset(key))
             )
-            got = self._rel_counts[key] = int(op.table.size)
         return got
 
     def label_selectivity(self, labels=()) -> float:

@@ -46,6 +46,7 @@ _MESH_DECLINES = _REGISTRY.counter(
 for _op, _reason in (
     ("join", "overflow"), ("distinct", "overflow"), ("agg", "gate"),
     ("expand", "unpadded_edges"), ("expand", "chain_constraint"),
+    ("expand", "tree_count"),
 ):
     _MESH_DECLINES.inc(0, op=_op, reason=_reason)
 

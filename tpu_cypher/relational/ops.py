@@ -33,14 +33,15 @@ class RelationalError(Exception):
 # (``rows``); ``AggregateOp`` is the one place that counts
 COUNT_PUSHDOWN = _OBS_REGISTRY.counter(
     "tpu_cypher_count_pushdown_total",
-    "ungrouped count(*) over a filter, an inner join, a DISTINCT or an "
-    "expand chain under constraints between two of its nodes (one a "
-    "constraint): answered from the operator's count phase (count) or "
-    "from its rows (rows)",
+    "ungrouped count(*) over a filter, an inner join, a DISTINCT, a tree "
+    "of fused expands or an expand chain under constraints between two of "
+    "its nodes (one a constraint): answered from the operator's count "
+    "phase (count) or from its rows (rows)",
     labels=("op", "outcome"),
 )
 for _outcome in ("count", "rows"):  # a sound deployment reads 0 rows: exported
     COUNT_PUSHDOWN.inc(0, op="chain_constraint", outcome=_outcome)
+    COUNT_PUSHDOWN.inc(0, op="tree", outcome=_outcome)
 
 
 @dataclass
@@ -278,6 +279,28 @@ class AddOp(RelationalOperator):
         return f"{self.fld} := {self.expr.pretty_expr()}"
 
 
+class RowIndexOp(RelationalOperator):
+    """The input's rows with their number (0 .. n-1) in a new integer
+    field: the key ``RelationalPlanner._plan_Optional`` joins on."""
+
+    def __init__(self, in_op: RelationalOperator, fld: str):
+        super().__init__(in_op)
+        self.fld = fld
+
+    @cached_property
+    def var(self) -> E.Var:
+        return E.Var(self.fld).with_type(T.CTInteger)
+
+    def _compute_header(self) -> RecordHeader:
+        return self.children[0].header.with_expr(self.var)
+
+    def _compute_table(self) -> Table:
+        return self.children[0].table.with_row_index(self.header.column(self.var))
+
+    def _show_inner(self) -> str:
+        return self.fld
+
+
 class DropOp(RelationalOperator):
     def __init__(self, in_op: RelationalOperator, exprs: Sequence[E.Expr]):
         super().__init__(in_op)
@@ -286,7 +309,7 @@ class DropOp(RelationalOperator):
     def _compute_header(self) -> RecordHeader:
         h = self.children[0].header
         m = {e: c for e, c in ((e, h.get(e)) for e in h.expressions) if e not in self.exprs}
-        return RecordHeader(m)
+        return RecordHeader(m, h._paths)
 
     def _compute_table(self) -> Table:
         keep = self.header.columns
@@ -535,17 +558,33 @@ class AggregateOp(RelationalOperator):
             return self._note_pushdown("distinct", n)
         # projections keep the multiset: look through them
         inner = in_op
-        while inner._table is None and isinstance(inner, (SelectOp, CacheOp)):
+        while inner._table is None and isinstance(inner, (SelectOp, CacheOp, DropOp)):
             inner = inner.children[0]
         if inner._table is not None:
             _obs_trace.note("count_from", "table")
             return inner._table.size
+        tree_count = getattr(inner, "tree_count", None)
+        if tree_count is not None:
+            # a tree of fused expands (a path, a star, OPTIONAL leaves):
+            # multiplicities per node, no row of the pattern
+            with _obs_trace.span(type(inner).__name__, kind="operator", count_only=True):
+                n = tree_count()
+            return self._note_pushdown("tree", n)
         if not isinstance(inner, (FilterOp, JoinOp)):
             return None
         if isinstance(inner, FilterOp):
             n = self._chain_constraint_count(inner)
             if n is not None:
                 return n
+            under = inner
+            while isinstance(under, (FilterOp, SelectOp, CacheOp, DropOp)):
+                under = under.children[0]
+            if hasattr(under, "tree_count"):
+                # a predicate over a tree of expands that is no mask of one
+                # of its nodes (relationship uniqueness between two hops of
+                # one type, ...): the tree count declines, the filter counts
+                COUNT_PUSHDOWN.inc(op="tree", outcome="rows")
+                _obs_trace.note("tree_decline", "predicate")
         op = "filter" if isinstance(inner, FilterOp) else "join"
         # the operator's span, as ``table`` would have opened it
         with _obs_trace.span(type(inner).__name__, kind="operator", count_only=True):

@@ -42,6 +42,7 @@ from .ops import (
     PathBindOp,
     RelationalError,
     RelationalOperator,
+    RowIndexOp,
     RelationalRuntimeContext,
     SelectOp,
     SkipOp,
@@ -255,16 +256,27 @@ class RelationalPlanner:
                     pairs.append((e, e))
         return pairs
 
+    def _plan_RowIndex(self, op: L.RowIndex) -> RelationalOperator:
+        return RowIndexOp(self.process(op.in_op), op.fld)
+
     def _plan_Optional(self, op: L.Optional) -> RelationalOperator:
         """Reference ``RelationalPlanner.scala:298``: Optional = left outer
         join — or the fused left-outer CSR expand when the backend offers
-        one (classic join kept as the same-header shadow plan)."""
+        one (classic join kept as the same-header shadow plan).
+
+        The join key is the NUMBER of the left row (``op.row_field``, which
+        ``rhs`` carries through its expands) and nothing else. The
+        reference joins on every field the sides share, and so did this
+        until PR 34: a field an earlier OPTIONAL MATCH left null is then a
+        null key, which matches nothing and loses the row's matches, and
+        equal left rows match each other's copies (m rows came out m * m)."""
         lhs, rhs = self.process(op.lhs), self.process(op.rhs)
-        pairs = self._common_join_pairs(lhs, rhs)
-        classic = JoinOp(lhs, rhs, pairs, "left_outer")
+        row = lhs.header.var(op.row_field)
+        classic = DropOp(JoinOp(lhs, rhs, [(row, row)], "left_outer"), [row])
         fast = getattr(self.ctx.table_cls, "plan_optional_expand_fastpath", None)
-        if fast is not None:
-            out = fast(self, op, lhs, rhs, classic)
+        if fast is not None and isinstance(lhs, RowIndexOp):
+            # the fused expand keeps its input's rows apart by position
+            out = fast(self, op, lhs.children[0], rhs, classic)
             if out is not None:
                 return out
         return classic

@@ -212,6 +212,7 @@ _KNOWN = (
     O.SkipOp,
     O.LimitOp,
     O.DropOp,
+    O.RowIndexOp,
 )
 
 
