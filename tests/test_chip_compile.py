@@ -142,6 +142,26 @@ def test_scan_free_count_chain_compiles(one_chip, hops):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_windowed_scan_hop_compiles_to_one_gather_at_the_window(one_chip):
+    """A ``scan`` hop whose CSR holds 2**11 rows of a 2**17-node id space:
+    the prefix sums are gathered once, at the window's 2**11 + 1 row
+    pointers, and the sums placed by a ``dynamic-update-slice`` — no gather
+    of the id space's length is left, and no scatter."""
+    nodes, edges, window = 1 << 17, 4_500_000, 1 << 11
+    rp, ci, _ = _csr(one_chip, nodes, edges)
+    span = (one_chip((window + 1,), I32), one_chip((), I32))
+    hop = (rp, ci, None, None, None, one_chip((nodes,), BOOL), span, None)
+    compiled = J.path_count_chain.lower(
+        one_chip((nodes,), I64), one_chip((nodes,), I64), None, (hop,),
+        num_nodes=nodes,
+    ).compile()
+    text = compiled.as_text()
+    assert "dynamic-update-slice" in text and " scatter(" not in text
+    assert f"[{window + 1}]" in text
+    gathers = [l for l in text.splitlines() if " gather(" in l]
+    assert gathers and all(f"[{nodes + 1}]" not in l for l in gathers)
+
+
 def test_expand_materialize_counted_compiles(one_chip):
     rp, ci, eo = _csr(one_chip)
     J.expand_materialize_counted.lower(
@@ -368,6 +388,25 @@ def test_sharded_count_chain_compiles_for_four_chips(four_chips, graph):
     ).compile()
     assert "all-reduce" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_sharded_windowed_scan_sums_the_window_over_the_mesh(four_chips):
+    """The sharded ``scan`` hop under a window: the ``psum`` carries the
+    window's 2**11 sums, not one per node of the id space."""
+    nodes, edges, window = 1 << 17, 4_500_000, 1 << 11
+    mesh, shape = four_chips
+    rp = shape((nodes + 1,), I32, P())
+    ci = shape((edges,), I32, P("rows"))
+    span = (shape((window + 1,), I32, P()), shape((), I32, P()))
+    hop = (rp, ci, None, None, None, shape((nodes,), BOOL, P()), span, None)
+    run = J.path_count_chain_on_mesh(mesh, "rows")
+    text = run.lower(
+        shape((nodes,), I64, P()), shape((nodes,), I64, P("rows")), None,
+        (hop,), num_nodes=nodes,
+    ).compile().as_text()
+    reduces = [l for l in text.splitlines() if " all-reduce(" in l or " all-reduce-start(" in l]
+    assert any(f"[{window}]" in l for l in reduces)
+    assert all(f"[{nodes}]" not in l for l in reduces)
 
 
 def test_sharded_scan_free_count_chain_compiles_for_four_chips(four_chips):

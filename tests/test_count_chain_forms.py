@@ -23,6 +23,7 @@ from tpu_cypher.backend.local.table import LocalTable
 from tpu_cypher.backend.tpu import bucketing
 from tpu_cypher.backend.tpu import jit_ops as J
 from tpu_cypher.obs import trace as obs_trace
+from tpu_cypher.obs.metrics import REGISTRY
 from tpu_cypher.relational.graphs import ElementTable, ScanGraph
 from tpu_cypher.relational.session import PropertyGraph
 
@@ -108,12 +109,20 @@ def small():
             "carries": carries, "witness": {}}
 
 
+NODE_LANES = "tpu_cypher_count_scan_node_lanes_total"
+
+
 def _forms_of(run):
+    """What ``run`` gives and its hops by form; on the way: row pointers
+    are gathered at by the ``scan`` hops alone."""
     before = {f: obs_trace.COUNT_CHAIN_HOPS.value(form=f) for f in FORMS}
+    lanes = REGISTRY.flat().get(NODE_LANES, 0.0)
     out = run()
-    return out, tuple(
+    forms = tuple(
         int(obs_trace.COUNT_CHAIN_HOPS.value(form=f) - before[f]) for f in FORMS
     )
+    assert (REGISTRY.flat()[NODE_LANES] > lanes) == (forms[2] > 0)
+    return out, forms
 
 
 def _lbl(label):

@@ -174,6 +174,27 @@ def test_the_three_queries_answer_as_the_enumeration_without_a_row(
     assert after[SERIES % "count"] - before.get(SERIES % "count", 0) == 3
     assert after[SERIES % "rows"] == before.get(SERIES % "rows", 0)
     assert built == []
+    # the new metric's file, read as the harness reads it over this pass:
+    # the row pointers the eight scan hops gathered at, far fewer than the
+    # eight whole id spaces the program read until PR 35
+    import types
+
+    sys.path.insert(0, os.path.join(CHIPBENCH, "readers"))
+    try:
+        import counter_per_pass
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(CHIPBENCH, "metrics", "scan_node_lanes.lsqb_tree.json")) as f:
+        spec = json.load(f)
+    window = types.SimpleNamespace(
+        counters={k: v - before.get(k, 0) for k, v in after.items()}, passes=1)
+    lanes = counter_per_pass.read(window, **spec["args"])
+    from tpu_cypher.backend.tpu.graph_index import GraphIndex
+
+    space = GraphIndex.of(graph._graph).num_nodes + 1
+    assert 8 * 32 < lanes < 8 * space and lanes == int(lanes)
+    assert counter_per_pass.read(
+        types.SimpleNamespace(counters={}, passes=1), **spec["args"]) is None
 
 
 def test_the_planner_as_it_was_answers_q7_wrongly_on_this_data(
@@ -235,6 +256,16 @@ def test_rehearsal_is_correct_with_three_shapes_a_pass():
     assert result["attempted"] == 3 * left["passes"]
     assert result["metrics"] == {}  # a CPU's numbers are withheld
     assert "metrics read and withheld" in proc.stdout
+    # the traced rehearsal loads every per-layer metric of the cell by its
+    # file: PR 35's among them, and the counter it names is exported
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = [m for m in json.load(f)["per_layer"]
+                  if m["name"] == "scan_node_lanes.lsqb_tree"]
+    assert [m["workloads"] for m in listed] == [[CELL]]
+    with open(os.path.join(CHIPBENCH, "metrics", "scan_node_lanes.lsqb_tree.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter_per_pass"
+    assert spec["args"]["counters"] == ["tpu_cypher_count_scan_node_lanes_total"]
 
 
 def test_the_stale_control_comes_out_not_correct():

@@ -294,6 +294,41 @@ def test_sharded_programs_emit_xla_collectives(meshed):
     assert any(k in txt2 for k in _COLLECTIVES), "no collective in join HLO"
 
 
+@pytest.mark.parametrize("spans", [True, False], ids=["windows", "whole_row_ptr"])
+def test_sharded_scan_gathers_once_and_equals_one_device(spans):
+    """A masked three-hop chain (three ``scan`` hops) over typed CSRs whose
+    edge lanes are sharded over eight devices: per shard ONE gather of its
+    prefix sums, at the window's row pointers clipped into the shard, the
+    ``psum`` over the window's sums alone — against the one-device program
+    and a dense NumPy product, with the index's windows and without."""
+    import jax
+    import jax.numpy as jnp
+
+    import test_spmv_row_span as RS
+    import tpu_cypher.backend.tpu.jit_ops as J
+
+    mesh = make_row_mesh(jax.devices()[:8])
+    w = np.ones(RS.NODES, dtype=np.int64)
+    one_device, sharded = [], []
+    for a in reversed(RS.typed_world()):
+        hop, dense = RS.hop_of(a, "fwd", True, spans=spans)
+        w = dense @ (RS.EVEN * w)
+        one_device.insert(0, hop)
+        with use_mesh(mesh):
+            sharded.insert(0, (hop[0], shard_rows(hop[1])) + hop[2:])
+    assert J.chain_forms([True] * 3, False) == ("scan",) * 3
+    dev_ids = jnp.asarray(np.arange(RS.NODES, dtype=np.int64))
+    picked = np.array([9, 9, 12, 25, 29])
+    want = int(w[picked].sum())
+    assert want > 0
+    alone = J.path_count_chain(
+        dev_ids, jnp.asarray(picked), None, tuple(one_device), num_nodes=RS.NODES)
+    with use_mesh(mesh):
+        on_mesh = J.path_count_chain_on_mesh(mesh, mesh.axis_names[0])(
+            dev_ids, jnp.asarray(picked), None, tuple(sharded), num_nodes=RS.NODES)
+    assert int(alone) == int(on_mesh) == want
+
+
 # ---------------------------------------------------------------------------
 # ISSUE 13 tiers: per-shard partial aggregates, hash-repartition DISTINCT,
 # and the sharded WCOJ count — each proven to RUN (its counter advances)

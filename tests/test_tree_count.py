@@ -31,6 +31,7 @@ from tpu_cypher.relational.session import PropertyGraph
 
 SERIES = "tpu_cypher_count_pushdown_total{op=tree,outcome=%s}"
 LANES = "tpu_cypher_tree_count_lanes_total"
+NODE_LANES = "tpu_cypher_count_scan_node_lanes_total"
 HOPS = "tpu_cypher_count_chain_hops_total{form=%s}"
 
 # programs that build rows of a pattern: none may run under a tree count
@@ -217,7 +218,7 @@ def world(request):
 
 def _counters():
     flat = REGISTRY.flat()
-    names = [SERIES % "count", SERIES % "rows", LANES]
+    names = [SERIES % "count", SERIES % "rows", LANES, NODE_LANES]
     names += [HOPS % form for form in ("degree", "reduce", "scan")]
     return {name: flat.get(name, 0.0) for name in names}
 
@@ -274,6 +275,7 @@ def test_a_tree_of_expands_is_counted_without_a_row(world, monkeypatch, shape):
     forms = {f: after[HOPS % f] - before[HOPS % f] for f in ("degree", "reduce", "scan")}
     assert sum(forms.values()) == hops, forms
     assert (after[LANES] > before[LANES]) == bool(forms["reduce"] + forms["scan"])
+    assert (after[NODE_LANES] > before[NODE_LANES]) == bool(forms["scan"])
     if starts_from is not None:
         assert _base_frontier(result.relational_plan) == starts_from
 
@@ -394,6 +396,63 @@ def test_an_unwound_null_under_an_optional_expand_is_one_row(session):
     assert [dict(r) for r in got] == [{"n": want}]
 
 
+def test_scan_node_lanes_are_the_windows_of_the_scan_hops(world):
+    """The counter moves by the row pointers each ``scan`` hop gathers at —
+    its CSR's window and one — and by none for the ``degree`` leaves; the
+    span carries the same number as ``node_lanes``."""
+    from tpu_cypher.backend.tpu.graph_index import GraphIndex
+
+    nodes, rels, graph = world
+    before = _counters()
+    result = graph.cypher(SHAPES["star_two_optional"][0])
+    result.records.collect()
+    after = _counters()
+    ops = expand_op._tree_ops(_counted(result.relational_plan))
+    tree, order = expand_op._read_tree(ops)
+    gi, ctx = GraphIndex.of(graph._graph), ops[-1].context
+    hops = [expand_op._hop_arrays(gi, op, ctx) for op in order]
+    forms = J.tree_forms(tree, [h[5] is not None for h in hops], False)
+    assert sorted(forms) == ["degree", "degree", "scan", "scan"]
+    want = sum(
+        gi.csr_row_span(op.types_key, op.backwards, ctx).row_ptr.shape[0]
+        for op, form in zip(order, forms) if form == "scan"
+    )
+    # the tags' and the messages' runs, not two whole id spaces
+    assert 0 < want < 2 * (gi.num_nodes + 1)
+    assert after[NODE_LANES] - before[NODE_LANES] == want
+    assert f"node_lanes={want}" in str(result.profile())
+
+
+def test_hops_that_scan_nothing_gather_at_no_row_pointer(world):
+    """Two leaves that are degrees (the plan starts from the middle): edge
+    lanes or none, but no prefix sum and so no row pointer gathered at.
+    (``test_count_chain_forms`` holds the same of every ``reduce`` hop.)"""
+    nodes, rels, graph = world
+    before = _counters()
+    got = graph.cypher(
+        "MATCH (a)-[:LIKES]->(b)-[:HAS_CREATOR]->(c) RETURN count(*) AS n"
+    ).records.collect()
+    after = _counters()
+    assert got[0]["n"] > 0
+    forms = {f: after[HOPS % f] - before[HOPS % f] for f in ("degree", "reduce", "scan")}
+    assert forms["scan"] == 0 and forms["degree"] + forms["reduce"] == 2
+    assert after[NODE_LANES] == before[NODE_LANES]
+
+
+def test_scan_node_lanes_are_exported_as_zero_before_any_query():
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import tpu_cypher.backend.tpu.expand_op\n"
+         "from tpu_cypher.obs.metrics import REGISTRY\n"
+         "print(REGISTRY.prometheus_text())"],
+        capture_output=True, text=True, timeout=300, check=True,
+    ).stdout
+    assert f"\n{NODE_LANES} 0\n" in out
+
+
 def test_both_series_of_the_tree_count_are_exported_from_the_start():
     text = REGISTRY.prometheus_text()
     for outcome in ("count", "rows"):
@@ -407,7 +466,7 @@ def test_the_tree_count_leaves_its_span_and_notes(world):
     text = str(result.profile())
     assert "AggregateOp" in text and "count_from=tree" in text
     for noted in ("tree_count", "hops=4", "optional_branches=2", "branches=3",
-                  "edge_lanes=", "sites=expand:1", "count_only=True"):
+                  "edge_lanes=", "node_lanes=", "sites=expand:1", "count_only=True"):
         assert noted in text, noted
 
 

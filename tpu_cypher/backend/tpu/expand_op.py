@@ -135,35 +135,58 @@ _TREE_LANES_TOTAL = _OBS_REGISTRY.counter(
 )
 
 
+_SCAN_NODE_LANES_TOTAL = _OBS_REGISTRY.counter(
+    "tpu_cypher_count_scan_node_lanes_total",
+    "row-pointer lanes the scan hops of counts gather prefix sums at: the "
+    "window of rows the hop's CSR holds and one (jit_ops._csr_spmv), both "
+    "orientations of an undirected hop; a degree or reduce hop gathers none",
+)
+_SCAN_NODE_LANES_TOTAL.inc(0)  # exported from the start
+
+
 def _note_tree_lanes(forms, hop_data) -> None:
-    """The edge lanes a count's hops read (``hop_data`` as
-    ``path_count_chain`` takes it, ``forms`` beside it), on the counter and
-    as ``edge_lanes`` on the open span."""
-    lanes = sum(
-        int(h[1].shape[0]) + (int(h[3].shape[0]) if h[3] is not None else 0)
-        for h, form in zip(hop_data, forms) if form != "degree"
-    )
-    _TREE_LANES_TOTAL.inc(lanes)
-    _obs_trace.note("edge_lanes", lanes)
+    """The lanes a count's hops read (``hop_data`` as ``path_count_chain``
+    takes it, ``forms`` beside it), on the counters and on the open span:
+    ``edge_lanes``, every lane of a ``reduce`` or ``scan`` hop's CSR, and
+    ``node_lanes``, the row pointers a ``scan`` hop gathers at — its
+    window's, or all of them where the hop carries no span."""
+    edge_lanes = node_lanes = 0
+    for h, form in zip(hop_data, forms):
+        rp_a, ci_a, rp_b, ci_b, _, _, span_a, span_b = J.hop_parts(h)
+        for rp, ci, span in ((rp_a, ci_a, span_a), (rp_b, ci_b, span_b)):
+            if rp is None or form == "degree":
+                continue
+            edge_lanes += int(ci.shape[0])
+            if form == "scan":
+                node_lanes += int((rp if span is None else span[0]).shape[0])
+    _TREE_LANES_TOTAL.inc(edge_lanes)
+    _SCAN_NODE_LANES_TOTAL.inc(node_lanes)
+    _obs_trace.note("edge_lanes", edge_lanes)
+    _obs_trace.note("node_lanes", node_lanes)
 
 
 def _hop_arrays(gi: GraphIndex, hop, ctx):
     """One hop as ``jit_ops.path_count_chain`` / ``tree_count`` take it:
-    ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask)`` — the CSR whose rows are
-    the hop's near node, for an undirected hop the opposite orientation and
-    the self-loop counts beside it, and the far node's label mask: None
-    where the index build proves the labels of every node the hop's edges
-    reach (every LIKES source is a Person; ``GraphIndex.hop_mask``), in
-    both orientations for an undirected hop. The one place that decides
-    whether a hop of a count needs a mask."""
+    ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask, span_a, span_b)`` — the CSR
+    whose rows are the hop's near node, for an undirected hop the opposite
+    orientation and the self-loop counts beside it, the far node's label
+    mask, and per orientation the window of rows its CSR holds
+    (``GraphIndex.csr_row_span``: what bounds a ``scan`` hop's node side).
+    The mask is None where the index build proves the labels of every node
+    the hop's edges reach (every LIKES source is a Person;
+    ``GraphIndex.hop_mask``), in both orientations for an undirected hop.
+    The one place that builds a hop of a count, and so the one that decides
+    whether it needs a mask."""
     rp, ci, _ = gi.csr(hop.types_key, hop.backwards, ctx)
+    span = gi.csr_row_span(hop.types_key, hop.backwards, ctx).window
     mask = gi.hop_mask(hop.types_key, hop.backwards, hop.far_labels, ctx)
     if not getattr(hop, "undirected", False):
-        return rp, ci, None, None, None, mask
+        return rp, ci, None, None, None, mask, span, None
     rp_b, ci_b, _ = gi.csr(hop.types_key, not hop.backwards, ctx)
+    span_b = gi.csr_row_span(hop.types_key, not hop.backwards, ctx).window
     if mask is None:
         mask = gi.hop_mask(hop.types_key, not hop.backwards, hop.far_labels, ctx)
-    return rp, ci, rp_b, ci_b, gi.loop_count(hop.types_key, ctx), mask
+    return rp, ci, rp_b, ci_b, gi.loop_count(hop.types_key, ctx), mask, span, span_b
 
 
 def _pad_mask(mask, npad: int):
@@ -879,13 +902,16 @@ class CsrExpandOp(_TreeCounted, _FusedExpandBase):
             )
         if on_mesh:
             # per edge pass (two for an undirected hop) every shard hands
-            # the mesh one 64-bit scalar in the reduce form and one per
-            # node in the scan form; a degree hop reads no edge
-            words = {"degree": 0, "reduce": 1, "scan": gi.num_nodes}
-            note_exchange("expand", size * 8 * sum(
-                words[form] * (2 if h[2] is not None else 1)
-                for h, form in zip(reversed(hop_data), forms)
-            ))
+            # the mesh one 64-bit scalar in the reduce form and one per row
+            # of the CSR's window in the scan form; a degree hop reads no edge
+            words = 0
+            for h, form in zip(reversed(hop_data), forms):
+                spans = [sp for sp in J.hop_parts(h)[6:] if sp is not None]
+                if form == "reduce":
+                    words += len(spans)
+                elif form == "scan":
+                    words += sum(int(sp[0].shape[0]) - 1 for sp in spans)
+            note_exchange("expand", size * 8 * words)
             _MESH_EXPAND_TOTAL.inc()
         with _obs_trace.sync("expand"):  # the read that waits for the chain
             return int(n_dev)
@@ -1010,10 +1036,15 @@ class CsrExpandOp(_TreeCounted, _FusedExpandBase):
         # left: the pattern's first node carried to node i, each step over
         # the CSR whose rows are the node reached; right: its last node
         # carried back to node i + 2
+        def step(types, reverse, node):
+            return gi.csr(types, reverse, ctx)[:2] + (
+                weight[node], gi.csr_row_span(types, reverse, ctx).window
+            )
+
         left = J.chain_node_weights(
             weight[names[0]],
             tuple(
-                gi.csr(types, not reverse, ctx)[:2] + (weight[names[j + 1]],)
+                step(types, not reverse, names[j + 1])
                 for j, (types, reverse, _) in enumerate(steps[:i])
             ),
             num_nodes=n,
@@ -1021,7 +1052,7 @@ class CsrExpandOp(_TreeCounted, _FusedExpandBase):
         right = J.chain_node_weights(
             weight[names[-1]],
             tuple(
-                gi.csr(types, reverse, ctx)[:2] + (weight[names[j]],)
+                step(types, reverse, names[j])
                 for j, (types, reverse, _) in reversed(
                     list(enumerate(steps))[i + 2:]
                 )

@@ -39,7 +39,7 @@ from ...ir import expr as E
 from ...obs import trace as _obs_trace
 from ...obs.metrics import REGISTRY as _REGISTRY
 from ...runtime.faults import fault_point
-from .bucketing import ID_SENTINEL, bucket_pad_host
+from .bucketing import ID_SENTINEL, bucket_pad_host, round_size
 from .column import Column, TpuBackendError, device_padded
 
 # canonical scan variable names (reserved: queries cannot produce '$' vars)
@@ -118,6 +118,24 @@ def rekey_element_expr(e: E.Expr, canon: E.Var) -> Optional[E.Expr]:
 
 
 @dataclass(frozen=True)
+class RowSpan:
+    """The rows one CSR holds, and the window of the node space a program
+    reads them through (``GraphIndex.csr_row_span``): ``start <= lo`` and
+    ``hi <= start + L <= num_nodes``, ``L`` on the bucket lattice."""
+
+    lo: int  # the first row with an edge
+    hi: int  # one past the last; lo == hi: the type has no edge
+    start: int  # the row the window starts at
+    row_ptr: Any  # int32[L + 1] device: row_ptr[start : start + L + 1]
+
+    @property
+    def window(self) -> Tuple[Any, np.int32]:
+        """``(row_ptr slice, start)`` as ``jit_ops._csr_spmv`` takes a span:
+        the slice's shape is static, where it starts is traced."""
+        return self.row_ptr, np.int32(self.start)
+
+
+@dataclass(frozen=True)
 class WedgeAdjacency:
     """One CSR as a dense matrix (``GraphIndex.wedge_adjacency``)."""
 
@@ -177,6 +195,8 @@ class GraphIndex:
         # (types_key, reverse) -> host max out-degree (Pallas eligibility
         # probe — computed once at build, never synced per query)
         self._csr_max_deg: Dict[Tuple[Tuple[str, ...], bool], int] = {}
+        # (types_key, reverse) -> the rows that CSR holds and their window
+        self._csr_span: Dict[Tuple[Tuple[str, ...], bool], RowSpan] = {}
         # per CSR orientation, host bool[num_nodes]: the nodes its edges end
         # in; per (orientation, labels): do all of those carry the labels
         self._csr_far_nodes: Dict[Tuple[Tuple[str, ...], bool], np.ndarray] = {}
@@ -471,6 +491,7 @@ class GraphIndex:
             device_padded(order.astype(np.int64), 0)[0],
         )
         self._csr[(types_key, reverse)] = out
+        self._csr_span[(types_key, reverse)] = self._row_span(row_ptr, out[0])
         if (types_key, reverse) not in self._edge_keys:
             # this CSR orientation is lexsorted by (a, b) => a*N + b keys
             # sorted (forward: src*N + dst; reverse: dst*N + src); the pad
@@ -491,6 +512,36 @@ class GraphIndex:
                 np.bincount(loops, minlength=n).astype(np.int64)
             )
         return out
+
+    @staticmethod
+    def _row_span(row_ptr: np.ndarray, row_ptr_dev) -> RowSpan:
+        """The run of rows with an edge, from the host ``row_ptr`` (it
+        never falls: two binary searches), and a window over it whose
+        length follows the lattice the node space itself follows — so two
+        graphs of one deployment share a program — clamped into the node
+        space. A type without an edge gets the smallest window; a window
+        as long as the node space is ``row_ptr`` itself, not a copy."""
+        n = len(row_ptr) - 1
+        edges = int(row_ptr[-1])
+        lo = int(np.searchsorted(row_ptr, 0, side="right")) - 1 if edges else 0
+        hi = int(np.searchsorted(row_ptr, edges, side="left")) if edges else 0
+        length = min(round_size(max(hi - lo, 1)), n)
+        start = min(lo, n - length)
+        window = (
+            row_ptr_dev if length == n
+            else jnp.asarray(row_ptr[start:start + length + 1])
+        )
+        return RowSpan(lo, hi, start, window)
+
+    def csr_row_span(
+        self, types_key: Tuple[str, ...], reverse: bool, ctx
+    ) -> RowSpan:
+        """The rows one CSR orientation holds (a host fact of its build):
+        a label's nodes are one run of the sorted id space, and a typed CSR
+        has rows only inside the run of the labels its edges start from."""
+        if (types_key, reverse) not in self._csr_span:
+            self.csr(types_key, reverse, ctx)
+        return self._csr_span[(types_key, reverse)]
 
     def csr_undirected(self, types_key: Tuple[str, ...], ctx):
         """(row_ptr, col_idx, edge_orig) for the BOTH-ORIENTATION graph of

@@ -626,17 +626,32 @@ def chain_forms(masked: Sequence[bool], whole: bool) -> Tuple[str, ...]:
     return tree_forms(tree, masked, whole)
 
 
-def _csr_spmv(rp, ci, w):
+def _csr_spmv(rp, ci, w, span=None):
     """(A w)[n] = sum of w[ci[e]] over n's CSR edge range — computed as a
     cumsum difference at row_ptr boundaries: gathers + one scan, ZERO
     scatters (TPU scatter-add serializes; this stays on the VPU). Pad
     safety: a sharding pad tail (``ci`` = -1, clipped to 0) accumulates
-    into cumsum positions past ``rp[-1]`` that no boundary ever reads."""
+    into cumsum positions past ``rp[-1]`` that no boundary ever reads.
+
+    The node side is bounded by the rows the CSR holds, not by the id
+    space: ``span`` = ``(window, start)`` (``GraphIndex.csr_row_span``) is
+    the slice ``rp[start : start + L + 1]`` and where it starts (traced;
+    ``L`` is the slice's static shape). The prefix sums are gathered ONCE,
+    at the window's ``L + 1`` row pointers, and differenced; the ``L``
+    sums are written into zeros of the node space at ``start`` (a
+    ``dynamic_update_slice``: one contiguous copy, no scatter). Any window
+    that covers the rows with an edge is exact — a row outside it has
+    degree 0 and sums to 0 — so the window's length may follow the bucket
+    lattice and its start be clamped to the node space. Without a span the
+    window is the whole ``rp`` at 0: the same code."""
     t = jnp.take(w, jnp.clip(ci, 0).astype(jnp.int64))
     with jax.named_scope("scan"):
         ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
-    rp64 = rp.astype(jnp.int64)
-    return jnp.take(ps, rp64[1:]) - jnp.take(ps, rp64[:-1])
+    window, start = (rp, 0) if span is None else span
+    b = jnp.take(ps, window.astype(jnp.int64))
+    return lax.dynamic_update_slice(
+        jnp.zeros(w.shape[0], ps.dtype), b[1:] - b[:-1], (start,)
+    )
 
 
 # edge lanes a step of ``_edge_sum`` gathers (my chip runs, PR 27, 2**26
@@ -694,14 +709,19 @@ def _sharded_spmv(mesh, axis: str):
     per shard a local cumsum of its contiguous edge range, per-node partial
     sums via row_ptr boundaries clipped into the shard, combined with one
     ``psum`` over ICI — the distributed form of ``_csr_spmv`` (SURVEY §2.3's
-    shuffle-reduce replacement). Explicit because GSPMD's partitioning of a
-    globally-sharded cumsum degenerates (observed: a 400k-edge partitioned
-    scan compiled to a ~100s program on the 8-CPU mesh; the shard_map form
-    runs in milliseconds). Pad edges (``ci`` = -1) contribute zero."""
+    shuffle-reduce replacement) and the same algebra: ONE gather of the
+    shard's prefix sums, at the window's row pointers clipped into the
+    shard, and a difference; the ``psum`` carries the window's ``L`` sums
+    and they are written into zeros of the node space at ``start``
+    (``span`` as ``_csr_spmv`` takes it; none: the whole ``rp`` at 0).
+    Explicit because GSPMD's partitioning of a globally-sharded cumsum
+    degenerates (observed: a 400k-edge partitioned scan compiled to a
+    ~100s program on the 8-CPU mesh; the shard_map form runs in
+    milliseconds). Pad edges (``ci`` = -1) contribute zero."""
     from ...parallel.mesh import shard_map
     from jax.sharding import PartitionSpec as P
 
-    def kernel(rp_r, ci_shard, w_r):
+    def kernel(window, start, ci_shard, w_r):
         size = ci_shard.shape[0]
         t = jnp.where(
             ci_shard >= 0,
@@ -711,16 +731,17 @@ def _sharded_spmv(mesh, axis: str):
         with jax.named_scope("scan"):
             ps = jnp.concatenate([jnp.zeros(1, t.dtype), jnp.cumsum(t)])
         lo = lax.axis_index(axis).astype(jnp.int64) * size
-        rp64 = rp_r.astype(jnp.int64)
-        a = jnp.clip(rp64[:-1] - lo, 0, size)
-        b = jnp.clip(rp64[1:] - lo, 0, size)
-        partial_sums = jnp.take(ps, b) - jnp.take(ps, a)
-        return lax.psum(partial_sums, axis)
+        b = jnp.take(ps, jnp.clip(window.astype(jnp.int64) - lo, 0, size))
+        sums = lax.psum(b[1:] - b[:-1], axis)
+        return lax.dynamic_update_slice(
+            jnp.zeros(w_r.shape[0], sums.dtype), sums, (start,)
+        )
 
-    def spmv(rp, ci, w):
+    def spmv(rp, ci, w, span=None):
+        window, start = (rp, 0) if span is None else span
         return shard_map(
-            kernel, mesh, in_specs=(P(), P(axis), P()), out_specs=P()
-        )(rp, ci, w)
+            kernel, mesh, in_specs=(P(), P(), P(axis), P()), out_specs=P()
+        )(window, jnp.asarray(start, jnp.int32), ci, w)
 
     return spmv
 
@@ -750,6 +771,13 @@ def _degrees(rp):
     return rp[1:] - rp[:-1]
 
 
+def hop_parts(hop):
+    """A hop's eight parts, ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask,
+    span_a, span_b)``; one built without its CSRs' row spans (six parts)
+    reads None for both."""
+    return tuple(hop) + (None,) * (8 - len(hop))
+
+
 def _chain_body(dev_ids, ids, valid, hops, num_nodes: int, whole: bool,
                 spmv, edge_sum):
     """Shared traced body of the fused count chain (see
@@ -765,7 +793,7 @@ def _chain_body(dev_ids, ids, valid, hops, num_nodes: int, whole: bool,
     forms = chain_forms([h[5] is not None for h in executed], whole)
     w = None
     for i, (hop, form) in enumerate(zip(executed, forms)):
-        rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hop
+        rp_a, ci_a, rp_b, ci_b, loop_cnt, mask, span_a, span_b = hop_parts(hop)
         with jax.named_scope(f"hop{i}"):
             if form == "degree":
                 w = _degrees(rp_a)
@@ -786,9 +814,9 @@ def _chain_body(dev_ids, ids, valid, hops, num_nodes: int, whole: bool,
                     )
                 return total
             w = w.astype(jnp.int64)
-            nw = spmv(rp_a, ci_a, w)
+            nw = spmv(rp_a, ci_a, w, span_a)
             if rp_b is not None:
-                nw = nw + spmv(rp_b, ci_b, w) - loop_cnt * w
+                nw = nw + spmv(rp_b, ci_b, w, span_b) - loop_cnt * w
             w = nw
     with jax.named_scope("frontier"):
         if whole:  # every node once: the plain sum (a pad node's w is 0)
@@ -820,12 +848,15 @@ def path_count_chain(dev_ids, ids, valid, hops, num_nodes: int,
 
     ``hops`` (in pattern order, the hop next to the frontier first; the
     LAST is executed first): per hop a tuple
-    ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask)`` —
-    fwd: (rp_fwd, ci_fwd, None, None, None, mask);
-    bwd: (rp_rev, ci_rev, None, None, None, mask);
+    ``(rp_a, ci_a, rp_b, ci_b, loop_cnt, mask, span_a, span_b)`` —
+    fwd: (rp_fwd, ci_fwd, None, None, None, mask, span_fwd, None);
+    bwd: (rp_rev, ci_rev, None, None, None, mask, span_rev, None);
     und: both orientations + per-node self-loop counts (primary half counts
     loops once, the opposite half excludes them — subtracting loop_cnt*w
-    reproduces exactly the two CsrExpandOp halves)."""
+    reproduces exactly the two CsrExpandOp halves). ``span_*`` is the
+    window of rows that orientation's CSR holds (``_csr_spmv``; a ``scan``
+    hop alone reads it); a hop of six parts has none and its scan reads
+    every row pointer."""
     return _chain_body(
         dev_ids, ids, valid, hops, num_nodes, whole, _csr_spmv, _csr_edge_sum
     )
@@ -892,7 +923,7 @@ def _tree_branch(child, hops, forms):
     near end, the matches of everything beyond the hop — 1 at least where
     the hop is OPTIONAL."""
     k, optional, below = child
-    rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hops[k]
+    rp_a, ci_a, rp_b, ci_b, loop_cnt, mask, span_a, span_b = hop_parts(hops[k])
     with jax.named_scope(f"hop{k}"):
         if forms[k] == "degree":
             got = _degrees(rp_a)
@@ -900,9 +931,9 @@ def _tree_branch(child, hops, forms):
                 got = got.astype(jnp.int64) + _degrees(rp_b) - loop_cnt
         else:
             w = _tree_node_weight(below, mask, hops, forms).astype(jnp.int64)
-            got = _csr_spmv(rp_a, ci_a, w)
+            got = _csr_spmv(rp_a, ci_a, w, span_a)
             if rp_b is not None:
-                got = got + _csr_spmv(rp_b, ci_b, w) - loop_cnt * w
+                got = got + _csr_spmv(rp_b, ci_b, w, span_b) - loop_cnt * w
         return jnp.maximum(got, 1) if optional else got
 
 
@@ -936,7 +967,7 @@ def tree_count(root_weight, live_nodes, hops, tree, whole: bool, rows=None):
     forms = tree_forms(tree, [h[5] is not None for h in hops], whole)
     if "reduce" in forms:
         (k, _, below), = tree
-        rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hops[k]
+        rp_a, ci_a, rp_b, ci_b, loop_cnt, mask = hops[k][:6]
         with jax.named_scope(f"hop{k}"):
             w = _tree_node_weight(below, mask, hops, forms)
             total = _csr_edge_sum(rp_a, ci_a, w)
@@ -971,12 +1002,13 @@ def tree_count(root_weight, live_nodes, hops, tree, whole: bool, rows=None):
 @partial(jax.jit, static_argnames=("num_nodes",))
 def chain_node_weights(start, steps, num_nodes: int):
     """int64[num_nodes]: ``start`` (None: 1 on every node) carried through
-    ``steps``, each ``(rp, ci, weight)`` — the sum over a node's CSR edges
-    of the far ends' weights, times the node's own: a label mask, a number
-    per node, or None (1)."""
+    ``steps``, each ``(rp, ci, weight)`` or ``(rp, ci, weight, span)`` — the
+    sum over a node's CSR edges of the far ends' weights (over the rows
+    the CSR holds where its ``span`` says which, ``_csr_spmv``), times the
+    node's own: a label mask, a number per node, or None (1)."""
     w = jnp.ones(num_nodes, jnp.int64) if start is None else start.astype(jnp.int64)
-    for rp, ci, weight in steps:
-        w = _csr_spmv(rp, ci, w)
+    for rp, ci, weight, *span in steps:
+        w = _csr_spmv(rp, ci, w, *span)
         if weight is not None:
             w = w * weight.astype(jnp.int64)
     return w
