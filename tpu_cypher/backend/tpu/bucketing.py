@@ -288,6 +288,19 @@ _PCACHE_LOAD_SECONDS = _REGISTRY.counter(
     "(the compile-or-load step of a cache hit: not a compile)",
 )
 
+_JIT_TRACES = _REGISTRY.counter(
+    "tpu_cypher_jit_traces_total",
+    "programs traced to a jaxpr: a retrace that a hit in the persistent "
+    "cache hides from the compile counters counts here (one per top-level "
+    "program; what is traced inside it is part of it)",
+)
+_JIT_TRACE_SECONDS = _REGISTRY.counter(
+    "tpu_cypher_jit_trace_seconds_total",
+    "seconds spent tracing programs to jaxprs and lowering them to MLIR",
+)
+for _c in (_JIT_TRACES, _JIT_TRACE_SECONDS):  # both export from the start
+    _c.inc(0)
+
 _LISTENER_INSTALLED = False
 
 # the installed JAX times ``backend_compile_duration`` around the whole
@@ -298,7 +311,26 @@ _LISTENER_INSTALLED = False
 _LOADED = threading.local()
 
 
-def _on_event_duration(name: str, secs: float, **_kw) -> None:
+def _on_event_duration(name: str, secs: float, **kw) -> None:
+    if name.endswith("jaxpr_trace_duration"):
+        _JIT_TRACE_SECONDS.inc(float(secs))
+        # JAX fires this for every function it traces, the jitted helpers
+        # INSIDE a program included: those end while the outer trace is
+        # still being built. The one that ends with no trace left open is
+        # the program itself — one retrace of one program counts one.
+        if not _obs_trace.inside_jax_trace():
+            _JIT_TRACES.inc()
+            # on the thread and in the context that traced: the innermost
+            # open span is the operator that caused it
+            sp = _obs_trace.current_span()
+            if sp is not None:
+                retraced = sp.attrs.setdefault("retraced", {})
+                fun = str(kw.get("fun_name", "?"))
+                retraced[fun] = retraced.get(fun, 0) + 1
+        return
+    if name.endswith("jaxpr_to_mlir_module_duration"):
+        _JIT_TRACE_SECONDS.inc(float(secs))
+        return
     if name.endswith("backend_compile_duration"):
         if getattr(_LOADED, "from_cache", False):
             _LOADED.from_cache = False

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from ...api import types as T
 from ...errors import reraise_if_device as _reraise_if_device
 from ...ir import expr as E
+from ...obs import trace as _obs_trace
 from .column import (
     BOOL,
     DATE,
@@ -269,7 +270,7 @@ class TpuEvaluator:
 
             # a name of its own in device traces: jit_eval_<expression>
             fn.__name__ = f"eval_{type(expr).__name__.lower()}"
-            fn = jax.jit(fn)
+            fn = _obs_trace.program(jax.jit(fn))
             if len(_EVAL_JIT_CACHE) >= _EVAL_JIT_CACHE_MAX:
                 _EVAL_JIT_CACHE.clear()
             try:

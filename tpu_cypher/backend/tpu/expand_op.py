@@ -2458,3 +2458,8 @@ def plan_expand_into_fastpath(planner, op, in_plan, classic) -> Optional[Relatio
         types_key=GraphIndex.types_key(types),
         undirected=op.direction == "-",
     )
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -832,3 +832,8 @@ def ensure_flat(t):
     anything already flat). Duck-typed so callers need no import."""
     to_flat = getattr(t, "to_flat_table", None)
     return to_flat() if to_flat is not None else t
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

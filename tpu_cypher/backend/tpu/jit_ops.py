@@ -880,6 +880,7 @@ def path_count_chain_on_mesh(mesh, axis: str):
             dev_ids, ids, valid, hops, num_nodes, whole, spmv, edge_sum
         )
 
+    run = _obs_trace.program(run)
     _MESH_CHAIN_CACHE[(mesh, axis)] = run
     return run
 
@@ -2134,3 +2135,8 @@ def extra_keys_keep(l_datas, l_valids, r_datas, r_valids, left_rows, right_rows,
             eq = eq & jnp.take(rv, right_rows)
         keep = keep & eq
     return keep
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -33,6 +33,8 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 from jax import lax
 
+from ...obs import trace as _obs_trace
+
 
 @dataclass
 class CsrGraph:
@@ -218,3 +220,8 @@ def walk_counts(
 
     _, per_hop = lax.scan(step, start.astype(jnp.int64), None, length=hops)
     return per_hop
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ....obs import trace as _obs_trace
 from ....utils.config import PALLAS_MAX_GROUPS
 from . import dispatch
 from .. import jit_ops as J
@@ -183,3 +184,8 @@ def segment_aggregate(
         )),
         eligible=eligible,
     )
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -258,9 +258,15 @@ class TpuTable(Table):
         if all(c.pad == 0 for c in self._cols.values()):
             return self
         if self._depadded is None:
-            self._depadded = TpuTable(
-                {c: col.depad() for c, col in self._cols.items()}, self._nrows
-            )
+            # one eager slice per device array of every padded column
+            # (data, valid, int_flag): op-by-op programs, not one dispatch
+            with _obs_trace.span(
+                "depad", kind="step", columns=len(self._cols)
+            ):
+                self._depadded = TpuTable(
+                    {c: col.depad() for c, col in self._cols.items()},
+                    self._nrows,
+                )
         return self._depadded
 
     @property

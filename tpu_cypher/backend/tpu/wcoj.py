@@ -1021,3 +1021,8 @@ def plan_multiway_intersect_fastpath(
         closes=(close,),
         enforced_pairs=node.enforced_pairs,
     )
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -188,6 +188,20 @@ class Counter(Metric):
             key = self._key_locked(labels)
             return self._series.get(key, 0.0)
 
+    def key(self, **labels) -> Tuple[str, ...]:
+        """The series key of one label set, for ``inc_key``: a call site on
+        a hot path resolves it once (validation and the cardinality cap
+        apply here) and then pays a lock and a dict update per increment."""
+        with self._reg._lock:
+            return self._key_locked(labels)
+
+    def inc_key(self, key: Tuple[str, ...]) -> None:
+        """Add one to the series ``key()`` named."""
+        with self._reg._lock:
+            self._series[key] = self._series.get(key, 0.0) + 1.0
+        for s in _SCOPES.get():
+            s._add(self, key, 1.0)
+
 
 class Gauge(Metric):
     kind = "gauge"

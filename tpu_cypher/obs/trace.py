@@ -96,6 +96,14 @@ ORDER_LIMIT = M.REGISTRY.counter(
 for _path in ("topk", "sort_prefix", "full"):  # a sound deployment: 0 full
     ORDER_LIMIT.inc(0, path=_path)
 
+PROGRAM_DISPATCHES = M.REGISTRY.counter(
+    "tpu_cypher_program_dispatches_total",
+    "calls of a jitted program from host code (obs.trace.dispatch), named "
+    "as the device trace names the program; a program called while another "
+    "is being traced is part of that one and is not counted",
+    labels=("program",),
+)
+
 
 class Span:
     """One node of the tree: a named, timed region with attributes."""
@@ -108,7 +116,7 @@ class Span:
         self.span_id = span_id
         self.name = name
         # "query" | "phase" | "operator" | "kernel" | "serve" | "sync" |
-        # "build" | "span"
+        # "build" | "dispatch" | "step" | "span"
         self.kind = kind
         self.attrs: Dict[str, Any] = dict(attrs or {})
         # start and end on the process's perf_counter (None: never timed)
@@ -482,6 +490,129 @@ def sync(site: str) -> span:
     return span(site, kind="sync")
 
 
+def _leaf(parent: Span, name: str, t0: float, t1: float) -> Span:
+    """A closed ``dispatch`` child of ``parent`` (``Span.add`` without the
+    attrs: this runs once per program call)."""
+    sp = Span(0, name, "dispatch")
+    sp.t0, sp.t1, sp.seconds = t0, t1, max(t1 - t0, 0.0)
+    parent.children.append(sp)
+    return sp
+
+
+class dispatch:
+    """``with dispatch("jit_cols_take"): out = program(...)`` — the leaf
+    (kind ``dispatch``) round exactly ONE call of a jitted program from
+    host code: the call, not the host work before it, and never a blocking
+    read. ``program`` is the name the device trace gives the program, so a
+    leaf reads ``.../LimitOp/jit_order_permutation``. The sibling of
+    ``sync``: the count survives outside a traced run in
+    ``tpu_cypher_program_dispatches_total{program=}``.
+
+    A closed child of the innermost open span, never the innermost span
+    itself: notes (``note``, ``note_rows``, ``note_site``, ...) keep
+    landing on the operator. No contextvar is set, and no
+    ``TraceAnnotation`` entered — inside a capture the program's launch
+    and its device event already stand there. A program made through
+    ``program`` / ``wrap_programs`` needs no ``with`` at its call sites."""
+
+    __slots__ = ("_name", "_parent", "_t0")
+
+    def __init__(self, program: str):
+        self._name = program
+
+    def __enter__(self) -> None:
+        PROGRAM_DISPATCHES.inc(program=self._name)
+        self._parent = _SPAN.get()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._parent is not None:
+            leaf = _leaf(
+                self._parent, self._name, self._t0, time.perf_counter()
+            )
+            if exc_type is not None:
+                leaf.status = "error"
+        return False
+
+
+_TRACE_STATE_CLEAN = None  # jax's "no trace is being built on this thread"
+
+
+def inside_jax_trace() -> bool:
+    """Is JAX building a trace on this thread (a jaxpr of some program)?"""
+    global _TRACE_STATE_CLEAN
+    if _TRACE_STATE_CLEAN is None:
+        from jax._src.core import trace_state_clean
+
+        _TRACE_STATE_CLEAN = trace_state_clean
+    return not _TRACE_STATE_CLEAN()
+
+
+class Program:
+    """A jitted program whose every call from host code is a ``dispatch``
+    leaf (``__call__`` is ``dispatch`` written out: it runs once per
+    program call of every request). Made once, where the program is made
+    (``program``, ``wrap_programs``); everything but the call (``lower``,
+    ``_cache_size``, ``clear_cache``, ...) is the ``jax.jit`` object's own.
+    Called while JAX traces another program it is part of that program,
+    not a dispatch, and steps aside."""
+
+    def __init__(self, fn):
+        self.__wrapped__ = fn
+        self.__name__ = getattr(fn, "__name__", type(fn).__name__)
+        self.__qualname__ = getattr(fn, "__qualname__", self.__name__)
+        self.__doc__ = getattr(fn, "__doc__", None)
+        self.__module__ = getattr(fn, "__module__", None)
+        # as the device trace names it: jit_<function>
+        self.program = "jit_" + self.__name__
+        self._key = None  # the counter's series, resolved at the first call
+        inside_jax_trace()  # binds jax's trace state before the first call
+
+    def __call__(self, *args, **kwargs):
+        if not _TRACE_STATE_CLEAN():
+            return self.__wrapped__(*args, **kwargs)
+        key = self._key
+        if key is None:
+            key = self._key = PROGRAM_DISPATCHES.key(program=self.program)
+        PROGRAM_DISPATCHES.inc_key(key)
+        parent = _SPAN.get()
+        if parent is None:
+            return self.__wrapped__(*args, **kwargs)
+        status = "error"
+        t0 = time.perf_counter()
+        try:
+            out = self.__wrapped__(*args, **kwargs)
+            status = "ok"
+            return out
+        finally:
+            _leaf(parent, self.program, t0, time.perf_counter()).status = status
+
+    def __getattr__(self, name):
+        if name == "__wrapped__":  # an instance made without __init__ (copy)
+            raise AttributeError(name)
+        return getattr(self.__wrapped__, name)
+
+    def __repr__(self) -> str:
+        return f"<Program {self.program}>"
+
+
+def program(fn) -> Program:
+    """``fn = program(jax.jit(...))``: see ``Program``. Idempotent."""
+    return fn if isinstance(fn, Program) else Program(fn)
+
+
+def wrap_programs(namespace: Dict[str, Any]) -> None:
+    """Wrap every ``jax.jit`` object bound in a module's namespace (call it
+    last in the module, with ``globals()``): the decorators stay what the
+    static analysis reads, and a program that calls another one while it
+    is traced finds a wrapper that steps aside."""
+    from jax.stages import Wrapped  # what ``jax.jit`` returns
+
+    for name, value in list(namespace.items()):
+        if isinstance(value, Wrapped):
+            namespace[name] = program(value)
+
+
 # ---------------------------------------------------------------------------
 # the log of finished request trees
 # ---------------------------------------------------------------------------
@@ -525,7 +656,9 @@ def _attr_str(sp: Span) -> str:
 
 
 def render(trace: QueryTrace) -> str:
-    """ASCII tree with per-span total and self wall times."""
+    """ASCII tree with per-span total and self wall times. The dispatch
+    leaves of one span print as ONE last line (their number and summed
+    time); the JSON form keeps them all."""
     lines = [
         f"{trace.root.name} (total {trace.total_seconds * 1000:.2f} ms)"
         f"{_attr_str(trace.root)}"
@@ -541,12 +674,20 @@ def render(trace: QueryTrace) -> str:
             f"{prefix}{branch}{sp.name} {sp.seconds * 1000:.2f} ms"
             f"{self_part}{mark}{_attr_str(sp)}"
         )
-        child_prefix = prefix + ("   " if last else "|  ")
-        for i, c in enumerate(sp.children):
-            walk(c, child_prefix, i == len(sp.children) - 1)
+        children(sp, prefix + ("   " if last else "|  "))
 
-    for i, c in enumerate(trace.root.children):
-        walk(c, "", i == len(trace.root.children) - 1)
+    def children(sp: Span, prefix: str) -> None:
+        shown = [c for c in sp.children if c.kind != "dispatch"]
+        leaves = len(sp.children) - len(shown)
+        for i, c in enumerate(shown):
+            walk(c, prefix, not leaves and i == len(shown) - 1)
+        if leaves:
+            ms = 1000 * sum(
+                c.seconds for c in sp.children if c.kind == "dispatch"
+            )
+            lines.append(f"{prefix}`- dispatch x{leaves} {ms:.2f} ms")
+
+    children(trace.root, "")
     return "\n".join(lines)
 
 

@@ -24,8 +24,11 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 from typing import Dict, Optional
 
+from ..obs import trace as _obs_trace
+from ..obs.metrics import REGISTRY as _REGISTRY
 from ..utils.config import OPT_FEEDBACK
 
 # EMA smoothing: one observation moves the estimate 20% of the way
@@ -168,12 +171,34 @@ def _load_dir(path: str) -> None:
         pass
 
 
+_PERSIST_SECONDS = _REGISTRY.counter(
+    "tpu_cypher_feedback_persist_seconds_total",
+    "seconds spent writing the calibration file (the whole store, on the "
+    "request path: once per observed query)",
+)
+_PERSIST_BYTES = _REGISTRY.counter(
+    "tpu_cypher_feedback_persist_bytes_total",
+    "bytes of calibration file written",
+)
+for _c in (_PERSIST_SECONDS, _PERSIST_BYTES):  # both export from the start
+    _c.inc(0)
+
+
 def _save(path: str) -> None:
-    tmp = path + ".tmp"
-    payload = {fp: cal.to_json() for fp, cal in _STORE.items()}
-    with open(tmp, "w", encoding="utf-8") as f:
-        json.dump(payload, f, sort_keys=True)
-    os.replace(tmp, path)
+    """Write the whole store to ``path`` (atomically), under a ``persist``
+    span of the request's tree and the two persist counters."""
+    with _obs_trace.span("persist", kind="step") as sp:
+        t0 = time.perf_counter()
+        tmp = path + ".tmp"
+        payload = {fp: cal.to_json() for fp, cal in _STORE.items()}
+        with open(tmp, "w", encoding="utf-8") as f:
+            json.dump(payload, f, sort_keys=True)
+            size = f.tell()
+        os.replace(tmp, path)
+        sp.note("bytes", size)
+        sp.note("fingerprints", len(payload))
+        _PERSIST_BYTES.inc(size)
+        _PERSIST_SECONDS.inc(time.perf_counter() - t0)
 
 
 def _fingerprint(graph, ctx) -> str:

@@ -97,11 +97,11 @@ def _agg_fn(mesh, axis: str, name: str, is_bool: bool, k: int):
         return agged, cnt
 
     spec = P(axis)
-    fn = jax.jit(
+    fn = _obs_trace.program(jax.jit(
         shard_map(
             local, mesh, in_specs=(spec, spec, spec), out_specs=(P(), P())
         )
-    )
+    ))
     _AGG_CACHE[key] = fn
     return fn
 
@@ -217,3 +217,8 @@ def weighted_segment_partials(data, valid, weight, seg_j, k: int):
             if got_sum is not None:
                 return got_sum[0], got_cnt[0]
     return _weighted_segment_sums(pre_sum, pre_cnt, seg_j, k)
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -269,9 +269,9 @@ def sharded_two_hop_count(mesh: Mesh, deg: jnp.ndarray, col_idx: jnp.ndarray):
             local = jnp.sum(jnp.where(valid, deg_rep[jnp.clip(col_shard, 0)], 0).astype(jnp.int64))
             return lax.psum(local, EDGE_AXIS)
 
-        f = jax.jit(
+        f = _obs_trace.program(jax.jit(
             shard_map(kernel, mesh, in_specs=(P(), P(EDGE_AXIS)), out_specs=P())
-        )
+        ))
         _TWO_HOP_CACHE[mesh] = f
     return f(deg, col_idx)
 
@@ -294,11 +294,11 @@ def sharded_walk_step(mesh: Mesh, num_nodes: int):
         )
         return lax.psum(partial_next, EDGE_AXIS)
 
-    f = jax.jit(
+    f = _obs_trace.program(jax.jit(
         shard_map(
             kernel, mesh, in_specs=(P(), P(EDGE_AXIS), P(EDGE_AXIS)), out_specs=P()
         )
-    )
+    ))
     _WALK_STEP_CACHE[key] = f
     return f
 
@@ -330,14 +330,14 @@ def sharded_training_step(mesh: Mesh, num_nodes: int, hops: int):
         two_hop = lax.psum(two_hop_local, EDGE_AXIS)
         return p_final, hop_counts, two_hop
 
-    f = jax.jit(
+    f = _obs_trace.program(jax.jit(
         shard_map(
             kernel,
             mesh,
             in_specs=(P(), P(), P(EDGE_AXIS), P(EDGE_AXIS)),
             out_specs=(P(), P(), P()),
         )
-    )
+    ))
     _TRAIN_STEP_CACHE[key] = f
     return f
 
@@ -366,10 +366,10 @@ def sharded_range_count(mesh: Mesh):
             local = jnp.where(qok, (hi - lo).astype(jnp.int64), 0)
             return lax.psum(local, ROW_AXIS)
 
-        f = jax.jit(
+        f = _obs_trace.program(jax.jit(
             shard_map(
                 kernel, mesh, in_specs=(P(ROW_AXIS), P(), P()), out_specs=P()
             )
-        )
+        ))
         _RANGE_COUNT_CACHE[mesh] = f
     return f

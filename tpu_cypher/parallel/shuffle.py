@@ -172,14 +172,14 @@ def _count_fn(mesh, axis, nsh, cap_l, cap_r):
         return jnp.sum(counts)[None], (ovf_l | ovf_r)[None]
 
     spec = P(axis)
-    fn = jax.jit(
+    fn = _obs_trace.program(jax.jit(
         shard_map(
             local,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec),
         )
-    )
+    ))
     _COUNT_CACHE[key] = fn
     return fn
 
@@ -210,14 +210,14 @@ def _materialize_fn(mesh, axis, nsh, cap_l, cap_r, out_cap):
         return l_out, r_out, valid
 
     spec = P(axis)
-    fn = jax.jit(
+    fn = _obs_trace.program(jax.jit(
         shard_map(
             local,
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec, spec),
         )
-    )
+    ))
     _MAT_CACHE[key] = fn
     return fn
 
@@ -318,14 +318,14 @@ def _bcast_count_fn(mesh, axis):
         _, _, counts = _local_probe(lk, rk)
         return jnp.sum(counts)[None]
 
-    fn = jax.jit(
+    fn = _obs_trace.program(jax.jit(
         shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis), P(None)),
             out_specs=P(axis),
         )
-    )
+    ))
     _BCAST_COUNT_CACHE[key] = fn
     return fn
 
@@ -355,14 +355,14 @@ def _bcast_materialize_fn(mesh, axis, out_cap):
         )
         return l_out, r_out, valid
 
-    fn = jax.jit(
+    fn = _obs_trace.program(jax.jit(
         shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(None), P(None)),
             out_specs=(P(axis), P(axis), P(axis)),
         )
-    )
+    ))
     _BCAST_MAT_CACHE[key] = fn
     return fn
 
@@ -618,11 +618,11 @@ def _distinct_fn(mesh, axis, nsh, cap):
         return lax.psum(local_distinct, axis)[None], overflow[None]
 
     spec = P(axis)
-    fn = jax.jit(
+    fn = _obs_trace.program(jax.jit(
         shard_map(
             local, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec)
         )
-    )
+    ))
     _DISTINCT_CACHE[key] = fn
     return fn
 
@@ -670,3 +670,8 @@ def sharded_distinct_count(
         _MESH_DISTINCT_TOTAL.inc()
         _obs_trace.note("distinct_shards", nsh)
         return int(_to_host("shuffle", counts)[0])
+
+
+# every jitted program of this module dispatches under an obs.trace
+# ``dispatch`` leaf (last: the decorators above stay plain ``jax.jit``)
+_obs_trace.wrap_programs(globals())

@@ -11,6 +11,7 @@ reference keeps inside its graph implementations."""
 from __future__ import annotations
 
 import math
+import time
 
 from dataclasses import dataclass, field as dc_field
 from functools import cached_property
@@ -538,7 +539,16 @@ class AggregateOp(RelationalOperator):
             n = self._input_row_count()
             if n is not None:
                 cols = {out_col: [n] for out_col, _ in aggs}
-                return self.context.table_cls.from_columns(cols)
+                # one host value per column to the device, no program. A
+                # closed leaf, not a nested span: the lattice's rows_true /
+                # rows_padded of this table stay on the operator, where
+                # optimizer/feedback reads them
+                sp = _obs_trace.current_span()
+                t0 = time.perf_counter()
+                table = self.context.table_cls.from_columns(cols)
+                if sp is not None:
+                    sp.add("build_table", "step", t0, time.perf_counter())
+                return table
         return in_op.table.group(by, aggs, in_h, self.context.parameters)
 
     def _input_row_count(self) -> Optional[int]:

@@ -15,9 +15,12 @@ cannot drift:
   session inside the caller's already-fresh context (request deadline and
   chaos schedule scoped in) and returns the JSON-safe result dict
   {rows, columns, seconds, execution_log, rungs, degraded, compile_stats,
-  fallbacks, profile}. ``QueryServer._execute`` and the worker's execute op are both
-  one-line wrappers over it — 'byte-identical rows across serving modes'
-  stays a checkable property.
+  fallbacks}, plus ``profile`` (the engine's span tree, rendered) where
+  the payload leaves the process: an engine worker's reply. In the
+  one-process server the tree hangs in the request's own and is rendered
+  when ``/queries/<id>`` is read. ``QueryServer._execute`` and the
+  worker's execute op are both one-line wrappers over it —
+  'byte-identical rows across serving modes' stays a checkable property.
 
 * **typed errors on the wire** — a worker failure travels as
   ``{"ok": false, "error": <type name>, "message": ...}``;
@@ -74,6 +77,18 @@ def _engine_trace(result, parent: Optional[OT.Span]) -> OT.QueryTrace:
     return trace
 
 
+def _leave_with_profile(payload: Dict[str, Any], trace: OT.QueryTrace,
+                        parent: Optional[OT.Span]) -> None:
+    """Render the engine's tree into ``payload["profile"]`` where the
+    payload is how the tree leaves the process: no ``parent`` (an engine
+    worker's reply, which the router grafts under ``route``). Under a
+    ``parent`` the tree is part of the request's own, which the server's
+    record keeps and renders when ``/queries/<id>`` is read — rendering it
+    here too, on the lane, for every request, was for no reader."""
+    if parent is None:
+        payload["profile"] = trace.to_dict()
+
+
 def execute_payload(
     session,
     graph,
@@ -89,7 +104,8 @@ def execute_payload(
     ``contextvars.Context``. ``deadline_s`` is the REMAINING budget (queue
     wait already deducted); ``faults`` is a client-scoped chaos schedule;
     ``parent`` is the span of the request's tree that the engine's tree
-    hangs under (the one-process server's ``dispatch``)."""
+    hangs under (the one-process server's ``dispatch``); without one the
+    payload carries the tree itself, rendered (``profile``)."""
     t0 = time.perf_counter()
     trace = None
     try:
@@ -124,10 +140,8 @@ def execute_payload(
         # {reason: count} of host-oracle fallbacks / host islands; None
         # unless the session records them (``session.record_fallbacks``)
         "fallbacks": result.fallbacks,
-        # the engine's tree alone: what the result cache stores and what an
-        # engine worker sends over the wire
-        "profile": trace.to_dict(),
     }
+    _leave_with_profile(payload, trace, parent)
     write_stats = getattr(result, "write_stats", None)
     if write_stats is not None:
         payload["write"] = write_stats
@@ -178,8 +192,8 @@ def open_stream(
         "rungs": rungs,
         "degraded": bool(rungs and rungs[-1] != G.RUNG_DEVICE),
         "compile_stats": result.compile_stats,
-        "profile": trace.to_dict(),
     }
+    _leave_with_profile(meta, trace, parent)
     return meta, RowStream(records, columns, page_rows=page_rows, trace=trace)
 
 
