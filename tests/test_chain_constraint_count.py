@@ -190,10 +190,138 @@ def test_constrained_chain_count_matches_brute_force(world, case):
     assert spans[0].attrs["constraints"] == len(constraints)
     # under a = c the closing edge is a's own loop: no closing program runs
     closing = {c[0] for c in constraints} in ({"edge"}, {"edge", "neq"})
-    assert (spans[0].attrs.get("form") == "dense") == closing
+    assert (spans[0].attrs.get("form") == "bits") == closing
     assert (REGISTRY.flat().get(LANES, 0.0) > lanes) == closing
     if closing:
         assert spans[0].attrs["wedge_lanes"] > 0
+
+
+def _csr(n, src, dst, lanes):
+    """(row_ptr, col_idx, row per lane) of the lanes (src, dst), sorted by
+    (src, dst) and padded to ``lanes`` as ``GraphIndex.csr`` pads them."""
+    order = np.lexsort((dst, src))
+    src, dst = src[order], dst[order]
+    rp = np.concatenate([[0], np.cumsum(np.bincount(src, minlength=n))])
+    ci = np.full(lanes, -1, np.int32)
+    ci[: len(dst)] = dst
+    rows = np.full(lanes, n - 1, np.int32)
+    rows[: len(src)] = src
+    return rp.astype(np.int32), ci, rows
+
+
+# name: (nodes, the most parallel lanes of a first-hop pair, of a second-hop
+# pair, of a closing pair, closing pairs, share of the nodes the middle may be)
+CLOSING_CASES = {
+    "simple": (64, 1, 1, 1, 150, None),
+    "parallel_first_hop": (64, 2, 1, 1, 150, None),
+    "parallel_second_hop": (64, 1, 5, 1, 150, None),
+    "parallel_both_hops": (64, 5, 7, 1, 150, None),
+    "middle_mask": (64, 3, 1, 1, 150, 0.6),
+    "parallel_closing_lanes": (64, 1, 2, 4, 150, 0.8),
+    "side_not_a_multiple_of_32": (77, 2, 2, 2, 200, 0.7),
+    "no_closing_lane": (40, 1, 3, 1, 0, None),
+}
+
+
+@pytest.mark.parametrize("chunk", [8, 256])
+@pytest.mark.parametrize("case", list(CLOSING_CASES))
+def test_wedge_close_sum_matches_the_matrix_product(case, chunk):
+    """``jit_ops.wedge_close_sum`` over ``bit_adjacency``'s planes against
+    ``m1 @ m2`` in NumPy read at the closing pairs, each pair once: parallel
+    lanes on either hop each count, whatever the chunks cut."""
+    import jax.numpy as jnp
+
+    from tpu_cypher.backend.tpu import jit_ops as J
+
+    n, most1, most2, most_c, closing_pairs, mid_share = CLOSING_CASES[case]
+    rng = np.random.default_rng(len(case))
+
+    def lanes(pairs, most):
+        key = rng.choice(n * n, pairs, replace=False)
+        reps = rng.integers(1, most + 1, pairs)
+        reps[: min(1, pairs)] = most  # some pair has the most
+        return np.repeat(key // n, reps), np.repeat(key % n, reps)
+
+    (s1, d1), (s2, d2), (sc, dc) = (
+        lanes(300, most1), lanes(300, most2), lanes(closing_pairs, most_c)
+    )
+    m1, m2 = np.zeros((n, n), np.int64), np.zeros((n, n), np.int64)
+    np.add.at(m1, (s1, d1), 1)
+    np.add.at(m2, (s2, d2), 1)
+    closes = np.zeros((n, n), bool)
+    closes[sc, dc] = True
+    mid = None if mid_share is None else rng.random(n) < mid_share
+    # weights of more than 32 bits: each is gathered as two words
+    left, right = rng.integers(0, 1 << 34, n), rng.integers(0, 1 << 12, n)
+    wedges = (m1 if mid is None else m1 * mid[None, :]) @ m2
+    want = int((left[:, None] * right[None, :] * wedges * closes).sum())
+
+    # every node has a row and a bit: whole tiles of 8 rows, of 128 words
+    rank = jnp.arange(n, dtype=jnp.int32)
+    size, words = -(-n // 8) * 8, 128
+    nodes = jnp.pad(rank, (0, size - n))
+
+    def planes(src, dst, most):
+        rp, ci, rows = _csr(n, src, dst, 2048)
+        assert int(J.csr_longest_run(rp, ci, rows)) == most
+        return J.bit_adjacency(rp, ci, rows, rank, rank, size=size, words=words,
+                               planes=most.bit_length())
+
+    b1 = planes(s1, d1, most1)
+    b2t = planes(d2, s2, most2)  # the second hop by its far end
+    # digit j of a pair's lanes is bit (row, col) of plane j
+    digits = sum(
+        ((np.asarray(p)[:n, :, None] >> np.arange(32)) & 1).reshape(n, -1)[:, :n]
+        .astype(np.int64) << j
+        for j, p in enumerate(b2t)
+    )
+    assert (digits == m2.T).all()
+    rp_c, ci_c, rows_c = _csr(n, sc, dc, 1024)
+    ra_c, kc_c = J.closing_pair_rows(rp_c, ci_c, rows_c, rank, rank)
+    # each closing pair once, on its first lane
+    assert int((np.asarray(ra_c) >= 0).sum()) == closing_pairs
+    got = J.wedge_close_sum(
+        b1, nodes, b2t, nodes, None if mid is None else jnp.asarray(mid),
+        rp_c, ra_c, kc_c, jnp.asarray(left), jnp.asarray(right), chunk=chunk,
+    )
+    assert (len(b1), len(b2t)) == (most1.bit_length(), most2.bit_length())
+    assert int(got) == want
+    if not closing_pairs:
+        none = np.zeros(0, np.int32)
+        assert int(J.wedge_close_sum(
+            b1, nodes, b2t, nodes, None, rp_c, none, none,
+            jnp.asarray(left), jnp.asarray(right), chunk=chunk,
+        )) == 0
+
+
+def test_parallel_lanes_take_planes_not_another_form(world):
+    """LIKES has pairs with several rows: the closing program takes them as
+    further planes of the same bit rows (``planes`` on the span)."""
+    nodes, rels, graph = world
+    pattern, chain, where, constraints = CASES["mixed_neq_and_closed"]
+    result = graph.cypher(f"MATCH {pattern} WHERE {where} RETURN count(*) AS n")
+    assert [dict(r) for r in result.records.collect()] == [
+        {"n": brute_force(nodes, rels, chain, constraints)}
+    ]
+    (span,) = [s for s in result.profile().trace.spans() if s.name == "chain_constraint"]
+    pairs = {}
+    for row in zip(*rels["LIKES"]):
+        pairs[row] = pairs.get(row, 0) + 1
+    most = max(pairs.values())
+    assert most > 1 and span.attrs["form"] == "bits"
+    knows = {}
+    for row in zip(*rels["KNOWS"]):
+        knows[row] = knows.get(row, 0) + 1
+    assert span.attrs["planes"] == (
+        f"{max(knows.values()).bit_length()}x{most.bit_length()}"
+    )
+    # graphs of one deployment share the closing programs: the bit rows'
+    # shapes follow a lattice of their own, not each graph's node count
+    from tpu_cypher.backend.tpu.graph_index import GraphIndex
+
+    shapes = {p.shape for planes in GraphIndex.of(graph._graph)._wedge_adj.values()
+              for p in planes}
+    assert shapes == {(GraphIndex.WEDGE_ROWS, 128)}
 
 
 DECLINED = {

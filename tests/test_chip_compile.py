@@ -215,37 +215,45 @@ def test_wcoj_range_count_compiles(one_chip):
 
 # the constrained count chain at LSQB's Person side of SNB SF10 (the cell
 # lsqb-sf10-person.lsqb-chain under pow2 buckets): 83,179 nodes of four labels
-# in 2**17 ids, 3.9M KNOWS lanes in 2**22, 65,645 persons in a side of 66,048
-LSQB_IDS, LSQB_LANES, LSQB_SIDE = 1 << 17, 1 << 22, 66_048
+# in 2**17 ids, 3.9M KNOWS lanes in 2**22, 65,645 persons as bit rows of
+# 66,048 x 2,176 words (GraphIndex.wedge_adjacency: rows in multiples of 512,
+# words in whole lanes of 128)
+LSQB_IDS, LSQB_LANES, LSQB_ROWS, LSQB_WORDS = 1 << 17, 1 << 22, 66_048, 2_176
 
 
 def test_wedge_close_sum_compiles_at_lsqb_sf10(one_chip):
-    """The closing program: 129 blocks of 512 rows, each an int8 product
-    (512 x 66,048) @ (66,048 x 66,048) with int32 sums on the MXU; one
-    block's product (0.14 GB) and its rows are all the program holds beside
-    the 4.4 GB matrix."""
+    """The closing program: per chunk of 512 closing lanes two gathers of
+    bit rows (4.5 MB each), an AND and a population count; no matrix product,
+    and the two gathered chunks are all it holds beside the bit rows."""
     rp, ci = one_chip((LSQB_IDS + 1,), I32), one_chip((LSQB_LANES,), I32)
     ids32, ids64 = one_chip((LSQB_IDS,), I32), one_chip((LSQB_IDS,), I64)
+    bits = (one_chip((LSQB_ROWS, LSQB_WORDS), jnp.uint32),)
+    nodes = one_chip((LSQB_ROWS,), I32)
     compiled = J.wedge_close_sum.lower(
-        one_chip((LSQB_SIDE, LSQB_SIDE), jnp.int8), ids32, rp, ci, ci, ids32,
-        one_chip((130,), I32), one_chip((LSQB_IDS,), BOOL),
-        rp, ci, ci, one_chip((LSQB_LANES,), BOOL), ids64, ids64,
-        block=512, width1=1 << 16, width_c=1 << 16,
+        bits, nodes, bits, nodes, one_chip((LSQB_IDS,), BOOL),
+        rp, ci, ci, ids64, ids64, chunk=1 << 9,
     ).compile()
     text = compiled.as_text()
-    assert "s8[" in text and "s32[512,66048]" in text  # an int8 product
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    # the bit rows stay row-major: a node's words are contiguous
+    assert "u32[66048,2176]{1,0" in text
+    assert "u32[512,2176]" in text and " gather(" in text and " popcnt(" in text
+    assert "s8[" not in text and " dot(" not in text and "convolution" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_constrained_chain_helpers_compile_at_lsqb_sf10(one_chip):
-    """The matrix build (a 3.9M-lane scatter into 4.3 GB of int8), the
+    """The bit rows' build (a 3.9M-lane scatter into 575 MB of words), the
     per-lane back counts, the two-cycle sum and the chain's node weights."""
     rp, ci = one_chip((LSQB_IDS + 1,), I32), one_chip((LSQB_LANES,), I32)
     ids32, ids64 = one_chip((LSQB_IDS,), I32), one_chip((LSQB_IDS,), I64)
     mask = one_chip((LSQB_IDS,), BOOL)
-    J.dense_adjacency.lower(ci, ci, rp, ids32, size=LSQB_SIDE).compile()
+    built = J.bit_adjacency.lower(
+        rp, ci, ci, ids32, ids32, size=LSQB_ROWS, words=LSQB_WORDS, planes=1
+    ).compile()
+    assert "u32[66048,2176]{1,0" in built.as_text()
     J.csr_lane_rows.lower(rp, ci).compile()
-    J.csr_pair_runs.lower(rp, ci, ci).compile()
+    J.csr_longest_run.lower(rp, ci, ci).compile()
+    J.closing_pair_rows.lower(rp, ci, ci, ids32, ids32).compile()
     J.csr_back_counts.lower(
         rp, ci, ci, one_chip((LSQB_LANES,), I64), num_nodes=LSQB_IDS
     ).compile()

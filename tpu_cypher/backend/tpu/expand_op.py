@@ -333,7 +333,7 @@ def _collected_pairs(hops, extra=()):
 _WEDGE_LANES_TOTAL = _OBS_REGISTRY.counter(
     "tpu_cypher_chain_constraint_wedge_lanes_total",
     "candidate (a, c) pairs the closing program of constrained count chains "
-    "went over: the cells of the wedge matrix it computed, block by block",
+    "went over: the closing lanes of the chunks it walked",
 )
 
 
@@ -960,12 +960,12 @@ class CsrExpandOp(_TreeCounted, _FusedExpandBase):
         over the whole chain's count, the wedges with ``a = c`` (one cached
         count per first-hop lane, ``GraphIndex.back_counts``) and the
         wedges an edge between ``a`` and ``c`` closes (EXISTS: parallel
-        closing edges count once; ``jit_ops.wedge_close_sum``, dense, on
-        the MXU). None where the shape does not fit — an undirected hop, a
-        branching pattern, a pair further apart, relationship uniqueness
-        the chain enforces itself (self-loops under a type walked twice),
-        a closing matrix too large for the device, a mesh — and the caller
-        builds the rows."""
+        closing edges count once; ``jit_ops.wedge_close_sum``, two bit rows
+        intersected per closing pair). None where the shape does not fit —
+        an undirected hop, a branching pattern, a pair further apart,
+        relationship uniqueness the chain enforces itself (self-loops under
+        a type walked twice), bit rows too large for the device, a mesh —
+        and the caller builds the rows."""
         try:
             path = self._pattern_path()
             if path is None:
@@ -1022,16 +1022,11 @@ class CsrExpandOp(_TreeCounted, _FusedExpandBase):
         if "closed" in signs:
             types_key, from_first, _ = edge
             closing = (types_key, not from_first)
-            adjacency = gi.wedge_adjacency(second, ctx)
-            if adjacency is None:
-                return None
-            blocks = gi.wedge_blocks(first, closing, ctx)
-            if (
-                blocks.longest_run > 127  # a block's rows are int8 too
-                # the wedge matrix's cells are int32 sums of int8 products
-                or gi.csr_max_degree(*first, ctx) * adjacency.longest_run
-                >= (1 << 31)
-            ):
+            # a's first-hop neighbours and the nodes with a second-hop lane
+            # into c, as bit rows over one numbering of the middle nodes
+            from_a = gi.wedge_adjacency(first, second[0], ctx)
+            into_c = gi.wedge_adjacency((second[0], not second[1]), second[0], ctx)
+            if from_a is None or into_c is None:
                 return None
         # left: the pattern's first node carried to node i, each step over
         # the CSR whose rows are the node reached; right: its last node
@@ -1077,17 +1072,18 @@ class CsrExpandOp(_TreeCounted, _FusedExpandBase):
                 )
         if "closed" in signs:
             rp_c, ci_c, _ = gi.csr(*closing, ctx)
+            chunk = min(gi.WEDGE_CHUNK, int(ci_c.shape[0]))
+            ra_c, kc_c = gi.closing_pair_rows(closing, first[0], second[0], ctx)
             terms["closed"] = J.wedge_close_sum(
-                adjacency.matrix, adjacency.rank, rp1, ci1, rows1,
-                blocks.rank, blocks.block_rows, mid_mask,
-                rp_c, ci_c, gi.csr_rows(*closing, ctx), blocks.first_closing,
-                left, right,
-                block=blocks.block, width1=blocks.width1,
-                width_c=blocks.width_closing,
+                from_a, gi.wedge_rank(first[0], ctx)[1],
+                into_c, gi.wedge_rank(second[0], ctx)[1], mid_mask,
+                rp_c, ra_c, kc_c, left, right, chunk=chunk,
             )
-            lanes = blocks.blocks * blocks.block * int(adjacency.matrix.shape[0])
+            # the closing lanes of the chunks walked, the last one's pad too
+            lanes = -(-gi.csr_lane_count(*closing, ctx) // chunk) * chunk if chunk else 0
             _WEDGE_LANES_TOTAL.inc(lanes)
-            _obs_trace.note("form", "dense")
+            _obs_trace.note("form", "bits")
+            _obs_trace.note("planes", f"{len(from_a)}x{len(into_c)}")
             _obs_trace.note("wedge_lanes", lanes)
         n_dev = sum(sign * terms[name] for name, sign in signs.items())
         # the whole chain's count is the chain's own program, as ever

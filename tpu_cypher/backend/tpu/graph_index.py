@@ -135,29 +135,6 @@ class RowSpan:
         return self.row_ptr, np.int32(self.start)
 
 
-@dataclass(frozen=True)
-class WedgeAdjacency:
-    """One CSR as a dense matrix (``GraphIndex.wedge_adjacency``)."""
-
-    matrix: Any  # int8[size, size], entries = parallel lanes
-    rank: Any  # int32[num_nodes]: a node's row and column, -1 = has none
-    longest_run: int  # the most parallel lanes any pair has
-
-
-@dataclass(frozen=True)
-class WedgeBlocks:
-    """A first hop's rows in blocks (``GraphIndex.wedge_blocks``)."""
-
-    rank: Any  # int32[num_nodes]: a node's number among the rows, -1 = none
-    block_rows: Any  # int32[blocks + 1]: the node each block starts at
-    first_closing: Any  # bool per closing lane: first of its (a, c) pair
-    longest_run: int  # the most parallel first-hop lanes any pair has
-    block: int
-    blocks: int
-    width1: int  # lanes: the most first-hop lanes of a block, bucketed
-    width_closing: int
-
-
 class GraphIndex:
     """CSR + canonical-scan cache for one RelationalCypherGraph."""
 
@@ -195,6 +172,7 @@ class GraphIndex:
         # (types_key, reverse) -> host max out-degree (Pallas eligibility
         # probe — computed once at build, never synced per query)
         self._csr_max_deg: Dict[Tuple[Tuple[str, ...], bool], int] = {}
+        self._csr_lanes: Dict[Tuple[Tuple[str, ...], bool], int] = {}
         # (types_key, reverse) -> the rows that CSR holds and their window
         self._csr_span: Dict[Tuple[Tuple[str, ...], bool], RowSpan] = {}
         # per CSR orientation, host bool[num_nodes]: the nodes its edges end
@@ -226,15 +204,19 @@ class GraphIndex:
         self._rel_id_index: Dict[Tuple[str, ...], Tuple[Any, Any]] = {}
         # (types_key, reverse) -> int32 row (node) of each lane of that CSR
         self._csr_rows: Dict[Tuple[Tuple[str, ...], bool], Any] = {}
-        # (types_key, reverse) -> (bool per lane: first of its (row, col)
-        # pair, the most parallel lanes any pair has)
-        self._pair_runs: Dict[Tuple[Tuple[str, ...], bool], Tuple[Any, int]] = {}
+        # (types_key, reverse) -> the most parallel lanes any (row, col) pair has
+        self._longest_run: Dict[Tuple[Tuple[str, ...], bool], int] = {}
         # (CSR key, CSR key) -> int32 per lane a -> b of the first CSR: the
         # lanes b -> a of the second (constrained count chains)
         self._back_counts: Dict[Tuple[Any, Any], Any] = {}
-        # CSR key(s) -> the dense forms of a constrained count chain
-        self._wedge_adj: Dict[Any, Optional["WedgeAdjacency"]] = {}
-        self._wedge_blocks: Dict[Any, "WedgeBlocks"] = {}
+        # types_key -> (int32 per node: its number among the nodes an edge of
+        # the types touches, -1 = none; int32 per number: the node)
+        self._wedge_ranks: Dict[Tuple[str, ...], Tuple[Any, Any]] = {}
+        # (CSR key, the types whose rank numbers the columns) -> its bit rows
+        self._wedge_adj: Dict[Any, Optional[Tuple[Any, ...]]] = {}
+        # (closing CSR key, first hop's types, second hop's types) -> the
+        # two bit rows each closing lane intersects (int32 per lane, -1 none)
+        self._closing_rows: Dict[Any, Tuple[Any, Any]] = {}
 
     # -- nodes -------------------------------------------------------------
 
@@ -475,6 +457,7 @@ class GraphIndex:
         row_ptr, order, a_sorted = self._sorted_csr(a, b, n)
         degs = row_ptr[1:] - row_ptr[:-1]
         self._csr_max_deg[(types_key, reverse)] = int(degs.max()) if n else 0
+        self._csr_lanes[(types_key, reverse)] = len(s)
         pointed_at = np.zeros(n, dtype=bool)
         pointed_at[b] = True  # the nodes this orientation's edges end in
         self._csr_far_nodes[(types_key, reverse)] = pointed_at
@@ -671,18 +654,18 @@ class GraphIndex:
             self._csr_rows[key] = J.csr_lane_rows(rp, ci)
         return self._csr_rows[key]
 
-    def pair_runs(self, key, ctx) -> Tuple[Any, int]:
-        """Of the CSR ``key``: (device bool per lane, the first lane of its
-        (row, col) pair; the most parallel lanes any pair has)."""
-        if key not in self._pair_runs:
+    def longest_run(self, key, ctx) -> int:
+        """The most parallel lanes any (row, col) pair of the CSR ``key``
+        has."""
+        if key not in self._longest_run:
             from . import jit_ops as J
 
             fault_point("expand")  # the scalar read below
             rp, ci, _ = self.csr(*key, ctx)
-            first, longest = J.csr_pair_runs(rp, ci, self.csr_rows(*key, ctx))
+            longest = J.csr_longest_run(rp, ci, self.csr_rows(*key, ctx))
             with _obs_trace.sync("expand"):
-                self._pair_runs[key] = (first, int(longest))
-        return self._pair_runs[key]
+                self._longest_run[key] = int(longest)
+        return self._longest_run[key]
 
     def back_counts(self, first, second, ctx):
         """Device int32 per lane ``a -> b`` of the CSR ``first`` (a
@@ -703,93 +686,97 @@ class GraphIndex:
             self._back_counts[(first, second)] = got
         return got
 
-    # cells of the dense matrix a constrained count may hold on the device
-    # (int8: 6 GiB of a chip's 16 GB; LDBC SNB SF10's persons take 4.3e9)
-    WEDGE_MAX_CELLS = 6 << 30
-    # first-hop rows a matrix product takes at a time, and the multiple the
-    # matrix's side is padded to (my chip run, PR 32, SNB SF10's KNOWS, int8:
-    # 1.99 s in blocks of 512 over a side of 66,048, 2.09 s at 1,024 over
-    # 66,560, 2.19 s at 2,048 over 67,584; a side of 65,664 = 513 x 128 under
-    # blocks of 1,024 took 2.47 s)
-    WEDGE_BLOCK = 512
+    # bytes of bit rows a constrained count may hold on the device: 6 GiB of
+    # a chip's 16 GB, about 155,000 nodes a side without parallel edges (a
+    # closing term holds two sets of n * n / 8 bytes a plane)
+    WEDGE_MAX_BYTES = 6 << 30
+    # the multiple a set of bit rows' number of rows is padded to, so that two
+    # graphs of one deployment share their programs (the persons with a friend
+    # at SNB SF10: 65,570 to 65,589 over seven seeds, 66,048 rows each)
+    WEDGE_ROWS = 512
+    # closing lanes ``jit_ops.wedge_close_sum`` intersects at a time (my chip
+    # run, PR 37, call M6, SNB SF10's KNOWS, rows of 2,176 words: 0.318 s a
+    # pass at 256, 0.257 at 512, 0.297 at 1,024, 0.291 at 2,048, 0.329 at
+    # 4,096, 0.439 at 8,192, 0.499 at 16,384)
+    WEDGE_CHUNK = 1 << 9
 
-    @classmethod
-    def _wedge_side(cls, count: int) -> int:
-        """``count`` rows or columns padded to whole blocks (a small graph:
-        to the MXU's 128)."""
-        step = cls.WEDGE_BLOCK if count > cls.WEDGE_BLOCK else 128
-        return max(-(-count // step) * step, step)
-
-    def wedge_adjacency(self, key, ctx) -> Optional["WedgeAdjacency"]:
-        """The CSR ``key`` as an int8 matrix over the nodes that touch one
-        of its edges, or None: too many of them for ``WEDGE_MAX_CELLS``, or
-        more than 127 parallel edges somewhere (int8)."""
-        if key not in self._wedge_adj:
-            from . import jit_ops as J
-
-            fault_point("expand")  # the build reads a count back
-            with _build("wedge_adjacency") as sp:
-                rp, ci, _ = self.csr(*key, ctx)
-                rp_in, _, _ = self.csr(key[0], not key[1], ctx)
-                rows = self.csr_rows(*key, ctx)
-                touched = (rp[1:] > rp[:-1]) | (rp_in[1:] > rp_in[:-1])
+    def wedge_rank(self, types_key: Tuple[str, ...], ctx) -> Tuple[Any, Any]:
+        """Of the nodes an edge of the types touches: (device int32 per
+        node: its number among them, -1 = it is none; device int32 per
+        number: the node, padded with node 0 to a multiple of
+        ``WEDGE_ROWS``)."""
+        if types_key not in self._wedge_ranks:
+            fault_point("expand")  # the count is read back
+            with _build("wedge_rank") as sp:
+                rp_out, _, _ = self.csr(types_key, False, ctx)
+                rp_in, _, _ = self.csr(types_key, True, ctx)
+                touched = (rp_out[1:] > rp_out[:-1]) | (rp_in[1:] > rp_in[:-1])
                 rank = jnp.where(
                     touched, jnp.cumsum(touched, dtype=jnp.int32) - 1, -1
                 )
-                longest = self.pair_runs(key, ctx)[1]
                 with _obs_trace.sync("expand"):
                     count = int(jnp.sum(touched))
-                size = self._wedge_side(count)
                 sp.note("nodes", count)
+                size = max(-(-count // self.WEDGE_ROWS), 1) * self.WEDGE_ROWS
+                # tpulint: allow[pad-invariant] reason=the bit rows' own lattice (multiples of WEDGE_ROWS), not the row buckets': a bucket's pad would be squared
+                nodes = jnp.nonzero(touched, size=size, fill_value=0)[0]
+                self._wedge_ranks[types_key] = (rank, nodes.astype(jnp.int32))
+        return self._wedge_ranks[types_key]
+
+    def wedge_adjacency(self, key, cols, ctx) -> Optional[Tuple[Any, ...]]:
+        """The CSR ``key`` as bit rows (``jit_ops.bit_adjacency``) — a row
+        per node its types touch, a bit per node the types ``cols`` touch
+        (``wedge_rank`` numbers both), one uint32[rows, words] plane per
+        binary digit of the most parallel lanes a pair has — or None: two
+        such sets (a closing term's) would pass ``WEDGE_MAX_BYTES``."""
+        if (key, cols) not in self._wedge_adj:
+            from . import jit_ops as J
+
+            with _build("wedge_adjacency") as sp:
+                rp, ci, _ = self.csr(*key, ctx)
+                row_rank, row_nodes = self.wedge_rank(key[0], ctx)
+                col_rank, col_nodes = self.wedge_rank(cols, ctx)
+                planes = max(self.longest_run(key, ctx).bit_length(), 1)
+                # words in whole lanes of 128: the layout in which the chip
+                # keeps a node's row contiguous (with 2,064 words a row it
+                # chose column-major and copied both sets on every call)
+                size = int(row_nodes.shape[0])
+                words = -(-int(col_nodes.shape[0]) // 4096) * 128
+                sp.note("planes", planes)
                 out = None
-                if size * size <= self.WEDGE_MAX_CELLS and longest <= 127:
-                    out = WedgeAdjacency(
-                        # tpulint: allow[pad-invariant] reason=the matrix's side is padded to whole blocks of WEDGE_BLOCK (_wedge_side), the MXU's lattice, not the row buckets'; one program per graph, as dense_adj's
-                        J.dense_adjacency(rows, ci, rp, rank, size=size),
-                        rank, longest,
+                if 2 * planes * size * words * 4 <= self.WEDGE_MAX_BYTES:
+                    out = J.bit_adjacency(
+                        rp, ci, self.csr_rows(*key, ctx), row_rank, col_rank,
+                        size=size, words=words, planes=planes,
                     )
-                self._wedge_adj[key] = out
-        return self._wedge_adj[key]
+                self._wedge_adj[(key, cols)] = out
+        return self._wedge_adj[(key, cols)]
 
-    def wedge_blocks(self, first, closing, ctx) -> "WedgeBlocks":
-        """The first hop's rows cut into blocks of ``WEDGE_BLOCK`` for
-        ``jit_ops.wedge_close_sum``, with the closing CSR's lanes of each
-        block beside them (both CSRs are sorted by the same node)."""
-        got = self._wedge_blocks.get((first, closing))
-        if got is None:
-            from .bucketing import round_size
+    def closing_pair_rows(self, closing, first_types, second_types, ctx):
+        """Device (int32, int32) per lane ``a -> c`` of the CSR ``closing``:
+        the rows of ``wedge_adjacency``'s bit rows a closing term intersects
+        for the pair — ``a``'s among the first hop's nodes, ``c``'s among
+        the second hop's — on the first lane of each pair, else -1. A fact
+        of the graph, gathered once and kept."""
+        key = (closing, first_types, second_types)
+        if key not in self._closing_rows:
+            from . import jit_ops as J
 
-            fault_point("expand")  # the build reads counts back
-            with _build("wedge_blocks"):
-                rp1, ci1, _ = self.csr(*first, ctx)
-                rp_c, ci_c, _ = self.csr(*closing, ctx)
-                n = self.num_nodes
-                has = rp1[1:] > rp1[:-1]
-                rank1 = jnp.where(has, jnp.cumsum(has, dtype=jnp.int32) - 1, -1)
-                with _obs_trace.sync("expand"):
-                    count = int(jnp.sum(has))
-                block = min(self.WEDGE_BLOCK, self._wedge_side(count))
-                nblocks = max(-(-count // block), 1)
-                # the node each block starts at; past the last row: n
-                # tpulint: allow[pad-invariant] reason=whole blocks of first-hop rows (nblocks * block >= count), the closing program's own lattice
-                starts = jnp.nonzero(has, size=nblocks * block, fill_value=n)[0]
-                block_rows = jnp.concatenate([
-                    starts[::block].astype(jnp.int32),
-                    jnp.full(1, n, jnp.int32),
-                ])
-                with _obs_trace.sync("expand"):
-                    w1, wc = (
-                        int(jnp.max(jnp.diff(jnp.take(rp, block_rows))))
-                        for rp in (rp1, rp_c)
-                    )
-                got = WedgeBlocks(
-                    rank1, block_rows, self.pair_runs(closing, ctx)[0],
-                    self.pair_runs(first, ctx)[1], block, nblocks,
-                    min(max(round_size(w1), 8), int(ci1.shape[0])),
-                    min(max(round_size(wc), 8), int(ci_c.shape[0])),
+            with _build("closing_pair_rows"):
+                rp, ci, _ = self.csr(*closing, ctx)
+                self._closing_rows[key] = J.closing_pair_rows(
+                    rp, ci, self.csr_rows(*closing, ctx),
+                    self.wedge_rank(first_types, ctx)[0],
+                    self.wedge_rank(second_types, ctx)[0],
                 )
-            self._wedge_blocks[(first, closing)] = got
-        return got
+        return self._closing_rows[key]
+
+    def csr_lane_count(self, types_key: Tuple[str, ...], reverse: bool, ctx) -> int:
+        """Host-cached number of edges one CSR orientation holds (its real
+        lanes; computed at build)."""
+        if (types_key, reverse) not in self._csr_lanes:
+            self.csr(types_key, reverse, ctx)
+        return self._csr_lanes[(types_key, reverse)]
 
     def csr_max_degree(self, types_key: Tuple[str, ...], reverse: bool, ctx) -> int:
         """Host-cached max degree of one CSR orientation (computed at

@@ -995,8 +995,8 @@ def tree_count(root_weight, live_nodes, hops, tree, whole: bool, rows=None):
 # ``left`` / ``right`` are the chain's per-node weights on either side of the
 # pair (``chain_node_weights``); the wedges with a = c are counted from one
 # cached count per edge lane (``two_cycle_sum``), the wedges closed by an
-# edge between a and c on the MXU (``wedge_close_sum``). No row of the chain
-# is built.
+# edge between a and c by intersecting two bit rows a closing pair
+# (``wedge_close_sum``). No row of the chain is built.
 # ---------------------------------------------------------------------------
 
 
@@ -1052,33 +1052,48 @@ def two_cycle_sum(rp, ci, rows, back, mid_mask, w):
     return jnp.sum(jnp.where(live, t, 0), dtype=jnp.int64)
 
 
-@jax.jit
-def csr_pair_runs(rp, ci, rows):
-    """(bool per lane: the first lane of its (row, col) pair; the most
-    lanes any pair has). The lanes of a pair are neighbours: the CSR is
-    sorted by (row, col)."""
+def _pair_runs(rp, ci, rows):
+    """Per lane of a CSR: (it holds an edge; it is the first lane of its
+    (row, col) pair; its place, from 1, among the pair's lanes). The lanes
+    of a pair are neighbours: the CSR is sorted by (row, col)."""
     lanes = jnp.arange(ci.shape[0], dtype=jnp.int32)
     live = _real_lanes(rp, ci)
     prev_same = jnp.concatenate([
         jnp.zeros(1, bool), (ci[1:] == ci[:-1]) & (rows[1:] == rows[:-1])
     ])
     first = live & ~prev_same
-    run_start = lax.cummax(jnp.where(first, lanes, 0))
-    longest = jnp.max(jnp.where(live, lanes - run_start + 1, 0), initial=0)
-    return first, longest
+    return live, first, lanes - lax.cummax(jnp.where(first, lanes, 0)) + 1
 
 
-@partial(jax.jit, static_argnames=("size",))
-def dense_adjacency(rows, ci, rp, rank, size: int):
-    """int8[size, size]: the CSR's edges as a matrix over the nodes ``rank``
-    numbers (-1: not among them), each entry the number of parallel lanes
-    (the caller has seen that none passes 127)."""
-    live = _real_lanes(rp, ci)
-    r = jnp.take(rank, rows)
-    c = jnp.take(rank, jnp.clip(ci, 0))
-    r = jnp.where(live & (r >= 0) & (c >= 0), r, size)  # dropped
-    return jnp.zeros((size, size), jnp.int8).at[r, c].add(
-        jnp.ones((), jnp.int8), mode="drop"
+@jax.jit
+def csr_longest_run(rp, ci, rows):
+    """The most lanes any (row, col) pair of a CSR has."""
+    live, _, place = _pair_runs(rp, ci, rows)
+    return jnp.max(jnp.where(live, place, 0), initial=0)
+
+
+@partial(jax.jit, static_argnames=("size", "words", "planes"))
+def bit_adjacency(rp, ci, rows, row_rank, col_rank, size: int, words: int, planes: int):
+    """``planes`` of uint32[size, words]: the CSR's (row, col) pairs as bit
+    rows — row ``row_rank[row]``, bit ``col_rank[col]`` (word ``>> 5``, bit
+    ``& 31``; a rank of -1: the node is not among them). Plane ``j`` holds
+    binary digit ``j`` of the pair's number of parallel lanes (the caller
+    has seen that none needs more than ``planes`` digits), so every lane
+    counts."""
+    live, first, place = _pair_runs(rp, ci, rows)
+    # a pair is written from its last lane, whose place is its lanes' number
+    goes_on = jnp.concatenate([live[1:] & ~first[1:], jnp.zeros(1, bool)])
+    r = jnp.take(row_rank, rows)
+    c = jnp.take(col_rank, jnp.clip(ci, 0))
+    keep = live & ~goes_on & (r >= 0) & (c >= 0)
+    r = jnp.where(keep, r, size)  # dropped
+    bit = jnp.uint32(1) << (c & 31).astype(jnp.uint32)
+    # a pair is written once, so the adds into a word are of distinct bits
+    return tuple(
+        jnp.zeros((size, words), jnp.uint32).at[r, c >> 5].add(
+            jnp.where((place >> j) & 1 == 1, bit, jnp.uint32(0)), mode="drop"
+        )
+        for j in range(planes)
     )
 
 
@@ -1092,57 +1107,97 @@ def _lane_window(length: int, lo, hi, width: int):
     return start, (lanes >= lo) & (lanes < hi)
 
 
-@partial(jax.jit, static_argnames=("block", "width1", "width_c"))
+@jax.jit
+def closing_pair_rows(rp, ci, rows, row_rank, col_rank):
+    """(int32, int32) per lane ``a -> c`` of a closing CSR: ``row_rank[a]``
+    and ``col_rank[c]`` on the first lane of each (a, c) pair where both
+    nodes have a rank — the two bit rows ``wedge_close_sum`` intersects for
+    the pair — and -1 on every other lane (pad, a further parallel lane, a
+    node without a row)."""
+    _, first, _ = _pair_runs(rp, ci, rows)
+    ra = jnp.take(row_rank, rows)
+    kc = jnp.take(col_rank, jnp.clip(ci, 0))
+    live = first & (ra >= 0) & (kc >= 0)
+    return jnp.where(live, ra, -1), jnp.where(live, kc, -1)
+
+
+def _weight_rows(w, nodes):
+    """uint32[rows, 8]: the 64-bit weight ``w`` of each row's node as a row
+    of words, low then high. This chip gathers a row of a table in 3 ns
+    and an element of a vector in 15 (my chip run, PR 37, call M4: two
+    64-bit weights a lane over 3.9M lanes 0.025 s as rows, 0.241 s as
+    elements), so the closing pairs' weights are gathered as their bit rows
+    are."""
+    w = jnp.take(w, nodes).astype(jnp.uint64)
+    words = jnp.stack([w.astype(jnp.uint32), (w >> 32).astype(jnp.uint32)], axis=1)
+    return jnp.pad(words, ((0, 0), (0, 6)))
+
+
+def _weights_at(table, at):
+    """int64 per lane: the weights ``_weight_rows`` laid out, at rows ``at``."""
+    got = jnp.take(table, at, axis=0, mode="clip")
+    return got[:, 0].astype(jnp.int64) | (got[:, 1].astype(jnp.int64) << 32)
+
+
+@partial(jax.jit, static_argnames=("chunk",))
 def wedge_close_sum(
-    a2, rank2, rp1, ci1, rows1, rank1, block_rows, mid_mask,
-    rp_c, ci_c, rows_c, first_c, left, right,
-    block: int, width1: int, width_c: int,
+    b1, nodes1, b2t, nodes2, mid_mask, rp_c, ra_c, kc_c, left, right,
+    chunk: int,
 ):
     """sum over the closing pairs (a, c) — each once, however many parallel
     closing lanes — of ``left[a] * right[c] *`` the number of wedges
     ``a -> b -> c`` (parallel lanes each count, ``mid_mask[b]`` holds).
 
-    Dense: per block of ``block`` first-hop rows the rows' 0/1.. matrix is
-    scattered from the first hop's CSR lanes, multiplied on the MXU by the
-    second hop's whole matrix ``a2`` (int8, int32 sums, exact), and the
-    product is read at the block's closing pairs alone; the product of one
-    block is all that is ever held. ``rank1`` numbers the nodes that have a
-    first-hop lane (the blocks' rows), ``rank2`` those ``a2`` is over;
-    ``block_rows[i]`` is the first node of block ``i``; ``width1`` /
-    ``width_c`` bound a block's first-hop and closing lanes."""
-    size = a2.shape[0]
-    nblocks = block_rows.shape[0] - 1
+    Per closing pair two bit rows are intersected: ``b1[j][ra_c]``, a's
+    first-hop neighbours, and ``b2t[k][kc_c]``, the nodes with a
+    second-hop lane into c (``bit_adjacency``: plane ``j`` = digit ``j`` of
+    a pair's parallel lanes; ``nodes1`` / ``nodes2``: the node of each row,
+    and ``nodes2`` the node of each bit of both; ``closing_pair_rows``: the
+    pair's rows, -1 on a lane that is none), so
+
+        wedges(a, c) = sum_jk popcount(b1[j][a] & b2t[k][c] & mid) << (j + k)
+
+    The closing CSR's lanes are walked ``chunk`` at a time, as far as its
+    real lanes go (``rp_c[-1]``); two gathered chunks of rows are all the
+    program holds beside the bit rows."""
+    words = b1[0].shape[1]
+    length = ra_c.shape[0]
+    if length == 0:  # no closing edge: no wedge is closed
+        return jnp.zeros((), jnp.int64)
+    real = rp_c[-1]
+    mid = None
+    if mid_mask is not None:  # packed as the bit rows are: bit r = nodes2[r]
+        bits = jnp.pad(jnp.take(mid_mask, nodes2), (0, words * 32 - nodes2.shape[0]))
+        mid = jnp.sum(
+            bits.reshape(words, 32).astype(jnp.uint32)
+            << jnp.arange(32, dtype=jnp.uint32),
+            axis=1, dtype=jnp.uint32,
+        )
+    left, right = _weight_rows(left, nodes1), _weight_rows(right, nodes2)
 
     def body(i, acc):
-        i = i.astype(jnp.int32)
-        a_lo, a_hi = block_rows[i], block_rows[i + 1]
-        start, live = _lane_window(ci1.shape[0], rp1[a_lo], rp1[a_hi], width1)
-        b = jnp.clip(lax.dynamic_slice(ci1, (start,), (width1,)), 0)
-        r = jnp.take(rank1, lax.dynamic_slice(rows1, (start,), (width1,)))
-        kb = jnp.take(rank2, b)
-        live = live & (kb >= 0)
-        if mid_mask is not None:
-            live = live & jnp.take(mid_mask, b)
-        rows = jnp.zeros((block, size), jnp.int8).at[
-            jnp.where(live, r - i * block, block), jnp.where(live, kb, 0)
-        ].add(jnp.ones((), jnp.int8), mode="drop")
-        with jax.named_scope("wedge_matmul"):
-            wedges = jnp.dot(rows, a2, preferred_element_type=jnp.int32)
-        start, live = _lane_window(ci_c.shape[0], rp_c[a_lo], rp_c[a_hi], width_c)
-        a = lax.dynamic_slice(rows_c, (start,), (width_c,))
-        c = jnp.clip(lax.dynamic_slice(ci_c, (start,), (width_c,)), 0)
-        ra = jnp.take(rank1, a)
-        kc = jnp.take(rank2, c)
-        live = (
-            live & lax.dynamic_slice(first_c, (start,), (width_c,))
-            & (ra >= 0) & (kc >= 0)
-        )
-        at = jnp.where(live, (ra - i * block) * size + kc, 0)
-        found = jnp.take(wedges.reshape(-1), at).astype(jnp.int64)
-        weight = jnp.take(left, a) * jnp.take(right, c)
+        lo = i.astype(jnp.int32) * chunk
+        start, live = _lane_window(length, lo, lo + chunk, chunk)
+        ra = lax.dynamic_slice(ra_c, (start,), (chunk,))
+        kc = lax.dynamic_slice(kc_c, (start,), (chunk,))
+        live = live & (ra >= 0)  # a pad lane is no pair's first
+        found = jnp.zeros(chunk, jnp.int64)
+        for j, plane1 in enumerate(b1):
+            from_a = jnp.take(plane1, ra, axis=0, mode="clip")
+            if mid is not None:
+                from_a = from_a & mid
+            for k, plane2 in enumerate(b2t):
+                with jax.named_scope("wedge_intersect"):
+                    into_c = jnp.take(plane2, kc, axis=0, mode="clip")
+                    both = lax.population_count(from_a & into_c)
+                    found = found + (
+                        jnp.sum(both, axis=1, dtype=jnp.int32).astype(jnp.int64)
+                        << (j + k)
+                    )
+        weight = _weights_at(left, ra) * _weights_at(right, kc)
         return acc + jnp.sum(jnp.where(live, weight * found, 0), dtype=jnp.int64)
 
-    return lax.fori_loop(0, nblocks, body, jnp.zeros((), jnp.int64))
+    return lax.fori_loop(0, -(-real // chunk), body, jnp.zeros((), jnp.int64))
 
 
 # ---------------------------------------------------------------------------
