@@ -114,9 +114,87 @@ def test_bucketed_records_identical_to_off(bucket_mode):
         )
 
 
+# grouped aggregations and ORDER BY over tail-padded tables: the pad-aware
+# group (``TpuTable._group_bucketed``) and the sort with the pad last, and
+# the shapes that decline to the exact path (DISTINCT, collect)
+GROUPED = [
+    "MATCH (a:Person) RETURN a.age AS age, count(*) AS c, sum(a.id) AS s "
+    "ORDER BY age",
+    "MATCH (a:Person) RETURN a.age % 7 AS r, min(a.name) AS lo, "
+    "max(a.age) AS hi, avg(a.id) AS m, count(a.age) AS n ORDER BY r DESC",
+    "MATCH (a:Person) WITH a.age % 5 AS r, count(*) AS c "
+    "RETURN c, count(*) AS k, sum(r) AS s ORDER BY c",
+    "MATCH (a:Person)-[r:KNOWS]->(b) RETURN a.name AS a, r.since % 3 AS y, "
+    "count(*) AS c ORDER BY a DESC, y",
+    "MATCH (a:Person) WHERE a.age > 30 RETURN a.age AS age, count(*) AS c",
+    "MATCH (a:Person) RETURN a.age AS age, count(DISTINCT a.name) AS c "
+    "ORDER BY age",
+    "MATCH (a:Person) RETURN a.age % 3 AS r, collect(a.id) AS ids ORDER BY r",
+    "MATCH (a:Person) RETURN a.name AS n ORDER BY a.age DESC, n",
+]
+
+
+@pytest.mark.parametrize("query", GROUPED)
+@pytest.mark.parametrize("bucket_mode", ["pow2", "1.25"], indirect=True)
+def test_bucketed_grouping_identical_to_off(bucket_mode, query):
+    create = _create_query(n=53, e=140, seed=11)
+    bucketing.MODE.set("off")
+    expected = CypherSession.tpu().create_graph_from_create_query(
+        create
+    ).cypher(query).records.collect()
+    bucketing.MODE.set(bucket_mode)
+    got = CypherSession.tpu().create_graph_from_create_query(
+        create
+    ).cypher(query).records.collect()
+    assert [dict(r) for r in got] == [dict(r) for r in expected]
+    local = CypherSession.local().create_graph_from_create_query(
+        create
+    ).cypher(query).records.collect()
+    assert [dict(r) for r in got] == [dict(r) for r in local]
+
+
 # ---------------------------------------------------------------------------
 # no-recompile regression: same plan, different data sizes, shared buckets
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "query",
+    [
+        # the groups stay three; the rows move within their bucket
+        "MATCH (a:P) RETURN a.x % 3 AS r, count(*) AS c, sum(a.x) AS s "
+        "ORDER BY r",
+        # the groups move with the rows, within their bucket too
+        "MATCH (a:P) WITH a.x AS x, count(*) AS c "
+        "RETURN x, c ORDER BY x DESC",
+    ],
+)
+@pytest.mark.parametrize("bucket_mode", ["pow2"], indirect=True)
+def test_group_and_order_no_recompile_within_bucket(bucket_mode, query):
+    session = CypherSession.tpu()
+
+    def run(n):
+        g = _ring_graph(session, n)
+        g.cypher("MATCH (a:P) RETURN count(*) AS c").records.collect()
+        before = bucketing.compile_snapshot()
+        rows = g.cypher(query).records.collect()
+        assert sum(r["c"] for r in rows) == n
+        return bucketing.compile_delta(before)["compiles"]
+
+    run(40)
+    assert run(48) == 0
+    assert run(56) == 0
+
+
+def test_round_fine_lattice():
+    assert bucketing.round_fine(1) == bucketing.FINE_FLOOR
+    assert bucketing.round_fine(4097) == 4096 + 128
+    # every graph500-22 drawn from a seed: one size, 1.2% over the count
+    sizes = {bucketing.round_fine(n) for n in (2_394_573, 2_396_090, 2_396_798)}
+    assert sizes == {37 * 2**16}
+    for n in (5_000, 65_645, 448_626, 2**22 - 1, 2**22):
+        r = bucketing.round_fine(n)
+        assert n <= r <= n * 1.032 and r <= bucketing.round_up_pow2(n)
 
 
 def _ring_graph(session, n):

@@ -16,6 +16,7 @@ from ...api.table import Table
 from ...api.types import CypherType
 from ...api.values import _equiv_key, order_key
 from ...ir import expr as E
+from ...relational.procedures import run_local
 from .eval import Evaluator, aggregate_values
 
 
@@ -260,6 +261,12 @@ class LocalTable(Table):
 
     def project(self, pairs) -> "LocalTable":
         return LocalTable({new: self._cols[old] for old, new in pairs}, self._nrows)
+
+    @staticmethod
+    def run_procedure(proc, graph, ctx, table, id_col, out_col, args) -> "LocalTable":
+        """A procedure call's values (``relational/procedures.py``): the
+        plain NumPy implementation."""
+        return run_local(proc, graph, ctx, table, id_col, out_col, args)
 
     def with_row_index(self, col: str) -> "LocalTable":
         out = dict(self._cols)

@@ -101,6 +101,22 @@ def round_up_multiple(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+# the fine lattice's floor: a table this small runs at its whole bucket
+FINE_FLOOR = 4096
+
+
+def round_fine(n: int) -> int:
+    """``n`` rounded up to a multiple of 1/32 of its power of two, at least
+    ``FINE_FLOOR``: the rows a pad-aware sort or scatter over a bucketed
+    table keeps (``TpuTable._group_bucketed``). A count that moves by a
+    little with the data — a graph's vertices from one draw to the next —
+    keeps its program, at under 3.2% more rows where the bucket could
+    double them."""
+    n = int(n)
+    step = 1 << max(n.bit_length() - 6, 0)
+    return max(FINE_FLOOR, round_up_multiple(n, step))
+
+
 # 1.25-lattice, grown lazily; starts at the floor
 _LATTICE_125 = [_BUCKET_FLOOR]
 _LATTICE_LOCK = threading.Lock()
@@ -140,6 +156,16 @@ def _active_shards() -> int:
 
 def _lattice(n: int, m: str) -> int:
     return _round_125(n) if m == "1.25" else round_up_pow2(n, _BUCKET_FLOOR)
+
+
+def bucket_of(n: int) -> int:
+    """What ``round_size`` gives ``n`` on one device, without its telemetry:
+    for a static size that sizes no materialize of rows (a grouped
+    aggregation's number of groups), which the operator's calibration
+    (``optimizer/feedback``, fed by the span's padded rows) must not see."""
+    n = int(n)
+    m = mode()
+    return n if n <= 0 or m == "off" else _lattice(n, m)
 
 
 def round_size(n: int) -> int:

@@ -990,6 +990,181 @@ def tree_count(root_weight, live_nodes, hops, tree, whole: bool, rows=None):
 
 
 # ---------------------------------------------------------------------------
+# graph algorithms of the procedures (``backend/tpu/procedures.py``): each a
+# fixed point reached inside ONE program, the test on the device. A
+# relationship type is undirected there, so each step reads both CSR
+# orientations of the type, ``orients``: a tuple of one per orientation that
+# holds an edge, each ``(rp, ci, window, start)`` (``RowSpan.window`` beside
+# its CSR), for WCC with the CSR's lane rows before the window. Both end
+# with the value of every input row (``ids`` searched among ``dev_ids``, as
+# a count chain's frontier is) and how many steps ran.
+# ---------------------------------------------------------------------------
+
+
+def _rows_of(dev_ids, ids, valid, values):
+    """``values`` (per node position) at each input row, and whether the
+    row is a node of the graph."""
+    n = dev_ids.shape[0]
+    pos = jnp.clip(jnp.searchsorted(dev_ids, ids), 0, n - 1)
+    present = jnp.take(dev_ids, pos) == ids
+    if valid is not None:
+        present = present & valid
+    return jnp.take(values, pos), present
+
+
+# frontier lanes one step of a BFS level pushes, and rows: a step holds up to
+# one row for every two lanes, and only rows with a lane in its orientation
+# are queued, so a step is short of lanes only where rows of one lane follow
+# one another. Every step has this one width: a smaller step costs more a
+# lane, and how many of them a traversal needs follows the source.
+BFS_PUSH_LANES = 1 << 19
+_PUSH_LANES_PER_ROW = 2
+
+
+def _push_step(rp, ci, queue, k, a, off, depth, level, lanes: int):
+    """Push the next ``lanes`` frontier lanes of one CSR orientation. The
+    frontier's lanes, in ``queue`` order (its ``k`` node positions), are
+    one sequence; ``(a, off)`` is where this step starts in it: the
+    ``off``-th lane of the row ``queue[a]``. Each lane's far node takes the
+    depth ``level + 1`` unless it has a smaller one (a scatter-min). The
+    lane a step position reads is found without a search: each row's first
+    lane adds, at its step position, the change of ``CSR lane - step
+    position`` from the row before (rows without a lane add a change that
+    the next row takes back), and a cumulative sum carries it over the
+    row's lanes. Returns (a, off, depth, lanes taken)."""
+    n = depth.shape[0]
+    rows = lanes // _PUSH_LANES_PER_ROW
+    q = lax.dynamic_slice(queue, (a,), (rows,))
+    t = jnp.arange(rows, dtype=jnp.int32)
+    first = jnp.where(t == 0, off, 0)
+    base = jnp.take(rp, q, mode="clip") + first
+    deg = jnp.where(a + t < k, jnp.take(rp, q + 1, mode="clip") - base, 0)
+    incl = jnp.cumsum(deg)
+    excl = incl - deg
+    took = jnp.minimum(incl[-1], lanes)
+    shift = base - excl
+    change = shift - jnp.concatenate([jnp.zeros(1, shift.dtype), shift[:-1]])
+    marks = jnp.zeros(lanes, shift.dtype).at[excl].add(change, mode="drop")
+    pos = jnp.arange(lanes, dtype=shift.dtype)
+    far = jnp.take(ci, jnp.clip(pos + jnp.cumsum(marks), 0, ci.shape[0] - 1))
+    depth = depth.at[jnp.where(pos < took, far, n)].min(level + 1, mode="drop")
+    done = jnp.sum(incl <= took, dtype=jnp.int32)  # whole rows: a prefix
+    at = jnp.minimum(done, rows - 1)
+    off = jnp.where(done < rows, took - excl[at] + first[at], 0)
+    return a + done, off, depth, took
+
+
+def _frontier_queue(frontier):
+    """The positions of the frontier's nodes first, ascending, and how many
+    there are: one sort of the positions keyed past the node count where
+    the node is off the frontier."""
+    n = frontier.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    order = lax.sort(jnp.where(frontier, pos, pos + n))
+    return jnp.where(order < n, order, 0), jnp.sum(frontier, dtype=jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("step",))
+def bfs_levels(orients, source, dev_ids, ids, valid, step: int = BFS_PUSH_LANES):
+    """Level-synchronous BFS from the node at position ``source`` (traced:
+    a new source compiles nothing), top-down: a level pushes the lanes of
+    its frontier's rows in each orientation (``_push_step``, ``step`` lanes
+    at a time, fewer where the orientation holds fewer), so the whole
+    traversal reads each lane of the source's component once, whatever the
+    source and the number of levels. Stops at the first level that reaches
+    no new node. Returns (int64 depth per input row, valid: reached, steps,
+    the lanes the push steps went over)."""
+    n = dev_ids.shape[0]
+    unseen = jnp.iinfo(jnp.int32).max
+    depth = lax.dynamic_update_slice(
+        jnp.full(n, unseen, jnp.int32), jnp.zeros(1, jnp.int32), (source,)
+    )
+    pushes = []
+    for rp, ci, *_ in orients:
+        lanes = min(step, 1 << max(int(ci.shape[0]) - 1, 1).bit_length())
+        pad = jnp.zeros(lanes // _PUSH_LANES_PER_ROW, jnp.int32)
+        pushes.append((rp, ci, rp[1:n + 1] - rp[:n], pad, lanes))
+
+    def level_step(state):
+        level, depth, _, width = state
+        frontier = depth == level
+        for rp, ci, deg, pad, lanes in pushes:
+            queue, k = _frontier_queue(frontier & (deg > 0))
+            queue = jnp.concatenate([queue, pad])
+
+            def push(state, rp=rp, ci=ci, queue=queue, k=k, lanes=lanes):
+                a, off, depth, left, width = state
+                a, off, depth, took = _push_step(
+                    rp, ci, queue, k, a, off, depth, level, lanes
+                )
+                return a, off, depth, left - took, width + lanes
+
+            left = jnp.sum(jnp.where(frontier, deg, 0), dtype=jnp.int32)
+            _, _, depth, _, width = lax.while_loop(
+                lambda s: s[3] > 0, push,
+                (jnp.int32(0), jnp.int32(0), depth, left, width),
+            )
+        return level + 1, depth, jnp.any(depth == level + 1), width
+
+    steps, depth, _, width = lax.while_loop(
+        lambda state: state[2], level_step,
+        (jnp.int32(0), depth, jnp.bool_(True), jnp.int64(0)),
+    )
+    at, present = _rows_of(dev_ids, ids, valid, depth)
+    reached = at < unseen
+    return jnp.where(reached, at, -1).astype(jnp.int64), present & reached, steps, width
+
+
+def _csr_segment_min(rp, ci, rows, window, start, w):
+    """int64 per node: the least ``w`` over the node's CSR neighbours, or
+    the node count where it has none — a segmented minimum over the edge
+    lanes without a scatter. Each lane's value is keyed by its row
+    (``w[ci] - row * n``: a later row's keys lie below every key of an
+    earlier one, as ``w`` < n), so ONE cumulative minimum restarts at every
+    row, and the minimum of a row is the key at its last lane, gathered at
+    the window's row pointers. Pad lanes lie past ``rp[-1]``, after every
+    lane a row pointer reads."""
+    n = w.shape[0]
+    t = jnp.take(w, jnp.clip(ci, 0), mode="clip").astype(jnp.int64)
+    with jax.named_scope("scan"):
+        least = lax.cummin(t - rows.astype(jnp.int64) * n)
+    length = window.shape[0] - 1
+    row = start.astype(jnp.int64) + jnp.arange(length, dtype=jnp.int64)
+    last = jnp.take(least, jnp.clip(window[1:] - 1, 0), mode="clip") + row * n
+    m = jnp.where(window[1:] > window[:-1], last, n)
+    return lax.dynamic_update_slice(jnp.full(n, n, jnp.int64), m, (start,))
+
+
+@jax.jit
+def wcc_labels(orients, dev_ids, ids, valid):
+    """Weakly connected components by minimum-label propagation: every
+    node starts as its own position, a step takes the least label among
+    its neighbours over each orientation (``_csr_segment_min``), then
+    jumps once through its label (``label[label[v]]``: a label is a
+    position in the node's component, never above its own), until no label
+    moves. A component's label is then its smallest position, the node of
+    the smallest id. Returns (int64 id of that node per input row, valid,
+    steps)."""
+    n = dev_ids.shape[0]
+
+    def step(state):
+        steps, label, _ = state
+        new = label
+        for rp, ci, rows, window, start in orients:
+            least = _csr_segment_min(rp, ci, rows, window, start, label)
+            new = jnp.minimum(new, least.astype(jnp.int32))
+        new = jnp.take(new, new)
+        return steps + 1, new, jnp.any(new != label)
+
+    steps, label, _ = lax.while_loop(
+        lambda state: state[2], step,
+        (jnp.int32(0), jnp.arange(n, dtype=jnp.int32), jnp.bool_(True)),
+    )
+    at, present = _rows_of(dev_ids, ids, valid, label)
+    return jnp.take(dev_ids, at), present, steps
+
+
+# ---------------------------------------------------------------------------
 # count chain under a constraint between two of its nodes, two hops apart:
 #   count = sum over wedges (a -> b -> c) of left[a] * right[c] * [constraint]
 # ``left`` / ``right`` are the chain's per-node weights on either side of the
@@ -1695,17 +1870,48 @@ def group_index(order, flags, k: int):
     return seg_j, first_rows
 
 
+@partial(jax.jit, static_argnames=("k",))
+def group_index_counted(order, flags, n, count, k: int):
+    """``group_index`` over a tail-padded table at a BUCKETED static ``k``
+    >= the traced true ``count`` of groups. ``flags`` are restricted to the
+    live rows (``live_first_flags``): the stable sort puts a pad row after
+    every live row of its key, so no live group loses its first. A pad row's
+    group id is ``k``, which no segment reduction keeps; ``first_rows`` past
+    ``count`` are dead duplicates for the counted gather."""
+    m = order.shape[0]
+    seg_sorted = jnp.where(
+        order < n, jnp.cumsum(flags.astype(jnp.int64)) - 1, k
+    )
+    seg_rows = jnp.zeros(m, jnp.int64).at[order].set(seg_sorted)
+    lane = jnp.arange(k, dtype=jnp.int64)
+    firsts = jnp.take(order, jnp.nonzero(flags, size=k)[0])
+    firsts = jnp.where(lane < count, firsts, m)
+    rank = jnp.full(k + 1, k, jnp.int64).at[jnp.argsort(firsts)].set(lane)
+    seg_j = jnp.take(rank, seg_rows)
+    first_rows = jnp.clip(jnp.sort(firsts), 0, m - 1)
+    return seg_j, first_rows
+
+
+@partial(jax.jit, static_argnames=("rows",))
+def cols_head(cols, rows: int):
+    """The first ``rows`` lanes of every array of a column set, in one
+    program (``TpuTable._head``)."""
+    return jax.tree.map(lambda a: a[:rows], cols)
+
+
 # ---------------------------------------------------------------------------
 # ORDER BY permutation
 # ---------------------------------------------------------------------------
 
 
 @partial(jax.jit, static_argnames=("kinds", "ascs"))
-def order_permutation(datas, valids, kinds, ascs):
+def order_permutation(datas, valids, kinds, ascs, n=None):
     """Stable device lexsort permutation under Cypher orderability
     (numbers < NaN < null ascending; DESC reverses all three ranks).
     Items arrive in ORDER BY priority order; keys are appended reversed so
-    lexsort's last-key-primary convention sees item 0 as primary."""
+    lexsort's last-key-primary convention sees item 0 as primary. With the
+    traced ``n`` of a tail-padded table, the rows from ``n`` on sort after
+    every live row whatever the items say."""
     keys = []
     for d, v, kind, asc in zip(
         reversed(datas), reversed(valids), reversed(kinds), reversed(ascs)
@@ -1738,6 +1944,9 @@ def order_permutation(datas, valids, kinds, ascs):
             if nan is not None:
                 keys.append(-nan.astype(jnp.int8))
             keys.append(-null.astype(jnp.int8))
+    if n is not None:
+        m = datas[0].shape[0]
+        keys.append((jnp.arange(m, dtype=jnp.int64) >= n).astype(jnp.int8))
     return jnp.lexsort(tuple(keys)).astype(jnp.int64)
 
 

@@ -61,6 +61,7 @@ from ...api.table import Table
 from ...api.types import CypherType
 from . import bucketing
 from . import jit_ops as J
+from . import procedures as _procedures  # its counters export from the start
 from .column import (
     BOOL,
     DATE,
@@ -1161,19 +1162,26 @@ class TpuTable(Table):
         """ORDER BY: one jitted stable lexsort under Cypher orderability
         (``jit_ops.order_permutation``) + one batched gather of every row;
         OBJ keys sort on the local backend."""
+        if not items:
+            return self
+        if self._pad_aware():
+            # the sort runs over the physical rows, the pad rows last, and
+            # the gather keeps the bucket: a row count that moves within
+            # its bucket (a grouped aggregation's groups) keeps its program
+            idx = self._order_permutation(items, live=self._nrows)
+            return self._take_counted(idx, self._nrows)
         t = self._depad()
         if t is not self:
             return t.order_by(items)
-        if not items:
-            return self
         idx = self._order_permutation(items)
         if idx is None:
             return self._from_local(self._to_local('order_by:obj-keys').order_by(items))
         return self._take(idx)
 
-    def _order_permutation(self, items: Sequence[Tuple[str, bool]]):
+    def _order_permutation(self, items: Sequence[Tuple[str, bool]], live=None):
         """Row indices in ORDER BY order; None if a key has no device
-        representation."""
+        representation. ``live``: the logical row count of a tail-padded
+        table, whose pad rows then sort last."""
         cols = [self._cols[c] for c, _ in items]
         if any(c.kind == OBJ for c in cols):
             return None
@@ -1182,7 +1190,43 @@ class TpuTable(Table):
             tuple(c.valid for c in cols),
             tuple(c.kind for c in cols),
             tuple(bool(asc) for _, asc in items),
+            live,
         )
+
+    def _pad_aware(self) -> bool:
+        """Whether an operator may run over this table's physical rows and
+        keep its bucket: bucketing on, a tail of pad rows, one device, and
+        every column on the device at the physical length."""
+        phys = self._phys
+        return (
+            bucketing.enabled()
+            and phys > self._nrows
+            and _mesh_size() <= 1
+            and all(
+                c.kind != OBJ and len(c) == phys for c in self._cols.values()
+            )
+        )
+
+    def _head(self, rows: int) -> "TpuTable":
+        """The first ``rows`` physical rows of every column (``nrows <= rows
+        <= phys``) in one program, the rows past ``nrows`` still pad."""
+        if rows == self._phys:
+            return self
+        cut = J.cols_head(
+            {c: (col.data, col.valid, col.int_flag) for c, col in self._cols.items()},
+            rows,
+        )
+        pad = rows - self._nrows
+        out = {}
+        for c, col in self._cols.items():
+            d, v, i = cut[c]
+            synth = col.pad_synth and pad > 0
+            out[c] = Column(
+                col.kind, d, None if col.pad_synth and not pad else v,
+                col.vocab, int_flag=i, _np_cache=col._np_cache,
+                _np_valid=col._np_valid, pad=pad, pad_synth=synth,
+            )
+        return TpuTable(out, self._nrows)
 
     # -- distinct / group factorization ------------------------------------
 
@@ -1366,7 +1410,18 @@ class TpuTable(Table):
     # DISTINCT runs as a device pre-dedup of (group, value) pairs
     _DISTINCT_AGGS = frozenset({"count", "sum", "avg", "min", "max", "collect"})
 
+    # aggregators the pad-aware grouping takes: each a segment reduction
+    # that drops a row whose group id lies past the groups
+    _BUCKETED_AGGS = frozenset({"count", "sum", "avg", "min", "max"})
+
     def group(self, by, aggregations, header, parameters) -> "TpuTable":
+        if by and self._pad_aware():
+            try:
+                out = self._group_bucketed(by, aggregations, header, parameters)
+            except (TpuUnsupportedExpr, TpuBackendError):
+                out = None
+            if out is not None:
+                return out
         t = self._depad()
         if t is not self:
             return t.group(by, aggregations, header, parameters)
@@ -1375,6 +1430,68 @@ class TpuTable(Table):
         except (TpuUnsupportedExpr, TpuBackendError):
             lt = self._to_local('group:agg').group(by, aggregations, header, parameters)
             return self._from_local(lt)
+
+    def _group_bucketed(
+        self, by, aggregations, header, parameters
+    ) -> Optional["TpuTable"]:
+        """Pad-aware grouped aggregation: the factorization and the segment
+        reductions run over the first ``bucketing.round_fine(nrows)``
+        physical rows (at most the bucket), and the groups come out on the
+        bucket lattice, tail-padded past their true number. A row count
+        that moves a little with the data (a graph's vertices from one draw
+        to the next) and a group count that moves within its bucket keep
+        every program. None (the caller takes the exact path) for an
+        aggregator outside ``_BUCKETED_AGGS``, a DISTINCT one, or an input
+        the device holds as objects or durations."""
+        from ...ir import expr as E
+
+        for _, agg in aggregations:
+            if (
+                not isinstance(agg, E.Agg)
+                or agg.distinct
+                or agg.name.lower() not in self._BUCKETED_AGGS
+            ):
+                return None
+        n = self._nrows
+        t = self._head(min(self._phys, bucketing.round_fine(n)))
+        ev = TpuEvaluator(t, header, parameters)
+        ev.n = t._phys
+        inputs = []
+        for out_col, agg in aggregations:
+            col = None if agg.expr is None else ev.eval(agg.expr)
+            if col is not None and col.kind in (OBJ, DUR):
+                return None
+            inputs.append((out_col, agg, col))
+        order, flags, _ = t._first_occurrence_index(by)
+        flags, cnt = J.live_first_flags(order, flags, n)
+        with _obs_trace.sync("agg"):  # the group count
+            k = int(cnt)
+        size = bucketing.bucket_of(k)
+        seg_j, first_rows = J.group_index_counted(order, flags, n, k, k=size)
+        keys = TpuTable({c: t._cols[c] for c in by}, n)
+        out_cols = dict(keys._take_counted(first_rows, k)._cols)
+        live = J.row_tail_mask(first_rows, k) if size > k else None
+        for out_col, agg, col in inputs:
+            if col is None:  # count(*): every live row counts
+                _obs_trace.note_agg_form(
+                    J.segment_aggregate_form("count", seg_j.dtype, size)
+                )
+                cnt_j, _, _, _ = J.segment_aggregate(
+                    seg_j, None, None, seg_j, name="count", kind=I64, k=size
+                )
+                c = Column(I64, cnt_j, None)
+            else:
+                c = t._segment_agg(
+                    agg.name.lower(), agg, seg_j, col, t._phys, size, parameters
+                )
+            if live is not None:
+                c = Column(
+                    c.kind, c.data, live if c.valid is None else c.valid & live,
+                    c.vocab, int_flag=c.int_flag, pad=size - k,
+                    pad_synth=c.valid is None,
+                )
+            out_cols[out_col] = c
+        return TpuTable(out_cols, k)
 
     def _group_device(self, by, aggregations, header, parameters) -> "TpuTable":
         """Grouped aggregation as device segment ops: group assignment reuses
@@ -1771,6 +1888,14 @@ class TpuTable(Table):
         from .expand_op import plan_filter_fastpath
 
         return plan_filter_fastpath(planner, op, child)
+
+    @staticmethod
+    def run_procedure(proc, graph, ctx, table, id_col, out_col, args) -> "TpuTable":
+        """A procedure call's values (``relational/procedures.py``): one
+        device program (``procedures.run``)."""
+        return _procedures.run(
+            proc, graph, ctx, ensure_flat(table), id_col, out_col, args
+        )
 
 
 def _float_as_exact_int(c: Column) -> Column:

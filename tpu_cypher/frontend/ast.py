@@ -217,7 +217,8 @@ class DeleteClause(Clause):
 
 @dataclass(frozen=True)
 class CallClause(Clause):
-    """CALL proc.name(args) [YIELD item, ...] — parsed for a clean typed
+    """CALL proc.name(args) [YIELD item, ...] — a leading call of a known
+    procedure runs (``relational/procedures.py``); any other raises a typed
     "unsupported" error downstream (the reference parses procedure calls via
     its frontend and blacklists ProcedureCallAcceptance at TCK level)."""
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
+from ..api.types import CypherType
 from ..frontend.ast import SortItem
 from .expr import Agg, Expr, Var
 from .pattern import IRPattern
@@ -75,6 +76,16 @@ class SelectBlock(Block):
 @dataclass
 class ResultBlock(Block):
     fields: Tuple[str, ...]
+
+
+@dataclass
+class ProcedureCallBlock(Block):
+    """A leading ``CALL proc(args) YIELD ...`` (``relational/procedures.py``):
+    every yield bound to a field, those not yielded to hidden ones."""
+
+    procedure: str
+    args: Tuple[Expr, ...]  # literals and parameters
+    yields: Tuple[Tuple[str, str, CypherType], ...]  # (yield, field, type)
 
 
 @dataclass

@@ -44,9 +44,10 @@ _WRITE_CLAUSES = (A.CreateClause, A.MergeClause, A.SetClause, A.DeleteClause)
 
 
 class UnsupportedFeatureError(IRBuildError):
-    """A feature the grammar accepts but the engine does not execute
-    (procedure calls). The reference's analog: its frontend parses CALL and
-    the backends blacklist ProcedureCallAcceptance at TCK level."""
+    """A feature the grammar accepts but the engine does not execute: an
+    unknown procedure, a correlated call. The reference's analog: its
+    frontend parses CALL and the backends blacklist ProcedureCallAcceptance
+    at TCK level."""
 
 
 @dataclass
@@ -151,15 +152,64 @@ class IRBuilder:
                     "in top-level single queries"
                 )
             elif isinstance(c, A.CallClause):
-                raise UnsupportedFeatureError(
-                    f"CALL {c.procedure}: procedure calls are not supported"
-                )
+                if blocks or env:
+                    raise UnsupportedFeatureError(
+                        f"CALL {c.procedure}: a call that takes input rows "
+                        "(a correlated call) is not supported; a procedure "
+                        "call leads its query"
+                    )
+                blocks.append(self._convert_call(c, env))
+                if len(clauses) == 1:  # a standalone call returns its yields
+                    returns = tuple(n for n in env if not n.startswith("__"))
+                    blocks.append(B.ResultBlock(returns))
+                    saw_return = True
             else:
                 raise IRBuildError(f"Unsupported clause {type(c).__name__}")
             i += 1
         if not saw_return:
             raise IRBuildError("Query must end in RETURN")
         return B.QueryIR(tuple(blocks), returns, self.ctx.working_graph)
+
+    def _convert_call(
+        self, c: A.CallClause, env: Dict[str, CypherType]
+    ) -> B.ProcedureCallBlock:
+        """A leading procedure call: its arguments typed (literals and
+        parameters only), each yield bound to its field — the ones not
+        yielded to hidden fields, so the call's node is always there."""
+        from ..relational.procedures import lookup
+
+        proc = lookup(c.procedure)
+        if len(c.args) != len(proc.args):
+            raise IRBuildError(
+                f"{proc.name} takes {len(proc.args)} argument(s) "
+                f"({', '.join(n for n, _ in proc.args)}), got {len(c.args)}"
+            )
+        args = tuple(self.convert_expr(a, env) for a in c.args)
+        if not all(isinstance(a, (E.Lit, E.Param)) for a in args):
+            raise UnsupportedFeatureError(
+                f"CALL {proc.name}: arguments are literals or parameters"
+            )
+        types = dict(proc.yields)
+        fields: Dict[str, str] = {}
+        if c.star or not c.yields:
+            fields = {y: y for y in types}
+        for item in c.yields:
+            if not isinstance(item.expr, E.Var) or item.expr.name not in types:
+                raise IRBuildError(
+                    f"{proc.name} yields {', '.join(types)}, not "
+                    f"{item.expr.pretty_expr()}"
+                )
+            if item.expr.name in fields:
+                raise IRBuildError(f"{item.expr.name} yielded twice")
+            fields[item.expr.name] = item.name
+        if len(set(fields.values())) != len(fields):
+            raise IRBuildError(f"Duplicate yield names in CALL {proc.name}")
+        yields = []
+        for y, t in proc.yields:
+            field = fields.get(y) or self.fresh_name("yield")
+            env[field] = t
+            yields.append((y, field, t))
+        return B.ProcedureCallBlock(proc.name, args, tuple(yields))
 
     # ------------------------------------------------------------------
     # write queries (docs/mutation.md)

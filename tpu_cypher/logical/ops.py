@@ -145,6 +145,26 @@ class RowIndex(UnaryOp):
 
 
 @dataclass(frozen=True)
+class ProcedureCall(UnaryOp):
+    """A leading procedure call (``relational/procedures.py``): one row per
+    node of the graph, the node and its value bound to their fields."""
+
+    procedure: str
+    args: Tuple[Expr, ...]
+    yields: Tuple[Tuple[str, str, CypherType], ...]  # (yield, field, type)
+
+    @property
+    def fields(self) -> FieldsT:
+        return self.in_op.fields + tuple((f, t) for _, f, t in self.yields)
+
+    def _show_inner(self) -> str:
+        args = ", ".join(a.pretty_expr() for a in self.args)
+        return f"{self.procedure}({args}) YIELD " + ", ".join(
+            f"{y} AS {f}" for y, f, _ in self.yields
+        )
+
+
+@dataclass(frozen=True)
 class PatternScan(UnaryOp):
     """Scan a stored composite pattern (NodeRel / Triplet): one table scan
     binds several query fields at once. Produced by the optimizer rule

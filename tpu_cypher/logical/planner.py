@@ -199,6 +199,8 @@ class LogicalPlanner:
             if current == tuple(blk.fields):
                 return plan
             return L.Select(plan, tuple(blk.fields))
+        if isinstance(blk, B.ProcedureCallBlock):
+            return L.ProcedureCall(plan, blk.procedure, blk.args, blk.yields)
         if isinstance(blk, B.FromGraphBlock):
             return L.FromGraph(plan, blk.qgn)
         if isinstance(blk, B.GraphResultBlock):

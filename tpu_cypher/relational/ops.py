@@ -22,6 +22,7 @@ from ..api.table import Table
 from ..ir import expr as E
 from ..obs import trace as _obs_trace
 from ..obs.metrics import REGISTRY as _OBS_REGISTRY
+from . import procedures
 from .header import RecordHeader
 
 
@@ -300,6 +301,42 @@ class RowIndexOp(RelationalOperator):
 
     def _show_inner(self) -> str:
         return self.fld
+
+
+class ProcedureCallOp(RelationalOperator):
+    """A leading procedure call over the scan of every node of the graph:
+    the procedure's value of each node in a new column, computed by the
+    backend's own implementation (``table_cls.run_procedure``; the
+    registry and the local one in ``procedures.py``)."""
+
+    def __init__(self, scan: RelationalOperator, procedure: str,
+                 args: Sequence[E.Expr], node_fld: str, value: E.Var):
+        super().__init__(scan)
+        self.procedure = procedure
+        self.args = tuple(args)
+        self.node_fld = node_fld
+        self.value = value
+
+    def _compute_header(self) -> RecordHeader:
+        return self.children[0].header.with_expr(self.value)
+
+    def _compute_table(self) -> Table:
+        proc = procedures.lookup(self.procedure)
+        params = self.context.parameters
+        args = procedures.check_args(
+            proc, [_static_value(a, params) for a in self.args]
+        )
+        scan = self.children[0]
+        h = scan.header
+        return self.context.table_cls.run_procedure(
+            proc, self.graph, self.context, scan.table,
+            h.column(h.id_expr(h.var(self.node_fld))),
+            self.header.column(self.value), args,
+        )
+
+    def _show_inner(self) -> str:
+        args = ", ".join(a.pretty_expr() for a in self.args)
+        return f"{self.procedure}({args}): {self.node_fld}, {self.value.name}"
 
 
 class DropOp(RelationalOperator):

@@ -40,6 +40,7 @@ from .ops import (
     LimitOp,
     OrderByOp,
     PathBindOp,
+    ProcedureCallOp,
     RelationalError,
     RelationalOperator,
     RowIndexOp,
@@ -138,6 +139,20 @@ class RelationalPlanner:
         if in_plan.header.expressions:
             return JoinOp(in_plan, scan, [], "cross")
         return scan
+
+    def _plan_ProcedureCall(self, op: L.ProcedureCall) -> RelationalOperator:
+        """The scan of every node of the graph, and the procedure's value of
+        each (``ProcedureCallOp``); the call leads its query."""
+        in_plan = self.process(op.in_op)
+        if in_plan.header.expressions:
+            raise RelationalError(f"CALL {op.procedure}: a call leads its query")
+        (node_fld, node_t), = [(f, t) for y, f, t in op.yields if y == "node"]
+        (value_fld, value_t), = [(f, t) for y, f, t in op.yields if y != "node"]
+        scan = in_plan.graph.scan_operator(node_fld, node_t.material, self.ctx)
+        return ProcedureCallOp(
+            scan, op.procedure, op.args, node_fld,
+            E.Var(value_fld).with_type(value_t),
+        )
 
     # -- unary ----------------------------------------------------------
 
