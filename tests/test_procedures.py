@@ -37,6 +37,7 @@ from tpu_cypher.relational.session import PropertyGraph
 
 ITERATIONS = "tpu_cypher_procedure_iterations_total{procedure=%s}"
 EDGE_LANES = "tpu_cypher_procedure_edge_lanes_total{procedure=%s}"
+ROWS_OUTSIDE = "tpu_cypher_procedure_rows_outside_total{procedure=%s}"
 SYNCS = "tpu_cypher_host_syncs_total"
 DECLINE = "tpu_cypher_mesh_declines_total{op=procedure,reason=sharded}"
 
@@ -112,12 +113,69 @@ def case_graph(case):
         s = np.concatenate([np.where(half, 0, leaves), tail[:-1]])
         d = np.concatenate([np.where(half, leaves, 0), tail[1:]])
         return ids, s, d, 1
+    if case == "late_lane_joins_the_giant":
+        # 900_000's first two lanes in both orientations lead to 1 and 2, a
+        # component of three the sampled lanes close; its third lane, and
+        # its last in the hub's row, join the giant
+        ids, s, d = kronecker(8, 16)
+        hub = int(np.argmax(np.bincount(d)))
+        ids = np.concatenate([[1, 2], ids, [900_000]])
+        s = np.concatenate([s, [900_000, 900_000, 1, 2, 900_000, 1]])
+        d = np.concatenate([d, [1, 2, 900_000, 900_000, hub, 2]])
+        return ids, s, d, 900_000
+    if case == "no_giant_component":
+        # a perfect matching and five triangles: the largest sampled
+        # component is the first triangle, every other row is outside
+        pairs = np.arange(10, 410, 2)
+        corners = 5_000 + 3 * np.arange(5)
+        ids = np.concatenate([pairs, pairs + 1, corners, corners + 1, corners + 2])
+        s = np.concatenate([pairs, corners, corners + 1, corners + 2])
+        d = np.concatenate([pairs + 1, corners + 1, corners + 2, corners])
+        return np.sort(ids), s, d, 5_000
+    if case == "sampled_links_split_the_largest":
+        # four pieces of ten (two anchors of small id, eight members, every
+        # anchor-member pair stored both ways) chained by one edge each
+        # from a member's third lane to a member's third lane: 40 vertices
+        # the sampled lanes leave in pieces of 10, beside a path of 20 that
+        # they close
+        s, d = [], []
+        for i in range(4):
+            anchors = [10 + 2 * i, 11 + 2 * i]
+            members = [1_000 + 100 * i + j for j in range(8)]
+            for a in anchors:
+                for b in [anchors[0] if a == anchors[1] else anchors[1]] + members:
+                    s += [a, b]
+                    d += [b, a]
+            if i:
+                s.append(1_000 + 100 * (i - 1) + 7)
+                d.append(members[0])
+        path_ids = np.arange(2_000, 2_020)
+        s = np.concatenate([s, path_ids[:-1]])
+        d = np.concatenate([d, path_ids[1:]])
+        ids = np.unique(np.concatenate([s, d]))
+        return ids, s, d, 2_000
+    if case == "the_sampled_lanes_boundary":
+        # beside a giant: a star of degree-1 rows; 4000's row holds a loop
+        # and two parallel lanes to 4001 before its lane to 4010, whose
+        # reverse row holds two smaller nodes first; rows of exactly two
+        ids, s, d = kronecker(7, 17)
+        extra = [(3001, 3000), (3002, 3000), (3003, 3000), (3004, 3000),
+                 (4000, 4000), (4000, 4001), (4000, 4001), (4000, 4010),
+                 (3990, 4010), (3991, 4010), (4001, 4000),
+                 (5000, 5001), (5000, 5002), (5003, 5001)]
+        s = np.concatenate([s, [a for a, _ in extra]])
+        d = np.concatenate([d, [b for _, b in extra]])
+        ids = np.unique(np.concatenate([ids, s, d]))
+        return ids, s, d, 4000
     raise KeyError(case)
 
 
 CASES = ["kronecker-8", "kronecker-10", "kronecker-12", "disconnected", "unreachable",
          "loops_and_parallel_edges", "one_vertex", "source_in_a_small_component",
          "hub_and_path"]
+# where WCC's sampled lanes alone do not decide the answer
+WCC_CASES = CASES + ["late_lane_joins_the_giant", "no_giant_component",
+                     "sampled_links_split_the_largest", "the_sampled_lanes_boundary"]
 
 
 def load(session, ids, s, d):
@@ -156,6 +214,38 @@ def brute_wcc(ids, s, d):
         ra, rb = root(a), root(b)
         parent[max(ra, rb)] = min(ra, rb)
     return [{"v": v, "component": root(v)} for v in sorted(parent)]
+
+
+def sampled_reads(ids, s, d, k=J.WCC_SAMPLED_LANES):
+    """What WCC reads, by its definition: (the lanes at the head of every
+    row, ``k`` at most, in both orientations; the rows, of either
+    orientation, of the nodes outside the largest component those lanes
+    form, the one of smallest position among equals; their lanes)."""
+    n = len(ids)
+    parent = list(range(n))
+
+    def root(v):
+        while parent[v] != v:
+            v = parent[v]
+        return v
+
+    sampled, degrees = 0, []
+    ps, pd = np.searchsorted(ids, s), np.searchsorted(ids, d)
+    for a, b in ((ps, pd), (pd, ps)):
+        b = b[np.lexsort((b, a))]  # the CSR's lanes: by row, then column
+        deg = np.bincount(a, minlength=n)
+        degrees.append(deg)
+        first = np.cumsum(deg) - deg
+        for r in range(k):
+            rows_ = np.nonzero(deg > r)[0]
+            sampled += len(rows_)
+            for u, v in zip(rows_.tolist(), b[first[rows_] + r].tolist()):
+                ru, rv = root(u), root(v)
+                parent[max(ru, rv)] = min(ru, rv)
+    roots = np.array([root(v) for v in range(n)])
+    outside = roots != np.argmax(np.bincount(roots, minlength=n))
+    return (sampled, sum(int((outside & (deg > 0)).sum()) for deg in degrees),
+            sum(int(deg[outside].sum()) for deg in degrees))
 
 
 def rows(graph, query, params=None):
@@ -228,7 +318,7 @@ def test_bfs_depths_agree(graphs, case):
         assert got == want, backend
 
 
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", WCC_CASES)
 def test_wcc_components_agree(graphs, case):
     want = None
     for backend in ("local", "tpu"):
@@ -306,7 +396,7 @@ def test_push_steps_narrower_than_a_row(sessions, monkeypatch, case, step):
     """The BFS pushes ``step`` lanes at a time: a hub's row, and a level's
     rows, run across many steps and both orientations, and each step
     starts inside a row where the last one stopped."""
-    monkeypatch.setattr(J, "BFS_PUSH_LANES", step)
+    monkeypatch.setattr(J, "PUSH_LANES", step)
     ids, s, d, source = case_graph(case)
     graph = load(sessions["tpu"], ids, s, d)
     result = graph.cypher(BFS_ROWS, {"source": source})
@@ -335,13 +425,26 @@ def test_a_second_source_compiles_nothing(graphs):
     assert got == [{"reached": len(want), "levels": max(r["depth"] for r in want)}]
 
 
-def path(session, length):
+def path_edges(length):
     """Vertices 0 .. length in a line, the edges stored from either end."""
     ids = np.arange(length + 1)
     flip = np.arange(length) % 2 == 0
-    s = np.where(flip, ids[1:], ids[:-1])
-    d = np.where(flip, ids[:-1], ids[1:])
-    return load(session, ids, s, d)
+    return ids, np.where(flip, ids[1:], ids[:-1]), np.where(flip, ids[:-1], ids[1:])
+
+
+def path(session, length):
+    return load(session, *path_edges(length))
+
+
+def bit_reversed_path(session, length):
+    """The path, the vertex at each place of it given the id of that place
+    with its bits reversed: every other vertex is a local minimum at every
+    scale, so each WCC hooking round halves the roots left, and the rounds
+    grow with the length."""
+    ids, s, d = path_edges(length)
+    bits = max(int(length).bit_length(), 1)
+    relabel = np.array([int(format(i, f"0{bits}b")[::-1], 2) for i in ids])
+    return load(session, np.sort(relabel), relabel[s], relabel[d])
 
 
 def _spans(result, name):
@@ -354,14 +457,26 @@ def _spans(result, name):
     return out
 
 
+def _wcc_reads(attrs, ids, s, d):
+    """The span's ``edge_lanes``: the sampled lanes once and the lanes of
+    the rows outside once a round that reads them, as counted here."""
+    sampled, outside, lanes = sampled_reads(ids, s, d)
+    assert attrs["rows_outside"] == outside
+    assert (attrs["outside_rounds"] > 0) == (outside > 0)
+    assert attrs["iterations"] > attrs["outside_rounds"]
+    assert attrs["edge_lanes"] == sampled + lanes * attrs["outside_rounds"]
+    return sampled, lanes
+
+
 @pytest.mark.parametrize("procedure", ["bfs", "wcc"])
-def test_the_span_and_both_counters(sessions, procedure):
+def test_the_span_and_both_counters(sessions, graphs, procedure):
     length = 12
     graph = path(sessions["tpu"], length)
     query = (REACH if procedure == "bfs" else WCC_SUMMARY)
+    counters = (ITERATIONS, EDGE_LANES) + ((ROWS_OUTSIDE,) if procedure == "wcc" else ())
     flat = REGISTRY.flat()
-    assert ITERATIONS % procedure in flat and EDGE_LANES % procedure in flat
-    before = {k: flat[k % procedure] for k in (ITERATIONS, EDGE_LANES)}
+    assert all(c % procedure in flat for c in counters)
+    before = {c: flat[c % procedure] for c in counters}
     result = graph.cypher(query, {"source": 0})
     answer = [dict(r) for r in result.records.collect()]
     (span,) = _spans(result, f"procedure:{procedure}")
@@ -378,8 +493,42 @@ def test_the_span_and_both_counters(sessions, procedure):
     assert flat[EDGE_LANES % procedure] - before[EDGE_LANES] == attrs["edge_lanes"]
     if procedure == "bfs":  # push steps of one width, every edge from both ends
         assert attrs["edge_lanes"] >= 2 * length
-    else:  # every lane of both orientations, once a round
-        assert attrs["edge_lanes"] % attrs["iterations"] == 0
+        assert ROWS_OUTSIDE % procedure not in flat
+        return
+    assert flat[ROWS_OUTSIDE % procedure] - before[ROWS_OUTSIDE] == attrs["rows_outside"]
+    # no row has more lanes than are sampled: every lane once, none outside
+    assert _wcc_reads(attrs, *path_edges(length)) == (2 * length, 0)
+    assert attrs["edge_lanes"] == 2 * length
+    graph, ids, s, d, _ = graphs("kronecker-12", "tpu")
+    (span,) = _spans(graph.cypher(WCC_SUMMARY), "procedure:wcc")
+    attrs = span["attrs"]
+    sampled, lanes = _wcc_reads(attrs, ids, s, d)
+    k = J.WCC_SAMPLED_LANES
+    assert sampled <= 2 * k * len(ids) and 0 < lanes < 0.001 * 2 * len(s)
+    assert attrs["edge_lanes"] <= 2 * k * len(ids) + lanes * attrs["outside_rounds"]
+
+
+@pytest.mark.parametrize("case", ["kronecker-10", "disconnected", "unreachable",
+                                  "loops_and_parallel_edges", "one_vertex"] + WCC_CASES[len(CASES):])
+def test_wcc_reads_the_sampled_lanes_and_the_rows_outside(graphs, case):
+    graph, ids, s, d, _ = graphs(case, "tpu")
+    (span,) = _spans(graph.cypher(WCC_SUMMARY), "procedure:wcc")
+    _wcc_reads(span["attrs"], ids, s, d)
+
+
+@pytest.mark.parametrize("step", [4, 64])
+@pytest.mark.parametrize("case", ["no_giant_component", "sampled_links_split_the_largest",
+                                  "the_sampled_lanes_boundary"])
+def test_wcc_rows_outside_in_steps_narrower_than_a_row(sessions, monkeypatch, case, step):
+    """The rows outside the largest sampled component are read ``step``
+    lanes at a time: rows run across steps and both orientations."""
+    monkeypatch.setattr(J, "PUSH_LANES", step)
+    ids, s, d, _ = case_graph(case)
+    graph = load(sessions["tpu"], ids, s, d)
+    result = graph.cypher(WCC_ROWS)
+    assert [dict(r) for r in result.records.collect()] == brute_wcc(ids, s, d)
+    (span,) = _spans(result, "procedure:wcc")
+    _wcc_reads(span["attrs"], ids, s, d)
 
 
 def _syncs():
@@ -389,14 +538,16 @@ def _syncs():
 @pytest.mark.parametrize("procedure", ["bfs", "wcc"])
 def test_host_syncs_do_not_grow_with_the_levels(sessions, procedure):
     query = REACH if procedure == "bfs" else WCC_SUMMARY
-    moved = {}
+    moved, steps = {}, {}
     for length in (5, 40):
-        graph = path(sessions["tpu"], length)
+        graph = (path if procedure == "bfs" else bit_reversed_path)(sessions["tpu"], length)
         rows(graph, query, {"source": 0})  # the graph's lazy indexes
         before = _syncs()
         result = graph.cypher(query, {"source": 0})
         result.records.collect()
         moved[length] = _syncs() - before
-        assert _spans(result, f"procedure:{procedure}")[0]["attrs"]["iterations"] > (
-            length if procedure == "bfs" else 1)
+        steps[length] = _spans(result, f"procedure:{procedure}")[0]["attrs"]["iterations"]
+    if procedure == "bfs":
+        assert steps[5] > 5 and steps[40] > 40
+    assert steps[40] > steps[5] + 1
     assert moved[5] == moved[40] > 0

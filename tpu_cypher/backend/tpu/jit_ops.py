@@ -20,6 +20,7 @@ select the right compiled variant automatically.
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -992,12 +993,11 @@ def tree_count(root_weight, live_nodes, hops, tree, whole: bool, rows=None):
 # ---------------------------------------------------------------------------
 # graph algorithms of the procedures (``backend/tpu/procedures.py``): each a
 # fixed point reached inside ONE program, the test on the device. A
-# relationship type is undirected there, so each step reads both CSR
-# orientations of the type, ``orients``: a tuple of one per orientation that
-# holds an edge, each ``(rp, ci, window, start)`` (``RowSpan.window`` beside
-# its CSR), for WCC with the CSR's lane rows before the window. Both end
-# with the value of every input row (``ids`` searched among ``dev_ids``, as
-# a count chain's frontier is) and how many steps ran.
+# relationship type is undirected there, so each reads both CSR orientations
+# of the type, ``orients``: a tuple of ``(rp, ci)``, one per orientation that
+# holds an edge. Both end with the value of every input row (``ids``
+# searched among ``dev_ids``, as a count chain's frontier is) and how many
+# steps ran.
 # ---------------------------------------------------------------------------
 
 
@@ -1012,27 +1012,34 @@ def _rows_of(dev_ids, ids, valid, values):
     return jnp.take(values, pos), present
 
 
-# frontier lanes one step of a BFS level pushes, and rows: a step holds up to
-# one row for every two lanes, and only rows with a lane in its orientation
-# are queued, so a step is short of lanes only where rows of one lane follow
-# one another. Every step has this one width: a smaller step costs more a
-# lane, and how many of them a traversal needs follows the source.
-BFS_PUSH_LANES = 1 << 19
+# queued lanes one push step goes over, and rows: a step holds up to one row
+# for every two lanes, and only rows with a lane in its orientation are
+# queued, so a step is short of lanes only where rows of one lane follow one
+# another. Every step has this one width: a smaller step costs more a lane,
+# and how many of them a traversal needs follows the source.
+PUSH_LANES = 1 << 19
 _PUSH_LANES_PER_ROW = 2
 
 
-def _push_step(rp, ci, queue, k, a, off, depth, level, lanes: int):
-    """Push the next ``lanes`` frontier lanes of one CSR orientation. The
-    frontier's lanes, in ``queue`` order (its ``k`` node positions), are
-    one sequence; ``(a, off)`` is where this step starts in it: the
-    ``off``-th lane of the row ``queue[a]``. Each lane's far node takes the
-    depth ``level + 1`` unless it has a smaller one (a scatter-min). The
-    lane a step position reads is found without a search: each row's first
-    lane adds, at its step position, the change of ``CSR lane - step
-    position`` from the row before (rows without a lane add a change that
-    the next row takes back), and a cumulative sum carries it over the
-    row's lanes. Returns (a, off, depth, lanes taken)."""
-    n = depth.shape[0]
+def _per_lane(excl, values, lanes: int):
+    """Each row's value on each of its lanes, the row's first lane at step
+    position ``excl``: without a search, each row's first lane adds the
+    change of the value from the row before (rows without a lane add a
+    change that the next row takes back), and a cumulative sum carries it
+    over the row's lanes."""
+    change = values - jnp.concatenate([jnp.zeros(1, values.dtype), values[:-1]])
+    marks = jnp.zeros(lanes, values.dtype).at[excl].add(change, mode="drop")
+    return jnp.cumsum(marks)
+
+
+def _queue_lanes(rp, ci, queue, k, a, off, lanes: int):
+    """The next ``lanes`` lanes of the queued rows of one CSR orientation.
+    The rows' lanes, in ``queue`` order (its ``k`` node positions), are one
+    sequence; ``(a, off)`` is where this step starts in it: the ``off``-th
+    lane of the row ``queue[a]``. Returns (a, off where the next step
+    starts, the step's rows, the step position of each row's first lane,
+    the far node at each position, whether the position holds a lane, the
+    lanes taken)."""
     rows = lanes // _PUSH_LANES_PER_ROW
     q = lax.dynamic_slice(queue, (a,), (rows,))
     t = jnp.arange(rows, dtype=jnp.int32)
@@ -1042,16 +1049,13 @@ def _push_step(rp, ci, queue, k, a, off, depth, level, lanes: int):
     incl = jnp.cumsum(deg)
     excl = incl - deg
     took = jnp.minimum(incl[-1], lanes)
-    shift = base - excl
-    change = shift - jnp.concatenate([jnp.zeros(1, shift.dtype), shift[:-1]])
-    marks = jnp.zeros(lanes, shift.dtype).at[excl].add(change, mode="drop")
-    pos = jnp.arange(lanes, dtype=shift.dtype)
-    far = jnp.take(ci, jnp.clip(pos + jnp.cumsum(marks), 0, ci.shape[0] - 1))
-    depth = depth.at[jnp.where(pos < took, far, n)].min(level + 1, mode="drop")
+    pos = jnp.arange(lanes, dtype=base.dtype)
+    lane = pos + _per_lane(excl, base - excl, lanes)  # the CSR lane read
+    far = jnp.take(ci, jnp.clip(lane, 0, ci.shape[0] - 1))
     done = jnp.sum(incl <= took, dtype=jnp.int32)  # whole rows: a prefix
     at = jnp.minimum(done, rows - 1)
     off = jnp.where(done < rows, took - excl[at] + first[at], 0)
-    return a + done, off, depth, took
+    return a + done, off, q, excl, far, pos < took, took
 
 
 def _frontier_queue(frontier):
@@ -1064,38 +1068,57 @@ def _frontier_queue(frontier):
     return jnp.where(order < n, order, 0), jnp.sum(frontier, dtype=jnp.int32)
 
 
+def _orientation_steps(orients, n: int, step: int):
+    """Per orientation: (rp, ci, every node's degree, the lanes a push step
+    of it takes: ``step``, fewer where the orientation holds fewer)."""
+    return [
+        (rp, ci, rp[1:n + 1] - rp[:n],
+         min(step, 1 << max(int(ci.shape[0]) - 1, 1).bit_length()))
+        for rp, ci in orients
+    ]
+
+
+def _row_queue(rows, deg, lanes: int):
+    """The queue of the nodes ``rows`` holds that have a lane
+    (``_frontier_queue``), padded for a step's slice, and their number."""
+    queue, k = _frontier_queue(rows & (deg > 0))
+    pad = jnp.zeros(lanes // _PUSH_LANES_PER_ROW, jnp.int32)
+    return jnp.concatenate([queue, pad]), k
+
+
 @partial(jax.jit, static_argnames=("step",))
-def bfs_levels(orients, source, dev_ids, ids, valid, step: int = BFS_PUSH_LANES):
+def bfs_levels(orients, source, dev_ids, ids, valid, step: int = PUSH_LANES):
     """Level-synchronous BFS from the node at position ``source`` (traced:
     a new source compiles nothing), top-down: a level pushes the lanes of
-    its frontier's rows in each orientation (``_push_step``, ``step`` lanes
-    at a time, fewer where the orientation holds fewer), so the whole
-    traversal reads each lane of the source's component once, whatever the
-    source and the number of levels. Stops at the first level that reaches
-    no new node. Returns (int64 depth per input row, valid: reached, steps,
-    the lanes the push steps went over)."""
+    its frontier's rows in each orientation (``_queue_lanes``, ``step``
+    lanes at a time, fewer where the orientation holds fewer; each lane's
+    far node takes the depth ``level + 1`` unless it has a smaller one, a
+    scatter-min), so the whole traversal reads each lane of the source's
+    component once, whatever the source and the number of levels. Stops at
+    the first level that reaches no new node. Returns (int64 depth per
+    input row, valid: reached, steps, the lanes the push steps went
+    over)."""
     n = dev_ids.shape[0]
     unseen = jnp.iinfo(jnp.int32).max
     depth = lax.dynamic_update_slice(
         jnp.full(n, unseen, jnp.int32), jnp.zeros(1, jnp.int32), (source,)
     )
-    pushes = []
-    for rp, ci, *_ in orients:
-        lanes = min(step, 1 << max(int(ci.shape[0]) - 1, 1).bit_length())
-        pad = jnp.zeros(lanes // _PUSH_LANES_PER_ROW, jnp.int32)
-        pushes.append((rp, ci, rp[1:n + 1] - rp[:n], pad, lanes))
+
+    orientations = _orientation_steps(orients, n, step)
 
     def level_step(state):
         level, depth, _, width = state
         frontier = depth == level
-        for rp, ci, deg, pad, lanes in pushes:
-            queue, k = _frontier_queue(frontier & (deg > 0))
-            queue = jnp.concatenate([queue, pad])
+        for rp, ci, deg, lanes in orientations:
+            queue, k = _row_queue(frontier, deg, lanes)
 
             def push(state, rp=rp, ci=ci, queue=queue, k=k, lanes=lanes):
                 a, off, depth, left, width = state
-                a, off, depth, took = _push_step(
-                    rp, ci, queue, k, a, off, depth, level, lanes
+                a, off, _, _, far, live, took = _queue_lanes(
+                    rp, ci, queue, k, a, off, lanes
+                )
+                depth = depth.at[jnp.where(live, far, n)].min(
+                    level + 1, mode="drop"
                 )
                 return a, off, depth, left - took, width + lanes
 
@@ -1115,53 +1138,161 @@ def bfs_levels(orients, source, dev_ids, ids, valid, step: int = BFS_PUSH_LANES)
     return jnp.where(reached, at, -1).astype(jnp.int64), present & reached, steps, width
 
 
-def _csr_segment_min(rp, ci, rows, window, start, w):
-    """int64 per node: the least ``w`` over the node's CSR neighbours, or
-    the node count where it has none — a segmented minimum over the edge
-    lanes without a scatter. Each lane's value is keyed by its row
-    (``w[ci] - row * n``: a later row's keys lie below every key of an
-    earlier one, as ``w`` < n), so ONE cumulative minimum restarts at every
-    row, and the minimum of a row is the key at its last lane, gathered at
-    the window's row pointers. Pad lanes lie past ``rp[-1]``, after every
-    lane a row pointer reads."""
-    n = w.shape[0]
-    t = jnp.take(w, jnp.clip(ci, 0), mode="clip").astype(jnp.int64)
-    with jax.named_scope("scan"):
-        least = lax.cummin(t - rows.astype(jnp.int64) * n)
-    length = window.shape[0] - 1
-    row = start.astype(jnp.int64) + jnp.arange(length, dtype=jnp.int64)
-    last = jnp.take(least, jnp.clip(window[1:] - 1, 0), mode="clip") + row * n
-    m = jnp.where(window[1:] > window[:-1], last, n)
-    return lax.dynamic_update_slice(jnp.full(n, n, jnp.int64), m, (start,))
+# Afforest's neighbour rounds: the lanes at the head of every row, in each
+# orientation, that WCC links before it looks for the largest component
+WCC_SAMPLED_LANES = 2
+# words in a row of ``_as_rows``: on a TPU v5e, 2^22 random int32 reads of a
+# 2^22 vector took 23 ms as row gathers and 37 as element gathers
+_ROW_WORDS = 8
 
 
-@jax.jit
-def wcc_labels(orients, dev_ids, ids, valid):
-    """Weakly connected components by minimum-label propagation: every
-    node starts as its own position, a step takes the least label among
-    its neighbours over each orientation (``_csr_segment_min``), then
-    jumps once through its label (``label[label[v]]``: a label is a
-    position in the node's component, never above its own), until no label
-    moves. A component's label is then its smallest position, the node of
-    the smallest id. Returns (int64 id of that node per input row, valid,
-    steps)."""
-    n = dev_ids.shape[0]
+def _as_rows(x):
+    """``x`` as rows of ``_ROW_WORDS`` elements (the last padded), for
+    ``_at``."""
+    return jnp.pad(x, (0, -x.shape[0] % _ROW_WORDS)).reshape(-1, _ROW_WORDS)
+
+
+def _at(rows, i):
+    """``x[i]`` (``i`` >= 0) of the ``x`` that ``_as_rows`` laid out: the
+    row that holds it is gathered, and its element picked out of the
+    row."""
+    got = jnp.take(rows, i // _ROW_WORDS, axis=0, mode="clip")
+    word = jnp.arange(_ROW_WORDS, dtype=i.dtype) == (i % _ROW_WORDS)[:, None]
+    return jnp.sum(jnp.where(word, got, 0), axis=1, dtype=rows.dtype)
+
+
+def _hook(new, lu, lv, live=True):
+    """``new`` with the larger of the roots ``lu`` / ``lv`` of each
+    ``live`` pair hung under the smaller (a scatter-min; a pair whose roots
+    agree writes a root onto itself)."""
+    hi = jnp.where(live, jnp.maximum(lu, lv), new.shape[0])
+    return new.at[hi].min(jnp.minimum(lu, lv), mode="drop")
+
+
+def _most_frequent(label):
+    """The value ``label`` holds most often, the least among equals: one
+    sort, and the longest run of equal values in it."""
+    ordered = lax.sort(label)
+    pos = jnp.arange(label.shape[0], dtype=jnp.int32)
+    head = jnp.concatenate([jnp.ones(1, bool), ordered[1:] != ordered[:-1]])
+    run = pos - lax.cummax(jnp.where(head, pos, 0))
+    return ordered[jnp.argmax(run)]
+
+
+def _compress(label):
+    """``label`` with every node pointing at its tree's root:
+    ``label[label]`` until nothing moves."""
+
+    def jump(state):
+        label, _ = state
+        up = _at(_as_rows(label), label)
+        return up, jnp.any(up != label)
+
+    return lax.while_loop(lambda s: s[1], jump, (label, jnp.bool_(True)))[0]
+
+
+def _hooking(round_, label, go):
+    """Rounds of ``round_`` (a compressed label -> the label with its hooks,
+    and whether a root moved) until one moves no root (none where ``go`` is
+    false), each round's forest compressed. Returns (rounds, label)."""
 
     def step(state):
-        steps, label, _ = state
-        new = label
-        for rp, ci, rows, window, start in orients:
-            least = _csr_segment_min(rp, ci, rows, window, start, label)
-            new = jnp.minimum(new, least.astype(jnp.int32))
-        new = jnp.take(new, new)
-        return steps + 1, new, jnp.any(new != label)
+        rounds, label, _ = state
+        new, moved = round_(label)
+        return rounds + 1, lax.cond(moved, _compress, lambda x: x, new), moved
 
-    steps, label, _ = lax.while_loop(
-        lambda state: state[2], step,
-        (jnp.int32(0), jnp.arange(n, dtype=jnp.int32), jnp.bool_(True)),
+    rounds, label, _ = lax.while_loop(
+        lambda s: s[2], step, (jnp.int32(0), label, go)
     )
+    return rounds, label
+
+
+@partial(jax.jit, static_argnames=("step",))
+def wcc_labels(orients, dev_ids, ids, valid, step: int = PUSH_LANES):
+    """Weakly connected components by hooking over a sample of the lanes
+    first, Afforest's (Sutton, Ben-Nun, Barak, IPDPS 2018).
+
+    A node points at a smaller position of its component, or is a root;
+    a root is the smallest position of its tree (a hook hangs the larger
+    root under the smaller, ``_hook``; every round ends with the forest
+    compressed, ``_compress``). (1) Each node samples the far nodes of the
+    first ``WCC_SAMPLED_LANES`` lanes of its row in each orientation (one
+    gather of each from ``ci``), points at the least of them and itself,
+    and the sampled pairs are hooked until no root moves. (2) The most
+    frequent root is the largest sampled component. (3) The
+    rows of every node outside it that have a lane are queued
+    (``_frontier_queue``) and all their lanes hooked, in push steps
+    (``_queue_lanes``, ``step`` lanes at a time), until no root moves.
+
+    Exact whatever the sample: an edge no step read lies in the row of its
+    first node in one orientation and of its second in the other, neither
+    queued, so both nodes were already in the largest component's tree; and
+    the choice of that component only decides how much (3) reads. A
+    component's label is its smallest position, the node of the smallest
+    id. Returns (int64 id of that node per input row, valid, the rounds of
+    (1) and of (3), the lanes they read: the sampled lanes once and the
+    queued rows' lanes once a round of (3), and how many rows were
+    queued)."""
+    n = dev_ids.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    orientations = _orientation_steps(orients, n, step)
+    sampled, sampled_lanes = [], jnp.int64(0)
+    for rp, ci, deg, _ in orientations:
+        lanes = _as_rows(ci)
+        for r in range(WCC_SAMPLED_LANES):
+            far = _at(lanes, jnp.clip(rp[:n] + r, 0, ci.shape[0] - 1))
+            sampled.append(jnp.where(deg > r, far, pos))
+            sampled_lanes = sampled_lanes + jnp.sum(deg > r, dtype=jnp.int64)
+
+    def sampled_round(label):
+        rows = _as_rows(label)
+        far = [_at(rows, v) for v in sampled]
+        moved = functools.reduce(
+            jnp.logical_or, [jnp.any(lv != label) for lv in far], jnp.bool_(False)
+        )
+
+        def hook(new):
+            for lv in far:
+                new = _hook(new, label, lv)
+            return new
+
+        return lax.cond(moved, hook, lambda x: x, label), moved
+
+    # each node's first hook, elementwise: under the least far node it samples
+    first = functools.reduce(jnp.minimum, sampled, pos)
+    rounds1, label = _hooking(sampled_round, _compress(first), jnp.bool_(True))
+    outside = label != _most_frequent(label)
+    pushes, queued, queued_lanes = [], jnp.int32(0), jnp.int64(0)
+    for rp, ci, deg, lanes in orientations:
+        queue, k = _row_queue(outside, deg, lanes)
+        total = jnp.sum(jnp.where(outside, deg, 0), dtype=jnp.int32)
+        pushes.append((rp, ci, queue, k, lanes, total))
+        queued, queued_lanes = queued + k, queued_lanes + total
+
+    def outside_round(label):
+        new, rows = label, _as_rows(label)
+        for rp, ci, queue, k, lanes, total in pushes:
+
+            def push(state, rp=rp, ci=ci, queue=queue, k=k, lanes=lanes):
+                a, off, new, left = state
+                a, off, q, excl, far, live, took = _queue_lanes(
+                    rp, ci, queue, k, a, off, lanes
+                )
+                lu = _per_lane(excl, _at(rows, q), lanes)
+                lv = _at(rows, jnp.clip(far, 0))
+                return a, off, _hook(new, lu, lv, live), left - took
+
+            _, _, new, _ = lax.while_loop(
+                lambda s: s[3] > 0, push,
+                (jnp.int32(0), jnp.int32(0), new, total),
+            )
+        return new, jnp.any(new != label)
+
+    rounds3, label = _hooking(outside_round, label, queued > 0)
     at, present = _rows_of(dev_ids, ids, valid, label)
-    return jnp.take(dev_ids, at), present, steps
+    lanes = sampled_lanes + queued_lanes * rounds3
+    return (jnp.take(dev_ids, at), present, jnp.stack([rounds1, rounds3]),
+            lanes, queued)
 
 
 # ---------------------------------------------------------------------------

@@ -4,16 +4,17 @@ program per call over the CSR the count chains walk.
 ``algo.bfs`` runs ``jit_ops.bfs_levels`` and ``algo.wcc``
 ``jit_ops.wcc_labels``: each reaches its fixed point inside the program
 (``lax.while_loop``, the convergence test on the device) over both
-orientations of the type's CSR (``GraphIndex.csr`` / ``csr_row_span``; WCC
-also its lane rows, ``GraphIndex.csr_rows``). The call reads one number
-back, how many steps ran, and only after the program has ended: no host sync
-per level or per round. A call is the span ``procedure:<name>`` (kind
-``kernel``, attributes ``iterations``, ``edge_lanes`` — the lanes the steps
-went over: for WCC both orientations' lanes, a bucket's pad included, times
-the rounds; for BFS the width of its push steps, read back with the step
-count — and ``orientations``) and
+orientations of the type's CSR (``GraphIndex.csr``). The call reads its
+counts back once, after the program has ended: no host sync per level or
+per round. A call is the span ``procedure:<name>`` (kind ``kernel``,
+attributes ``iterations``, ``edge_lanes`` — the lanes the steps went over:
+for BFS the width of its push steps; for WCC the first lanes of every row
+it links once, and the lanes of the rows outside the largest component
+those links form once a round that reads them — and ``orientations``; for
+WCC also ``rows_outside``, the rows it queued, and ``outside_rounds``) and
 moves ``tpu_cypher_procedure_iterations_total`` /
-``tpu_cypher_procedure_edge_lanes_total{procedure=}``.
+``tpu_cypher_procedure_edge_lanes_total{procedure=}`` and, for WCC,
+``tpu_cypher_procedure_rows_outside_total``.
 
 A mesh session declines: a sharded form is not written, and the call raises
 ``UnsupportedFeatureError`` and counts ``mesh_declines{op="procedure"}``.
@@ -37,33 +38,38 @@ from .graph_index import GraphIndex
 ITERATIONS = _REGISTRY.counter(
     "tpu_cypher_procedure_iterations_total",
     "steps the procedures' device programs ran to their fixed point: BFS "
-    "levels (the last finds no new node), WCC rounds (the last moves no label)",
+    "levels (the last finds no new node), WCC hooking rounds over the sampled "
+    "lanes and then over the rows outside their largest component (the last "
+    "of each moves no root)",
     labels=("procedure",),
 )
 EDGE_LANES = _REGISTRY.counter(
     "tpu_cypher_procedure_edge_lanes_total",
-    "edge lanes the procedures' steps went over: WCC both CSR orientations' "
-    "lanes, a bucket's pad included, once a round; BFS the width of its push "
-    "steps",
+    "edge lanes the procedures' steps went over: BFS the width of its push "
+    "steps; WCC the sampled lanes at the head of every row once, and the "
+    "lanes of the rows outside their largest component once a round",
+    labels=("procedure",),
+)
+ROWS_OUTSIDE = _REGISTRY.counter(
+    "tpu_cypher_procedure_rows_outside_total",
+    "CSR rows, of either orientation, of the nodes outside the largest "
+    "component WCC's sampled lanes form: the rows whose every lane it read",
     labels=("procedure",),
 )
 for _name in ("bfs", "wcc"):  # exported from the start
     ITERATIONS.inc(0, procedure=_name)
     EDGE_LANES.inc(0, procedure=_name)
+ROWS_OUTSIDE.inc(0, procedure="wcc")
 
 
-def _orientations(gi: GraphIndex, types_key, ctx, with_rows: bool):
+def _orientations(gi: GraphIndex, types_key, ctx):
     """Each CSR orientation of the type that holds an edge, as the programs
-    take it."""
-    out = []
-    for reverse in (False, True):
-        if not gi.csr_lane_count(types_key, reverse, ctx):
-            continue
-        rp, ci, _ = gi.csr(types_key, reverse, ctx)
-        window, start = gi.csr_row_span(types_key, reverse, ctx).window
-        rows = (gi.csr_rows(types_key, reverse, ctx),) if with_rows else ()
-        out.append((rp, ci) + rows + (window, start))
-    return tuple(out)
+    take it: ``(row_ptr, col_idx)``."""
+    return tuple(
+        gi.csr(types_key, reverse, ctx)[:2]
+        for reverse in (False, True)
+        if gi.csr_lane_count(types_key, reverse, ctx)
+    )
 
 
 def run(proc: P.Procedure, graph, ctx, table, id_col: str, out_col: str, args):
@@ -84,31 +90,30 @@ def run(proc: P.Procedure, graph, ctx, table, id_col: str, out_col: str, args):
         if proc is P.WCC and not len(host_ids):  # no node: nothing to label
             out[out_col] = Column(I64, col.data, col.valid, pad=col.pad)
             return type(table)(out, table.size)
+        orients = _orientations(gi, types_key, ctx)
         if proc is P.BFS:
             source = P.source_position(proc, host_ids, args["source"])
-            orients = _orientations(gi, types_key, ctx, with_rows=False)
-            values, valid, steps, width = J.bfs_levels(
+            values, valid, steps, lanes = J.bfs_levels(
                 orients, np.int32(source), dev_ids, col.data, col.valid,
-                step=J.BFS_PUSH_LANES,
+                step=J.PUSH_LANES,
             )
+            outside = None
         else:
-            orients = _orientations(gi, types_key, ctx, with_rows=True)
-            values, valid, steps = J.wcc_labels(
-                orients, dev_ids, col.data, col.valid
+            values, valid, steps, lanes, outside = J.wcc_labels(
+                orients, dev_ids, col.data, col.valid, step=J.PUSH_LANES,
             )
-            width = None
-        fault_point("procedure")  # the step count is read back
+        fault_point("procedure")  # the counts are read back
         with _obs_trace.sync("procedure"):
-            iterations, width = jax.device_get((steps, width))
-        iterations = int(iterations)
-        if width is None:  # WCC reads every lane of both orientations a round
-            lanes = sum(int(o[1].shape[0]) for o in orients) * iterations
-        else:
-            lanes = int(width)
+            steps, lanes, outside = jax.device_get((steps, lanes, outside))
+        iterations, lanes = int(np.sum(steps)), int(lanes)
         sp.note("iterations", iterations)
         sp.note("edge_lanes", lanes)
         sp.note("orientations", len(orients))
         ITERATIONS.inc(iterations, procedure=name)
         EDGE_LANES.inc(lanes, procedure=name)
+        if outside is not None:
+            sp.note("rows_outside", int(outside))
+            sp.note("outside_rounds", int(steps[1]))
+            ROWS_OUTSIDE.inc(int(outside), procedure=name)
         out[out_col] = Column(I64, values, valid, pad=col.pad)
         return type(table)(out, table.size)
